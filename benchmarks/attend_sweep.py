@@ -38,11 +38,10 @@ import numpy as np  # noqa: E402
 
 from benchmarks.decode_analysis import chain_time  # noqa: E402
 from rlo_tpu.models.generate import _attend_cache  # noqa: E402
+from rlo_tpu.utils.device import bench_device  # noqa: E402
 
-V5E_HBM_GBPS = 819.0
 
-
-def attend_leg(batch, kvh, L, hd, *, block_k=None, use_flash=True,
+def attend_leg(batch, kvh, L, hd, *, peaks, block_k=None, use_flash=True,
                dt=jnp.bfloat16, label=""):
     rng = np.random.default_rng(0)
     nh = 16  # total query heads fixed: (kvh, hd) vary, bytes constant
@@ -76,8 +75,9 @@ def attend_leg(batch, kvh, L, hd, *, block_k=None, use_flash=True,
     t = chain_time(run, q0, nbytes, label=label)
     gbps = nbytes / t / 1e9
     print(f"{label}: {t*1e6:.1f} us, {nbytes/2**20:.1f} MB -> "
-          f"{gbps:.0f} GB/s ({gbps/V5E_HBM_GBPS:.0%} of nominal)",
-          file=sys.stderr)
+          f"{gbps:.0f} GB/s"
+          + (f" ({gbps*1e9/peaks.hbm_bytes_per_s:.0%} of nominal)"
+             if peaks is not None else ""), file=sys.stderr)
     return gbps
 
 
@@ -85,33 +85,36 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
     args = ap.parse_args()
+    kind, peaks = bench_device(args.tiny)
+    leg = partial(attend_leg, peaks=peaks)
     if args.tiny:
         legs = {
-            "hd64": attend_leg(2, 4, 64, 64, dt=jnp.float32,
-                               label="hd64"),
-            "hd128": attend_leg(2, 2, 64, 128, dt=jnp.float32,
-                                label="hd128"),
+            "hd64": leg(2, 4, 64, 64, dt=jnp.float32,
+                        label="hd64"),
+            "hd128": leg(2, 2, 64, 128, dt=jnp.float32,
+                         label="hd128"),
         }
     else:
         legs = {}
-        legs["hd64_L208"] = attend_leg(32, 16, 208, 64,
-                                       label="hd64_L208")
-        legs["hd128_L208"] = attend_leg(32, 8, 208, 128,
-                                        label="hd128_L208")
-        legs["hd64_L208_bk128"] = attend_leg(32, 16, 208, 64,
-                                             block_k=128,
-                                             label="hd64_L208_bk128")
-        legs["hd64_L208_einsum"] = attend_leg(32, 16, 208, 64,
-                                              use_flash=False,
-                                              label="hd64_L208_einsum")
-        legs["hd64_L1280"] = attend_leg(32, 16, 1280, 64,
-                                        label="hd64_L1280")
-        legs["hd128_L1280"] = attend_leg(32, 8, 1280, 128,
-                                         label="hd128_L1280")
-        legs["hd64_L1280_bk128"] = attend_leg(32, 16, 1280, 64,
-                                              block_k=128,
-                                              label="hd64_L1280_bk128")
-    print(json.dumps({"attend_gbps": {k: round(v, 1)
+        legs["hd64_L208"] = leg(32, 16, 208, 64,
+                                label="hd64_L208")
+        legs["hd128_L208"] = leg(32, 8, 208, 128,
+                                 label="hd128_L208")
+        legs["hd64_L208_bk128"] = leg(32, 16, 208, 64,
+                                      block_k=128,
+                                      label="hd64_L208_bk128")
+        legs["hd64_L208_einsum"] = leg(32, 16, 208, 64,
+                                       use_flash=False,
+                                       label="hd64_L208_einsum")
+        legs["hd64_L1280"] = leg(32, 16, 1280, 64,
+                                 label="hd64_L1280")
+        legs["hd128_L1280"] = leg(32, 8, 1280, 128,
+                                  label="hd128_L1280")
+        legs["hd64_L1280_bk128"] = leg(32, 16, 1280, 64,
+                                       block_k=128,
+                                       label="hd64_L1280_bk128")
+    print(json.dumps({"device": kind,
+                      "attend_gbps": {k: round(v, 1)
                                       for k, v in legs.items()}}))
 
 

@@ -22,8 +22,10 @@ substrate at n in {4, 8, 16} and pin, at ZERO tolerance:
 
 **Informational wall-clock legs** (``wall_*``): per-algorithm achieved
 GB/s of the jax executor (ops/tpu_collectives.allreduce) against
-``lax.psum`` on a shard_map mesh. On CPU (this repo's CI) the mesh is
-4 forced host devices and the figures are informational only (CPU
+``lax.psum`` on a shard_map mesh over 4 devices of whatever backend the
+caller started the process on. On CPU (this repo's CI: JAX_PLATFORMS=cpu
+XLA_FLAGS=--xla_force_host_platform_device_count=4, as check.sh passes)
+the figures are informational only (CPU
 serializes every ppermute through one memory bus — see
 ``allreduce_cost``'s model notes); on a real TPU slice the same legs
 become the ROADMAP item 2 bandwidth bar. ``direction: higher`` with
@@ -38,7 +40,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -104,34 +105,25 @@ def sim_legs() -> dict:
 
 def wall_legs() -> dict:
     """The informational family: jax executor GB/s per algorithm vs
-    lax.psum on a shard_map mesh (forced host devices on CPU)."""
-    import inspect
-
+    lax.psum on a shard_map mesh over the first WALL_DEVICES devices of
+    the live backend (the caller picks it: the chips, or
+    JAX_PLATFORMS=cpu
+    XLA_FLAGS=--xla_force_host_platform_device_count=4)."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import PartitionSpec as P
 
     from rlo_tpu.observe.ledger import ledger
     from rlo_tpu.ops import tpu_collectives
+    from rlo_tpu.parallel.mesh import make_mesh, shard_jit
+    from rlo_tpu.utils.device import (enable_compile_cache,
+                                      require_devices)
 
-    # older-jax compat: lax.axis_size is the psum of a static 1 (which
-    # old jax already evaluates statically), and the replication check
-    # kwarg was renamed check_rep -> check_vma across versions
-    if not hasattr(lax, "axis_size"):
-        lax.axis_size = lambda name: lax.psum(1, name)
-    sm_kw = {}
-    sm_params = inspect.signature(shard_map).parameters
-    for kwname in ("check_rep", "check_vma"):
-        if kwname in sm_params:
-            sm_kw[kwname] = False
-            break
-
+    require_devices(WALL_DEVICES)
+    enable_compile_cache()
     n_dev = len(jax.devices())
-    devs = jax.devices()[:WALL_DEVICES]
-    n = len(devs)
-    mesh = Mesh(devs, ("x",))
+    n = WALL_DEVICES
+    mesh = make_mesh((n,), ("x",))
     x = jnp.ones((n, NBYTES // 4), jnp.float32)
 
     # ring-allreduce bus bytes per chip from the ledger — the same
@@ -148,8 +140,7 @@ def wall_legs() -> dict:
             def body(v, _alg=alg):
                 return tpu_collectives.allreduce(
                     x=v, axis="x", algorithm=_alg)
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("x"),
-                               out_specs=P(), **sm_kw))
+        fn = shard_jit(body, mesh, P("x"), P(), check_vma=False)
         fn(x).block_until_ready()  # compile outside the timed window
         best = float("inf")
         for _ in range(3):
@@ -205,11 +196,4 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    # the wall legs need a multi-device mesh; force host devices
-    # BEFORE jax initializes (harmless under a real TPU runtime,
-    # which ignores the host-platform flag)
-    if "jax" not in sys.modules:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={WALL_DEVICES}")
     sys.exit(main())

@@ -22,6 +22,7 @@ import numpy as np                      # noqa: E402
 
 import bench                            # noqa: E402
 from rlo_tpu.pallas.reduce import fused_combine  # noqa: E402
+from rlo_tpu.utils.device import bench_device  # noqa: E402
 
 CONFIGS = [  # (block_rows, lane)
     (256, 128), (512, 128), (1024, 128), (2048, 128),
@@ -35,6 +36,8 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--bytes", type=int, default=256 << 20)
     args = ap.parse_args()
+    kind, _ = bench_device()  # times the chip: refuses another backend
+    print(f"device: {kind}", flush=True)
     n = args.bytes // 4
     rows = n // 128
     rng = np.random.default_rng(0)
@@ -67,7 +70,7 @@ def main() -> int:
             results.append((gbps, block_rows, lane))
             print(f"block_rows={block_rows:5d} lane={lane:4d}: "
                   f"{gbps:7.1f} GB/s ({gbps/base:.3f}x xla)", flush=True)
-        except Exception as e:  # remote-compile size limits etc.
+        except Exception as e:  # a block shape Mosaic refuses, VMEM
             print(f"block_rows={block_rows:5d} lane={lane:4d}: "
                   f"FAILED ({type(e).__name__}: {str(e)[:80]})",
                   flush=True)

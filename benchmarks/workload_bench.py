@@ -147,8 +147,10 @@ def serve_trace_metrics(serve_bench):
 
     from rlo_tpu.models.transformer import (TransformerConfig,
                                             init_params)
+    from rlo_tpu.utils.device import enable_compile_cache
     from rlo_tpu.workloads.traces import make_trace
 
+    enable_compile_cache()
     cfg = TransformerConfig(vocab=128, d_model=64, n_heads=4,
                             n_layers=2, d_ff=256, dtype="float32")
     params = init_params(jax.random.PRNGKey(0), cfg)

@@ -82,12 +82,11 @@ _FACTORIES["hybrid"] = _lazy("rlo_tpu.bridge", "HybridBackend")
 
 
 def _auto_backend() -> str:
-    try:
-        import jax
-        if jax.default_backend() == "tpu" or len(jax.devices()) > 1:
-            return "tpu"
-    except Exception:
-        pass
+    # a TPU runtime that fails to initialize raises here: it must not
+    # quietly become a Python-engine world
+    import jax
+    if jax.default_backend() == "tpu" or len(jax.devices()) > 1:
+        return "tpu"
     return "loopback"
 
 

@@ -35,6 +35,7 @@ from jax import lax
 from rlo_tpu.models.transformer import (TransformerConfig, apply_layer,
                                         embed_tokens, _rmsnorm)
 from rlo_tpu.ops.ring_attention import _NEG
+from rlo_tpu.pallas.reduce import KernelFallbackWarning, kernel_gate
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -150,8 +151,9 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
     if use_flash is None:
         from rlo_tpu.pallas.decode import can_flash_decode
-        use_flash = (jax.default_backend() == "tpu"
-                     and can_flash_decode(max_len, hd))
+        use_flash = kernel_gate(
+            can_flash_decode(max_len, hd),
+            f"decode attend (max_len={max_len}, head_dim={hd})")
     if use_flash:
         # fused decode attention: cache tiles stream through VMEM
         # (int8 tiles dequantize there — the einsum path measured XLA
@@ -196,9 +198,9 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
         from rlo_tpu.pallas.decode import (_block_fits_vmem,
                                            can_flash_decode)
         itemsize = 4 if k_cache.dtype == jnp.float32 else 2
-        gate = (pos0 is not None
-                and jax.default_backend() == "tpu"
-                and can_flash_decode(max_len, hd))
+        gate = pos0 is not None and kernel_gate(
+            can_flash_decode(max_len, hd),
+            f"block attend (max_len={max_len}, head_dim={hd})")
         fits = gate and _block_fits_vmem(max_len, hd, nkv, nh // nkv,
                                          T, itemsize)
         if gate and not fits:
@@ -213,7 +215,7 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                 f"max_len={max_len}); falling back to einsum — verify "
                 f"numerics will NOT match the flash decode step "
                 f"(use a smaller gamma for exact speculative parity)",
-                RuntimeWarning, stacklevel=2)
+                KernelFallbackWarning, stacklevel=2)
         use_flash = fits
     if use_flash:
         from rlo_tpu.pallas.decode import flash_block_decode
@@ -287,8 +289,9 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
             from rlo_tpu.pallas.decode import (can_write_row,
                                                write_kv_row)
             max_len_c = lc["k"].shape[3]
-            if (jax.default_backend() == "tpu"
-                    and can_write_row(max_len_c)):
+            use_wr = kernel_gate(can_write_row(max_len_c),
+                                 f"cache row write (max_len={max_len_c})")
+            if use_wr:
                 # aliased pallas write: an XLA lane-offset DUS makes
                 # layout assignment transpose the cache and copy it
                 # back for the flash kernel every step (~2 ms/step at
@@ -315,8 +318,7 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
             entry = {"k": kc, "v": vc}
             ks = vs = None
             if quant:
-                if (jax.default_backend() == "tpu"
-                        and can_write_row(max_len_c)):
+                if use_wr:
                     # the scale sidecars are seq-minor too — a lane-
                     # offset DUS would reintroduce the layout-war
                     # copies; view (b, kvh, L) as (b, kvh, 1, L) (a
@@ -389,9 +391,10 @@ def block_decode(params: dict, tokens, pos0, cache,
             kvh = lc["k"].shape[1]
             from rlo_tpu.pallas.decode import (can_write_block,
                                                write_kv_block)
-            use_wb = (jax.default_backend() == "tpu"
-                      and can_write_block(lc["k"].shape[3])
-                      and T <= 128)
+            use_wb = kernel_gate(
+                can_write_block(lc["k"].shape[3]) and T <= 128,
+                f"cache block write (max_len={lc['k'].shape[3]}, "
+                f"T={T})")
             if use_wb:
                 # the XLA lane-index scatter lowers to a generic
                 # scatter measured ~1.2 ms PER VERIFY at batch 1

@@ -48,6 +48,7 @@ from rlo_tpu.models.generate import (_attend_cache_block, _decode_cfg,
                                      _quantize_kv)
 from rlo_tpu.models.transformer import (TransformerConfig, apply_layer,
                                         embed_tokens, _rmsnorm)
+from rlo_tpu.pallas.reduce import kernel_gate
 
 
 def init_page_pool(cfg: TransformerConfig, n_pages: int,
@@ -114,7 +115,7 @@ def paged_write_rows(entry, k_row, v_row, ks_new, vs_new, page, off):
     kvh, hd = entry["k"].shape[1], entry["k"].shape[2]
     quant = ks_new is not None
     store_dt = entry["k"].dtype
-    if jax.default_backend() == "tpu" and ps % 128 == 0:
+    if kernel_gate(ps % 128 == 0, f"page row write (page_size={ps})"):
         from rlo_tpu.pallas.decode import write_kv_page_row
         kc = write_kv_page_row(entry["k"], k_row, page, off)
         vc = write_kv_page_row(entry["v"], v_row, page, off)
@@ -157,7 +158,7 @@ def paged_write_chunk(entry, kt, vt, ks_new, vs_new, page, off0,
     T = kt.shape[2]
     store_dt = entry["k"].dtype
     quant = ks_new is not None
-    if jax.default_backend() == "tpu" and ps % 128 == 0:
+    if kernel_gate(ps % 128 == 0, f"page chunk write (page_size={ps})"):
         from rlo_tpu.pallas.decode import write_kv_page_block
         kc = write_kv_page_block(entry["k"], kt, page, off0, n_valid)
         vc = write_kv_page_block(entry["v"], vt, page, off0, n_valid)
@@ -204,7 +205,8 @@ def _paged_attend(q, entry, table, pos_q, scale):
     ps = entry["k"].shape[3]
     d = q.shape[3]
     from rlo_tpu.pallas.decode import can_paged_flash
-    if jax.default_backend() == "tpu" and can_paged_flash(ps, d):
+    if kernel_gate(can_paged_flash(ps, d),
+                   f"paged attend (page_size={ps}, head_dim={d})"):
         from rlo_tpu.pallas.decode import paged_flash_decode
         # contiguous per-row positions: pos0 = first query position
         return paged_flash_decode(

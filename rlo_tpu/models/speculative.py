@@ -25,9 +25,11 @@ on both backends. One carve-out: a gamma-wide block too large for
 VMEM at the T=1 tiling (pallas.decode._block_fits_vmem; needs extreme
 nkv*gamma*head_dim, far beyond any shipped config at gamma <= 8)
 falls back to einsum with a RuntimeWarning and the parity degrades to
-near-tie class there (pinned on-chip by benchmarks/tpu_parity_check.py —
-run on the real TPU, outside the CPU-forced pytest conftest — and by
-the CPU oracles in tests/test_speculative.py always).
+near-tie class there. On the chip, chip_smoke.py pins the T=1 and the
+T=128 kernel's LOGITS against the reference forward; speculative
+token-for-token parity itself has not been re-run on the chip since
+the 2026-08 records. The CPU oracles in tests/test_speculative.py
+hold always.
 
 Cache bookkeeping rides the same masking trick as ragged decode:
 rejected drafts leave garbage cache entries BEYOND each row's valid

@@ -312,25 +312,26 @@ def _local_attention(q, k, v, use_flash=None, interpret=None):
     position-driven, identical for every batch row), so the whole batch
     is ONE kernel launch instead of a vmapped per-row program — and the
     batch-folded head indices keep the GQA group mapping intact
-    (b·H + h ↦ b·Hkv + h//G), so compact K/V streams from HBM. Falls
-    back to the unfused oracle off-TPU or for shapes the kernel
-    rejects. ``use_flash`` overrides the gate; ``interpret`` passes
+    (b·H + h ↦ b·Hkv + h//G), so compact K/V streams from HBM. Off-TPU
+    it is the unfused oracle; a shape the kernel rejects on TPU takes
+    the oracle too and warns (pallas.reduce.kernel_gate).
+    ``use_flash`` overrides the gate; ``interpret`` passes
     through to the kernel unchanged (interpret=True also enables flash
     off-TPU, where the compiled kernel cannot run)."""
     b, L, nh, hd = q.shape
     nkv = k.shape[2]
     g = nh // nkv
     from rlo_tpu.pallas.flash import auto_block_q, can_flash
+    from rlo_tpu.pallas.reduce import kernel_gate
     # adaptive Q tile: the batch folds into the kernel's head grid, so
     # large batches mean many programs — bigger tiles claw back the
     # per-program overhead (the round-4 MFU-cliff mechanism; measured
     # bq 1024 = 1.14x bq 256 at 128 folded heads)
     bq = auto_block_q(g * L, L, hd)
     if use_flash is None:
-        use_flash = (jax.default_backend() == "tpu"
-                     or bool(interpret)) and can_flash(L, L, hd,
-                                                       block_q=bq,
-                                                       groups=g)
+        ok = can_flash(L, L, hd, block_q=bq, groups=g)
+        use_flash = ok if interpret else kernel_gate(
+            ok, f"causal attention (L={L}, head_dim={hd}, groups={g})")
     if not use_flash:
         if g > 1:
             k = jnp.repeat(k, g, axis=2)

@@ -139,8 +139,11 @@ def ring_attention(q, k, v, axis: str, *, causal: bool = False,
         scale = 1.0 / (d ** 0.5)
     if use_pallas is None:
         from rlo_tpu.pallas.flash import can_flash
-        use_pallas = jax.default_backend() == "tpu" and \
-            can_flash(blk, blk, d, block_q, block_k, groups=g)
+        from rlo_tpu.pallas.reduce import kernel_gate
+        use_pallas = kernel_gate(
+            can_flash(blk, blk, d, block_q, block_k, groups=g),
+            f"ring attention step (block={blk}, head_dim={d}, "
+            f"groups={g})")
     # K/V travel rank -> rank+1, so the block held at step s originated
     # at shard (idx - s) mod ws — same schedule as the ring allreduce.
     perm = list(topology.ring_perm(ws))

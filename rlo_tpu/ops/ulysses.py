@@ -88,7 +88,7 @@ def ulysses_attention(q, k, v, axis: str, *, causal: bool = False,
     sequence length is not VMEM-bound). Default: on TPU when the full
     sequence tiles by both block sizes.
     """
-    from rlo_tpu.pallas.reduce import _on_tpu
+    from rlo_tpu.pallas.reduce import kernel_gate
 
     ws = lax.axis_size(axis)
     hq, hk = q.shape[1], k.shape[1]
@@ -115,8 +115,9 @@ def ulysses_attention(q, k, v, axis: str, *, causal: bool = False,
     seq, _, d = qh.shape
     if use_pallas is None:
         from rlo_tpu.pallas.flash import can_flash
-        use_pallas = _on_tpu() and can_flash(seq, seq, d, block_q,
-                                             block_k, groups=g)
+        use_pallas = kernel_gate(
+            can_flash(seq, seq, d, block_q, block_k, groups=g),
+            f"ulysses attention (seq={seq}, head_dim={d}, groups={g})")
     # full sequence, local heads: the quadratic part is communication-
     # free and positions are globally consistent (causal masks included)
     if use_pallas:

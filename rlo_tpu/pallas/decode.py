@@ -53,13 +53,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from rlo_tpu.pallas.reduce import out_struct
-
-try:  # pltpu only imports on TPU-enabled builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 _NEG = -1e30
 
@@ -161,8 +157,10 @@ def _write_row_kernel(pos_ref, row_ref, cache_ref, out_ref, *,
 
 
 def can_write_row(max_len: int) -> bool:
-    """The aliased row-write kernel needs a legal 128-lane block."""
-    return max_len >= 128
+    """The aliased row-write kernel addresses whole 128-lane blocks: a
+    ragged tail past the last full block would be unreachable (the
+    position clamps to block L//128 - 1 and the write is dropped)."""
+    return max_len >= 128 and max_len % 128 == 0
 
 
 def _write_block_kernel(pos_ref, rows_ref, cache_ref, out_ref, *,
@@ -247,6 +245,7 @@ def write_kv_block(cache, rows, pos0, *,
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="write_kv_block",
     )(pos0, rows.astype(cache.dtype), cache)
 
 
@@ -344,6 +343,7 @@ def write_kv_row(cache, row, pos, *, interpret: Optional[bool] = None):
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
         input_output_aliases={2: 0},  # cache (after pos, row) -> out
         interpret=interpret,
+        name="write_kv_row",
     )(pos, row.astype(cache.dtype)[..., None], cache)
 
 
@@ -396,6 +396,7 @@ def write_kv_page_row(pool, row, page, off, *,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={3: 0},  # pool (after page, off, row)
         interpret=interpret,
+        name="write_kv_page_row",
     )(page, off, row.astype(pool.dtype)[..., None], pool)
 
 
@@ -460,6 +461,7 @@ def write_kv_page_block(pool, rows, page, off0, n_valid, *,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={4: 0},
         interpret=interpret,
+        name="write_kv_page_block",
     )(page, off0, nv, rows.astype(pool.dtype)[None], pool)
 
 
@@ -571,17 +573,12 @@ def paged_flash_decode(q, k_pool, v_pool, table, pos0, scale,
         args += [ks_pool[:, :, None, :], vs_pool[:, :, None, :]]
 
     kwargs = {}
-    if not interpret and pltpu is not None:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((nkv, R), jnp.float32),
-                   pltpu.VMEM((nkv, R), jnp.float32),
-                   pltpu.VMEM((nkv, R, d), jnp.float32)]
-    else:  # pragma: no cover — interpret-only builds without pltpu
-        scratch = [jax.ShapeDtypeStruct((nkv, R), jnp.float32),
-                   jax.ShapeDtypeStruct((nkv, R), jnp.float32),
-                   jax.ShapeDtypeStruct((nkv, R, d), jnp.float32)]
+    scratch = [pltpu.VMEM((nkv, R), jnp.float32),
+               pltpu.VMEM((nkv, R), jnp.float32),
+               pltpu.VMEM((nkv, R, d), jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -597,6 +594,7 @@ def paged_flash_decode(q, k_pool, v_pool, table, pos0, scale,
         grid_spec=grid_spec,
         out_shape=out_struct((b, nkv, R, d), jnp.float32, q, k_pool),
         interpret=interpret,
+        name="paged_flash_decode",
         **kwargs,
     )(tablev, posv, *args)
     return (out.reshape(b, nkv, T, r, d).transpose(0, 2, 1, 3, 4)
@@ -724,17 +722,12 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
         args += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
 
     kwargs = {}
-    if not interpret and pltpu is not None:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((nkv, R), jnp.float32),
-                   pltpu.VMEM((nkv, R), jnp.float32),
-                   pltpu.VMEM((nkv, R, d), jnp.float32)]
-    else:  # pragma: no cover — interpret-only builds without pltpu
-        scratch = [jax.ShapeDtypeStruct((nkv, R), jnp.float32),
-                   jax.ShapeDtypeStruct((nkv, R), jnp.float32),
-                   jax.ShapeDtypeStruct((nkv, R, d), jnp.float32)]
+    scratch = [pltpu.VMEM((nkv, R), jnp.float32),
+               pltpu.VMEM((nkv, R), jnp.float32),
+               pltpu.VMEM((nkv, R, d), jnp.float32)]
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale), n_k=n_k,
@@ -745,6 +738,9 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
         out_shape=out_struct((b, nkv, R, d), jnp.float32, q, k_cache),
         scratch_shapes=scratch,
         interpret=interpret,
+        # one kernel body, two names: the T=1 decode step and the
+        # T>1 extend/verify block are told apart in program text
+        name="flash_decode" if T == 1 else "flash_block_decode",
         **kwargs,
     )(*args)
     return (out.reshape(b, nkv, T, r, d).transpose(0, 2, 1, 3, 4)

@@ -33,13 +33,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from rlo_tpu.pallas.reduce import _on_tpu, out_struct
-
-try:  # pltpu only imports on TPU-enabled builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 _NEG = -1e30  # matches ring_attention._NEG (finite: exp/max NaN-free)
 
@@ -108,7 +104,14 @@ def can_flash(lq: int, lk: int, d: int, block_q: int = 256,
     bq = min(block_q, lq)
     if lq % bq:
         return False
-    return _select_bk(bq, lk, d, block_k) is not None
+    bk = _select_bk(bq, lk, d, block_k)
+    # Mosaic's block rule: the (1, 1, bq) stats block and the (1, bk)
+    # position block put bq and bk in the lane dim, which must be a
+    # 128-multiple or the whole axis (libtpu 0.0.34 refuses e.g.
+    # block_q=64 at Lq=256). The interpreter has no such rule, so it
+    # lives in the gate and not in _select_bk.
+    return bk is not None and all(
+        t == full or t % 128 == 0 for t, full in ((bq, lq), (bk, lk)))
 
 
 def _kernel(q_ref, k_ref, v_ref, m_ref, l_ref, o_ref, qp_ref, kp_ref,
@@ -174,7 +177,7 @@ def _flash_fwd_call(q, k, v, m, l, o, q_pos, k_pos, *, causal: bool,
     kp_spec = pl.BlockSpec((1, bk), lambda hh, iq, ik: (0, ik))
 
     kwargs = {}
-    if not interpret and pltpu is not None:
+    if not interpret:
         # the kv axis accumulates through scratch: sequential
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -182,14 +185,9 @@ def _flash_fwd_call(q, k, v, m, l, o, q_pos, k_pos, *, causal: bool,
     def struct(shape):
         return out_struct(shape, jnp.float32, q, k, v, m, l, o)
 
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((bq,), jnp.float32),
-                   pltpu.VMEM((bq,), jnp.float32),
-                   pltpu.VMEM((bq, d), jnp.float32)]
-    else:  # pragma: no cover — interpret-only builds without pltpu
-        scratch = [jax.ShapeDtypeStruct((bq,), jnp.float32),
-                   jax.ShapeDtypeStruct((bq,), jnp.float32),
-                   jax.ShapeDtypeStruct((bq, d), jnp.float32)]
+    scratch = [pltpu.VMEM((bq,), jnp.float32),
+               pltpu.VMEM((bq,), jnp.float32),
+               pltpu.VMEM((bq, d), jnp.float32)]
 
     return pl.pallas_call(
         functools.partial(_kernel, causal=causal, scale=float(scale),
@@ -204,6 +202,7 @@ def _flash_fwd_call(q, k, v, m, l, o, q_pos, k_pos, *, causal: bool,
         # accumulate in place: the (m, l, o) carries alias the outputs
         input_output_aliases={3: 0, 4: 1, 5: 2} if alias else {},
         interpret=interpret,
+        name="flash_fwd",
         **kwargs,
     )(q, k, v, m, l, o, q_pos, k_pos)
 
@@ -415,7 +414,7 @@ def _pallas_bwd(q, k, v, m, l, o, qp, kp, m2, l2, o2, dm2, dl2, do2, *,
     sp2 = specs(False)
 
     kwargs = {}
-    if not interpret and pltpu is not None:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
 
@@ -423,12 +422,8 @@ def _pallas_bwd(q, k, v, m, l, o, qp, kp, m2, l2, o2, dm2, dl2, do2, *,
         return out_struct(shape, jnp.float32, q, k, v, m, l, o, dm2,
                           dl2, do2)
 
-    if pltpu is not None:
-        def scr(shape):
-            return pltpu.VMEM(shape, jnp.float32)
-    else:  # pragma: no cover — interpret-only builds without pltpu
-        def scr(shape):
-            return jax.ShapeDtypeStruct(shape, jnp.float32)
+    def scr(shape):
+        return pltpu.VMEM(shape, jnp.float32)
 
     if exact_max:
         cnt = pl.pallas_call(
@@ -440,6 +435,7 @@ def _pallas_bwd(q, k, v, m, l, o, qp, kp, m2, l2, o2, dm2, dl2, do2, *,
             out_shape=[struct((h, 1, lq))],
             scratch_shapes=[scr((bq,))],
             interpret=interpret,
+            name="flash_bwd_rowstats",
             **kwargs,
         )(q, k, m2, qp, kp)[0]
 
@@ -467,6 +463,7 @@ def _pallas_bwd(q, k, v, m, l, o, qp, kp, m2, l2, o2, dm2, dl2, do2, *,
         out_shape=[struct((h, lq, d))],
         scratch_shapes=[scr((bq, d))],
         interpret=interpret,
+        name="flash_bwd_dq",
         **kwargs,
     )(*operands)[0]
     dk, dv = pl.pallas_call(
@@ -479,6 +476,7 @@ def _pallas_bwd(q, k, v, m, l, o, qp, kp, m2, l2, o2, dm2, dl2, do2, *,
         out_shape=[struct((h, lk, d)), struct((h, lk, d))],
         scratch_shapes=[scr((bk, d)), scr((bk, d))],
         interpret=interpret,
+        name="flash_bwd_dkv",
         **kwargs,
     )(*operands)
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),

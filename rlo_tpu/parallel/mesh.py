@@ -95,12 +95,7 @@ def vary_like(x, like):
     zeros-initialized accumulators start unvarying while the loop body
     makes them varying — cast the inits up front. No-op when vma typing
     is off or ``like`` carries no vma."""
-    try:
-        need = set(jax.typeof(like).vma) - set(jax.typeof(x).vma)
-    except (AttributeError, TypeError):
-        return x
+    need = set(jax.typeof(like).vma) - set(jax.typeof(x).vma)
     if not need:
         return x
     return jax.lax.pcast(x, tuple(sorted(need)), to="varying")
-
-

@@ -236,31 +236,14 @@ def test_timeline_renders_coll_slices_and_flows():
 # 4. trace-time step hooks (jax executor)
 # ---------------------------------------------------------------------------
 
-def test_tpu_step_hook_fires_in_ledger_order(monkeypatch):
-    jax = pytest.importorskip("jax")
-    shard_map_mod = pytest.importorskip("jax.experimental.shard_map")
-    import inspect
-
+def test_tpu_step_hook_fires_in_ledger_order():
     import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     from rlo_tpu.ops import tpu_collectives
+    from rlo_tpu.parallel.mesh import make_mesh, shard_jit
 
-    if not hasattr(lax, "axis_size"):
-        monkeypatch.setattr(lax, "axis_size",
-                            lambda name: lax.psum(1, name),
-                            raising=False)
-    sm_kw = {}
-    params = inspect.signature(shard_map_mod.shard_map).parameters
-    for kwname in ("check_rep", "check_vma"):
-        if kwname in params:
-            sm_kw[kwname] = False
-            break
-    devs = jax.devices()[:N]
-    if len(devs) < N:
-        pytest.skip(f"need {N} devices")
-    mesh = Mesh(devs, ("x",))
+    mesh = make_mesh((N,), ("x",))
     x = jnp.ones((N, 64), jnp.float32)
 
     for alg, phases in [
@@ -271,11 +254,11 @@ def test_tpu_step_hook_fires_in_ledger_order(monkeypatch):
         prev = tpu_collectives.set_step_hook(
             lambda a, s, ws, _c=calls: _c.append((a, s, ws)))
         try:
-            fn = shard_map_mod.shard_map(
+            fn = shard_jit(
                 lambda v, _a=alg: tpu_collectives.allreduce(
                     x=v, axis="x", algorithm=_a),
-                mesh=mesh, in_specs=P("x"), out_specs=P(), **sm_kw)
-            jax.jit(fn).lower(x)  # trace only — hooks are trace-time
+                mesh, P("x"), P(), check_vma=False)
+            fn.lower(x)  # trace only — hooks are trace-time
         finally:
             assert tpu_collectives.set_step_hook(prev) is not None
         led = ledger(alg, N, 64 * N * 4)
@@ -287,42 +270,25 @@ def test_tpu_step_hook_fires_in_ledger_order(monkeypatch):
             assert idxs == list(range(len(idxs)))
 
 
-def test_tpu_step_hook_fires_for_bcast(monkeypatch):
-    jax = pytest.importorskip("jax")
-    shard_map_mod = pytest.importorskip("jax.experimental.shard_map")
-    import inspect
-
+def test_tpu_step_hook_fires_for_bcast():
     import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     from rlo_tpu.ops import tpu_collectives
+    from rlo_tpu.parallel.mesh import make_mesh, shard_jit
 
-    if not hasattr(lax, "axis_size"):
-        monkeypatch.setattr(lax, "axis_size",
-                            lambda name: lax.psum(1, name),
-                            raising=False)
-    sm_kw = {}
-    params = inspect.signature(shard_map_mod.shard_map).parameters
-    for kwname in ("check_rep", "check_vma"):
-        if kwname in params:
-            sm_kw[kwname] = False
-            break
-    devs = jax.devices()[:N]
-    if len(devs) < N:
-        pytest.skip(f"need {N} devices")
-    mesh = Mesh(devs, ("x",))
+    mesh = make_mesh((N,), ("x",))
     x = jnp.ones((N, 8), jnp.float32)
 
     calls = []
     prev = tpu_collectives.set_step_hook(
         lambda a, s, ws: calls.append((a, s, ws)))
     try:
-        fn = shard_map_mod.shard_map(
+        fn = shard_jit(
             lambda v: tpu_collectives.rootless_bcast(
                 v, origin=0, axis="x", schedule="binomial"),
-            mesh=mesh, in_specs=P("x"), out_specs=P("x"), **sm_kw)
-        jax.jit(fn).lower(x)
+            mesh, P("x"), P("x"), check_vma=False)
+        fn.lower(x)
     finally:
         tpu_collectives.set_step_hook(prev)
     led = ledger("binomial_bcast", N, 8 * 4, origin=0)
