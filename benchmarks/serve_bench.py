@@ -117,8 +117,8 @@ def _poisson_trace(cfg, *, n_req, rate, seed, max_len, buckets,
 
 
 def _drive_open_loop(srv, reqs, arrival):
-    """Run the open-loop trace to drain; returns (occupancy mean %,
-    e2e p50/p99 in rounds, wall seconds)."""
+    """Run the open-loop trace to drain; returns (useful share of the
+    rounds' slot-steps in %, e2e p50/p99 in rounds, wall seconds)."""
     submit_round = {}
     e2e_rounds = []
     submitted = 0
@@ -140,8 +140,11 @@ def _drive_open_loop(srv, reqs, arrival):
             e2e_rounds.append(round_idx - submit_round[rid])
         round_idx += 1
     wall = time.perf_counter() - t0
-    occ = srv.metrics.histogram("serve.occupancy_pct")
-    occ_mean = occ.sum / occ.count if occ.count else 0.0
+    # the share of the slot-steps the rounds computed that gave a token
+    # a request asked for (the server's own two counters)
+    steps = srv.metrics.counter("serve.slot_steps").value
+    occ_mean = (100.0 * srv.metrics.counter(
+        "serve.slot_steps_useful").value / steps) if steps else 0.0
     e2e_rounds.sort()
     p50 = e2e_rounds[len(e2e_rounds) // 2]
     p99 = e2e_rounds[min(len(e2e_rounds) - 1,
@@ -176,7 +179,7 @@ def trace_leg(params, cfg, trace, *, tiny, slots, round_len, max_len,
     eff = useful / (srv.steps_run * slots)
     pfx = f"trace_{trace.kind}"
     print(f"{pfx}: {len(reqs)} reqs, {srv.rounds_run} rounds, "
-          f"occupancy {occ:.1f}%, efficiency {eff:.3f}, e2e p50/p99 "
+          f"useful slot-steps {occ:.1f}%, efficiency {eff:.3f}, e2e p50/p99 "
           f"{p50}/{p99} rounds, digest {trace.digest()[:12]}",
           file=sys.stderr)
     metrics = {
@@ -184,7 +187,7 @@ def trace_leg(params, cfg, trace, *, tiny, slots, round_len, max_len,
         f"{pfx}.requests": exact(len(reqs)),
         f"{pfx}.useful_tokens": exact(useful),
         f"{pfx}.rounds": exact(srv.rounds_run),
-        f"{pfx}.occupancy_mean_pct": exact(round(occ, 6)),
+        f"{pfx}.slot_steps_useful_pct": exact(round(occ, 6)),
         f"{pfx}.slot_step_efficiency": exact(round(eff, 6)),
         f"{pfx}.e2e_rounds_p50": exact(p50),
         f"{pfx}.e2e_rounds_p99": exact(p99),
@@ -242,14 +245,14 @@ def poisson_leg(params, cfg, *, tiny, n_req, slots, round_len,
     occ_mean, p50, p99, wall = _drive_open_loop(srv, reqs, arrival)
     eff = useful / (srv.steps_run * slots)
     print(f"poisson mix: {n_req} reqs, rate {rate}/round, "
-          f"{srv.rounds_run} rounds, occupancy {occ_mean:.1f}%, "
+          f"{srv.rounds_run} rounds, useful slot-steps {occ_mean:.1f}%, "
           f"e2e p50/p99 {p50}/{p99} rounds, "
           f"{useful/wall:,.0f} tok/s wall", file=sys.stderr)
     metrics = {
         # seed-deterministic scheduling numbers: gate exact
         "poisson.rounds": exact(srv.rounds_run),
         "poisson.useful_tokens": exact(useful),
-        "poisson.occupancy_mean_pct": exact(round(occ_mean, 6)),
+        "poisson.slot_steps_useful_pct": exact(round(occ_mean, 6)),
         "poisson.slot_step_efficiency": exact(round(eff, 6)),
         "poisson.e2e_rounds_p50": exact(p50),
         "poisson.e2e_rounds_p99": exact(p99),
@@ -277,7 +280,7 @@ def poisson_leg(params, cfg, *, tiny, n_req, slots, round_len,
                                                    arrival)
     eff_p = useful / (srv_p.steps_run * slots)
     snap_p = reg_p.snapshot()["counters"]
-    print(f"paged:       {srv_p.rounds_run} rounds, occupancy "
+    print(f"paged:       {srv_p.rounds_run} rounds, useful slot-steps "
           f"{occ_p:.1f}%, efficiency {eff_p:.3f} (dense {eff:.3f}), "
           f"e2e p50/p99 {p50_p}/{p99_p}, "
           f"{useful/wall_p:,.0f} tok/s wall", file=sys.stderr)
@@ -288,7 +291,7 @@ def poisson_leg(params, cfg, *, tiny, n_req, slots, round_len,
     assert eff_p > eff, (eff_p, eff)
     metrics.update({
         "poisson_paged.rounds": exact(srv_p.rounds_run),
-        "poisson_paged.occupancy_mean_pct": exact(round(occ_p, 6)),
+        "poisson_paged.slot_steps_useful_pct": exact(round(occ_p, 6)),
         "poisson_paged.slot_step_efficiency": exact(round(eff_p, 6)),
         "poisson_paged.e2e_rounds_p50": exact(p50_p),
         "poisson_paged.e2e_rounds_p99": exact(p99_p),
@@ -326,7 +329,7 @@ def poisson_leg(params, cfg, *, tiny, n_req, slots, round_len,
     metrics.update({
         "poisson_prefix.useful_tokens": exact(useful_x),
         "poisson_prefix.rounds": exact(srv_x.rounds_run),
-        "poisson_prefix.occupancy_mean_pct": exact(round(occ_x, 6)),
+        "poisson_prefix.slot_steps_useful_pct": exact(round(occ_x, 6)),
         "poisson_prefix.prefix_hits": exact(hits),
         "poisson_prefix.prefix_tokens_shared": exact(shared_toks),
         "poisson_prefix.cow_copies": exact(

@@ -47,6 +47,7 @@ indirection are scheduling/layout changes, not numerics changes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -60,7 +61,12 @@ from rlo_tpu.models.generate import (block_decode, decode_step,
                                      _decode_cfg)
 from rlo_tpu.models.transformer import TransformerConfig
 from rlo_tpu.observe.spans import Stage
-from rlo_tpu.utils.metrics import Registry, SERVING, hist_summary
+from rlo_tpu.utils.metrics import Registry, SERVING
+from rlo_tpu.utils.tracing import annotate
+
+#: newest samples kept beside the log2 buckets of the four latency
+#: histograms, so stats() percentiles are exact (8 bytes a sample)
+_HIST_KEEP = 4096
 
 
 @dataclasses.dataclass
@@ -90,14 +96,29 @@ class DecodeServer:
 
     Serving telemetry (docs/DESIGN.md §7) records into ``metrics``
     (default: the process-wide ``metrics.SERVING`` registry, shared
-    with ``generate_timed``): TTFT (submit -> first token,
-    ``serve.ttft_usec``), admission-queue wait
+    with ``generate_timed``): TTFT (submit, or the request's ``due``
+    time, -> first token, ``serve.ttft_usec``), admission-queue wait
     (``serve.queue_wait_usec``), per-request end-to-end latency
-    (submit -> last token, ``serve.e2e_usec``), per-round and
-    per-token decode latency (``serve.round_usec`` /
-    ``serve.tok_usec``), batch occupancy per round
-    (``serve.occupancy_pct``), request/token counters, and live
-    queue-depth gauges. ``stats()`` snapshots it.
+    (-> last token, ``serve.e2e_usec``) and per-round decode latency
+    (``serve.round_usec``) — log2 histograms that also keep their
+    newest 4096 samples, so ``stats()`` percentiles are exact —
+    request/token counters, and live queue-depth gauges.
+
+    Every stage of the host loop runs inside ONE span helper
+    (``utils.tracing.annotate``, always on): a profiler annotation
+    ``perf.serve.<stage>`` on the profiler's clock, and its elapsed
+    time in the counters ``serve.<stage>_ns`` / ``_n``. Stages:
+    ``step_round``; ``admit`` with ``admit.stage_input``,
+    ``admit.prefill_dispatch``, ``admit.extend``,
+    ``admit.scatter_dispatch``, ``admit.first_token_sync`` (paged:
+    ``admit.map_pages``, ``admit.prefill_chunk``, ``page_gauges``);
+    ``round.dispatch``, ``round.wait``, ``round.readback``;
+    ``distribute``. Work counters sit at the same boundaries:
+    ``serve.admissions``, ``serve.prefill_tokens`` against
+    ``serve.prefill_padded_tokens``, ``serve.slot_steps`` against
+    ``serve.slot_steps_useful``, and ``serve.retraces`` (with
+    ``serve.retraces.<fn>``): trace-cache entries of the server's own
+    jitted functions beyond the shapes it was built for.
 
     PAGED mode adds the page-pool telemetry (docs/DESIGN.md §12):
     ``serve.pages_in_use`` / ``serve.pages_free`` gauges, prefix-cache
@@ -155,10 +176,12 @@ class DecodeServer:
         self.steps_run = 0
         # optional rlo-trace hooks (docs/DESIGN.md §19): a SpanRecorder
         # plus a server-rid -> fabric-rid resolver, attached by
-        # ModelBackend when the owning fabric traces. None => the
-        # scheduler runs zero span code (one is-None test per chunk).
+        # ModelBackend when the owning fabric traces; _span() hands
+        # them the stages that carry a fabric Stage.
         self.spans = None
         self.span_rid_of = None
+        # re-traces already counted, by function (see self._jits)
+        self._retraced: Dict[str, int] = {}
 
         cfg_d = _decode_cfg(cfg)
         if paged:
@@ -231,6 +254,14 @@ class DecodeServer:
             return jax.tree.map(put, cache, row)
 
         self._scatter = jax.jit(scatter_slot, donate_argnums=(0,))
+        # jitted function -> trace-cache entries it was built to hold
+        # (what _cache_size() shows beyond that is a re-trace); held by
+        # the jitted object: callers may wrap the attributes on the
+        # instance
+        self._jits = {"_round": (self._round, 1),
+                      "_prefill": (self._prefill, len(self.buckets)),
+                      "_extend": (self._extend, 1),
+                      "_scatter": (self._scatter, 1)}
 
     # ---- paged mode (docs/DESIGN.md §12) -----------------------------
     def _init_paged(self, cfg_d, page_size, n_pages, prefill_budget,
@@ -284,14 +315,21 @@ class DecodeServer:
 
         self._chunk = jax.jit(chunk_fn, donate_argnums=(1,))
         self._copy = jax.jit(copy_page, donate_argnums=(0,))
+        self._jits = {"_round_paged": (self._round_paged, 1),
+                      "_chunk": (self._chunk, 1),
+                      "_copy": (self._copy, 1)}
 
     # ---- request lifecycle ------------------------------------------
     def submit(self, prompt, max_new: int,
-               eos_id: Optional[int] = None) -> int:
+               eos_id: Optional[int] = None,
+               due: Optional[float] = None) -> int:
         """Queue a request; returns its id (position in results).
         Any prompt with plen + max_new <= max_len is admissible (long
         prompts stream through chunked prefill); only truly oversized
-        requests are rejected."""
+        requests are rejected. ``due`` is the ``time.perf_counter()``
+        reading at which the request was due to arrive (an open-loop
+        driver's schedule): TTFT, queue wait and end-to-end latency
+        count from it when given, from this call when not."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if len(prompt) == 0:
             # an empty prompt has no last token to take logits from —
@@ -312,12 +350,35 @@ class DecodeServer:
         self._queue.append((rid, Request(prompt, max_new, eos_id)))
         self._out.append(None)
         self._eos.append(eos_id)
-        now = time.perf_counter()
-        self._submit_ts[rid] = now
-        self._accept_ts[rid] = now
+        t_sub = time.perf_counter() if due is None else float(due)
+        self._submit_ts[rid] = t_sub
+        self._accept_ts[rid] = t_sub
         self.metrics.counter("serve.requests_submitted").inc()
         self.metrics.gauge("serve.queue_depth").set(len(self._queue))
         return rid
+
+    def _span(self, stage: str, rid: Optional[int] = None,
+              fabric_stage: Optional[int] = None) -> annotate:
+        """The span around one stage of the host loop: the profiler
+        annotation ``perf.serve.<stage>`` (with ``rid`` when the stage
+        belongs to a request) and the counters ``serve.<stage>_ns`` /
+        ``_n``. A stage that carries a ``fabric_stage`` is also emitted
+        as that ``Ev.SPAN`` when the fabric attached a recorder AND the
+        fabric-level request is sampled."""
+        emit = None
+        recorder = self.spans
+        if (fabric_stage is not None and recorder is not None
+                and rid is not None and self.span_rid_of is not None):
+            frid = self.span_rid_of(rid)
+            if frid is not None and recorder.sampled(frid):
+                emit = functools.partial(recorder.emit, frid,
+                                         fabric_stage)
+        ids = {} if rid is None else {"rid": rid}
+        return annotate("perf.serve." + stage, self.metrics,
+                        "serve." + stage, emit, **ids)
+
+    def _hist(self, name: str):
+        return self.metrics.histogram(name, _HIST_KEEP)
 
     def _admit(self) -> int:
         """Fill every free slot from the queue; returns the number of
@@ -325,8 +386,12 @@ class DecodeServer:
         immediate eos retires the slot at once — the freed slot is
         re-offered to the queue in the same pass, and the completion
         count keeps step_round truthful about progress)."""
-        if self.paged:
-            return self._admit_paged()
+        with self._span("admit"):
+            if self.paged:
+                return self._admit_paged()
+            return self._admit_dense()
+
+    def _admit_dense(self) -> int:
         completed = 0
         slot = 0
         while slot < self.n_slots:
@@ -337,37 +402,50 @@ class DecodeServer:
             t_sub = self._submit_ts.pop(rid, None)
             now = time.perf_counter()
             if t_sub is not None:
-                self.metrics.histogram("serve.queue_wait_usec").observe(
+                self._hist("serve.queue_wait_usec").observe(
                     (now - t_sub) * 1e6)
             plen = len(req.prompt)
             head = min(plen, self.buckets[-1])
             bucket = _bucket(head, self.buckets)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :head] = req.prompt[:head]
-            row, first = self._prefill(
-                self.params, jnp.asarray(padded),
-                jnp.asarray([head], jnp.int32))
+            with self._span("admit.stage_input", rid):
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :head] = req.prompt[:head]
+                prompt = jnp.asarray(padded)
+                length = jnp.asarray([head], jnp.int32)
+            with self._span("admit.prefill_dispatch", rid):
+                row, first = self._prefill(self.params, prompt, length)
             # long prompt: extend the row past the bucket in jitted
             # block_decode chunks (write-then-attend; the final
             # chunk's last-position logits seed the first token)
             off = head
+            ran = bucket  # positions the prefill ran, padding included
             while off < plen:
                 n = min(self._chunk_w, plen - off)
                 toks = np.zeros((1, self._chunk_w), np.int32)
                 toks[0, :n] = req.prompt[off:off + n]
-                first, row = self._extend(
-                    self.params, row, jnp.asarray(toks),
-                    jnp.int32(off), jnp.int32(n))
+                with self._span("admit.extend", rid):
+                    first, row = self._extend(
+                        self.params, row, jnp.asarray(toks),
+                        jnp.int32(off), jnp.int32(n))
                 off += n
-            self.cache = self._scatter(self.cache, row,
-                                       jnp.int32(slot))
-            first = int(np.asarray(first).reshape(-1)[0])
+                ran += self._chunk_w
+            with self._span("admit.scatter_dispatch", rid):
+                self.cache = self._scatter(self.cache, row,
+                                           jnp.int32(slot))
+            with self._span("admit.first_token_sync", rid):
+                # the host blocks here until this request's prefill
+                # and scatter finished on the device
+                first = int(np.asarray(first).reshape(-1)[0])
             if t_sub is not None:
                 # first token is materialized on the host here: TTFT
-                # = submit -> first token (queue wait included)
-                self.metrics.histogram("serve.ttft_usec").observe(
+                # = submit (or due) -> first token, queue wait included
+                self._hist("serve.ttft_usec").observe(
                     (time.perf_counter() - t_sub) * 1e6)
-            self.metrics.counter("serve.tokens_out").inc()
+            count = self.metrics.counter
+            count("serve.admissions").inc()
+            count("serve.prefill_tokens").inc(plen)
+            count("serve.prefill_padded_tokens").inc(ran)
+            count("serve.tokens_out").inc()
             self.metrics.gauge("serve.queue_depth").set(len(self._queue))
             self.req_of_slot[slot] = rid
             self._out[rid] = [first]
@@ -462,14 +540,21 @@ class DecodeServer:
             if self.req_of_slot[slot] is not None or not self._queue:
                 continue
             rid, req = self._queue[0]
-            if not self._try_map(slot, req):
+            with self._span("admit.map_pages", rid):
+                mapped = self._try_map(slot, req)
+            if not mapped:
                 self.metrics.counter("serve.admission_stalls").inc()
                 break
             self._queue.pop(0)
+            self.metrics.counter("serve.admissions").inc()
+            # what the prefill has to compute: the prompt less the
+            # prefix the trie shared
+            st = self._prefilling[slot]
+            self.metrics.counter("serve.prefill_tokens").inc(
+                st["plen"] - st["next"])
             t_sub = self._submit_ts.pop(rid, None)
             if t_sub is not None:
-                self.metrics.histogram(
-                    "serve.queue_wait_usec").observe(
+                self._hist("serve.queue_wait_usec").observe(
                     (time.perf_counter() - t_sub) * 1e6)
             self.metrics.gauge("serve.queue_depth").set(
                 len(self._queue))
@@ -491,6 +576,7 @@ class DecodeServer:
             req, plen = st["req"], st["plen"]
             budget = (plen if self.prefill_budget is None
                       else self.prefill_budget)
+            rid = self.req_of_slot[slot]
             logits = None
             while st["next"] < plen and budget > 0:
                 a = st["next"]
@@ -498,29 +584,27 @@ class DecodeServer:
                 n = end - a
                 toks = np.zeros((1, ps), np.int32)
                 toks[0, :n] = req.prompt[a:end]
-                t_chunk = (time.perf_counter()
-                           if self.spans is not None else 0.0)
-                logits, self.pools = self._chunk(
-                    self.params, self.pools,
-                    jnp.asarray(self.table[slot:slot + 1]),
-                    jnp.asarray(toks), jnp.int32(a), jnp.int32(n))
+                with self._span("admit.prefill_chunk", rid,
+                                Stage.PREFILL_CHUNK):
+                    logits, self.pools = self._chunk(
+                        self.params, self.pools,
+                        jnp.asarray(self.table[slot:slot + 1]),
+                        jnp.asarray(toks), jnp.int32(a), jnp.int32(n))
                 st["next"] = end
                 budget -= n
                 progressed = True
                 self.metrics.counter("serve.prefill_chunks").inc()
-                if self.spans is not None:
-                    self._span(self.req_of_slot[slot],
-                               Stage.PREFILL_CHUNK, t_chunk,
-                               time.perf_counter())
+                self.metrics.counter(
+                    "serve.prefill_padded_tokens").inc(ps)
             if st["next"] < plen:
                 continue  # budget spent; more chunks next round
             # prefill complete: seed the first token, open decoding
-            first = int(np.asarray(
-                jnp.argmax(logits, axis=-1)).reshape(-1)[0])
-            rid = self.req_of_slot[slot]
+            with self._span("admit.first_token_sync", rid):
+                first = int(np.asarray(
+                    jnp.argmax(logits, axis=-1)).reshape(-1)[0])
             t_sub = self._accept_ts.get(rid)
             if t_sub is not None:
-                self.metrics.histogram("serve.ttft_usec").observe(
+                self._hist("serve.ttft_usec").observe(
                     (time.perf_counter() - t_sub) * 1e6)
             self.metrics.counter("serve.tokens_out").inc()
             self._out[rid] = [first]
@@ -540,21 +624,11 @@ class DecodeServer:
         return completed, progressed
 
     def _page_gauges(self) -> None:
-        self.metrics.gauge("serve.pages_in_use").set(
-            self.allocator.pages_in_use)
-        self.metrics.gauge("serve.pages_free").set(
-            self.allocator.free_pages)
-
-    def _span(self, rid: Optional[int], stage: int, t0: float,
-              t1: float) -> None:
-        """Emit a scheduler-stage span for server rid ``rid`` when the
-        fabric attached a recorder AND the fabric-level request is
-        sampled. Off the traced path this method is never called."""
-        if rid is None or self.span_rid_of is None:
-            return
-        frid = self.span_rid_of(rid)
-        if frid is not None and self.spans.sampled(frid):
-            self.spans.emit(frid, stage, t0, t1)
+        with self._span("page_gauges"):
+            self.metrics.gauge("serve.pages_in_use").set(
+                self.allocator.pages_in_use)
+            self.metrics.gauge("serve.pages_free").set(
+                self.allocator.free_pages)
 
     def _retire_if_done(self, slot: int):
         rid = self.req_of_slot[slot]
@@ -572,7 +646,7 @@ class DecodeServer:
                 # end-to-end latency: submit -> last token, queue wait
                 # and every decode round included (the fail-over-aware
                 # fleet twin is fabric.e2e_usec, docs/DESIGN.md §11)
-                self.metrics.histogram("serve.e2e_usec").observe(
+                self._hist("serve.e2e_usec").observe(
                     (time.perf_counter() - t_sub) * 1e6)
 
     # ---- fabric-facing hooks (docs/DESIGN.md §11) --------------------
@@ -637,27 +711,34 @@ class DecodeServer:
         """Admit pending requests, run one jitted round of ragged
         decode steps (``round_len`` of them; paged mode clips the
         round to the shortest active budget), distribute tokens."""
-        if self.paged:
-            return self._step_round_paged()
+        with self._span("step_round"):
+            if self.paged:
+                return self._step_round_paged()
+            return self._step_round_dense()
+
+    def _step_round_dense(self):
         completed = self._admit()
         if all(r is None for r in self.req_of_slot):
             return completed > 0
-        active = sum(1 for r in self.req_of_slot if r is not None)
         kk = self.round_len
         if self.clip_rounds:
             kk = max(1, min(kk, int(min(
                 self.budget[s] for s in range(self.n_slots)
                 if self.req_of_slot[s] is not None))))
         t0 = time.perf_counter()
-        tok, pos, cache, toks = self._round(
-            self.params, self.cache, jnp.asarray(self.last_tok),
-            jnp.asarray(self.pos), kk)
-        self.cache = cache
-        toks = np.asarray(toks)
-        self.last_tok = np.asarray(tok).copy()
-        self.pos = np.asarray(pos).copy()
+        with self._span("round.dispatch"):
+            tok, pos, cache, toks = self._round(
+                self.params, self.cache, jnp.asarray(self.last_tok),
+                jnp.asarray(self.pos), kk)
+            self.cache = cache
+        with self._span("round.wait"):
+            # the host blocks here for the device's whole round
+            toks = np.asarray(toks)
+        with self._span("round.readback"):
+            self.last_tok = np.asarray(tok).copy()
+            self.pos = np.asarray(pos).copy()
         dt = time.perf_counter() - t0  # toks materialized: round done
-        self._observe_round(dt, kk, active)
+        self._observe_round(dt, kk)
         self._distribute(toks, kk)
         return True
 
@@ -668,58 +749,71 @@ class DecodeServer:
         completed = self._admit()
         if not self.active.any():
             return completed > 0 or bool(self._prefilling)
-        active_slots = [s for s in range(self.n_slots)
-                        if self.active[s]]
         kk = self.round_len
         if self.clip_rounds:
-            kk = max(1, min(kk, int(min(self.budget[s]
-                                        for s in active_slots))))
+            kk = max(1, min(kk, int(min(
+                self.budget[s] for s in range(self.n_slots)
+                if self.active[s]))))
         t0 = time.perf_counter()
-        tok, pos, pools, toks = self._round_paged(
-            self.params, self.pools, jnp.asarray(self.table),
-            jnp.asarray(self.last_tok), jnp.asarray(self.pos),
-            jnp.asarray(self.active), kk)
-        self.pools = pools
-        toks = np.asarray(toks)
-        self.last_tok = np.asarray(tok).copy()
-        self.pos = np.asarray(pos).copy()
+        with self._span("round.dispatch"):
+            tok, pos, pools, toks = self._round_paged(
+                self.params, self.pools, jnp.asarray(self.table),
+                jnp.asarray(self.last_tok), jnp.asarray(self.pos),
+                jnp.asarray(self.active), kk)
+            self.pools = pools
+        with self._span("round.wait"):
+            toks = np.asarray(toks)
+        with self._span("round.readback"):
+            self.last_tok = np.asarray(tok).copy()
+            self.pos = np.asarray(pos).copy()
         dt = time.perf_counter() - t0
-        self._observe_round(dt, kk, len(active_slots))
+        self._observe_round(dt, kk)
         self._distribute(toks, kk, only_active=True)
         self._page_gauges()
         return True
 
-    def _observe_round(self, dt: float, kk: int, active: int) -> None:
-        self.metrics.histogram("serve.round_usec").observe(dt * 1e6)
-        self.metrics.histogram("serve.tok_usec").observe(
-            dt * 1e6 / kk)
-        self.metrics.histogram("serve.occupancy_pct").observe(
-            100.0 * active / self.n_slots)
+    def _observe_round(self, dt: float, kk: int) -> None:
+        self._hist("serve.round_usec").observe(dt * 1e6)
         self.metrics.counter("serve.rounds").inc()
         self.metrics.counter("serve.steps").inc(kk)
+        self.metrics.counter("serve.slot_steps").inc(kk * self.n_slots)
         self.rounds_run += 1
         self.steps_run += kk
+        # once a round: did a jitted function of the server trace a
+        # shape it was not built for?
+        for name, (fn, built_for) in self._jits.items():
+            extra = fn._cache_size() - built_for
+            new = extra - self._retraced.get(name, 0)
+            if new > 0:
+                self._retraced[name] = extra
+                self.metrics.counter("serve.retraces").inc(new)
+                self.metrics.counter("serve.retraces." + name).inc(new)
 
     def _distribute(self, toks, kk: int,
                     only_active: bool = False) -> None:
-        tokens_out = self.metrics.counter("serve.tokens_out")
-        for slot in range(self.n_slots):
-            rid = self.req_of_slot[slot]
-            if rid is None:
-                continue
-            if only_active and not self.active[slot]:
-                continue  # mid-prefill: nothing decoded this round
-            take = int(min(self.budget[slot], kk))
-            seq = toks[slot, :take].tolist()
-            eos = self._eos[rid]
-            if eos is not None and eos in seq:
-                seq = seq[:seq.index(eos) + 1]
-                self.budget[slot] = 0
-            else:
-                self.budget[slot] -= take
-            self._out[rid].extend(seq)
-            tokens_out.inc(len(seq))
-            self._retire_if_done(slot)
+        with self._span("distribute"):
+            kept = 0
+            for slot in range(self.n_slots):
+                rid = self.req_of_slot[slot]
+                if rid is None:
+                    continue
+                if only_active and not self.active[slot]:
+                    continue  # mid-prefill: nothing decoded this round
+                take = int(min(self.budget[slot], kk))
+                seq = toks[slot, :take].tolist()
+                eos = self._eos[rid]
+                if eos is not None and eos in seq:
+                    seq = seq[:seq.index(eos) + 1]
+                    self.budget[slot] = 0
+                else:
+                    self.budget[slot] -= take
+                self._out[rid].extend(seq)
+                kept += len(seq)
+                self._retire_if_done(slot)
+            # the slot-steps of this round that gave a token a request
+            # asked for; the rest decoded past a row's end
+            self.metrics.counter("serve.tokens_out").inc(kept)
+            self.metrics.counter("serve.slot_steps_useful").inc(kept)
 
     def run(self) -> List[np.ndarray]:
         """Drive rounds until every submitted request completes."""
@@ -733,16 +827,17 @@ class DecodeServer:
                 for o in self._out]
 
     def stats(self) -> dict:
-        """Serving-telemetry snapshot: counters and gauges verbatim,
+        """Serving-telemetry snapshot: counters and gauges verbatim
+        (the span totals ``serve.<stage>_ns`` / ``_n`` among them),
         histograms as percentile SUMMARIES (count/mean/min/max +
-        p50/p90/p99 estimated from the log2 buckets,
+        p50/p90/p99: exact over the newest 4096 samples for the four
+        the server keeps them on, log2 estimates for any other,
         metrics.hist_summary) — dashboards read quantiles, not raw
         28-bucket dumps. The bucket layout stays available through
         ``self.metrics.snapshot()`` for anyone who wants it. Paged
         servers add the allocator's own counters under ``pages``."""
         snap = self.metrics.snapshot()
-        snap["histograms"] = {k: hist_summary(h)
-                              for k, h in snap["histograms"].items()}
+        snap["histograms"] = self.metrics.summaries()
         if self.paged:
             snap["pages"] = self.allocator.stats()
             if self.trie is not None:

@@ -29,7 +29,8 @@ application face: ``DecodeServer`` and ``generate_timed`` record into
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from collections import deque
+from typing import Deque, Dict, Iterable, List, Optional
 
 #: number of histogram buckets — mirror of RLO_HIST_BUCKETS (rlo_core.h)
 HIST_BUCKETS = 28  # rlo-lint: paired-with rlo_core.h:RLO_HIST_BUCKETS
@@ -136,15 +137,23 @@ class Histogram:
     convention). Bucket i counts samples whose int part has bit_length
     i — i.e. [2^(i-1), 2^i) — bucket 0 counts samples <= 0 (or < 1)
     and the final bucket absorbs overflow. Identical layout to the C
-    core's rlo_hist so cross-implementation snapshots compare."""
-    __slots__ = ("count", "sum", "min", "max", "buckets")
+    core's rlo_hist so cross-implementation snapshots compare.
 
-    def __init__(self):
+    ``keep=N`` also keeps the newest ``N`` samples beside the buckets
+    (a bounded deque, 8 bytes a sample): ``quantile()`` and
+    ``summary()`` are then EXACT over those samples instead of good to
+    a factor of two. ``snapshot()`` is the same dict either way — the
+    kept samples are this process's, not part of the shared schema."""
+    __slots__ = ("count", "sum", "min", "max", "buckets", "samples")
+
+    def __init__(self, keep: int = 0):
         self.count = 0
         self.sum = 0.0
         self.min = 0.0
         self.max = 0.0
         self.buckets: List[int] = [0] * HIST_BUCKETS
+        self.samples: Optional[Deque[float]] = (
+            deque(maxlen=keep) if keep > 0 else None)
 
     @staticmethod
     def bucket_index(v) -> int:
@@ -166,6 +175,8 @@ class Histogram:
         self.count += 1
         self.sum += v
         self.buckets[self.bucket_index(v)] += 1
+        if self.samples is not None:
+            self.samples.append(v)
 
     def snapshot(self) -> Dict:
         return {"count": self.count, "sum": self.sum,
@@ -173,10 +184,11 @@ class Histogram:
                 "buckets": list(self.buckets)}
 
     def quantile(self, q: float) -> Optional[float]:
-        """Approximate quantile (log2 bucket upper bound; exact max for
-        the overflow bucket) — None while empty. Good to a factor of 2,
-        which is what log2 buckets buy."""
-        return hist_quantile(self.snapshot(), q)
+        """Quantile ``q`` — None while empty. Exact over the newest
+        ``keep`` samples when they are kept; else the log2 bucket's
+        upper bound (exact max for the overflow bucket), good to a
+        factor of 2, which is what log2 buckets buy."""
+        return hist_quantile(self.snapshot(), q, self.samples)
 
     def p50(self) -> Optional[float]:
         return self.quantile(0.50)
@@ -188,11 +200,12 @@ class Histogram:
         return self.quantile(0.99)
 
     def summary(self) -> Dict:
-        """Human/dashboard-shaped digest: count, mean, min/max and the
-        p50/p90/p99 estimates — what DecodeServer.stats() and the bench
-        reports emit instead of the raw 28-bucket dump (the raw layout
-        stays available via snapshot())."""
-        return hist_summary(self.snapshot())
+        """Human/dashboard-shaped digest: count, mean, min/max and
+        p50/p90/p99 (exact when samples are kept, log2 estimates when
+        not) — what DecodeServer.stats() and the bench reports emit
+        instead of the raw 28-bucket dump (the raw layout stays
+        available via snapshot())."""
+        return hist_summary(self.snapshot(), self.samples)
 
 
 class LinkStats:
@@ -251,11 +264,20 @@ class Registry:
             g = self._gauges[name] = Gauge()
         return g
 
-    def histogram(self, name: str) -> Histogram:
+    def histogram(self, name: str, keep: int = 0) -> Histogram:
+        """``keep`` matters when the histogram is created (the first
+        call by that name): it then keeps its newest ``keep`` samples
+        for exact quantiles."""
         h = self._histograms.get(name)
         if h is None:
-            h = self._histograms[name] = Histogram()
+            h = self._histograms[name] = Histogram(keep)
         return h
+
+    def summaries(self) -> Dict[str, Dict]:
+        """Every histogram's ``summary()`` by name: exact percentiles
+        where samples are kept, which a snapshot cannot carry."""
+        return {k: h.summary()
+                for k, h in sorted(self._histograms.items())}
 
     def snapshot(self) -> Dict:
         return {
@@ -278,10 +300,21 @@ class Registry:
 SERVING = Registry()
 
 
-def hist_quantile(hist: Dict, q: float) -> Optional[float]:
-    """Approximate quantile (bucket upper bound) from a histogram
-    snapshot — good to a factor of 2, which is what log2 buckets buy.
-    None when the histogram is empty."""
+def hist_quantile(hist: Dict, q: float,
+                  samples: Optional[Iterable[float]] = None
+                  ) -> Optional[float]:
+    """Quantile from a histogram snapshot. With ``samples`` (what a
+    ``Histogram(keep=N)`` kept) it is exact over them: linear
+    interpolation between order statistics at ``q * (n - 1)``, as
+    ``statistics.quantiles(method="inclusive")`` and numpy do. Without,
+    the bucket's upper bound — good to a factor of 2, which is what
+    log2 buckets buy. None when the histogram is empty."""
+    if samples:
+        xs = sorted(samples)
+        at = min(max(q, 0.0), 1.0) * (len(xs) - 1)
+        lo = int(at)
+        hi = min(lo + 1, len(xs) - 1)
+        return xs[lo] + (xs[hi] - xs[lo]) * (at - lo)
     n = hist["count"]
     if n == 0:
         return None
@@ -297,18 +330,22 @@ def hist_quantile(hist: Dict, q: float) -> Optional[float]:
     return float(hist["max"])
 
 
-def hist_summary(hist: Dict) -> Dict:
+def hist_summary(hist: Dict,
+                 samples: Optional[Iterable[float]] = None) -> Dict:
     """Percentile digest of a histogram SNAPSHOT (the dict shape both
-    engines and the Registry emit): count/mean/min/max + p50/p90/p99
-    estimated from the log2 buckets — the serving/bench-facing shape
-    (raw buckets stay in the snapshot for anyone who wants them)."""
+    engines and the Registry emit): count/mean/min/max + p50/p90/p99,
+    exact over ``samples`` when given, else estimated from the log2
+    buckets — the serving/bench-facing shape (raw buckets stay in the
+    snapshot for anyone who wants them)."""
     n = hist["count"]
+    if samples is not None:
+        samples = sorted(samples)   # one sort for the three quantiles
     return {
         "count": n,
         "mean": (hist["sum"] / n) if n else None,
         "min": hist["min"] if n else None,
         "max": hist["max"] if n else None,
-        "p50": hist_quantile(hist, 0.50),
-        "p90": hist_quantile(hist, 0.90),
-        "p99": hist_quantile(hist, 0.99),
+        "p50": hist_quantile(hist, 0.50, samples),
+        "p90": hist_quantile(hist, 0.90, samples),
+        "p99": hist_quantile(hist, 0.99, samples),
     }

@@ -10,9 +10,12 @@ This is the rebuild's replacement:
     at every protocol step — bcast initiate/forward/deliver, proposal
     judge/vote/decision — cheap enough to leave compiled in (one branch
     when disabled), drainable as dicts or JSONL;
-  - device-side: `annotate(name)` wraps jax.profiler.TraceAnnotation so
-    collective launches show up named in TPU profiles, and
-    `profile(logdir)` wraps jax.profiler.trace for a capture window.
+  - device-side: `annotate(name)` is the one span helper — a
+    jax.profiler.TraceAnnotation (so host stages and collective
+    launches show up named in TPU profiles, on the profiler's clock)
+    that also totals its elapsed time into a metrics Registry and can
+    hand its bracket to a fabric SpanRecorder; `profile(logdir)` wraps
+    jax.profiler.trace for a capture window.
 
 The native C core has the same facility (rlo_trace_* in rlo_core.h);
 tests assert both sides emit the same event sequence for the same
@@ -173,13 +176,51 @@ TRACER = Tracer()
 # Device-side: jax.profiler hooks
 # ---------------------------------------------------------------------------
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named trace annotation around device work — shows up as a labeled
-    region in TPU profiles (xplane/tensorboard)."""
-    import jax.profiler
-    with jax.profiler.TraceAnnotation(name):
-        yield
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, on first use
+
+
+class annotate:
+    """The span helper: ``with annotate(name, ...):`` around a stage.
+
+    Entering opens ``jax.profiler.TraceAnnotation(name, **ids)``: under
+    a profiler session the stage is a named region on the profiler's
+    own clock, beside the device planes (``ids`` ride as its
+    arguments); with no session it costs about a microsecond. Leaving
+    adds the elapsed ``time.perf_counter_ns()`` to the counter
+    ``<counter>_ns`` of ``metrics`` and 1 to ``<counter>_n``
+    (``counter`` defaults to ``name``; no ``metrics``, no counters),
+    and calls ``emit(t0, t1)`` with the bracket in
+    ``time.perf_counter()`` seconds when one is given — how a fabric's
+    SpanRecorder gets the same stage as an ``Ev.SPAN``. Always on: no
+    flag arms it. The jax import is lazy (the engine stack imports
+    this module without JAX)."""
+    __slots__ = ("_ann", "_metrics", "_counter", "_emit", "_t0")
+
+    def __init__(self, name: str, metrics=None,
+                 counter: Optional[str] = None, emit=None, **ids):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation
+            _TraceAnnotation = TraceAnnotation
+        self._ann = _TraceAnnotation(name, **ids)
+        self._metrics = metrics
+        self._counter = name if counter is None else counter
+        self._emit = emit
+
+    def __enter__(self) -> "annotate":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        if self._metrics is not None:
+            self._metrics.counter(self._counter + "_ns").inc(
+                t1 - self._t0)
+            self._metrics.counter(self._counter + "_n").inc()
+        if self._emit is not None:
+            self._emit(self._t0 * 1e-9, t1 * 1e-9)
 
 
 @contextlib.contextmanager

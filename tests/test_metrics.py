@@ -224,3 +224,77 @@ class TestEngineMetrics:
                    for h in s["op_latency_usec"].values())
         for e in engines:
             e.cleanup()
+
+
+# ---------------------------------------------------------------------------
+# Histogram(keep=N): exact percentiles beside the log2 buckets
+# ---------------------------------------------------------------------------
+
+class TestKeptSamples:
+    @pytest.mark.parametrize("n", [1, 2, 7, 400])
+    def test_quantiles_are_exact_over_kept_samples(self, n):
+        import random
+        import statistics
+        rng = random.Random(n)
+        data = [rng.lognormvariate(13.0, 0.4) for _ in range(n)]
+        h = Histogram(keep=1024)
+        for v in data:
+            h.observe(v)
+        if n == 1:
+            cuts = {q: data[0] for q in (50, 90, 99)}
+        else:
+            c = statistics.quantiles(data, n=100, method="inclusive")
+            cuts = {q: c[q - 1] for q in (50, 90, 99)}
+        assert h.p50() == pytest.approx(cuts[50], rel=1e-12)
+        assert h.p90() == pytest.approx(cuts[90], rel=1e-12)
+        assert h.p99() == pytest.approx(cuts[99], rel=1e-12)
+        assert h.quantile(0.0) == min(data)
+        assert h.quantile(1.0) == max(data)
+        s = h.summary()
+        assert (s["p50"], s["p90"], s["p99"]) == (h.p50(), h.p90(),
+                                                  h.p99())
+        # the log2 estimate of the same data is a bucket's upper bound
+        plain = Histogram()
+        for v in data:
+            plain.observe(v)
+        assert plain.p50() == float(2 ** Histogram.bucket_index(
+            sorted(data)[(n - 1) // 2]))
+
+    def test_newest_n_rule(self):
+        h = Histogram(keep=4)
+        for v in range(1, 11):
+            h.observe(v)
+        assert list(h.samples) == [7.0, 8.0, 9.0, 10.0]
+        assert h.quantile(0.0) == 7.0 and h.p50() == 8.5
+        # count/sum/min/max and the buckets still cover all ten
+        assert (h.count, h.sum, h.min, h.max) == (10, 55.0, 1.0, 10.0)
+        assert sum(h.buckets) == 10
+
+    def test_snapshot_layout_is_the_same_with_and_without_keep(self):
+        kept, plain = Histogram(keep=8), Histogram()
+        assert kept.quantile(0.5) is None and plain.samples is None
+        for v in (0, 1, 3, 1024, 2.5e6):
+            kept.observe(v)
+            plain.observe(v)
+        assert kept.snapshot() == plain.snapshot()
+        assert list(kept.snapshot()) == ["count", "sum", "min", "max",
+                                         "buckets"]
+        assert len(kept.snapshot()["buckets"]) == HIST_BUCKETS
+
+    def test_registry_keep_is_set_at_creation_and_summaries(self):
+        reg = Registry()
+        a = reg.histogram("a_usec", keep=16)
+        assert reg.histogram("a_usec") is a          # found, not re-made
+        assert reg.histogram("a_usec", keep=2).samples.maxlen == 16
+        b = reg.histogram("b_usec")
+        for v in (100.0, 300.0, 700.0):
+            a.observe(v)
+            b.observe(v)
+        s = reg.summaries()
+        assert list(s) == ["a_usec", "b_usec"]
+        assert s["a_usec"]["p50"] == 300.0           # exact
+        assert s["b_usec"]["p50"] == 512.0           # bucket upper bound
+        assert reg.snapshot()["histograms"]["a_usec"] == \
+            reg.snapshot()["histograms"]["b_usec"]
+        # a snapshot alone still summarizes as before
+        assert hist_quantile(a.snapshot(), 0.5) == 512.0

@@ -222,3 +222,51 @@ def test_profiler_annotation_smoke():
     with annotate("rlo-allreduce"):
         x = jnp.ones((8, 8)) @ jnp.ones((8, 8))
     assert float(x[0, 0]) == 8.0
+
+
+def test_annotate_totals_into_a_registry_and_hands_out_its_bracket():
+    """The one span helper: elapsed perf_counter_ns into ``<c>_ns``,
+    one into ``<c>_n``, the bracket in perf_counter seconds to
+    ``emit`` — also when the body raises."""
+    import time
+
+    from rlo_tpu.utils.metrics import Registry
+
+    reg = Registry()
+    got = []
+    t_before = time.perf_counter()
+    for _ in range(3):
+        with annotate("perf.stage.x", reg, "stage.x",
+                      lambda t0, t1: got.append((t0, t1)), rid=4):
+            time.sleep(0.002)
+    with pytest.raises(KeyError):
+        with annotate("perf.stage.x", reg, "stage.x"):
+            raise KeyError("body")
+    t_after = time.perf_counter()
+    c = reg.snapshot()["counters"]
+    assert c["stage.x_n"] == 4
+    assert 3 * 2_000_000 <= c["stage.x_ns"] <= (t_after - t_before) * 1e9
+    assert len(got) == 3
+    assert all(t_before <= t0 <= t1 <= t_after for t0, t1 in got)
+    assert sum(t1 - t0 for t0, t1 in got) * 1e9 <= c["stage.x_ns"] + 1e3
+    # the counter defaults to the span's name; no registry, no counters
+    with annotate("plain", reg):
+        pass
+    with annotate("nowhere"):
+        pass
+    assert reg.snapshot()["counters"]["plain_n"] == 1
+    assert "nowhere_n" not in reg.snapshot()["counters"]
+
+
+def test_tracing_module_imports_without_jax():
+    """The engine stack imports utils/tracing.py without JAX: the
+    profiler import waits for the first span."""
+    import subprocess
+    import sys
+    code = ("import sys; sys.modules['jax'] = None; "
+            "import rlo_tpu.utils.tracing as t; "
+            "assert t._TraceAnnotation is None; "
+            "t.Tracer().emit(0, t.Ev.VOTE); print('ok')")
+    out = subprocess.run([sys.executable, "-c", code],
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
