@@ -61,6 +61,8 @@ from rlo_tpu.models.generate import (block_decode, decode_step,
                                      _decode_cfg)
 from rlo_tpu.models.transformer import TransformerConfig
 from rlo_tpu.observe.spans import Stage
+from rlo_tpu.pallas.decode import can_flash_decode, flash_decode_tile
+from rlo_tpu.pallas.reduce import _on_tpu
 from rlo_tpu.utils.metrics import Registry, SERVING
 from rlo_tpu.utils.tracing import annotate
 
@@ -116,7 +118,10 @@ class DecodeServer:
     ``distribute``. Work counters sit at the same boundaries:
     ``serve.admissions``, ``serve.prefill_tokens`` against
     ``serve.prefill_padded_tokens``, ``serve.slot_steps`` against
-    ``serve.slot_steps_useful``, and ``serve.retraces`` (with
+    ``serve.slot_steps_useful``, ``serve.attend_tiles`` against
+    ``serve.attend_tiles_live`` (dense scheduler, flash_decode path:
+    cache tiles in the round's grid, and those a row's live context
+    reaches — the rest the kernel skips), and ``serve.retraces`` (with
     ``serve.retraces.<fn>``): trace-cache entries of the server's own
     jitted functions beyond the shapes it was built for.
 
@@ -198,6 +203,15 @@ class DecodeServer:
                 f"no prompt bucket fits max_len {max_len} "
                 f"(buckets {tuple(sorted(prompt_buckets))})")
         self.cache = init_kv_cache(cfg, n_slots, max_len)
+        # the decode kernel's tiling of the cache axis as (tile width,
+        # tiles), for the attend-tile counters; None where decode_step
+        # attends through the einsum (off the tpu backend, or a shape
+        # can_flash_decode refuses): nothing is tiled, nothing counted
+        k = self.cache[0]["k"]
+        self._attend_tiling = None
+        if _on_tpu() and can_flash_decode(k.shape[3], k.shape[2]):
+            bk = flash_decode_tile(k, cfg.n_heads)
+            self._attend_tiling = (bk, -(-k.shape[3] // bk))
 
         def round_fn(params, cache, last_tok, pos, kk):
             def body(carry, _):
@@ -731,6 +745,7 @@ class DecodeServer:
                 self.params, self.cache, jnp.asarray(self.last_tok),
                 jnp.asarray(self.pos), kk)
             self.cache = cache
+        self._count_attend_tiles(kk)  # while the device runs the round
         with self._span("round.wait"):
             # the host blocks here for the device's whole round
             toks = np.asarray(toks)
@@ -771,6 +786,23 @@ class DecodeServer:
         self._distribute(toks, kk, only_active=True)
         self._page_gauges()
         return True
+
+    def _count_attend_tiles(self, kk: int) -> None:
+        """How often flash_decode's skip engages, from the host's own
+        ``pos``: ``serve.attend_tiles`` is every (row, step, cache
+        tile) of the round's grid, ``serve.attend_tiles_live`` those a
+        row's context reaches — step s of a row attends positions
+        <= pos + s, so tiles 0 .. (pos + s) // bk; the rest are
+        neither fetched nor computed. Every slot counts, free and
+        finished ones too: the kernel runs them."""
+        if self._attend_tiling is None:
+            return
+        bk, n_k = self._attend_tiling
+        last = (self.pos[:, None] + np.arange(kk)) // bk
+        self.metrics.counter("serve.attend_tiles").inc(
+            kk * self.n_slots * n_k)
+        self.metrics.counter("serve.attend_tiles_live").inc(
+            int(np.minimum(last, n_k - 1).sum()) + kk * self.n_slots)
 
     def _observe_round(self, dt: float, kk: int) -> None:
         self._hist("serve.round_usec").observe(dt * 1e6)

@@ -1,7 +1,8 @@
 """Pallas flash-decode kernel: one query against the KV cache.
 
 Decode attention is the other half of the serving HBM story: each step
-reads the whole live cache prefix, and the XLA einsum path
+reads every row's live cache prefix (and, tile-rounded, no more: see
+the grid paragraph), and the XLA einsum path
 (models.generate._attend_cache) was measured 2-4x off the
 weight+cache streaming bound at batch 32 / plen 1024 on v5e — and,
 worse, de-optimized the int8 cache (XLA materializes the dequantized
@@ -22,6 +23,21 @@ heads resident per program (a batched dot over the head axis), two
 orders of magnitude fewer launches, each streaming kvh*BK*d cache
 bytes.
 
+The grid spans max_len, but a row reads only the tiles its live
+context reaches. ``pos`` is scalar-prefetched and the K/V (and int8
+scale) index maps (_cache_block) present a row's own tiles up to the
+one holding position pos + T - 1; on the grid steps past it — the
+dead steps — they present tile 0 of the NEXT row, and the kernel body
+does not run. Pallas issues no copy for a block index that repeats,
+so a row's dead tiles cost neither HBM bytes nor compute, only an
+empty grid step each (~0.3 us), and the next row's first tile is
+already under way when the row's own steps begin. A wholly masked
+tile contributes p = 0 and corr = 1, so the skip changes no bit of
+the output. The tile width BK is therefore the granularity of the
+skip as well as of the stream (_pick_bk). A server's rows sit at a
+third of max_len on average, which is what this buys (PERF.md, PR 26:
+the attend of a 96-row gpt2-medium decode step 0.62 -> 0.33 ms).
+
 Shapes (GQA-grouped, head-leading, SEQ-MINOR — models.generate
 stores the cache with max_len as the minor dim so HBM tiles stream at
 full 128-lane width; head_dim=64-minor measured half the bandwidth,
@@ -33,16 +49,18 @@ decode, so both paths share one kernel and its numerics:
   q        (b, kv_heads, T*r, head_dim)  r = n_heads / kv_heads
   k/v      (b, kv_heads, head_dim, max_len)  act dtype or int8
   ks/vs    (b, kv_heads, max_len) f32 scales (int8 caches only)
-  pos      (b, 1) int32 — query t of row b masks prefix [0, pos_b + t]
+  pos      (b,) int32, scalar-prefetched (SMEM) — query t of row b
+           masks prefix [0, pos_b + t]
   out      (b, kv_heads, T*r, head_dim) f32
   scratch  m/l (kv_heads, T*r), o (kv_heads, T*r, head_dim) f32
 
 Dots run in bf16 with f32 accumulation (int8 -> bf16 is lossless;
 f32 caches keep f32 dots — their tiles are smaller than VMEM allows
 anyway). The cache axis is innermost and sequential ('arbitrary'),
-accumulating (m, l, o) in VMEM scratch; the padded tail block past
-max_len is masked (and V zeroed under the mask, so out-of-range
-garbage can never ride a 0*NaN into the accumulator).
+accumulating (m, l, o) in VMEM scratch — initialised at a row's first
+grid step and flushed at its last, both outside the skip; the padded
+tail block past max_len is masked (and V zeroed under the mask, so
+out-of-range garbage can never ride a 0*NaN into the accumulator).
 """
 
 from __future__ import annotations
@@ -79,55 +97,63 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         l_s[...] = jnp.zeros_like(l_s[...])
         o_s[...] = jnp.zeros_like(o_s[...])
 
-    # dots in bf16 (f32 accumulate): int8 -> bf16 is lossless, bf16 is
-    # the MXU-native width, and an f32 cast would materialize 4x the
-    # tile bytes in VMEM. f32 caches keep f32 (exactness; their tiles
-    # fit). g = kvh heads batched per program. The query axis holds
-    # T*r rows, t-major: row t*r+rr is block token t, group-member rr,
-    # at sequence position pos + t (T=1 recovers single-token decode).
-    dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
-    q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
-    k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
-    v = v_ref[0].astype(dot_dt)                      # (g, d, BK)
-    pos = pos_ref[ib, 0]
-    # masks built >=2-D from iota: Mosaic cannot insert a minor dim on
-    # sub-32-bit (bool) values, so never reshape a 1-D mask
-    base = ik * bk
-    row = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
-    # per-query causal position: query row t*r+rr masks at pos + t
-    qoff = jax.lax.broadcasted_iota(jnp.int32, (1, T * r, 1), 1) // r
-    mask_row = (row <= pos + qoff) & (row < max_len)  # (1, T*r, BK)
-    # V zeroing: any key a query of this block may attend (<= pos+T-1)
-    # — seq-minor V masks over its LAST axis
-    mask_col = (row <= pos + (T - 1)) & (row < max_len)  # (1, 1, BK)
+    pos = pos_ref[ib]
 
-    # batched over the head axis, contracting head_dim — the seq-minor
-    # cache arrives as the MXU-native (d, BK) operand
-    s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32) * scale
-    if quant:
-        s = s * ks_ref[0]                            # (g, 1, BK)
-    s = jnp.where(mask_row, s, _NEG)                 # (g, T*r, BK)
-    # zero V under the mask: a padded tail tile may hold uninitialized
-    # VMEM, and 0 * NaN would poison the accumulator
-    v = jnp.where(mask_col, v, jnp.zeros((), dot_dt))
+    # a tile that starts past the last position any query of this row
+    # attends (pos + T - 1) is masked to p = 0, m unchanged, corr = 1:
+    # skipping it is bit-identical to computing it. Its K/V were not
+    # fetched either: on exactly these steps k_ref/v_ref hold the
+    # next row's first tile (_cache_block, the same rule).
+    @pl.when(ik <= _last_live_tile(pos, T, bk, n_k))
+    def _attend():
+        # dots in bf16 (f32 accumulate): int8 -> bf16 is lossless, bf16 is
+        # the MXU-native width, and an f32 cast would materialize 4x the
+        # tile bytes in VMEM. f32 caches keep f32 (exactness; their tiles
+        # fit). g = kvh heads batched per program. The query axis holds
+        # T*r rows, t-major: row t*r+rr is block token t, group-member rr,
+        # at sequence position pos + t (T=1 recovers single-token decode).
+        dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
+        q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
+        k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
+        v = v_ref[0].astype(dot_dt)                      # (g, d, BK)
+        # masks built >=2-D from iota: Mosaic cannot insert a minor dim on
+        # sub-32-bit (bool) values, so never reshape a 1-D mask
+        base = ik * bk
+        row = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+        # per-query causal position: query row t*r+rr masks at pos + t
+        qoff = jax.lax.broadcasted_iota(jnp.int32, (1, T * r, 1), 1) // r
+        mask_row = (row <= pos + qoff) & (row < max_len)  # (1, T*r, BK)
+        # V zeroing: any key a query of this block may attend (<= pos+T-1)
+        # — seq-minor V masks over its LAST axis
+        mask_col = (row <= pos + (T - 1)) & (row < max_len)  # (1, 1, BK)
 
-    m = m_s[...]                                     # (g, T*r)
-    m_new = jnp.maximum(m, s.max(axis=-1))
-    p = jnp.where(mask_row, jnp.exp(s - m_new[..., None]), 0.0)
-    corr = jnp.exp(m - m_new)
-    m_s[...] = m_new
-    l_s[...] = l_s[...] * corr + p.sum(axis=-1)
-    # fold the v dequant into the probabilities (f32, no relayout of
-    # v) — AFTER the l accumulation (the softmax denominator must sum
-    # the unscaled probabilities) and re-masked: the padded tail's vs
-    # tile is uninitialized VMEM and p's zeros would ride 0*NaN into
-    # the accumulator, the same hazard v is zeroed for above
-    pv = jnp.where(mask_row, p * vs_ref[0], 0.0) if quant else p
-    # p (g, R, BK) x v (g, d, BK), contracting BK
-    o_s[...] = o_s[...] * corr[..., None] + jax.lax.dot_general(
-        pv.astype(dot_dt), v, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+        # batched over the head axis, contracting head_dim — the seq-minor
+        # cache arrives as the MXU-native (d, BK) operand
+        s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * ks_ref[0]                            # (g, 1, BK)
+        s = jnp.where(mask_row, s, _NEG)                 # (g, T*r, BK)
+        # zero V under the mask: a padded tail tile may hold uninitialized
+        # VMEM, and 0 * NaN would poison the accumulator
+        v = jnp.where(mask_col, v, jnp.zeros((), dot_dt))
+
+        m = m_s[...]                                     # (g, T*r)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(mask_row, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        m_s[...] = m_new
+        l_s[...] = l_s[...] * corr + p.sum(axis=-1)
+        # fold the v dequant into the probabilities (f32, no relayout of
+        # v) — AFTER the l accumulation (the softmax denominator must sum
+        # the unscaled probabilities) and re-masked: the padded tail's vs
+        # tile is uninitialized VMEM and p's zeros would ride 0*NaN into
+        # the accumulator, the same hazard v is zeroed for above
+        pv = jnp.where(mask_row, p * vs_ref[0], 0.0) if quant else p
+        # p (g, R, BK) x v (g, d, BK), contracting BK
+        o_s[...] = o_s[...] * corr[..., None] + jax.lax.dot_general(
+            pv.astype(dot_dt), v, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
 
     @pl.when(ik == n_k - 1)
     def _flush():
@@ -612,13 +638,30 @@ def can_flash_decode(max_len: int, head_dim: int,
     return bk == max_len or bk % 128 == 0
 
 
+#: K bytes (kv_heads x head_dim x BK x itemsize) one grid step should
+#: stream at least. The tile is the granularity at which a row's dead
+#: context is skipped, so narrower skips more — until a grid step's
+#: fixed cost shows. Measured at 96 rows x 16 heads x 64 x 1024 bf16 on
+#: a v5e (PERF.md, PR 26), whole decode step on a server's mix of
+#: contexts / one call with every row at max_len: BK 512 (1 MiB)
+#: 17.6 ms / 0.556 ms; 256 (512 KiB) 15.8 / 0.588; 128 (256 KiB)
+#: 16.0 / 0.696.
+_TILE_BYTES = 512 << 10
+
+
 def _pick_bk(L: int, d: int, nkv: int, r: int, itemsize: int,
              block_k: int) -> int:
-    """Cache-tile width from the T=1 VMEM budget (two (kvh, bk, d)
-    tiles in the dot dtype + the f32 score/probability tensors within
-    ~10 MB). Deliberately independent of T: every block size must
-    tile the cache identically or verify/decode numerics diverge."""
+    """Cache-tile width: at most ``block_k``, no wider than streams
+    _TILE_BYTES of K a grid step (finer tiles skip more of a short
+    row's dead context), and within the T=1 VMEM budget (two
+    (kvh, bk, d) tiles in the dot dtype + the f32 score/probability
+    tensors within ~10 MB). A rule of the shape alone, deliberately
+    independent of T: every block size must tile the cache identically
+    or verify/decode numerics diverge."""
     bk = min(block_k, max(L, 1))
+    if bk > 128:
+        bk = min(bk, max(128, _TILE_BYTES // (nkv * d * itemsize)
+                         // 128 * 128))
     if bk < L and L % 128 == 0:
         # prefer a DIVISOR of L: a non-dividing bk makes Mosaic pad
         # the whole cache operand (materialized XLA pads per step)
@@ -632,6 +675,42 @@ def _pick_bk(L: int, d: int, nkv: int, r: int, itemsize: int,
         while bk > 128 and L % 128 == 0 and L % bk:
             bk -= 128
     return bk
+
+
+def flash_decode_tile(k_cache, n_heads: int) -> int:
+    """The cache-tile width flash_decode / flash_block_decode run a
+    (b, kv_heads, head_dim, max_len) cache at (anything with its
+    ``shape`` and ``dtype``): the granularity at which a row's dead
+    context is skipped. For callers that count tiles (DecodeServer's
+    ``serve.attend_tiles``); the rule lives here."""
+    _, nkv, d, L = k_cache.shape
+    itemsize = 4 if k_cache.dtype == jnp.float32 else 2
+    return _pick_bk(L, d, nkv, n_heads // nkv, itemsize, _BLOCK_K)
+
+
+def _last_live_tile(pos, T: int, bk: int, n_k: int):
+    """The last cache tile a row attends into: the one holding
+    position pos + T - 1, its T-th query's own. Clipped into
+    [0, n_k): serve advances retired slots past max_len."""
+    return jnp.clip((pos + (T - 1)) // bk, 0, n_k - 1)
+
+
+def _cache_block(ib, ik, pos_ref, T: int, bk: int, n_k: int, b: int):
+    """Block index (row, 0, 0, tile) of the K/V tile — and of the
+    int8 scale tile, same rank — that grid step (ib, ik) presents:
+    its own tile up to the row's last live one; past it — the dead
+    steps, which _decode_kernel does not compute — tile 0 of the NEXT
+    row. Pallas issues no copy when a block index repeats, so a row's
+    dead tiles move no bytes, and the next row's first tile is in
+    flight from the first dead step on (when that row begins, the
+    index repeats again and the tile is already in VMEM) instead of
+    being requested at the row's last grid step with nothing left to
+    hide it behind. The last row has no next: its dead steps stay on
+    its last live tile."""
+    last = _last_live_tile(pos_ref[ib], T, bk, n_k)
+    ahead = (ik > last) & (ib + 1 < b)
+    return (jnp.where(ahead, ib + 1, ib), 0, 0,
+            jnp.where(ahead, 0, jnp.minimum(ik, last)))
 
 
 def _block_fits_vmem(L: int, d: int, nkv: int, r: int, T: int,
@@ -696,8 +775,7 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     qg = (q.reshape(b, T, nkv, r, d).transpose(0, 2, 1, 3, 4)
           .reshape(b, nkv, R, d))
     posv = jnp.asarray(pos0, jnp.int32)
-    posv = (jnp.full((b, 1), posv) if posv.ndim == 0
-            else posv.reshape(b, 1))
+    posv = jnp.full((b,), posv) if posv.ndim == 0 else posv.reshape(b)
     # inside shard_map (vma typing) every kernel operand must carry
     # the same varying-axes set: a replicated pos rides along with the
     # tp-sharded q/cache
@@ -705,19 +783,21 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     posv = vary_like(posv, q)
     posv = vary_like(posv, k_cache)
 
-    # pos: whole-array block (block dims == array dims is always legal)
-    pos_spec = pl.BlockSpec((b, 1), lambda ib, ik: (0, 0))
-    q_spec = pl.BlockSpec((1, nkv, R, d), lambda ib, ik: (ib, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, nkv, d, bk),
-                           lambda ib, ik: (ib, 0, 0, ik))
-    o_spec = q_spec
-    in_specs = [pos_spec, q_spec, kv_spec, kv_spec]
-    args = [posv, qg, k_cache, v_cache]
+    # pos is scalar-prefetched: the cache index maps read it, so the
+    # grid steps past a row's live context name a block that is
+    # already in VMEM or on its way (_cache_block; no copy is issued
+    # for a repeated block index) and the kernel body skips them
+    q_spec = pl.BlockSpec((1, nkv, R, d),
+                          lambda ib, ik, pos_ref: (ib, 0, 0, 0))
+    cache_map = lambda ib, ik, pos_ref: _cache_block(  # noqa: E731
+        ib, ik, pos_ref, T, bk, n_k, b)
+    kv_spec = pl.BlockSpec((1, nkv, d, bk), cache_map)
+    in_specs = [q_spec, kv_spec, kv_spec]
+    args = [qg, k_cache, v_cache]
     if quant:
         # scales reshaped (b, kvh, 1, L): the (1, bk) trailing block
         # dims satisfy Mosaic's tiling rule for any bk multiple of 128
-        s_spec = pl.BlockSpec((1, nkv, 1, bk),
-                              lambda ib, ik: (ib, 0, 0, ik))
+        s_spec = pl.BlockSpec((1, nkv, 1, bk), cache_map)
         in_specs += [s_spec, s_spec]
         args += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
 
@@ -725,23 +805,25 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
-    scratch = [pltpu.VMEM((nkv, R), jnp.float32),
-               pltpu.VMEM((nkv, R), jnp.float32),
-               pltpu.VMEM((nkv, R, d), jnp.float32)]
-
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, n_k),
+        in_specs=in_specs,
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((nkv, R), jnp.float32),
+                        pltpu.VMEM((nkv, R), jnp.float32),
+                        pltpu.VMEM((nkv, R, d), jnp.float32)],
+    )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale), n_k=n_k,
                           bk=bk, max_len=L, quant=quant, r=r, T=T),
-        grid=(b, n_k),
-        in_specs=in_specs,
-        out_specs=o_spec,
+        grid_spec=grid_spec,
         out_shape=out_struct((b, nkv, R, d), jnp.float32, q, k_cache),
-        scratch_shapes=scratch,
         interpret=interpret,
         # one kernel body, two names: the T=1 decode step and the
         # T>1 extend/verify block are told apart in program text
         name="flash_decode" if T == 1 else "flash_block_decode",
         **kwargs,
-    )(*args)
+    )(posv, *args)
     return (out.reshape(b, nkv, T, r, d).transpose(0, 2, 1, 3, 4)
             .reshape(b, T, nh, d))

@@ -1639,6 +1639,12 @@ def _p3_probes() -> List[Probe]:
                  v_cache=A((2, 4, 64, 1024)), pos0=A((2,), [1023, 512]),
                  scale=0.125, k_scale=A((2, 4, 1024)),
                  v_scale=A((2, 4, 1024)), interpret=True),
+            # the LAST row short: its dead grid steps have no next
+            # row to present, and a retired slot's pos past max_len
+            dict(q=A((2, 1, 8, 64)), k_cache=A((2, 4, 64, 1024)),
+                 v_cache=A((2, 4, 64, 1024)), pos0=A((2,), [4096, 3]),
+                 scale=0.125, k_scale=None, v_scale=None,
+                 interpret=True),
         ], sites=1),
         Probe("rlo_tpu/pallas/flash.py", "_flash_fwd_call", [
             dict(q=A((8, 1024, 128)), k=A((8, 2048, 128)),

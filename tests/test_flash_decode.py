@@ -14,8 +14,10 @@ import pytest
 
 from rlo_tpu.models.generate import (_attend_cache, _attend_cache_block,
                                      _quantize_kv)
-from rlo_tpu.pallas.decode import (can_flash_decode, flash_block_decode,
-                                   flash_decode)
+from rlo_tpu.pallas import decode as decode_mod
+from rlo_tpu.pallas.decode import (_cache_block, can_flash_decode,
+                                   flash_block_decode, flash_decode,
+                                   flash_decode_tile)
 
 B, NH, NKV, D, L = 3, 8, 4, 64, 48
 
@@ -203,6 +205,149 @@ def test_block_T1_is_flash_decode(data):
     b = np.asarray(flash_block_decode(q, kc, vc, 13, scale,
                                       interpret=True, block_k=16))
     np.testing.assert_array_equal(a, b)
+
+
+# -- dead-tile skip: tiles wholly past a row's last attended position
+# are neither fetched (_live_tile clamps the index maps) nor computed
+# (the body runs under the same rule). L = 48: block_k 16 gives three
+# tiles with edges at 16 and 32; block_k 32 two, the second padded.
+_EDGE = 16
+SKIP_CASES = {
+    # name: (T, pos0, int8, block_k)
+    "T1-pos0": (1, 0, False, _EDGE),
+    "T1-last-of-tile0": (1, _EDGE - 1, False, _EDGE),
+    "T1-first-of-tile1": (1, _EDGE, False, _EDGE),
+    "T1-last-position": (1, L - 1, False, _EDGE),
+    "T1-ragged": (1, [0, L - 1, _EDGE], False, _EDGE),
+    "T4-crosses-edge": (4, _EDGE - 2, False, _EDGE),
+    "T4-ends-on-edge": (4, _EDGE - 4, False, _EDGE),
+    "T4-ragged": (4, [_EDGE - 3, 0, L - 4], False, _EDGE),
+    "T1-int8-edge": (1, _EDGE, True, _EDGE),
+    "T4-int8-ragged": (4, [_EDGE - 1, 2 * _EDGE, 3], True, _EDGE),
+    "T1-padded-tail-skipped": (1, 31, False, 32),
+    "T1-padded-tail-live": (1, [32, L - 1, 5], False, 32),
+    "T4-padded-tail-crossed": (4, 29, False, 32),
+    "T1-int8-padded-tail": (1, [31, 40, 0], True, 32),
+}
+
+
+def _skip_case(data, name):
+    T, pos0, quant, block_k = SKIP_CASES[name]
+    _, kc, vc, scale = data
+    rng = np.random.default_rng(sorted(SKIP_CASES).index(name))
+    q = jnp.asarray(rng.standard_normal((B, T, NH, D)), jnp.float32)
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    if not quant:
+        return q, (kc, vc), (kc, vc), pos0, scale, block_k
+    qk, ks = _quant_seqminor(kc)
+    qv, vs = _quant_seqminor(vc)
+    kd = jnp.asarray(np.asarray(qk, np.float32)
+                     * np.asarray(ks)[:, :, None, :])
+    vd = jnp.asarray(np.asarray(qv, np.float32)
+                     * np.asarray(vs)[:, :, None, :])
+    return q, (qk, qv, ks, vs), (kd, vd), pos0, scale, block_k
+
+
+def _run_kernel(q, cache, pos0, scale, block_k):
+    k, v, *scales = cache
+    return np.asarray(flash_block_decode(q, k, v, pos0, scale, *scales,
+                                         interpret=True,
+                                         block_k=block_k))
+
+
+@pytest.mark.parametrize("name", sorted(SKIP_CASES))
+def test_skip_matches_oracle(data, name):
+    q, cache, dequant, pos0, scale, block_k = _skip_case(data, name)
+    got = _run_kernel(q, cache, pos0, scale, block_k)
+    assert np.isfinite(got).all()
+    tol = 1e-2 if len(cache) == 4 else 2e-5
+    np.testing.assert_allclose(
+        got, _block_oracle(q, *dequant, pos0, scale), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("name", sorted(SKIP_CASES))
+def test_skip_is_bitwise_the_unskipped_kernel(data, name, monkeypatch):
+    """A wholly masked tile adds p = 0 with corr = 1, so leaving it
+    out changes no bit. The unskipped evaluation is the same kernel
+    with the rule switched off: every grid step presents and computes
+    its own tile, masked, as before the skip existed."""
+    q, cache, _, pos0, scale, block_k = _skip_case(data, name)
+    got = _run_kernel(q, cache, pos0, scale, block_k)
+    monkeypatch.setattr(decode_mod, "_last_live_tile",
+                        lambda pos, T, bk, n_k: n_k - 1)
+    want = _run_kernel(q, cache, pos0, scale, block_k)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("T", [1, 4])
+@pytest.mark.parametrize("bk,n_k", [(16, 3), (32, 2), (512, 2), (128, 8)])
+def test_cache_block_rule(bk, n_k, T):
+    """The index-map rule itself (interpret mode would hide a tile
+    that is fetched for nothing, the mask zeroes it either way). For
+    every (ik, pos, T): a live step presents its own tile; a dead
+    step — past the tile holding position pos + T - 1 — presents
+    tile 0 of the next row (so that row's first fetch is under way),
+    or on the last row stays on its last live tile. Whatever pos, a
+    retired slot's past max_len too, the block exists; and a row's
+    dead steps all present ONE block, so they cost one copy at most."""
+    L, b = bk * n_k, 3
+    edges = {e + d for e in range(0, L + bk, bk) for d in (-T, -1, 0, 1)}
+    for pos in sorted(p for p in edges | {0, 2 * L, 10 * L} if p >= 0):
+        last = min((pos + T - 1) // bk, n_k - 1)
+        for ib in range(b):
+            pos_ref = jnp.asarray([7, 7, 7], jnp.int32).at[ib].set(pos)
+            got = [tuple(int(x) for x in _cache_block(
+                jnp.int32(ib), jnp.int32(ik), pos_ref, T, bk, n_k, b))
+                for ik in range(n_k)]
+            for ik, (row, z1, z2, tile) in enumerate(got):
+                assert (z1, z2) == (0, 0)
+                assert 0 <= row < b and 0 <= tile < n_k
+                live = ik * bk <= min(pos + T - 1, L - 1)
+                assert live == (ik <= last)
+                if live:
+                    assert (row, tile) == (ib, ik), (ib, ik, pos)
+                elif ib + 1 < b:
+                    assert (row, tile) == (ib + 1, 0), (ib, ik, pos)
+                else:
+                    assert (row, tile) == (ib, last), (ib, ik, pos)
+            assert len(set(got[last + 1:])) <= 1
+
+
+def test_skip_with_poisoned_dead_context(data):
+    """NaN in K and inf in V at every position past the rows'
+    contexts: the live tile's own tail is masked as before, and a row
+    whose later grid steps were all skipped still flushes its output
+    (a dead step's refs hold the next row's tile, not its own)."""
+    q, kc, vc, scale = data
+    posv = np.asarray([3, _EDGE, L - 1], np.int32)
+    col = np.arange(L)[None, None, None, :]
+    dead = col > posv[:, None, None, None]
+    kp = jnp.where(dead, jnp.nan, kc)
+    vp = jnp.where(dead, jnp.inf, vc)
+    got = np.asarray(flash_decode(q, kp, vp, jnp.asarray(posv), scale,
+                                  interpret=True, block_k=_EDGE))
+    np.testing.assert_allclose(got, _oracle(q, kc, vc, posv, scale),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_tile_width_export():
+    """flash_decode_tile is the kernel's own choice for a cache (what
+    DecodeServer's attend-tile counters divide by), a legal Mosaic
+    tile that divides a lane-aligned cache: no wider than streams
+    _TILE_BYTES of K a grid step, 512 at most."""
+    for L_, d, nkv, nh, dtype, want in [
+            (1024, 64, 16, 16, jnp.bfloat16, 256),   # the benchmark's
+            (1024, 64, 16, 16, jnp.int8, 256),       # dots in bf16
+            (1024, 64, 16, 16, jnp.float32, 128),
+            (1024, 64, 4, 8, jnp.bfloat16, 512),     # GQA: fewer heads
+            (2048, 128, 8, 32, jnp.bfloat16, 256),
+            (256, 64, 4, 8, jnp.bfloat16, 256),
+            (128, 128, 2, 2, jnp.bfloat16, 128)]:
+        cache = jax.ShapeDtypeStruct((2, nkv, d, L_), dtype)
+        bk = flash_decode_tile(cache, nh)
+        assert bk == want, (L_, d, nkv, nh, dtype)
+        assert bk % 128 == 0 and L_ % bk == 0 and bk <= L_
+        assert can_flash_decode(L_, d)
 
 
 def test_jittable_and_sharded(data):

@@ -522,3 +522,78 @@ def test_fabric_recorder_still_gets_prefill_chunk_spans(setup):
                                            100, 7)
         assert e.b >= 0 and e.ts_usec > 0
     assert reg.snapshot()["counters"]["serve.prefill_chunks"] == 5
+
+
+# head_dim 64 so the decode kernel's shape gate accepts the cache
+CFG_HD64 = TransformerConfig(vocab=64, d_model=64, n_heads=1, n_layers=1,
+                             d_ff=64, dtype="float32")
+
+
+@pytest.mark.parametrize("near_edge", [-2, 0])
+def test_attend_tile_counters_equal_the_hand_count(monkeypatch,
+                                                   near_edge):
+    """``serve.attend_tiles`` / ``serve.attend_tiles_live`` against a
+    count by hand from pos, kk and the kernel's exported tile width:
+    step s of a round attends positions <= pos + s, so a row reaches
+    tiles 0 .. (pos + s) // bk of the n_k in the grid. Every slot
+    counts, the never-admitted and the finished too (the kernel runs
+    them; their pos advances with the round). The server is told it
+    is on the tpu backend so that it counts at all; the model still
+    attends through the einsum here, and the count needs only pos."""
+    from rlo_tpu.models import serve as serve_mod
+    from rlo_tpu.pallas.decode import flash_decode_tile
+    from rlo_tpu.utils.metrics import Registry
+
+    monkeypatch.setattr(serve_mod, "_on_tpu", lambda: True)
+    max_len, kk, n_slots = 1024, 4, 3
+    bk = flash_decode_tile(jax.ShapeDtypeStruct(
+        (n_slots, 1, CFG_HD64.head_dim, max_len), jnp.float32), 1)
+    n_k = max_len // bk
+    assert n_k >= 2     # else there is no tile to skip at this size
+    params = init_params(jax.random.PRNGKey(3), CFG_HD64)
+    reg = Registry()
+    srv = DecodeServer(params, CFG_HD64, n_slots=n_slots,
+                       max_len=max_len, round_len=kk,
+                       prompt_buckets=(8, 1024), metrics=reg)
+    assert srv._attend_tiling == (bk, n_k)
+    rng = np.random.default_rng(26)
+    # one short row; one whose context crosses (or starts on) the
+    # first tile edge inside its first round; slot 2 stays free
+    plens, outs = [5, bk + near_edge], [9, 6]
+    for plen, out in zip(plens, outs):
+        srv.submit(rng.integers(0, CFG_HD64.vocab, (plen,)), out)
+    srv.run()
+    rounds = srv.rounds_run
+    assert rounds == 2      # 9 tokens: one at admission, 8 / kk rounds
+    live = 0
+    for slot_pos0 in plens + [0]:           # pos at the first round
+        for step in range(rounds * kk):     # pos advances every step
+            live += min((slot_pos0 + step) // bk, n_k - 1) + 1
+    c = srv.stats()["counters"]
+    assert c["serve.attend_tiles"] == rounds * kk * n_slots * n_k
+    assert c["serve.attend_tiles_live"] == live
+    # the short rows reach one tile a step, the long one two from the
+    # step its context passes the edge
+    crossed = rounds * kk - max(0, -near_edge)
+    assert live == 3 * rounds * kk + crossed
+
+
+def test_attend_tile_counters_absent_on_the_einsum_path(setup):
+    """Off the tpu backend (here) decode_step attends through the
+    einsum: nothing is tiled, so the server holds no tiling, never
+    touches the two counters and stats() does not show them."""
+    from rlo_tpu.utils.metrics import Registry
+
+    reg = Registry()
+    srv = DecodeServer(setup, CFG, n_slots=2, max_len=64, round_len=4,
+                       prompt_buckets=(8, 16), metrics=reg)
+    assert srv._attend_tiling is None
+    srv.submit(np.arange(5), 6)
+    srv.run()
+    c = srv.stats()["counters"]
+    assert c["serve.slot_steps"] > 0
+    assert not any(k.startswith("serve.attend_tiles") for k in c)
+    # a shape the kernel's gate refuses holds none on the chip either
+    srv._count_attend_tiles(4)
+    assert not any(k.startswith("serve.attend_tiles")
+                   for k in srv.stats()["counters"])
