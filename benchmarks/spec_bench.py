@@ -84,25 +84,29 @@ def chain_time_pair(run_a, run_b, args_a, args_b, k, pairs=9):
     t(k)) / k — the ~110 ms dispatch floor AND window drift both
     cancel inside the pair (an early revision skipped the floor
     subtraction and reported 1.7 ms of floor as the 'step cost')."""
-    for run, a in ((run_a, args_a), (run_b, args_b)):
-        np.asarray(run(*a, k))
-        np.asarray(run(*a, 2 * k))  # compile + warm both lengths
-    ta, tb = [], []
-    for _ in range(pairs):
-        t = []
-        for run, a, kk in ((run_a, args_a, k), (run_a, args_a, 2 * k),
-                           (run_b, args_b, k), (run_b, args_b, 2 * k)):
-            t0 = time.perf_counter()
-            np.asarray(run(*a, kk))
-            t.append(time.perf_counter() - t0)
-        ta.append((t[1] - t[0]) / k)
-        tb.append((t[3] - t[2]) / k)
-    ta, tb = float(np.median(ta)), float(np.median(tb))
-    if ta <= 0 or tb <= 0:
-        raise RuntimeError(
-            f"chain differencing swallowed by noise (ta={ta}, tb={tb})"
-            f" — raise k")
-    return ta, tb
+    # a chain too short for the host clock differences to noise: double
+    # it (three times at most) before giving up
+    for attempt in range(4):
+        for run, a in ((run_a, args_a), (run_b, args_b)):
+            np.asarray(run(*a, k))
+            np.asarray(run(*a, 2 * k))  # compile + warm both lengths
+        ta, tb = [], []
+        for _ in range(pairs):
+            t = []
+            for run, a, kk in ((run_a, args_a, k), (run_a, args_a, 2 * k),
+                               (run_b, args_b, k), (run_b, args_b, 2 * k)):
+                t0 = time.perf_counter()
+                np.asarray(run(*a, kk))
+                t.append(time.perf_counter() - t0)
+            ta.append((t[1] - t[0]) / k)
+            tb.append((t[3] - t[2]) / k)
+        ta, tb = float(np.median(ta)), float(np.median(tb))
+        if ta > 0 and tb > 0:
+            return ta, tb
+        k *= 2
+    raise RuntimeError(
+        f"chain differencing swallowed by noise (ta={ta}, tb={tb})"
+        f" — raise k")
 
 
 def distill_draft(params, cfg, dcfg, *, plen, seq, n_batches, batch,

@@ -33,7 +33,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from rlo_tpu.models.transformer import (TransformerConfig, apply_layer,
-                                        embed_tokens, _rmsnorm)
+                                        embed_tokens, head_weights,
+                                        mla_unabsorbed, _rmsnorm)
 from rlo_tpu.ops.ring_attention import _NEG
 from rlo_tpu.pallas.reduce import KernelFallbackWarning, kernel_gate
 
@@ -63,6 +64,9 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
     ntp = lax.axis_size(tp_axis) if tp_axis is not None else 1
     assert cfg.kv_heads % ntp == 0
     kvh = cfg.kv_heads // ntp
+    if cfg.mla and (tp_axis is not None or cfg.kv_cache_dtype):
+        raise ValueError("the latent cache is unsharded and in the "
+                         "activation dtype so far")
     if jax.default_backend() == "tpu":
         # round the seq axis up to the 128-lane tile: a non-multiple
         # max_len makes EVERY pallas call pad the whole cache (16
@@ -70,6 +74,15 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
         # tail is position-masked everywhere, so +<=127 slots is
         # semantics-free and removes the pads
         max_len = -(-max_len // 128) * 128
+    if cfg.mla:
+        # latent attention: ONE row [c_kv | rotated key dims] a token
+        # and layer, whatever the number of heads, in the same
+        # sequence-minor layout; no "v" (the values are the row's
+        # leading kv_lora_rank features)
+        width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        return [{"k": jnp.zeros((batch, 1, width, max_len),
+                                cfg.act_dtype)}
+                for _ in range(cfg.n_layers)]
     shape = (batch, kvh, cfg.head_dim, max_len)
     # DISTINCT buffers per entry: sharing one zeros array across k/v/
     # layers breaks donation ("attempt to donate the same buffer
@@ -96,6 +109,8 @@ def kv_cache_pspecs(cfg: TransformerConfig,
     param_pspecs); batch/positions replicated. Pass as the cache
     in/out spec for shard_jit'd decode."""
     from jax.sharding import PartitionSpec as P
+    if cfg.mla:
+        raise ValueError("the latent cache is unsharded so far")
     spec = P(None, tp_axis, None, None)
     if cfg.kv_cache_dtype == "int8":
         sspec = P(None, tp_axis, None)
@@ -123,15 +138,16 @@ def _decode_cfg(cfg: TransformerConfig) -> TransformerConfig:
     its argmax expert and parity with the training forward holds
     exactly when the forward drops nothing (capacity_factor >=
     n_experts guarantees that)."""
-    if cfg.n_experts == 0:
-        return cfg
+    if cfg.n_experts == 0 or cfg.moe_router != "switch":
+        return cfg      # no experts, or a router that never drops
     return dataclasses.replace(
         cfg, capacity_factor=max(cfg.capacity_factor,
                                  float(cfg.n_experts)))
 
 
 def _attend_cache(q, k_cache, v_cache, pos, scale,
-                  k_scale=None, v_scale=None, use_flash=None):
+                  k_scale=None, v_scale=None, use_flash=None,
+                  v_dim: int = 0):
     """q (b, 1, H, hd) against the cache prefix [0, pos]: full-length
     matmul over the static cache, masked beyond the position. ``pos``
     is a scalar (all rows at the same position) or a (b,) vector
@@ -146,14 +162,19 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
     scores scale per key position, probabilities pre-multiply the
     value scale — so the (b, kv, hd, max_len) cache operands enter
     their matmuls as stored int8 and the big HBM reads stay 1
-    byte/element."""
+    byte/element.
+
+    A LATENT cache passes ``v_cache`` None and ``v_dim``: one stream
+    (b, 1, hd, max_len) that every head attends, whose leading
+    ``v_dim`` features are the values; returns (b, 1, H, v_dim)."""
     b, one, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
     if use_flash is None:
         from rlo_tpu.pallas.decode import can_flash_decode
         use_flash = kernel_gate(
-            can_flash_decode(max_len, hd),
-            f"decode attend (max_len={max_len}, head_dim={hd})")
+            can_flash_decode(max_len, hd, v_dim=v_dim),
+            f"decode attend (max_len={max_len}, head_dim={hd}, "
+            f"v_dim={v_dim})")
     if use_flash:
         # fused decode attention: cache tiles stream through VMEM
         # (int8 tiles dequantize there — the einsum path measured XLA
@@ -161,7 +182,7 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
         # pass — rlo_tpu.pallas.decode
         from rlo_tpu.pallas.decode import flash_decode
         return flash_decode(q, k_cache, v_cache, pos, scale,
-                            k_scale, v_scale)
+                            k_scale, v_scale, v_dim=v_dim)
     # the einsum path IS the T=1 case of the block attend — one
     # implementation, so a dequant/mask/dtype fix can never diverge
     # decode_step from block_decode (speculative decoding's
@@ -170,12 +191,13 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
     pos_q = (jnp.full((b, 1), posv) if posv.ndim == 0
              else posv.reshape(b, 1))
     return _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
-                               k_scale=k_scale, v_scale=v_scale)
+                               k_scale=k_scale, v_scale=v_scale,
+                               v_dim=v_dim)
 
 
 def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                         k_scale=None, v_scale=None, pos0=None,
-                        use_flash=None):
+                        use_flash=None, v_dim: int = 0):
     """Block variant of the cache attend: q (b, T, nh, hd) where query
     i of row b sits at position pos_q[b, i] and attends cache
     positions <= pos_q[b, i]. Because the block's own K/V rows are
@@ -196,13 +218,16 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
     if use_flash is None:
         from rlo_tpu.pallas.decode import (_block_fits_vmem,
+                                           _tile_rule,
                                            can_flash_decode)
         itemsize = 4 if k_cache.dtype == jnp.float32 else 2
         gate = pos0 is not None and kernel_gate(
-            can_flash_decode(max_len, hd),
-            f"block attend (max_len={max_len}, head_dim={hd})")
-        fits = gate and _block_fits_vmem(max_len, hd, nkv, nh // nkv,
-                                         T, itemsize)
+            can_flash_decode(max_len, hd, v_dim=v_dim),
+            f"block attend (max_len={max_len}, head_dim={hd}, "
+            f"v_dim={v_dim})")
+        fits = gate and _block_fits_vmem(
+            max_len, hd, nkv, nh // nkv, T, itemsize,
+            *_tile_rule(bool(v_dim)))
         if gate and not fits:
             # T=1 would flash but this block cannot share its tiling:
             # the einsum fallback DIVERGES numerically from the flash
@@ -220,7 +245,9 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
     if use_flash:
         from rlo_tpu.pallas.decode import flash_block_decode
         return flash_block_decode(q, k_cache, v_cache, pos0, scale,
-                                  k_scale, v_scale)
+                                  k_scale, v_scale, v_dim=v_dim)
+    if v_dim:  # latent: the values are the stream's leading features
+        v_cache = k_cache[:, :, :v_dim]
     rep = nh // nkv
     qg = q.reshape(b, T, nkv, rep, hd)
     cache_dt = jnp.bfloat16 if (k_scale is not None and
@@ -240,12 +267,33 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
     out = jnp.einsum("bgrqk,bgdk->bqgrd", p.astype(cache_dt),
                      v_cache.astype(cache_dt),
                      preferred_element_type=jnp.float32)
-    return out.astype(jnp.float32).reshape(b, T, nh, hd)
+    return out.astype(jnp.float32).reshape(b, T, nh, v_dim or hd)
+
+
+def _mla_absorbed(q_nope, q_rope, layer, cfg, attend):
+    """Latent attention against the cache in the absorbed form: each
+    head's query is carried into the latent space (q_nope W_uk^T beside
+    the rotated dims), ``attend`` (q (b, T, H, kv_lora + rope)) ->
+    (b, T, H, kv_lora) attends the latent rows as keys and, in their
+    leading kv_lora features, as values, and W_uv brings the result
+    out: no per-head key or value is ever formed."""
+    dt = q_nope.dtype
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bthn,chn->bthc", q_nope,
+                           layer["wuk"].astype(dt))
+        q = jnp.concatenate([q_lat, q_rope], -1)
+    with jax.named_scope("mla.attend"):
+        o_lat = attend(q).astype(dt)
+    with jax.named_scope("mla.v_up"):
+        return jnp.einsum("bthc,chv->bthv", o_lat,
+                          layer["wuv"].astype(dt))
 
 
 def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
                 tp_axis: Optional[str] = None,
-                ep_axis: Optional[str] = None) -> Tuple[jax.Array, list]:
+                ep_axis: Optional[str] = None,
+                moe_info: Optional[list] = None
+                ) -> Tuple[jax.Array, list]:
     """One token (b,) int32 at position ``pos`` through all layers
     using the K/V cache. Returns (logits (b, vocab) f32, new cache).
     The layer math IS apply_layer (single source); only the attention
@@ -261,7 +309,12 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
     each shard attends its local (kv-)heads and the row-parallel
     output projections combine with the framework allreduce, exactly
     like training. MoE configs route drop-free (see _decode_cfg);
-    ``ep_axis`` shards the experts with all_to_all dispatch."""
+    ``ep_axis`` shards the experts with all_to_all dispatch.
+
+    Latent attention (``cfg.mla``): the cache holds one latent row a
+    token and layer; the step writes it (write_kv_row, as ever) and
+    attends in the absorbed form (_mla_absorbed). ``moe_info``: see
+    apply_layer."""
     cfg = _decode_cfg(cfg)
     dt = cfg.act_dtype
     posv = jnp.asarray(pos)
@@ -270,9 +323,28 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
     # (1,) shared positions, or (b, 1) per-row, for embed/rope
     pos_arr = posv[:, None] if ragged else posv[None]
     x = embed_tokens(params["embed"], token[:, None], pos_arr, cfg)
-    scale = 1.0 / (cfg.head_dim ** 0.5)
+    scale = cfg.attn_scale
     new_cache = []
     for layer, lc in zip(params["layers"], cache):
+        def attend_latent(q_nope, q_rope, latent, lc=lc, layer=layer):
+            from rlo_tpu.pallas.decode import can_write_row, write_kv_row
+            row = latent[:, 0][:, None, :]           # (b, 1, width)
+            max_len_c = lc["k"].shape[3]
+            if kernel_gate(can_write_row(max_len_c),
+                           f"cache row write (max_len={max_len_c})"):
+                kc = write_kv_row(lc["k"], row, posv)
+            elif ragged:
+                idx = (jnp.arange(b)[:, None], 0,
+                       jnp.arange(row.shape[2])[None, :], posv[:, None])
+                kc = lc["k"].at[idx].set(row[:, 0].astype(dt))
+            else:
+                kc = lax.dynamic_update_slice(
+                    lc["k"], row[..., None].astype(dt), (0, 0, 0, pos))
+            new_cache.append({"k": kc})
+            return _mla_absorbed(
+                q_nope, q_rope, layer, cfg, lambda q: _attend_cache(
+                    q, kc, None, posv, scale, v_dim=cfg.kv_lora_rank))
+
         def attend(q, k, v, lc=lc):
             # rope configs: q/k arrive rotated from apply_layer; keys
             # are cached rotated (standard RoPE decode). k/v arrive
@@ -346,11 +418,12 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
             return _attend_cache(q, kc, vc, posv, scale,
                                  k_scale=ks, v_scale=vs).astype(dt)
 
-        x, _ = apply_layer(x, layer, cfg, attention=attend,
+        x, _ = apply_layer(x, layer, cfg,
+                           attention=attend_latent if cfg.mla else attend,
                            tp_axis=tp_axis, ep_axis=ep_axis,
-                           pos=pos_arr)
-    x = _rmsnorm(x, params["ln_f"]["g"])
-    logits = (x[:, 0, :] @ params["embed"].T.astype(dt)) \
+                           pos=pos_arr, moe_info=moe_info)
+    x = _rmsnorm(x, params["ln_f"]["g"], cfg.norm_eps)
+    logits = (x[:, 0, :] @ head_weights(params).T.astype(dt)) \
         .astype(jnp.float32)
     return logits, new_cache
 
@@ -373,9 +446,29 @@ def block_decode(params: dict, tokens, pos0, cache,
     pos0 = jnp.asarray(pos0, jnp.int32).reshape(b)
     pos_arr = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)  # (b, T)
     x = embed_tokens(params["embed"], tokens, pos_arr, cfg)
-    scale = 1.0 / (cfg.head_dim ** 0.5)
+    scale = cfg.attn_scale
     new_cache = []
     for layer, lc in zip(params["layers"], cache):
+        def attend_latent(q_nope, q_rope, latent, lc=lc, layer=layer):
+            from rlo_tpu.pallas.decode import (can_write_block,
+                                               write_kv_block)
+            rows = latent.transpose(0, 2, 1)[:, None]  # (b, 1, width, T)
+            L = lc["k"].shape[3]
+            if kernel_gate(can_write_block(L) and T <= 128,
+                           f"cache block write (max_len={L}, T={T})"):
+                kc = write_kv_block(lc["k"], rows.astype(dt), pos0)
+            else:
+                kc = lc["k"].at[
+                    jnp.arange(b)[:, None, None], 0,
+                    jnp.arange(rows.shape[2])[None, :, None],
+                    pos_arr[:, None, :]].set(rows[:, 0].astype(dt))
+            new_cache.append({"k": kc})
+            return _mla_absorbed(
+                q_nope, q_rope, layer, cfg,
+                lambda q: _attend_cache_block(
+                    q, kc, None, pos_arr, scale, pos0=pos0,
+                    v_dim=cfg.kv_lora_rank))
+
         def attend(q, k, v, lc=lc):
             quant = "ks" in lc
             kt = k.transpose(0, 2, 1, 3)           # (b, kvh, T, hd)
@@ -439,12 +532,13 @@ def block_decode(params: dict, tokens, pos0, cache,
                                        k_scale=ks, v_scale=vs,
                                        pos0=pos0).astype(dt)
 
-        x, _ = apply_layer(x, layer, cfg, attention=attend,
+        x, _ = apply_layer(x, layer, cfg,
+                           attention=attend_latent if cfg.mla else attend,
                            tp_axis=tp_axis, ep_axis=ep_axis,
                            pos=pos_arr)
-    x = _rmsnorm(x, params["ln_f"]["g"])
+    x = _rmsnorm(x, params["ln_f"]["g"], cfg.norm_eps)
     logits = jnp.einsum("btd,vd->btv", x,
-                        params["embed"].astype(dt)
+                        head_weights(params).astype(dt)
                         ).astype(jnp.float32)
     return logits, new_cache
 
@@ -452,7 +546,7 @@ def block_decode(params: dict, tokens, pos0, cache,
 def prefill(params: dict, tokens, cache, cfg: TransformerConfig,
             tp_axis: Optional[str] = None,
             ep_axis: Optional[str] = None,
-            last_index=None):
+            last_index=None, moe_info: Optional[list] = None):
     """Fill the cache with the whole prompt in ONE forward pass.
     Returns (logits of the last prompt position, filled cache).
     ``last_index`` (b,) selects a PER-ROW logits position instead of
@@ -482,6 +576,12 @@ def prefill(params: dict, tokens, cache, cfg: TransformerConfig,
     reads back — so the parity is within matmul association error,
     not the quantization envelope); measured ~two orders of magnitude
     faster at plen 1024 on the v5e chip (decode_bench.py --ttft).
+
+    Latent attention (``cfg.mla``): the block is attended in the plain
+    form (every head's keys and values decompressed from the latent:
+    mla_unabsorbed) and the hook stashes the LATENT rows, the same rows
+    decode_step writes and then attends absorbed. ``moe_info``: see
+    apply_layer.
     """
     b, plen = tokens.shape
     if last_index is not None:
@@ -491,6 +591,12 @@ def prefill(params: dict, tokens, cache, cfg: TransformerConfig,
     x = embed_tokens(params["embed"], tokens, pos, cfg)
     new_cache = []
     for layer, lc in zip(params["layers"], cache):
+        def attend_latent(q_nope, q_rope, latent, lc=lc, layer=layer):
+            new_cache.append({"k": lax.dynamic_update_slice(
+                lc["k"], latent.transpose(0, 2, 1)[:, None].astype(dt),
+                (0, 0, 0, 0))})
+            return mla_unabsorbed(q_nope, q_rope, latent, layer, cfg)
+
         def attend(q, k, v, lc=lc):
             # k/v arrive (b, plen, kvh, hd); the cache is head-leading
             # and SEQ-MINOR: (b, kvh, hd, plen)
@@ -530,16 +636,18 @@ def prefill(params: dict, tokens, cache, cfg: TransformerConfig,
             from rlo_tpu.models.transformer import _local_attention
             return _local_attention(q, k, v).astype(dt)
 
-        x, _ = apply_layer(x, layer, cfg, attention=attend,
-                           tp_axis=tp_axis, ep_axis=ep_axis, pos=pos)
-    x = _rmsnorm(x, params["ln_f"]["g"])
+        x, _ = apply_layer(x, layer, cfg,
+                           attention=attend_latent if cfg.mla else attend,
+                           tp_axis=tp_axis, ep_axis=ep_axis, pos=pos,
+                           moe_info=moe_info)
+    x = _rmsnorm(x, params["ln_f"]["g"], cfg.norm_eps)
     if last_index is None:
         xl = x[:, -1, :]
     else:
         idx = jnp.asarray(last_index, jnp.int32)[:, None, None]
         xl = jnp.take_along_axis(
             x, jnp.broadcast_to(idx, (b, 1, x.shape[-1])), axis=1)[:, 0]
-    logits = (xl @ params["embed"].T.astype(dt)).astype(jnp.float32)
+    logits = (xl @ head_weights(params).T.astype(dt)).astype(jnp.float32)
     return logits, new_cache
 
 

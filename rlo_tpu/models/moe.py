@@ -26,6 +26,20 @@ dynamic shapes, so XLA tiles it onto the MXU):
     ships results back;
   - aux load-balancing loss (Switch Transformer form):
     E * sum_e fraction_dispatched(e) * mean_gate_prob(e).
+
+The second layer here, ``routed_ffn`` (``moe_router='sigmoid_group'``),
+is sparse experts as large open models deploy them, for the serving
+path: sigmoid scores in float32, a bias that moves the selection only,
+the top ``experts_per_tok`` experts among the best ``topk_group`` of
+``n_group`` groups, their scores normalised to sum 1 and times
+``routed_scale``, ``n_shared_experts`` always-on experts, gated FFNs,
+and NO dropped token at any skew. The layer is told which experts it
+holds (``expert_first``, ``n_experts_held``): it routes over all
+``n_experts`` and adds the terms of its own experts and of the shared
+expert — one chip's share of an expert-parallel layer. On one chip
+there is no exchange; what the absent experts would add is left out.
+On the TPU the held experts run as one grouped product over rows
+sorted by expert (pallas.expert_ffn), elsewhere as a dense einsum.
 """
 
 from __future__ import annotations
@@ -129,3 +143,185 @@ def moe_ffn(params: dict, h, n_experts: int, *,
     out = jnp.einsum("tec,ecd->td", combine, expert_out,
                      preferred_element_type=jnp.float32)
     return out.reshape(orig_shape).astype(dt), aux
+
+
+# ---- sigmoid-scored, group-limited, dropless experts -----------------
+
+#: per-layer counts routed_ffn reports, in this order (int32)
+STATS = ("tokens", "assignments_held", "rows_computed", "experts_hit",
+         "dropped")
+
+
+def init_routed_params(rng: jax.Array, cfg) -> dict:
+    """Router (d, n_experts) and its selection bias ``br``, the held
+    experts' gated FFNs — ``wg``/``wu`` (held, d, f), ``wd``
+    (held, f, d) — and the shared expert's (``swg``/``swu``/``swd``,
+    width f x n_shared_experts), stored in ``cfg.param_dtype``. The
+    bias is drawn at 0.01, twice the median gap between the last expert
+    chosen and the first left out: it changes most selections while
+    every expert stays about equally loaded, which is what the bias is
+    trained for."""
+    d, f, pdt = cfg.d_model, cfg.moe_d_ff, cfg.weight_dtype
+    held = cfg.experts_held
+    ks = jax.random.split(rng, 8)
+
+    def norm(key, shape, scale):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * scale).astype(pdt)
+
+    out_scale = (2 * f * cfg.n_layers) ** -0.5
+    p = {"wr": norm(ks[0], (d, cfg.n_experts), d ** -0.5),
+         "br": jax.random.normal(ks[1], (cfg.n_experts,),
+                                 jnp.float32) * 0.01,
+         "wg": norm(ks[2], (held, d, f), d ** -0.5),
+         "wu": norm(ks[3], (held, d, f), d ** -0.5),
+         "wd": norm(ks[4], (held, f, d), out_scale)}
+    if cfg.n_shared_experts:
+        fs = f * cfg.n_shared_experts
+        p.update(swg=norm(ks[5], (d, fs), d ** -0.5),
+                 swu=norm(ks[6], (d, fs), d ** -0.5),
+                 swd=norm(ks[7], (fs, d), out_scale))
+    return p
+
+
+def route(x, wr, br, *, n_group: int, topk_group: int, top_k: int,
+          routed_scale: float):
+    """Tokens ``x`` (T, d) -> (ids (T, k) int32, weights (T, k) f32,
+    choice scores (T, E) f32). Scores are sigmoid(x wr) in float32 at
+    full matmul precision; the choice is made on scores + ``br``: a
+    group's score is the sum of its top 2, the best ``topk_group``
+    groups stay, the top ``top_k`` experts among them are chosen. The
+    weights are the chosen experts' scores WITHOUT the bias, normalised
+    to sum 1, times ``routed_scale``."""
+    t = x.shape[0]
+    n_exp = wr.shape[1]
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), wr.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    choice = scores + br.astype(jnp.float32)
+    grouped = choice.reshape(t, n_group, n_exp // n_group)
+    group_score = lax.top_k(grouped, 2)[0].sum(-1)        # (T, G)
+    _, kept = lax.top_k(group_score, topk_group)
+    keep = jnp.zeros((t, n_group), bool).at[
+        jnp.arange(t)[:, None], kept].set(True)
+    masked = jnp.where(keep[:, :, None], grouped, -jnp.inf)
+    _, ids = lax.top_k(masked.reshape(t, n_exp), top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * routed_scale
+    return ids.astype(jnp.int32), w, choice
+
+
+def row_tile(n_assign: int, n_experts: int) -> int:
+    """Rows of one tile of the grouped product: a power of two from 16
+    (a bf16 sublane tile) to 256, about twice the rows one of the
+    ``n_experts`` routed over expects, so that an expert's rows mostly
+    fit one tile and its weights stream once."""
+    want = max(16, 2 * n_assign // max(n_experts, 1))
+    return min(256, 1 << (want - 1).bit_length())
+
+
+def _gated(x, wg, wu, wd):
+    dt = x.dtype
+    gate = jax.nn.silu(x @ wg.astype(dt))
+    return (gate * (x @ wu.astype(dt))) @ wd.astype(dt)
+
+
+def held_experts_ffn(params: dict, x, ids, w, first: int,
+                     use_kernel=None, interpret=None):
+    """Sum over the held experts e in [first, first + held) of
+    w[t, e] * ffn_e(x[t]) for tokens ``x`` (T, d) -> ((T, d) f32,
+    stats). Kernel path: every (token, choice) that landed on a held
+    expert becomes one row; rows are sorted by expert, each expert's
+    run is padded to whole tiles of ``row_tile`` rows, and
+    pallas.expert_ffn runs the live tiles (an expert with no row costs
+    nothing). The buffer holds the worst case — every assignment here —
+    so nothing is ever dropped. Oracle path: every held expert on every
+    token, masked."""
+    from rlo_tpu.pallas import expert_ffn as ek
+    from rlo_tpu.pallas.reduce import kernel_gate
+    t, d = x.shape
+    k = ids.shape[1]
+    held, _, f = params["wg"].shape
+    n_assign = t * k
+    tm = row_tile(n_assign, params["wr"].shape[1])
+    local = ids - first
+    here = (local >= 0) & (local < held)                 # (T, k)
+    # (T, k, held): which held expert a choice landed on, if any
+    onto = jax.nn.one_hot(jnp.where(here, local, held), held + 1,
+                          dtype=jnp.float32)[..., :held]
+    counts = jnp.sum(onto, axis=(0, 1)).astype(jnp.int32)  # (held,)
+    padded = -(-counts // tm) * tm
+    n_rows = ek.buffer_rows(n_assign, held, tm)
+    placed = jnp.minimum(jnp.sum(padded), n_rows)  # rows in live tiles
+
+    def stats(dropped):
+        return jnp.stack([
+            jnp.int32(t), jnp.sum(counts), placed,
+            jnp.sum(counts > 0).astype(jnp.int32),
+            jnp.asarray(dropped, jnp.int32)])
+    if use_kernel is None:
+        ok = ek.can_expert_ffn(d, f, tm)
+        use_kernel = ok if interpret else kernel_gate(
+            ok, f"expert_ffn (d={d}, f={f}, tile={tm})")
+    if not use_kernel:
+        # every held expert on every token; weights of the choices that
+        # landed here, summed per expert
+        we = jnp.sum(onto * w[..., None], axis=1)        # (T, held)
+        dt = x.dtype
+        gate = jax.nn.silu(jnp.einsum("td,edf->tef", x,
+                                      params["wg"].astype(dt)))
+        up = jnp.einsum("td,edf->tef", x, params["wu"].astype(dt))
+        out = jnp.einsum("tef,efd->ted", gate * up,
+                         params["wd"].astype(dt))
+        return (jnp.einsum("te,ted->td", we, out.astype(jnp.float32)),
+                stats(0))
+
+    # rows sorted by expert; an assignment elsewhere sorts last
+    key = jnp.where(here, local, held).reshape(n_assign)
+    order = jnp.argsort(key, stable=True)
+    rank = jnp.zeros((n_assign,), jnp.int32).at[order].set(
+        jnp.arange(n_assign, dtype=jnp.int32))           # sorted position
+    start = jnp.cumsum(counts) - counts                  # unpadded
+    pstart = jnp.cumsum(padded) - padded                 # tile-aligned
+    safe = jnp.minimum(key, held - 1)
+    dest = jnp.where(key < held,
+                     pstart[safe] + rank - start[safe], n_rows)
+    token_of_row = jnp.zeros((n_rows,), jnp.int32).at[dest].set(
+        jnp.arange(n_assign, dtype=jnp.int32) // k, mode="drop")
+    n_tiles = n_rows // tm
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        jnp.cumsum(padded), jnp.arange(n_tiles, dtype=jnp.int32) * tm,
+        side="right"), held - 1).astype(jnp.int32)
+    n_live = (placed // tm).astype(jnp.int32)
+    rows = ek.expert_ffn(x[token_of_row], params["wg"], params["wu"],
+                         params["wd"], tile_expert, n_live, tile=tm,
+                         interpret=interpret)            # (n_rows, d)
+    # back to tokens: each choice reads its own row (a select, never a
+    # product: a tile that did not run holds no number)
+    picked = rows[jnp.minimum(dest, n_rows - 1).reshape(t, k)]
+    picked = jnp.where(here[..., None], picked.astype(jnp.float32), 0.0)
+    dropped = jnp.sum((key < held) & (dest >= n_rows))
+    return jnp.einsum("tk,tkd->td", w, picked), stats(dropped)
+
+
+def routed_ffn(params: dict, h, cfg):
+    """The 'sigmoid_group' expert layer on ``h`` (..., d): routed terms
+    of the held experts plus the shared expert. Returns (out, info);
+    ``info`` = {"ids" (T, k), "choice" (T, E) f32: the scores the
+    selection was made on, "stats" int32 (5,) in STATS order}."""
+    shape, dt = h.shape, h.dtype
+    x = h.reshape(-1, shape[-1])
+    with jax.named_scope("moe.route"):
+        ids, w, choice = route(
+            x, params["wr"], params["br"], n_group=cfg.n_group,
+            topk_group=cfg.topk_group, top_k=cfg.experts_per_tok,
+            routed_scale=cfg.routed_scale)
+    with jax.named_scope("moe.experts"):
+        out, stats = held_experts_ffn(params, x, ids, w,
+                                      cfg.expert_first)
+    if "swg" in params:
+        with jax.named_scope("moe.shared"):
+            out = out + _gated(x, params["swg"], params["swu"],
+                               params["swd"]).astype(jnp.float32)
+    info = {"ids": ids, "choice": choice, "stats": stats}
+    return out.reshape(shape).astype(dt), info

@@ -59,6 +59,7 @@ from jax import lax
 from rlo_tpu.models.generate import (block_decode, decode_step,
                                      init_kv_cache, prefill,
                                      _decode_cfg)
+from rlo_tpu.models import moe
 from rlo_tpu.models.transformer import TransformerConfig
 from rlo_tpu.observe.spans import Stage
 from rlo_tpu.pallas.decode import can_flash_decode, flash_decode_tile
@@ -121,7 +122,14 @@ class DecodeServer:
     ``serve.slot_steps_useful``, ``serve.attend_tiles`` against
     ``serve.attend_tiles_live`` (dense scheduler, flash_decode path:
     cache tiles in the round's grid, and those a row's live context
-    reaches — the rest the kernel skips), and ``serve.retraces`` (with
+    reaches — the rest the kernel skips), the gauge
+    ``serve.cache_bytes_per_token`` (dense scheduler: what one position
+    of one slot holds over all layers), ``serve.moe.<count>`` for
+    'sigmoid_group' expert layers (models.moe.STATS, summed over the
+    round's steps and layers on the device and read back with the
+    tokens: ``tokens``, ``assignments_held``, ``rows_computed`` — tile
+    padding included —, ``experts_hit``, ``dropped``, which stays 0),
+    and ``serve.retraces`` (with
     ``serve.retraces.<fn>``): trace-cache entries of the server's own
     jitted functions beyond the shapes it was built for.
 
@@ -208,22 +216,37 @@ class DecodeServer:
         # attends through the einsum (off the tpu backend, or a shape
         # can_flash_decode refuses): nothing is tiled, nothing counted
         k = self.cache[0]["k"]
+        self.metrics.gauge("serve.cache_bytes_per_token").set(
+            sum(a.size * a.dtype.itemsize
+                for a in jax.tree.leaves(self.cache))
+            // (n_slots * k.shape[3]))
+        latent = cfg.kv_lora_rank if cfg.mla else 0
         self._attend_tiling = None
-        if _on_tpu() and can_flash_decode(k.shape[3], k.shape[2]):
-            bk = flash_decode_tile(k, cfg.n_heads)
+        if _on_tpu() and can_flash_decode(k.shape[3], k.shape[2],
+                                          v_dim=latent):
+            bk = flash_decode_tile(k, cfg.n_heads, latent=cfg.mla)
             self._attend_tiling = (bk, -(-k.shape[3] // bk))
+        # 'sigmoid_group' expert layers report their routing counts
+        # (models.moe.STATS): the round then carries their sum over its
+        # steps and layers and returns it as a fifth output
+        moe_stats = cfg.moe_router == "sigmoid_group" and any(
+            "moe" in layer for layer in params["layers"])
 
         def round_fn(params, cache, last_tok, pos, kk):
             def body(carry, _):
-                tok, pos, cache = carry
+                tok, pos, cache, *stats = carry
+                info = []
                 logits, cache = decode_step(params, tok, pos, cache,
-                                            cfg_d)
+                                            cfg_d, moe_info=info)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (tok, pos + 1, cache), tok
+                stats = [n + sum(i["stats"] for i in info) for n in stats]
+                return (tok, pos + 1, cache, *stats), tok
 
-            (tok, pos, cache), toks = lax.scan(
-                body, (last_tok, pos, cache), None, length=kk)
-            return tok, pos, cache, jnp.transpose(toks)  # (b, kk)
+            stats = ([jnp.zeros((len(moe.STATS),), jnp.int32)]
+                     if moe_stats else [])
+            (tok, pos, cache, *stats), toks = lax.scan(
+                body, (last_tok, pos, cache, *stats), None, length=kk)
+            return (tok, pos, cache, jnp.transpose(toks), *stats)  # (b, kk)
 
         # donate the pool cache: without aliasing, every round would
         # double-buffer the full n_slots x max_len cache in HBM
@@ -741,7 +764,7 @@ class DecodeServer:
                 if self.req_of_slot[s] is not None))))
         t0 = time.perf_counter()
         with self._span("round.dispatch"):
-            tok, pos, cache, toks = self._round(
+            tok, pos, cache, toks, *stats = self._round(
                 self.params, self.cache, jnp.asarray(self.last_tok),
                 jnp.asarray(self.pos), kk)
             self.cache = cache
@@ -752,6 +775,9 @@ class DecodeServer:
         with self._span("round.readback"):
             self.last_tok = np.asarray(tok).copy()
             self.pos = np.asarray(pos).copy()
+            for counts in stats:    # expert layers' counts, if any
+                for name, n in zip(moe.STATS, np.asarray(counts)):
+                    self.metrics.counter("serve.moe." + name).inc(int(n))
         dt = time.perf_counter() - t0  # toks materialized: round done
         self._observe_round(dt, kk)
         self._distribute(toks, kk)

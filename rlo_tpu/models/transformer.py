@@ -49,7 +49,7 @@ class TransformerConfig:
     n_heads: int = 4
     n_layers: int = 2
     d_ff: int = 512
-    dtype: str = "bfloat16"  # activation dtype; params stay float32
+    dtype: str = "bfloat16"  # activation dtype (params: param_dtype)
     # mixture-of-experts FFN (0 = dense). Experts shard over `ep_axis`
     # with all_to_all dispatch/return — see rlo_tpu.models.moe.
     n_experts: int = 0
@@ -111,11 +111,86 @@ class TransformerConfig:
     # 10-15% SLOWER at vocab 32k (the scan serializes the head matmul
     # and the checkpointed backward recomputes it), so 0/None = off.
     loss_vocab_chunk: Optional[int] = None
+    # ---- what a published configuration sets (the "model" section of
+    # perf/configs/<name>.json; README "Model settings"). The defaults
+    # are the block every earlier configuration runs.
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    # storage dtype of the weights ('float32' | 'bfloat16'); every use
+    # casts to the activation dtype
+    param_dtype: str = "float32"
+    # False: a separate output head ``params["head"]`` (vocab, d)
+    tie_embeddings: bool = True
+    # 'gelu': two matrices w1/w2; 'swiglu': down(silu(gate x) * up x)
+    # with three (wg/wu/wd), of width d_ff
+    ffn: str = "gelu"
+    # latent attention (MLA), selected by kv_lora_rank > 0: queries
+    # through a q_lora_rank bottleneck, keys and values through one
+    # shared kv_lora_rank latent plus qk_rope_head_dim rotated dims that
+    # all heads share; the decode cache holds that latent row only
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # rope_scaling='yarn' (rope_scale is its factor): per-dimension
+    # blend of interpolated and extrapolated frequencies (_yarn_freqs)
+    rope_original_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # expert layers. moe_router 'switch' is models.moe.moe_ffn (top-1,
+    # static capacity); 'sigmoid_group' is models.moe.routed_ffn:
+    # sigmoid scores, a selection-only bias, top experts_per_tok among
+    # the topk_group best of n_group groups, normalised weights times
+    # routed_scale, n_shared_experts always-on experts, no token
+    # dropped. The first n_dense_layers layers keep the dense FFN. The
+    # layer holds experts [expert_first, expert_first + n_experts_held)
+    # of the n_experts it routes over (0 = all of them) and computes
+    # their part of the result.
+    moe_router: str = "switch"
+    n_dense_layers: int = 0
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    experts_per_tok: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    expert_first: int = 0
+    n_experts_held: int = 0
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def head_dim(self) -> int:
+        if self.mla:  # width of a query/key head
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale: head_dim^-0.5, times yarn's m^2 where the
+        configuration sets mscale_all_dim."""
+        scale = self.head_dim ** -0.5
+        if self.rope_scaling == "yarn" and self.rope_mscale_all_dim:
+            m = _yarn_mscale(self.rope_scale, self.rope_mscale_all_dim)
+            scale *= m * m
+        return scale
+
+    @property
+    def weight_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+    def layer_is_moe(self, i: int) -> bool:
+        return self.n_experts > 0 and i >= self.n_dense_layers
 
     @property
     def kv_heads(self) -> int:
@@ -132,47 +207,95 @@ class TransformerConfig:
 
 
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
-    """Scaled-normal init; embedding tied with the output head.
+    """Scaled-normal init in ``cfg.param_dtype``; the embedding is tied
+    with the output head unless ``cfg.tie_embeddings`` is off (then
+    ``head`` (vocab, d)).
 
     ``wqkv`` has shape (d, 3, d): axis 1 selects q/k/v and axis 2 is
     (heads x head_dim) flattened, so sharding axis 2 over `tp` splits
     each of q, k, v by head (the memory layout equals the fused
-    (d, 3*d) [q|k|v] matrix)."""
+    (d, 3*d) [q|k|v] matrix). Latent attention (``cfg.mla``) has
+    ``wdq`` (d, q_lora), ``q_norm``, ``wuq`` (q_lora, heads x
+    (nope + rope)), ``wdkv`` (d, kv_lora + rope), ``kv_norm``, ``wuk``
+    (kv_lora, heads, nope) and ``wuv`` (kv_lora, heads, v) — two
+    tensors: the absorbed decode uses each alone, and a slice of a
+    fused one is a copy of it every step — and ``wo`` (heads x v, d).
+    A gated FFN
+    has ``wg``/``wu`` (d, f) and ``wd`` (f, d); expert layers hold
+    ``moe`` (models.moe)."""
     keys = jax.random.split(rng, 2 + 6 * cfg.n_layers)
     d, f = cfg.d_model, cfg.d_ff
+    pdt = cfg.weight_dtype
 
     def norm(key, shape, scale):
-        return (jax.random.normal(key, shape, jnp.float32) * scale)
+        return (jax.random.normal(key, shape, jnp.float32)
+                * scale).astype(pdt)
+
+    def ones(n):
+        return jnp.ones((n,), pdt)
 
     params = {
         "embed": norm(keys[0], (cfg.vocab, d), 0.02),
-        "ln_f": {"g": jnp.ones((d,), jnp.float32)},
+        "ln_f": {"g": ones(d)},
         "layers": [],
     }
+    if not cfg.tie_embeddings:
+        params["head"] = norm(keys[1], (cfg.vocab, d), d ** -0.5)
     k = 2
-    for _ in range(cfg.n_layers):
+    for i in range(cfg.n_layers):
+        att_out = cfg.n_heads * cfg.v_head_dim if cfg.mla else d
         layer = {
-            "ln1": {"g": jnp.ones((d,), jnp.float32)},
-            "wo": norm(keys[k + 1], (d, d), (2 * d * cfg.n_layers) ** -0.5),
-            "ln2": {"g": jnp.ones((d,), jnp.float32)},
+            "ln1": {"g": ones(d)},
+            "wo": norm(keys[k + 1], (att_out, d),
+                       (2 * att_out * cfg.n_layers) ** -0.5),
+            "ln2": {"g": ones(d)},
         }
-        if cfg.kv_heads == cfg.n_heads:
+        if cfg.mla:
+            ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
+            nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+            k1, k2, k3, k4, k5 = jax.random.split(keys[k], 5)
+            layer["wdq"] = norm(k1, (d, ql), d ** -0.5)
+            layer["q_norm"] = {"g": ones(ql)}
+            layer["wuq"] = norm(k2, (ql, cfg.n_heads * (nope + rope)),
+                                ql ** -0.5)
+            layer["wdkv"] = norm(k3, (d, kl + rope), d ** -0.5)
+            layer["kv_norm"] = {"g": ones(kl)}
+            layer["wuk"] = norm(k4, (kl, cfg.n_heads, nope), kl ** -0.5)
+            layer["wuv"] = norm(k5, (kl, cfg.n_heads, cfg.v_head_dim),
+                                kl ** -0.5)
+        elif cfg.kv_heads == cfg.n_heads:
             layer["wqkv"] = norm(keys[k], (d, 3, d), d ** -0.5)
         else:  # GQA: smaller K/V projections, separate q
             dkv = cfg.kv_heads * cfg.head_dim
             kq, kkv = jax.random.split(keys[k])
             layer["wq"] = norm(kq, (d, d), d ** -0.5)
             layer["wkv"] = norm(kkv, (d, 2, dkv), d ** -0.5)
-        if cfg.n_experts > 0:
+        if cfg.layer_is_moe(i) and cfg.moe_router == "sigmoid_group":
+            layer["moe"] = moe.init_routed_params(keys[k + 2], cfg)
+        elif cfg.layer_is_moe(i):
             layer["moe"] = moe.init_moe_params(keys[k + 2], d, f,
                                                cfg.n_experts)
-        else:
+        elif cfg.ffn == "swiglu":
+            layer["wg"] = norm(keys[k + 2], (d, f), d ** -0.5)
+            layer["wu"] = norm(keys[k + 4], (d, f), d ** -0.5)
+            layer["wd"] = norm(keys[k + 3], (f, d),
+                               (2 * f * cfg.n_layers) ** -0.5)
+        elif cfg.ffn == "gelu":
             layer["w1"] = norm(keys[k + 2], (d, f), d ** -0.5)
             layer["w2"] = norm(keys[k + 3], (f, d),
                                (2 * f * cfg.n_layers) ** -0.5)
+        else:
+            raise ValueError(f"unknown ffn {cfg.ffn!r}; known: 'gelu', "
+                             f"'swiglu'")
         params["layers"].append(layer)
         k += 6
     return params
+
+
+def head_weights(params: dict):
+    """The output head (vocab, d): ``head`` where the configuration
+    unties it, else the embedding."""
+    return params.get("head", params["embed"])
 
 
 def param_pspecs(cfg: TransformerConfig, tp_axis: Optional[str] = None,
@@ -186,6 +309,12 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: Optional[str] = None,
     Everything else is replicated. Pass as shard_map in/out specs for the
     params argument."""
     from jax.sharding import PartitionSpec as P
+    if cfg.mla or cfg.moe_router != "switch" or cfg.ffn != "gelu" \
+            or not cfg.tie_embeddings:
+        raise ValueError(
+            "param_pspecs knows the MHA/GQA block with a gelu FFN and "
+            "switch experts; latent attention, gated FFNs, an untied "
+            "head and sigmoid_group experts run on one chip so far")
     t = tp_axis
     layer = {
         "ln1": {"g": P()},
@@ -208,10 +337,10 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: Optional[str] = None,
                        for _ in range(cfg.n_layers)]}
 
 
-def _rmsnorm(x, g):
+def _rmsnorm(x, g, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
                    keepdims=True)
-    return (x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * g.astype(
+    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * g.astype(
         x.dtype)
 
 
@@ -236,6 +365,13 @@ def embed_tokens(embed, tokens, pos, cfg: TransformerConfig):
         raise ValueError(
             f"unknown pos_encoding {cfg.pos_encoding!r}; "
             f"known: 'sincos', 'rope'")
+    if cfg.mla and (cfg.pos_encoding != "rope" or cfg.qk_rope_head_dim % 2
+                    or min(cfg.q_lora_rank, cfg.qk_nope_head_dim,
+                           cfg.qk_rope_head_dim, cfg.v_head_dim) < 1):
+        raise ValueError(
+            "latent attention (kv_lora_rank > 0) needs pos_encoding="
+            "'rope', q_lora_rank, qk_nope_head_dim, v_head_dim and an "
+            "even qk_rope_head_dim")
     if cfg.pos_encoding == "rope" and cfg.head_dim % 2:
         raise ValueError(
             f"rope rotates (i, i+head_dim/2) dim pairs and needs an "
@@ -246,10 +382,10 @@ def embed_tokens(embed, tokens, pos, cfg: TransformerConfig):
             raise ValueError(
                 f"rope_scaling={cfg.rope_scaling!r} requires "
                 f"pos_encoding='rope' (got {cfg.pos_encoding!r})")
-        if cfg.rope_scaling not in ("linear", "ntk"):
+        if cfg.rope_scaling not in ("linear", "ntk", "yarn"):
             raise ValueError(
                 f"unknown rope_scaling {cfg.rope_scaling!r}; "
-                f"known: 'linear', 'ntk'")
+                f"known: 'linear', 'ntk', 'yarn'")
         if cfg.rope_scale < 1.0:
             raise ValueError(
                 f"rope_scale must be >= 1 (an extension factor); got "
@@ -260,7 +396,44 @@ def embed_tokens(embed, tokens, pos, cfg: TransformerConfig):
     return x
 
 
-def _rope(t, pos, scaling: Optional[str] = None, scale: float = 1.0):
+def _yarn_mscale(scale: float, mscale: float) -> float:
+    return 0.1 * mscale * float(np.log(scale)) + 1.0 if scale > 1 else 1.0
+
+
+def _yarn_freqs(hd: int, base: float, factor: float, original_len: int,
+                beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's hd/2 rotation frequencies: dimension pair i rotates at
+    base^(-2i/hd) (extrapolated, kept for the pairs that turn more than
+    ``beta_fast`` times over ``original_len``), at that over ``factor``
+    (interpolated, for those that turn fewer than ``beta_slow`` times),
+    and at a linear blend over the ramp between the two correction
+    dims."""
+    half = hd // 2
+    extra = base ** (-np.arange(half, dtype=np.float64) / half)
+    inter = extra / factor
+
+    def correction_dim(n_rot):
+        return hd * np.log(original_len / (n_rot * 2 * np.pi)) / (
+            2 * np.log(base))
+
+    low = max(int(np.floor(correction_dim(beta_fast))), 0)
+    high = min(int(np.ceil(correction_dim(beta_slow))), hd - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def _rope_cfg(t, pos, cfg: TransformerConfig):
+    """_rope with the configuration's base, scaling and yarn settings."""
+    return _rope(t, pos, cfg.rope_scaling, cfg.rope_scale,
+                 base=cfg.rope_theta, yarn=(
+                     cfg.rope_original_len, cfg.rope_beta_fast,
+                     cfg.rope_beta_slow, cfg.rope_mscale,
+                     cfg.rope_mscale_all_dim))
+
+
+def _rope(t, pos, scaling: Optional[str] = None, scale: float = 1.0,
+          base: float = 10000.0, yarn=None):
     """Rotary position embedding: rotate dim pairs (i, i+hd/2) of
     ``t`` (b, blk, heads, head_dim) by position-dependent angles
     (pos (blk,) GLOBAL token positions — sp shards pass their own
@@ -275,19 +448,30 @@ def _rope(t, pos, scaling: Optional[str] = None, scale: float = 1.0):
     'linear' divides positions by ``scale`` (position interpolation —
     identical to evaluating the unscaled rotation at pos/scale); 'ntk'
     rescales the base by scale^(hd/(hd-2)) so the lowest frequency's
-    period grows ~scale-fold while the highest stays ~unchanged."""
+    period grows ~scale-fold while the highest stays ~unchanged; 'yarn'
+    blends both per dimension (_yarn_freqs; ``yarn`` = (original_len,
+    beta_fast, beta_slow, mscale, mscale_all_dim)) and multiplies cos
+    and sin by mscale(scale, mscale) / mscale(scale, mscale_all_dim)."""
     hd = t.shape[-1]
     half = hd // 2
-    base = 10000.0
     posf = pos.astype(jnp.float32)
+    amp = 1.0
     if scaling == "linear":
         posf = posf / scale
     elif scaling == "ntk":
         base = base * float(scale) ** (hd / (hd - 2))
+    elif scaling == "yarn":
+        orig, fast, slow, msc, msc_all = yarn
+        amp = _yarn_mscale(scale, msc) / _yarn_mscale(scale, msc_all)
     elif scaling is not None:
         raise ValueError(
-            f"unknown rope_scaling {scaling!r}; known: 'linear', 'ntk'")
-    freqs = jnp.exp(-np.log(base) * jnp.arange(half) / half)
+            f"unknown rope_scaling {scaling!r}; known: 'linear', 'ntk', "
+            f"'yarn'")
+    if scaling == "yarn":
+        freqs = jnp.asarray(_yarn_freqs(hd, base, scale, orig, fast,
+                                        slow))
+    else:
+        freqs = jnp.exp(-np.log(base) * jnp.arange(half) / half)
     ang = posf[..., None] * freqs          # (blk, half) | (b, blk, half)
     if ang.ndim == 2:
         cos = jnp.cos(ang)[None, :, None, :]
@@ -295,16 +479,23 @@ def _rope(t, pos, scaling: Optional[str] = None, scale: float = 1.0):
     else:                                  # per-row positions
         cos = jnp.cos(ang)[:, :, None, :]
         sin = jnp.sin(ang)[:, :, None, :]
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     t32 = t.astype(jnp.float32)
     t1, t2 = t32[..., :half], t32[..., half:]
     return jnp.concatenate([t1 * cos - t2 * sin,
                             t1 * sin + t2 * cos], -1).astype(t.dtype)
 
 
-def _local_attention(q, k, v, use_flash=None, interpret=None):
+def _local_attention(q, k, v, use_flash=None, interpret=None,
+                     scale: Optional[float] = None):
     """Unsharded causal attention: q (b, L, H, D); k/v (b, L, Hkv, D)
     with Hkv ≤ H (grouped-query attention — query head h attends K/V
-    head h // (H/Hkv)).
+    head h // (H/Hkv)). ``scale`` defaults to D^-0.5. ``v`` may be
+    narrower than q and k (latent attention: 128 beside 192): the
+    kernel has one width, so v rides zero-padded to D and the output is
+    cut back — half again the PV products and V bytes it needs (PERF.md
+    §7); the oracle attends it as it is.
 
     On TPU this is the fused flash kernel (pallas/flash.py — trainable
     since the custom_vjp landed): the batch folds into the head axis
@@ -337,16 +528,87 @@ def _local_attention(q, k, v, use_flash=None, interpret=None):
             k = jnp.repeat(k, g, axis=2)
             v = jnp.repeat(v, g, axis=2)
         return jax.vmap(lambda q_, k_, v_: full_attention(
-            q_, k_, v_, causal=True))(q, k, v)
+            q_, k_, v_, causal=True, scale=scale))(q, k, v)
     from rlo_tpu.pallas.flash import flash_attention
+    vd = v.shape[-1]
+    if vd != hd:
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, hd - vd),))
 
     def fold(t):
         n = t.shape[2]
         return t.transpose(1, 0, 2, 3).reshape(L, b * n, hd)
 
     out = flash_attention(fold(q), fold(k), fold(v), causal=True,
-                          block_q=bq, interpret=interpret)
-    return out.reshape(L, b, nh, hd).transpose(1, 0, 2, 3)
+                          scale=scale, block_q=bq, interpret=interpret)
+    return out.reshape(L, b, nh, hd).transpose(1, 0, 2, 3)[..., :vd]
+
+
+def mla_project(h, layer: dict, cfg: TransformerConfig, pos):
+    """Latent attention's projections of the normed activation ``h``
+    (b, blk, d): (q_nope (b, blk, H, nope), q_rope (b, blk, H, rope)
+    rotated, latent (b, blk, kv_lora + rope)). The latent row is
+    [rms(c_kv) | rope(k_r)]: what the decode cache stores, once per
+    token and layer, whatever the number of heads."""
+    b, blk, _ = h.shape
+    dt = h.dtype
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    with jax.named_scope("mla.down"):
+        c_q = _rmsnorm(h @ layer["wdq"].astype(dt),
+                       layer["q_norm"]["g"], cfg.norm_eps)
+        ckv = h @ layer["wdkv"].astype(dt)
+        c_kv = _rmsnorm(ckv[..., :cfg.kv_lora_rank],
+                        layer["kv_norm"]["g"], cfg.norm_eps)
+        k_r = _rope_cfg(ckv[..., None, cfg.kv_lora_rank:], pos,
+                        cfg)[:, :, 0]
+    with jax.named_scope("mla.q_up"):
+        q = (c_q @ layer["wuq"].astype(dt)).reshape(
+            b, blk, cfg.n_heads, nope + rope)
+        q_rope = _rope_cfg(q[..., nope:], pos, cfg)
+    return q[..., :nope], q_rope, jnp.concatenate([c_kv, k_r], -1)
+
+
+def mla_unabsorbed(q_nope, q_rope, latent, layer: dict,
+                   cfg: TransformerConfig):
+    """Causal latent attention over a whole block in its plain form:
+    every head's k_nope and v are decompressed from the latent, the
+    rotated key dims are shared by all heads. Returns (b, blk, H, v)."""
+    b, blk, nh, nope = q_nope.shape
+    dt = q_nope.dtype
+    kl = cfg.kv_lora_rank
+    with jax.named_scope("mla.kv_up"):
+        c_kv = latent[..., :kl]
+        k_nope = jnp.einsum("btc,chn->bthn", c_kv, layer["wuk"].astype(dt))
+        v = jnp.einsum("btc,chv->bthv", c_kv, layer["wuv"].astype(dt))
+        k_r = jnp.broadcast_to(latent[:, :, None, kl:],
+                               (b, blk, nh, cfg.qk_rope_head_dim))
+        k = jnp.concatenate([k_nope, k_r], -1)
+        q = jnp.concatenate([q_nope, q_rope], -1)
+    with jax.named_scope("mla.attend"):
+        return _local_attention(q, k, v, scale=cfg.attn_scale)
+
+
+def _ffn(h, layer: dict, cfg: TransformerConfig, tp_sum, ep_axis,
+         moe_info):
+    """The layer's second sublayer on the normed ``h``: which kind is
+    read off the layer's own parameters (leading dense layers and
+    expert layers share one model). Returns (out, aux)."""
+    dt = h.dtype
+    zero = jnp.zeros((), jnp.float32)
+    if "moe" in layer and cfg.moe_router == "sigmoid_group":
+        out, info = moe.routed_ffn(layer["moe"], h, cfg)
+        if moe_info is not None:
+            moe_info.append(info)
+        return out, zero
+    if "moe" in layer:
+        return moe.moe_ffn(
+            layer["moe"], h, cfg.n_experts,
+            capacity_factor=cfg.capacity_factor, ep_axis=ep_axis)
+    if "wg" in layer:
+        gate = jax.nn.silu(h @ layer["wg"].astype(dt))
+        return tp_sum((gate * (h @ layer["wu"].astype(dt)))
+                      @ layer["wd"].astype(dt)), zero
+    h = jax.nn.gelu(h @ layer["w1"].astype(dt))
+    return tp_sum(h @ layer["w2"].astype(dt)), zero
 
 
 def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
@@ -355,7 +617,8 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
                 tp_algorithm: str = "psum",
                 ep_axis: Optional[str] = None,
                 attention=None,
-                pos: Optional[jax.Array] = None):
+                pos: Optional[jax.Array] = None,
+                moe_info: Optional[list] = None):
     """One transformer layer (attention + FFN sublayers) on activation
     ``x`` (b, blk, d). Returns (x, aux). The single source of the layer
     math — `forward` iterates it, the pipeline stage (models.pipeline)
@@ -367,7 +630,14 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
     so e.g. the decode cache stays compact) — and returns the q shape;
     None selects the training dispatch (local flash / ring / ulysses),
     which also attends the compact grouped K/V directly — no repeat
-    is materialized anywhere on the training path."""
+    is materialized anywhere on the training path.
+
+    Latent attention (``cfg.mla``): the hook is ``attention(q_nope,
+    q_rope, latent)`` (mla_project's outputs) and returns
+    (b, blk, heads, v_head_dim); None attends the block in the plain
+    form (mla_unabsorbed). ``moe_info``: a list that each
+    'sigmoid_group' expert layer appends its routing record to
+    (models.moe.routed_ffn), for callers that count or check it."""
     b, blk, _ = x.shape
     dt = x.dtype
     ntp = lax.axis_size(tp_axis) if tp_axis is not None else 1
@@ -381,7 +651,26 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
         return tc.allreduce(t, tp_axis, algorithm=tp_algorithm).astype(
             t.dtype)
 
-    h = _rmsnorm(x, layer["ln1"]["g"])
+    def ffn_sublayer(x):
+        h = _rmsnorm(x, layer["ln2"]["g"], cfg.norm_eps)
+        out, aux = _ffn(h, layer, cfg, tp_sum, ep_axis, moe_info)
+        return x + out, aux
+
+    h = _rmsnorm(x, layer["ln1"]["g"], cfg.norm_eps)
+    if cfg.mla:
+        if sp_axis is not None or tp_axis is not None:
+            raise ValueError("latent attention runs unsharded so far "
+                             "(no sp_axis / tp_axis)")
+        assert pos is not None, "rope needs per-layer positions"
+        q_nope, q_rope, latent = mla_project(h, layer, cfg, pos)
+        if attention is not None:
+            att = attention(q_nope, q_rope, latent)
+        else:
+            att = mla_unabsorbed(q_nope, q_rope, latent, layer, cfg)
+        with jax.named_scope("mla.out"):
+            x = x + att.reshape(b, blk, -1).astype(dt) @ layer[
+                "wo"].astype(dt)
+        return ffn_sublayer(x)
     if cfg.kv_heads == cfg.n_heads:
         w = layer["wqkv"].astype(dt)   # (d, 3, local heads x hd)
         qkv = h @ w.reshape(w.shape[0], -1)
@@ -403,8 +692,8 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
     k, v = heads(k, nkv_local), heads(v, nkv_local)
     if cfg.pos_encoding == "rope":
         assert pos is not None, "rope needs per-layer positions"
-        q = _rope(q, pos, cfg.rope_scaling, cfg.rope_scale)
-        k = _rope(k, pos, cfg.rope_scaling, cfg.rope_scale)  # compact
+        q = _rope_cfg(q, pos, cfg)
+        k = _rope_cfg(k, pos, cfg)  # compact
         # k: pre-grouping (the hook/caches see rotated compact keys)
 
     # GQA K/V stay COMPACT on every dispatch path: the attention ops
@@ -430,17 +719,7 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
             f"known: 'ring', 'ulysses'")
     att = att.reshape(b, blk, nh_local * cfg.head_dim)
     x = x + tp_sum(att @ layer["wo"].astype(dt))
-
-    h = _rmsnorm(x, layer["ln2"]["g"])
-    if cfg.n_experts > 0:
-        ffn_out, aux = moe.moe_ffn(
-            layer["moe"], h, cfg.n_experts,
-            capacity_factor=cfg.capacity_factor, ep_axis=ep_axis)
-        x = x + ffn_out
-        return x, aux
-    h = jax.nn.gelu(h @ layer["w1"].astype(dt))
-    x = x + tp_sum(h @ layer["w2"].astype(dt))
-    return x, jnp.zeros((), jnp.float32)
+    return ffn_sublayer(x)
 
 
 def next_token_targets(tokens):
@@ -551,7 +830,7 @@ def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     x, aux_total = _features(params, tokens, cfg, sp_axis, tp_axis,
                              tp_algorithm, ep_axis)
     dt = cfg.act_dtype
-    logits = (x @ params["embed"].T.astype(dt)).astype(jnp.float32)
+    logits = (x @ head_weights(params).T.astype(dt)).astype(jnp.float32)
     if with_aux:
         return logits, aux_total
     return logits
@@ -589,7 +868,7 @@ def _features(params: dict, tokens: jax.Array, cfg: TransformerConfig,
         x, aux = block(x, layer)
         aux_total = aux_total + aux
 
-    return _rmsnorm(x, params["ln_f"]["g"]), aux_total
+    return _rmsnorm(x, params["ln_f"]["g"], cfg.norm_eps), aux_total
 
 
 def loss_fn(params: dict, tokens: jax.Array, cfg: TransformerConfig,
@@ -619,17 +898,17 @@ def loss_fn(params: dict, tokens: jax.Array, cfg: TransformerConfig,
                  (b, 1), jnp.float32)], axis=1)
     chunk = cfg.loss_vocab_chunk or 0
     if chunk:
-        local, count = nll_sum_chunked(x, params["embed"], targets,
+        local, count = nll_sum_chunked(x, head_weights(params), targets,
                                        valid, chunk)
     else:
-        logits = (x @ params["embed"].T.astype(cfg.act_dtype)) \
+        logits = (x @ head_weights(params).T.astype(cfg.act_dtype)) \
             .astype(jnp.float32)
         local, count = nll_sum(logits, targets, valid)
     if sp_axis is not None:
         local = lax.psum(local, sp_axis)
         count = lax.psum(count, sp_axis)
     loss = local / count
-    if cfg.n_experts > 0:
+    if cfg.n_experts > 0 and cfg.moe_router == "switch":
         if sp_axis is not None:
             # each sp shard routed its own token slice: average the
             # local aux terms so the total loss is sp-invariant like

@@ -54,6 +54,17 @@ decode, so both paths share one kernel and its numerics:
   out      (b, kv_heads, T*r, head_dim) f32
   scratch  m/l (kv_heads, T*r), o (kv_heads, T*r, head_dim) f32
 
+A LATENT cache (latent attention's absorbed decode) is the same kernel
+with no V operand: every head attends ONE stream — k (b, 1, d, max_len),
+a latent row [c_kv | rotated key dims] per position — and the values
+are the stream's leading ``v_dim`` features, so a tile fetched once
+serves scores and values, for all heads (r = n_heads query rows):
+  q        (b, 1, T*n_heads, d)     d = kv_lora + rope dims (576)
+  k        (b, 1, d, max_len)       v = k[:, :, :v_dim]  (512)
+  out      (b, 1, T*n_heads, v_dim) f32
+Tile picking, the live-tile skip and the ``pos`` prefetch are the same
+code (_pick_bk with its own bytes-a-step constant, _cache_block).
+
 Dots run in bf16 with f32 accumulation (int8 -> bf16 is lossless;
 f32 caches keep f32 dots — their tiles are smaller than VMEM allows
 anyway). The cache axis is innermost and sequential ('arbitrary'),
@@ -80,10 +91,16 @@ _NEG = -1e30
 #: cache-axis tile width; ceil-divides max_len (padded tail is masked)
 _BLOCK_K = 512
 
+#: rows of one bf16 sublane tile: a latent cache's feature axis (576) is
+#: the tile's sublane axis and must come in whole groups of these
+_SUBLANES = 16
 
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale: float,
+
+def _decode_kernel(pos_ref, q_ref, k_ref, *rest, scale: float,
                    n_k: int, bk: int, max_len: int, quant: bool,
-                   r: int, T: int):
+                   r: int, T: int, v_dim: int = 0):
+    if not v_dim:       # a V operand; a latent cache has none
+        v_ref, rest = rest[0], rest[1:]
     if quant:
         ks_ref, vs_ref, o_ref, m_s, l_s, o_s = rest
     else:
@@ -115,7 +132,9 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
         q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
         k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
-        v = v_ref[0].astype(dot_dt)                      # (g, d, BK)
+        # latent: the values are the key stream's leading features
+        v = (k[:, :v_dim, :] if v_dim
+             else v_ref[0].astype(dot_dt))               # (g, d, BK)
         # masks built >=2-D from iota: Mosaic cannot insert a minor dim on
         # sub-32-bit (bool) values, so never reshape a 1-D mask
         base = ik * bk
@@ -628,11 +647,19 @@ def paged_flash_decode(q, k_pool, v_pool, table, pos0, scale,
 
 
 def can_flash_decode(max_len: int, head_dim: int,
-                     block_k: int = _BLOCK_K) -> bool:
+                     block_k: int = _BLOCK_K, v_dim: int = 0) -> bool:
     """Shape gate: a lane-friendly head_dim, and a cache tile Mosaic
     accepts — bk a multiple of 128 (bk ceil-divides max_len; the
-    padded tail is masked) or the whole axis in one tile."""
-    if max_len < 1 or not (head_dim % 128 == 0 or head_dim == 64):
+    padded tail is masked) or the whole axis in one tile. A latent
+    cache (``v_dim`` > 0: values are the leading v_dim features of the
+    key stream) has head_dim as the tile's sublane axis only: whole
+    bf16 sublane groups, and a lane-friendly v_dim for the output."""
+    if v_dim:
+        if head_dim % _SUBLANES or v_dim % 128 or v_dim > head_dim:
+            return False
+    elif not (head_dim % 128 == 0 or head_dim == 64):
+        return False
+    if max_len < 1:
         return False
     bk = min(block_k, max_len)
     return bk == max_len or bk % 128 == 0
@@ -648,9 +675,27 @@ def can_flash_decode(max_len: int, head_dim: int,
 #: 16.0 / 0.696.
 _TILE_BYTES = 512 << 10
 
+#: the same for a latent cache (one stream of d = 576 rows a tile, 128
+#: query rows, compute at the ridge), and the widest tile it may take.
+#: Measured on a v5e at 128 rows x 576 x 4096 bf16 (PERF.md, PR 27), one
+#: call on a reasoning server's contexts (mean 928) / every row at pos
+#: 300 / at max_len: BK 256 0.842 / 0.544 / 2.530 ms; 512 0.585 / 0.338
+#: / 1.633; 1024 (1152 KiB) 0.529 / 0.402 / 1.307; 2048 0.641 / 0.603 /
+#: 1.133. A row is ONE stream, so a grid step moves a ninth of what a
+#: 16-head step does at equal width and the empty steps weigh more.
+_LATENT_TILE_BYTES = 1152 << 10
+_LATENT_BLOCK_K = 1024
+
+
+def _tile_rule(latent: bool):
+    """(widest tile, K bytes a grid step) of _pick_bk for a per-head
+    K/V cache or a latent one."""
+    return ((_LATENT_BLOCK_K, _LATENT_TILE_BYTES) if latent
+            else (_BLOCK_K, _TILE_BYTES))
+
 
 def _pick_bk(L: int, d: int, nkv: int, r: int, itemsize: int,
-             block_k: int) -> int:
+             block_k: int, tile_bytes: int = _TILE_BYTES) -> int:
     """Cache-tile width: at most ``block_k``, no wider than streams
     _TILE_BYTES of K a grid step (finer tiles skip more of a short
     row's dead context), and within the T=1 VMEM budget (two
@@ -660,7 +705,7 @@ def _pick_bk(L: int, d: int, nkv: int, r: int, itemsize: int,
     or verify/decode numerics diverge."""
     bk = min(block_k, max(L, 1))
     if bk > 128:
-        bk = min(bk, max(128, _TILE_BYTES // (nkv * d * itemsize)
+        bk = min(bk, max(128, tile_bytes // (nkv * d * itemsize)
                          // 128 * 128))
     if bk < L and L % 128 == 0:
         # prefer a DIVISOR of L: a non-dividing bk makes Mosaic pad
@@ -677,7 +722,7 @@ def _pick_bk(L: int, d: int, nkv: int, r: int, itemsize: int,
     return bk
 
 
-def flash_decode_tile(k_cache, n_heads: int) -> int:
+def flash_decode_tile(k_cache, n_heads: int, latent: bool = False) -> int:
     """The cache-tile width flash_decode / flash_block_decode run a
     (b, kv_heads, head_dim, max_len) cache at (anything with its
     ``shape`` and ``dtype``): the granularity at which a row's dead
@@ -685,7 +730,8 @@ def flash_decode_tile(k_cache, n_heads: int) -> int:
     ``serve.attend_tiles``); the rule lives here."""
     _, nkv, d, L = k_cache.shape
     itemsize = 4 if k_cache.dtype == jnp.float32 else 2
-    return _pick_bk(L, d, nkv, n_heads // nkv, itemsize, _BLOCK_K)
+    return _pick_bk(L, d, nkv, n_heads // nkv, itemsize,
+                    *_tile_rule(latent))
 
 
 def _last_live_tile(pos, T: int, bk: int, n_k: int):
@@ -714,30 +760,33 @@ def _cache_block(ib, ik, pos_ref, T: int, bk: int, n_k: int, b: int):
 
 
 def _block_fits_vmem(L: int, d: int, nkv: int, r: int, T: int,
-                     itemsize: int, block_k: int = _BLOCK_K) -> bool:
+                     itemsize: int, block_k: int = _BLOCK_K,
+                     tile_bytes: int = _TILE_BYTES) -> bool:
     """Whether a T-query block fits VMEM at the T=1 tile size (the
     only tile size that preserves shared numerics with plain decode)."""
-    bk = _pick_bk(L, d, nkv, r, itemsize, block_k)
+    bk = _pick_bk(L, d, nkv, r, itemsize, block_k, tile_bytes)
     return (2 * nkv * bk * d * itemsize + 2 * nkv * T * r * bk * 4
             + nkv * T * r * d * 4) <= (14 << 20)
 
 
 def flash_decode(q, k_cache, v_cache, pos, scale, k_scale=None,
-                 v_scale=None, *, block_k: int = _BLOCK_K,
-                 interpret: Optional[bool] = None):
+                 v_scale=None, *, block_k: Optional[int] = None,
+                 interpret: Optional[bool] = None, v_dim: int = 0):
     """Fused decode attention. ``q`` is (b, 1, n_heads, head_dim) (the
     _attend_cache caller layout); caches head-leading as in
     models.generate. ``pos`` scalar or (b,). Returns
-    (b, 1, n_heads, head_dim) f32."""
+    (b, 1, n_heads, head_dim) f32. A latent cache passes ``v_cache``
+    None and ``v_dim`` (see flash_block_decode)."""
     assert q.shape[1] == 1, q.shape  # single query; flash_block_decode for T>1
     return flash_block_decode(q, k_cache, v_cache, pos, scale,
                               k_scale=k_scale, v_scale=v_scale,
-                              block_k=block_k, interpret=interpret)
+                              block_k=block_k, interpret=interpret,
+                              v_dim=v_dim)
 
 
 def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
-                       v_scale=None, *, block_k: int = _BLOCK_K,
-                       interpret: Optional[bool] = None):
+                       v_scale=None, *, block_k: Optional[int] = None,
+                       interpret: Optional[bool] = None, v_dim: int = 0):
     """Fused T-query block decode attention (the speculative-decoding
     verify shape): ``q`` is (b, T, n_heads, head_dim) where row b's
     query t sits at sequence position ``pos0[b] + t`` and attends
@@ -747,7 +796,11 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     speculative verify and the plain decode step share numerics (the
     losslessness of greedy speculative decoding rides on their
     argmaxes agreeing; tests/test_speculative.py pins parity).
-    Returns (b, T, n_heads, head_dim) f32."""
+    Returns (b, T, n_heads, head_dim) f32.
+
+    A LATENT cache (``v_cache`` None, ``v_dim`` > 0): ``k_cache`` is
+    (b, 1, d, max_len), every head attends it, and the values are its
+    leading ``v_dim`` features; returns (b, T, n_heads, v_dim)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, T, nh, d = q.shape
@@ -755,16 +808,29 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     r = nh // nkv
     R = T * r
     quant = k_scale is not None
+    latent = v_cache is None
+    widest, tile_bytes = _tile_rule(latent)
+    if block_k is None:
+        block_k = widest
+    if latent != bool(v_dim) or (latent and (
+            quant or not can_flash_decode(L, d, block_k, v_dim))):
+        raise ValueError(
+            f"flash_block_decode: a latent cache comes without v_cache "
+            f"and scales and with v_dim (a 128-multiple <= head_dim "
+            f"{d}, head_dim a 16-multiple); got v_dim={v_dim}, "
+            f"v_cache {'absent' if latent else 'given'}")
+    dv = v_dim or d
     # bk comes from the T=1 budget — identical for every T, or the
     # verify kernel's tile partition (and so its accumulation order)
     # would differ from plain decode's, breaking the shared-numerics
     # guarantee speculative losslessness rests on.
     itemsize = 4 if k_cache.dtype == jnp.float32 else 2
-    bk = _pick_bk(L, d, nkv, r, itemsize, block_k)
+    bk = _pick_bk(L, d, nkv, r, itemsize, block_k, tile_bytes)
     # the T-scaled tensors at that same bk must still fit VMEM; a
     # block too big to share the T=1 tiling cannot share numerics, so
     # refuse rather than silently retile (caller falls back to einsum)
-    if not _block_fits_vmem(L, d, nkv, r, T, itemsize, block_k):
+    if not _block_fits_vmem(L, d, nkv, r, T, itemsize, block_k,
+                            tile_bytes):
         raise ValueError(
             f"flash_block_decode: T={T} block exceeds the VMEM budget "
             f"at the T=1 tile size bk={bk} (nkv={nkv}, r={r}, d={d}) "
@@ -792,8 +858,13 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     cache_map = lambda ib, ik, pos_ref: _cache_block(  # noqa: E731
         ib, ik, pos_ref, T, bk, n_k, b)
     kv_spec = pl.BlockSpec((1, nkv, d, bk), cache_map)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [qg, k_cache, v_cache]
+    in_specs = [q_spec, kv_spec]
+    args = [qg, k_cache]
+    if not latent:
+        in_specs += [kv_spec]
+        args += [v_cache]
+    o_spec = pl.BlockSpec((1, nkv, R, dv),
+                          lambda ib, ik, pos_ref: (ib, 0, 0, 0))
     if quant:
         # scales reshaped (b, kvh, 1, L): the (1, bk) trailing block
         # dims satisfy Mosaic's tiling rule for any bk multiple of 128
@@ -809,21 +880,22 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
         num_scalar_prefetch=1,
         grid=(b, n_k),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=o_spec,
         scratch_shapes=[pltpu.VMEM((nkv, R), jnp.float32),
                         pltpu.VMEM((nkv, R), jnp.float32),
-                        pltpu.VMEM((nkv, R, d), jnp.float32)],
+                        pltpu.VMEM((nkv, R, dv), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale), n_k=n_k,
-                          bk=bk, max_len=L, quant=quant, r=r, T=T),
+                          bk=bk, max_len=L, quant=quant, r=r, T=T,
+                          v_dim=v_dim),
         grid_spec=grid_spec,
-        out_shape=out_struct((b, nkv, R, d), jnp.float32, q, k_cache),
+        out_shape=out_struct((b, nkv, R, dv), jnp.float32, q, k_cache),
         interpret=interpret,
         # one kernel body, two names: the T=1 decode step and the
         # T>1 extend/verify block are told apart in program text
         name="flash_decode" if T == 1 else "flash_block_decode",
         **kwargs,
     )(posv, *args)
-    return (out.reshape(b, nkv, T, r, d).transpose(0, 2, 1, 3, 4)
-            .reshape(b, T, nh, d))
+    return (out.reshape(b, nkv, T, r, dv).transpose(0, 2, 1, 3, 4)
+            .reshape(b, T, nh, dv))

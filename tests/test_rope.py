@@ -142,7 +142,7 @@ class TestRopeScaling:
 
     def test_invalid_configs_rejected(self):
         toks = tokens_for(ROPE, seq=4)
-        bad1 = dataclasses.replace(ROPE, rope_scaling="yarn")
+        bad1 = dataclasses.replace(ROPE, rope_scaling="dynamic")
         params = init_params(jax.random.PRNGKey(0), bad1)
         with pytest.raises(ValueError, match="unknown rope_scaling"):
             forward(params, toks, bad1)

@@ -1,5 +1,7 @@
-"""The decode-attention kernel compiled by the real TPU compiler at the
-benchmark cell's shape (gpt2-medium, 96 rows x max_len 1024), without a
+"""The serving kernels compiled by the real TPU compiler at the benchmark
+cells' shapes (gpt2-medium: decode attention at 96 rows x max_len 1024;
+deepseek-v3-ep16: the latent attend at 128 rows x 576 x 4096 and the
+grouped expert FFN over 16 held experts at 7168 x 2048), without a
 chip: Mosaic's refusals (block shapes, scoped VMEM, scalar-prefetch index
 maps) show up here, numerics and times do not. The topology is described
 inside a fixture, never at import (only one process may load libtpu, and
@@ -10,7 +12,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from rlo_tpu.pallas.decode import flash_block_decode
+from rlo_tpu.pallas.decode import flash_block_decode, write_kv_row
+from rlo_tpu.pallas.expert_ffn import buffer_rows, expert_ffn
 
 B, NH, D, L = 96, 16, 64, 1024
 
@@ -46,3 +49,50 @@ def test_flash_decode_compiles_for_v5e(one_chip, T, cache_dtype):
     text = jax.jit(attend).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert ("flash_decode" if T == 1 else "flash_block_decode") in text
+
+
+# deepseek-v3-ep16 x reason-sat: 128 slots, 128 heads, latent rows of
+# 512 + 64, 16 of 256 experts held
+SLOTS, HEADS, LATENT, V_DIM, MAX_LEN = 128, 128, 576, 512, 4096
+D_MODEL, D_EXPERT, HELD = 7168, 2048, 16
+
+
+def test_latent_attend_and_row_write_compile_for_v5e(one_chip):
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def step(q, cache, row, pos):
+        cache = write_kv_row(cache, row, pos, interpret=False)
+        return flash_block_decode(q, cache, None, pos, 0.135,
+                                  v_dim=V_DIM, interpret=False), cache
+
+    text = jax.jit(step).lower(
+        shape((SLOTS, 1, HEADS, LATENT), jnp.bfloat16),
+        shape((SLOTS, 1, LATENT, MAX_LEN), jnp.bfloat16),
+        shape((SLOTS, 1, LATENT), jnp.bfloat16),
+        shape((SLOTS,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "flash_decode" in text and "write_kv_row" in text
+
+
+@pytest.mark.parametrize("tokens,tile", [(128, 16), (256, 16), (1024, 64)])
+def test_expert_ffn_compiles_for_v5e(one_chip, tokens, tile):
+    """A decode step of 128 rows, and prefill buckets of 256 and 1024:
+    every (token, choice) pair here in the worst case."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    n_rows = buffer_rows(tokens * 8, HELD, tile)
+
+    def ffn(x, wg, wu, wd, tile_expert, n_live):
+        return expert_ffn(x, wg, wu, wd, tile_expert, n_live, tile=tile,
+                          interpret=False)
+
+    text = jax.jit(ffn).lower(
+        shape((n_rows, D_MODEL), jnp.bfloat16),
+        shape((HELD, D_MODEL, D_EXPERT), jnp.bfloat16),
+        shape((HELD, D_MODEL, D_EXPERT), jnp.bfloat16),
+        shape((HELD, D_EXPERT, D_MODEL), jnp.bfloat16),
+        shape((n_rows // tile,), jnp.int32),
+        shape((), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "expert_ffn" in text
