@@ -119,6 +119,54 @@ def kv_cache_pspecs(cfg: TransformerConfig,
     return [{"k": spec, "v": spec} for _ in range(cfg.n_layers)]
 
 
+def init_kv_tail(cache, n: int):
+    """A decode round's write-behind tail: per layer, ``n`` zeroed rows
+    TOKEN-MAJOR, (n, batch, kv_heads, head_dim) for each tensor of the
+    cache entry ((n, batch, 1, width) for a latent cache), in the
+    cache's dtype. A loop that runs n decode steps with nobody else
+    reading the cache hands it to decode_step (``tail=``): step s
+    stores its row at [s] — a contiguous store on the leading axis,
+    where the seq-minor cache would rewrite a 128-lane block a row to
+    change one lane of it — and attends the cache up to where the
+    loop began plus tail rows 0..s; fold_kv_tail writes all n rows
+    into the cache once. int8 caches (scale sidecars) keep the
+    per-step write."""
+    if any("ks" in lc for lc in cache):
+        raise ValueError("an int8 cache has no write-behind tail")
+    return [{name: jnp.zeros((n,) + a.shape[:3], a.dtype)
+             for name, a in lc.items()} for lc in cache]
+
+
+def fold_kv_tail(cache, tail, pos0):
+    """The cache with every tail row in place: layer by layer, row t
+    of ``tail`` (init_kv_tail's layout) lands at column pos0_b + t of
+    batch row b; columns at or past max_len are dropped, as the
+    per-step write drops them. Equal, entry for entry, to the cache n
+    decode_step calls without a tail leave."""
+    from rlo_tpu.pallas.decode import can_write_block, write_kv_tail
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    out = []
+    for lc, tl in zip(cache, tail):
+        entry = {}
+        for name, big in lc.items():
+            rows = tl[name]
+            n, b, L = rows.shape[0], big.shape[0], big.shape[3]
+            if kernel_gate(can_write_block(L) and n <= 128,
+                           f"cache tail fold (max_len={L}, rows={n})"):
+                entry[name] = write_kv_tail(big, rows, pos0)
+            else:
+                cols = (jnp.broadcast_to(pos0, (b,))[:, None]
+                        + jnp.arange(n))                     # (b, n)
+                entry[name] = big.at[
+                    jnp.arange(b)[:, None, None, None],
+                    jnp.arange(big.shape[1])[None, :, None, None],
+                    jnp.arange(big.shape[2])[None, None, :, None],
+                    cols[:, None, None, :]].set(
+                        rows.transpose(1, 2, 3, 0), mode="drop")
+        out.append(entry)
+    return out
+
+
 def _quantize_kv(x):
     """(..., head_dim) -> (int8 values, f32 scale over the last axis).
     Symmetric per-(batch, position, head) quantization: scale =
@@ -147,7 +195,7 @@ def _decode_cfg(cfg: TransformerConfig) -> TransformerConfig:
 
 def _attend_cache(q, k_cache, v_cache, pos, scale,
                   k_scale=None, v_scale=None, use_flash=None,
-                  v_dim: int = 0):
+                  v_dim: int = 0, tail=None):
     """q (b, 1, H, hd) against the cache prefix [0, pos]: full-length
     matmul over the static cache, masked beyond the position. ``pos``
     is a scalar (all rows at the same position) or a (b,) vector
@@ -166,7 +214,14 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
 
     A LATENT cache passes ``v_cache`` None and ``v_dim``: one stream
     (b, 1, hd, max_len) that every head attends, whose leading
-    ``v_dim`` features are the values; returns (b, 1, H, v_dim)."""
+    ``v_dim`` features are the values; returns (b, 1, H, v_dim).
+
+    ``tail`` ``(tk, tv, newest)``: a round's write-behind rows
+    (init_kv_tail's layout; tv None for a latent cache). The query
+    attends cache positions <= pos AND tail rows 0..newest — row t is
+    position pos + 1 + t, which the cache does not hold yet — in one
+    softmax; tail positions at or past max_len are left out, as the
+    per-step write drops them."""
     b, one, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
     if use_flash is None:
@@ -182,7 +237,7 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
         # pass — rlo_tpu.pallas.decode
         from rlo_tpu.pallas.decode import flash_decode
         return flash_decode(q, k_cache, v_cache, pos, scale,
-                            k_scale, v_scale, v_dim=v_dim)
+                            k_scale, v_scale, v_dim=v_dim, tail=tail)
     # the einsum path IS the T=1 case of the block attend — one
     # implementation, so a dequant/mask/dtype fix can never diverge
     # decode_step from block_decode (speculative decoding's
@@ -192,12 +247,12 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
              else posv.reshape(b, 1))
     return _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                                k_scale=k_scale, v_scale=v_scale,
-                               v_dim=v_dim)
+                               v_dim=v_dim, tail=tail)
 
 
 def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                         k_scale=None, v_scale=None, pos0=None,
-                        use_flash=None, v_dim: int = 0):
+                        use_flash=None, v_dim: int = 0, tail=None):
     """Block variant of the cache attend: q (b, T, nh, hd) where query
     i of row b sits at position pos_q[b, i] and attends cache
     positions <= pos_q[b, i]. Because the block's own K/V rows are
@@ -213,7 +268,10 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
     flash-block path on TPU: the SAME kernel family decode_step's
     attend uses (T=1), so speculative verify logits and plain decode
     logits share numerics (losslessness of greedy speculative decoding
-    needs their argmaxes to agree)."""
+    needs their argmaxes to agree).
+
+    ``tail`` (T = 1, the einsum path only; see _attend_cache): the
+    tail's scores join the cache's before the softmax."""
     b, T, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
     if use_flash is None:
@@ -261,12 +319,29 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
         s = s * k_scale[:, :, None, None, :]
     mask = jnp.arange(max_len)[None, None, :] <= pos_q[:, :, None]
     s = jnp.where(mask[:, None, None, :, :], s, _NEG)
+    if tail is not None:
+        tk, tv, newest = tail
+        if v_dim:
+            tv = tk[..., :v_dim]
+        t = jnp.arange(tk.shape[0])
+        live = (t <= newest) & (pos_q + 1 + t < max_len)   # (b, kk)
+        s_t = jnp.einsum("bqgrd,tbgd->bgrqt", qg.astype(cache_dt),
+                         tk.astype(cache_dt),
+                         preferred_element_type=jnp.float32) * scale
+        s = jnp.concatenate(
+            [s, jnp.where(live[:, None, None, None, :], s_t, _NEG)], -1)
     p = jax.nn.softmax(s, axis=-1)
+    if tail is not None:
+        p, p_t = p[..., :max_len], p[..., max_len:]
     if v_scale is not None:
         p = p * v_scale[:, :, None, None, :]
     out = jnp.einsum("bgrqk,bgdk->bqgrd", p.astype(cache_dt),
                      v_cache.astype(cache_dt),
                      preferred_element_type=jnp.float32)
+    if tail is not None:
+        out = out + jnp.einsum("bgrqt,tbgd->bqgrd", p_t.astype(cache_dt),
+                               tv.astype(cache_dt),
+                               preferred_element_type=jnp.float32)
     return out.astype(jnp.float32).reshape(b, T, nh, v_dim or hd)
 
 
@@ -292,7 +367,8 @@ def _mla_absorbed(q_nope, q_rope, layer, cfg, attend):
 def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
                 tp_axis: Optional[str] = None,
                 ep_axis: Optional[str] = None,
-                moe_info: Optional[list] = None
+                moe_info: Optional[list] = None,
+                tail: Optional[tuple] = None
                 ) -> Tuple[jax.Array, list]:
     """One token (b,) int32 at position ``pos`` through all layers
     using the K/V cache. Returns (logits (b, vocab) f32, new cache).
@@ -314,21 +390,47 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
     Latent attention (``cfg.mla``): the cache holds one latent row a
     token and layer; the step writes it (write_kv_row, as ever) and
     attends in the absorbed form (_mla_absorbed). ``moe_info``: see
-    apply_layer."""
+    apply_layer.
+
+    ``tail`` ``(rows, newest)``: step ``newest`` of a loop that keeps
+    its new K/V rows in a write-behind tail (init_kv_tail; the loop
+    began at position pos - newest). The step stores its rows at
+    [newest] of each layer's tail, attends the cache up to where the
+    loop began and tail rows 0..newest (same keys, values and
+    positions as write-then-attend: _attend_cache), and returns
+    (logits, new tail rows); the cache is read, not written."""
     cfg = _decode_cfg(cfg)
     dt = cfg.act_dtype
     posv = jnp.asarray(pos)
     ragged = posv.ndim == 1
     b = token.shape[0]
+    if tail is not None:
+        tail_rows, newest = tail
+        newest = jnp.asarray(newest, jnp.int32)
+        before = posv - newest - 1      # the last position the cache holds
+
+        def store(rows, row):           # at [newest], in the cache's dtype
+            return lax.dynamic_update_slice(
+                rows, row[None].astype(rows.dtype), (newest, 0, 0, 0))
+    else:
+        tail_rows = [None] * len(cache)
     # (1,) shared positions, or (b, 1) per-row, for embed/rope
     pos_arr = posv[:, None] if ragged else posv[None]
     x = embed_tokens(params["embed"], token[:, None], pos_arr, cfg)
     scale = cfg.attn_scale
     new_cache = []
-    for layer, lc in zip(params["layers"], cache):
-        def attend_latent(q_nope, q_rope, latent, lc=lc, layer=layer):
+    for layer, lc, tl in zip(params["layers"], cache, tail_rows):
+        def attend_latent(q_nope, q_rope, latent, lc=lc, layer=layer,
+                          tl=tl):
             from rlo_tpu.pallas.decode import can_write_row, write_kv_row
             row = latent[:, 0][:, None, :]           # (b, 1, width)
+            if tl is not None:
+                tk = store(tl["k"], row)
+                new_cache.append({"k": tk})
+                return _mla_absorbed(
+                    q_nope, q_rope, layer, cfg, lambda q: _attend_cache(
+                        q, lc["k"], None, before, scale,
+                        v_dim=cfg.kv_lora_rank, tail=(tk, None, newest)))
             max_len_c = lc["k"].shape[3]
             if kernel_gate(can_write_row(max_len_c),
                            f"cache row write (max_len={max_len_c})"):
@@ -345,13 +447,18 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
                 q_nope, q_rope, layer, cfg, lambda q: _attend_cache(
                     q, kc, None, posv, scale, v_dim=cfg.kv_lora_rank))
 
-        def attend(q, k, v, lc=lc):
+        def attend(q, k, v, lc=lc, tl=tl):
             # rope configs: q/k arrive rotated from apply_layer; keys
             # are cached rotated (standard RoPE decode). k/v arrive
             # (b, 1, kvh, hd); the cache is head-leading — transpose
             # the new entry to (b, kvh, hd) rows
             quant = "ks" in lc
             k_row, v_row = k[:, 0], v[:, 0]          # (b, kvh, hd)
+            if tl is not None:
+                tk, tv = store(tl["k"], k_row), store(tl["v"], v_row)
+                new_cache.append({"k": tk, "v": tv})
+                return _attend_cache(q, lc["k"], lc["v"], before, scale,
+                                     tail=(tk, tv, newest)).astype(dt)
             if quant:  # int8 cache: quantize the new entry at append
                 k_row, ks_new = _quantize_kv(k_row)
                 v_row, vs_new = _quantize_kv(v_row)
@@ -366,8 +473,9 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
             if use_wr:
                 # aliased pallas write: an XLA lane-offset DUS makes
                 # layout assignment transpose the cache and copy it
-                # back for the flash kernel every step (~2 ms/step at
-                # plen 1024 — see write_kv_row)
+                # back for the flash kernel every step. Still a whole
+                # 128-lane block a row and call (see write_kv_row):
+                # a loop that owns its steps passes ``tail``
                 kc = write_kv_row(lc["k"], k_row, posv)
                 vc = write_kv_row(lc["v"], v_row, posv)
             elif ragged:

@@ -57,8 +57,8 @@ import numpy as np
 from jax import lax
 
 from rlo_tpu.models.generate import (block_decode, decode_step,
-                                     init_kv_cache, prefill,
-                                     _decode_cfg)
+                                     fold_kv_tail, init_kv_cache,
+                                     init_kv_tail, prefill, _decode_cfg)
 from rlo_tpu.models import moe
 from rlo_tpu.models.transformer import TransformerConfig
 from rlo_tpu.observe.spans import Stage
@@ -231,22 +231,36 @@ class DecodeServer:
         # steps and layers and returns it as a fifth output
         moe_stats = cfg.moe_router == "sigmoid_group" and any(
             "moe" in layer for layer in params["layers"])
+        # the round owns its kk steps and nobody reads the cache in
+        # between, so the new K/V rows wait in a write-behind tail and
+        # reach the seq-minor cache once a round (init_kv_tail). An
+        # int8 cache has no tail: it keeps the write of every step.
+        self._kv_tail = cfg.kv_cache_dtype is None
 
         def round_fn(params, cache, last_tok, pos, kk):
-            def body(carry, _):
-                tok, pos, cache, *stats = carry
+            tail = self._kv_tail        # read when the round is traced
+
+            def body(carry, s):
+                tok, kv, *stats = carry
                 info = []
-                logits, cache = decode_step(params, tok, pos, cache,
-                                            cfg_d, moe_info=info)
+                # kv is the tail (the cache is closed over, read only)
+                # or, without one, the cache itself
+                logits, kv = decode_step(
+                    params, tok, pos + s, cache if tail else kv, cfg_d,
+                    moe_info=info, tail=(kv, s) if tail else None)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 stats = [n + sum(i["stats"] for i in info) for n in stats]
-                return (tok, pos + 1, cache, *stats), tok
+                return (tok, kv, *stats), tok
 
             stats = ([jnp.zeros((len(moe.STATS),), jnp.int32)]
                      if moe_stats else [])
-            (tok, pos, cache, *stats), toks = lax.scan(
-                body, (last_tok, pos, cache, *stats), None, length=kk)
-            return (tok, pos, cache, jnp.transpose(toks), *stats)  # (b, kk)
+            kv = init_kv_tail(cache, kk) if tail else cache
+            (tok, kv, *stats), toks = lax.scan(
+                body, (last_tok, kv, *stats),
+                jnp.arange(kk, dtype=jnp.int32))
+            cache = fold_kv_tail(cache, kv, pos) if tail else kv
+            return (tok, pos + kk, cache, jnp.transpose(toks),  # (b, kk)
+                    *stats)
 
         # donate the pool cache: without aliasing, every round would
         # double-buffer the full n_slots x max_len cache in HBM
@@ -768,7 +782,8 @@ class DecodeServer:
                 self.params, self.cache, jnp.asarray(self.last_tok),
                 jnp.asarray(self.pos), kk)
             self.cache = cache
-        self._count_attend_tiles(kk)  # while the device runs the round
+        self._count_kv_tail(kk)       # while the device runs the round
+        self._count_attend_tiles(kk)
         with self._span("round.wait"):
             # the host blocks here for the device's whole round
             toks = np.asarray(toks)
@@ -813,22 +828,43 @@ class DecodeServer:
         self._page_gauges()
         return True
 
+    def _count_kv_tail(self, kk: int) -> None:
+        """A dense round that ran with the write-behind tail:
+        ``serve.kv_tail.rounds``, ``serve.kv_tail.rows`` (kk x n_slots
+        rows attended from the tail and folded in) and
+        ``serve.kv_flush_blocks`` (128-lane cache blocks the fold
+        rewrote: two a slot, layer and tensor, write_kv_block's grid)."""
+        if not self._kv_tail:
+            return
+        count = self.metrics.counter
+        count("serve.kv_tail.rounds").inc()
+        count("serve.kv_tail.rows").inc(kk * self.n_slots)
+        count("serve.kv_flush_blocks").inc(
+            2 * self.n_slots * len(jax.tree.leaves(self.cache)))
+
     def _count_attend_tiles(self, kk: int) -> None:
         """How often flash_decode's skip engages, from the host's own
         ``pos``: ``serve.attend_tiles`` is every (row, step, cache
         tile) of the round's grid, ``serve.attend_tiles_live`` those a
-        row's context reaches — step s of a row attends positions
-        <= pos + s, so tiles 0 .. (pos + s) // bk; the rest are
-        neither fetched nor computed. Every slot counts, free and
-        finished ones too: the kernel runs them."""
+        row's context reaches; the rest are neither fetched nor
+        computed. With the tail every step of the round attends the
+        cache as the round found it, positions < pos: tiles
+        0 .. (pos - 1) // bk, the same in every step (tile 0 alone for
+        an empty row). Without it step s attends positions <= pos + s.
+        Every slot counts, free and finished ones too: the kernel runs
+        them."""
         if self._attend_tiling is None:
             return
         bk, n_k = self._attend_tiling
-        last = (self.pos[:, None] + np.arange(kk)) // bk
+        if self._kv_tail:
+            last = kk * np.clip((self.pos - 1) // bk, 0, n_k - 1)
+        else:
+            last = np.minimum((self.pos[:, None] + np.arange(kk)) // bk,
+                              n_k - 1)
         self.metrics.counter("serve.attend_tiles").inc(
             kk * self.n_slots * n_k)
         self.metrics.counter("serve.attend_tiles_live").inc(
-            int(np.minimum(last, n_k - 1).sum()) + kk * self.n_slots)
+            int(last.sum()) + kk * self.n_slots)
 
     def _observe_round(self, dt: float, kk: int) -> None:
         self._hist("serve.round_usec").observe(dt * 1e6)

@@ -65,6 +65,15 @@ serves scores and values, for all heads (r = n_heads query rows):
 Tile picking, the live-tile skip and the ``pos`` prefetch are the same
 code (_pick_bk with its own bytes-a-step constant, _cache_block).
 
+A WRITE-BEHIND TAIL (T = 1; DecodeServer's round, docs/DESIGN.md §4):
+the rows a round has produced so far wait token-major beside the cache
+and reach it once a round (write_kv_tail), because one new column costs
+a whole 128-lane block a row to store. The kernel takes them as
+  tk/tv    (kk, b, kv_heads, head_dim)  cache dtype; no tv if latent
+  newest   (1,) int32, prefetched beside pos — rows 0..newest are live,
+           row t is position pos_b + 1 + t
+and folds them into the same softmax at a row's first grid step.
+
 Dots run in bf16 with f32 accumulation (int8 -> bf16 is lossless;
 f32 caches keep f32 dots — their tiles are smaller than VMEM allows
 anyway). The cache axis is innermost and sequential ('arbitrary'),
@@ -96,23 +105,67 @@ _BLOCK_K = 512
 _SUBLANES = 16
 
 
-def _decode_kernel(pos_ref, q_ref, k_ref, *rest, scale: float,
+def _decode_kernel(pos_ref, *refs, scale: float,
                    n_k: int, bk: int, max_len: int, quant: bool,
-                   r: int, T: int, v_dim: int = 0):
+                   r: int, T: int, v_dim: int = 0, n_tail: int = 0):
+    if n_tail:          # a second prefetched scalar: the tail's newest row
+        newest_ref, refs = refs[0], refs[1:]
+    q_ref, k_ref, *rest = refs
     if not v_dim:       # a V operand; a latent cache has none
         v_ref, rest = rest[0], rest[1:]
+    if n_tail:          # the round's own rows, after the cache operands
+        tk_ref, rest = rest[0], rest[1:]
+        if not v_dim:
+            tv_ref, rest = rest[0], rest[1:]
     if quant:
         ks_ref, vs_ref, o_ref, m_s, l_s, o_s = rest
     else:
         o_ref, m_s, l_s, o_s = rest
     ib = pl.program_id(0)
     ik = pl.program_id(1)
+    # dots in bf16 (f32 accumulate): int8 -> bf16 is lossless, bf16 is
+    # the MXU-native width, and an f32 cast would materialize 4x the
+    # tile bytes in VMEM. f32 caches keep f32 (exactness; their tiles
+    # fit).
+    dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
 
     @pl.when(ik == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s[...], _NEG)
-        l_s[...] = jnp.zeros_like(l_s[...])
-        o_s[...] = jnp.zeros_like(o_s[...])
+        if not n_tail:
+            m_s[...] = jnp.full_like(m_s[...], _NEG)
+            l_s[...] = jnp.zeros_like(l_s[...])
+            o_s[...] = jnp.zeros_like(o_s[...])
+            return
+        # the write-behind tail (models.generate.decode_step): rows
+        # 0..newest of this round, token-major, row t at position
+        # pos + 1 + t. It is one more tile of the same online softmax,
+        # with K arriving (kk, d) instead of (d, BK), and it goes
+        # FIRST — it starts the accumulators where zeros would — so
+        # that its block is free from the row's second grid step on
+        # and the next row's can be fetched behind this row's cache
+        # tiles (_tail_block). Rows past ``newest`` are the zeros the
+        # tail was made of; a position at or past max_len is one the
+        # per-step write would have dropped; a row with neither
+        # leaves m = _NEG, l = 0, o = 0, as no tail does.
+        q = q_ref[0].astype(dot_dt)                      # (g, r, d)
+        if v_dim:
+            kt = tk_ref[:, 0, 0, :][None].astype(dot_dt)  # (1, kk, d)
+            vt = kt[:, :, :v_dim]
+        else:                                 # (kk, g, d) -> (g, kk, d)
+            kt = jnp.swapaxes(tk_ref[:, 0], 0, 1).astype(dot_dt)
+            vt = jnp.swapaxes(tv_ref[:, 0], 0, 1).astype(dot_dt)
+        t = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_tail), 2)
+        live = (t <= newest_ref[0]) & (pos_ref[ib] + 1 + t < max_len)
+        s = jax.lax.dot_general(q, kt, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(live, s, _NEG)                     # (g, r, kk)
+        m = s.max(axis=-1)
+        p = jnp.where(live, jnp.exp(s - m[..., None]), 0.0)
+        m_s[...] = m
+        l_s[...] = p.sum(axis=-1)
+        o_s[...] = jax.lax.dot_general(
+            p.astype(dot_dt), vt, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
 
     pos = pos_ref[ib]
 
@@ -123,13 +176,9 @@ def _decode_kernel(pos_ref, q_ref, k_ref, *rest, scale: float,
     # next row's first tile (_cache_block, the same rule).
     @pl.when(ik <= _last_live_tile(pos, T, bk, n_k))
     def _attend():
-        # dots in bf16 (f32 accumulate): int8 -> bf16 is lossless, bf16 is
-        # the MXU-native width, and an f32 cast would materialize 4x the
-        # tile bytes in VMEM. f32 caches keep f32 (exactness; their tiles
-        # fit). g = kvh heads batched per program. The query axis holds
+        # g = kvh heads batched per program. The query axis holds
         # T*r rows, t-major: row t*r+rr is block token t, group-member rr,
         # at sequence position pos + t (T=1 recovers single-token decode).
-        dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
         q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
         k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
         # latent: the values are the key stream's leading features
@@ -242,7 +291,8 @@ def can_write_block(max_len: int) -> bool:
 
 
 def write_kv_block(cache, rows, pos0, *,
-                   interpret: Optional[bool] = None):
+                   interpret: Optional[bool] = None,
+                   name: str = "write_kv_block"):
     """Aliased T-column cache write: ``cache`` (b, kvh, hd, L)
     seq-minor, ``rows`` (b, kvh, hd, T) — column t of row b lands at
     [b, :, :, pos0_b + t]. The block_decode analogue of write_kv_row:
@@ -250,7 +300,12 @@ def write_kv_block(cache, rows, pos0, *,
     that measured 1.2 ms PER VERIFY at batch 1 (block_decode 1.65 ms
     vs 0.46 ms for a decode step with the same weights) — the whole
     speculative-decoding margin. Requires L >= 256 (two slidable
-    128-lane blocks) and pos0 + T <= L."""
+    128-lane blocks). A column at or past L is DROPPED, as T
+    successive write_kv_row calls would drop it: the kernel matches
+    GLOBAL columns against blocks clamped into the cache, so a row
+    with pos0 >= L (serve advances retired slots past max_len) writes
+    nothing and one that crosses L writes the columns below it
+    (tests/test_flash_decode.py pins both)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, nkv, d, L = cache.shape
@@ -290,36 +345,55 @@ def write_kv_block(cache, rows, pos0, *,
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
-        name="write_kv_block",
+        name=name,
     )(pos0, rows.astype(cache.dtype), cache)
+
+
+def write_kv_tail(cache, tail, pos0, *,
+                  interpret: Optional[bool] = None):
+    """Fold a round's write-behind tail into the cache: ``tail``
+    (kk, b, kvh, hd) token-major (models.generate.init_kv_tail), row t
+    of batch row b lands at [b, :, :, pos0_b + t]; columns at or past L
+    are dropped (write_kv_block's rule). This IS the row write of a
+    decode round, kk rows at a time — a 128-lane block costs the same
+    to rewrite for kk new columns as for one — so it runs
+    write_kv_block's body under write_kv_row's name (one body, two
+    names, like flash_decode / flash_block_decode)."""
+    return write_kv_block(cache, tail.transpose(1, 2, 3, 0), pos0,
+                          interpret=interpret, name="write_kv_row")
 
 
 def write_kv_row(cache, row, pos, *, interpret: Optional[bool] = None):
     """Aliased single-position cache write: ``cache`` (b, kvh, hd, L)
     seq-minor, ``row`` (b, kvh, hd), ``pos`` (b,) int32 — returns the
-    cache with row b written at [b, :, :, pos_b].
+    cache with row b written at [b, :, :, pos_b]; a pos at or past L
+    matches no column and the write is dropped.
 
     Exists because the XLA dynamic-update-slice at a LANE offset
     fights the flash kernel over layout: layout assignment prefers a
     transposed layout for the lane-granular DUS and then inserts a
-    full-cache copy per layer per step to feed the pallas custom call
-    (measured: 12 x 76 MB copies per decode step = the entire ~2 ms
-    residual in benchmarks/decode_analysis.py at plen 1024). Doing
-    the write as a pallas kernel with input_output_aliasing removes
-    the XLA-level DUS entirely: every cache consumer is a custom call
-    wanting the default layout, and only the one 128-lane block
-    containing pos is read + written (~8 MB instead of 76)."""
+    full-cache copy per layer per step to feed the pallas custom call.
+    As a pallas kernel with input_output_aliasing there is no
+    XLA-level DUS: every cache consumer is a custom call wanting the
+    default layout, and only the one 128-lane block containing pos is
+    read + written.
+
+    That block is still 128 columns moved to store one: at 96 rows x
+    16 heads x 64 bf16 a call moves 50 MB (and the (…, 1) row it is
+    fed pads to 128 lanes, 25 MB more) for 196 KB of new values —
+    0.093 ms a call, 4.5 ms of a 15.6 ms gpt2-medium decode step over
+    48 calls, with 2.7 ms of row copies beside it (PERF_LEDGER, PR 27).
+    A loop that owns its steps should not pay that per step:
+    DecodeServer's round keeps the new rows in a token-major tail and
+    folds them in once a round (write_kv_tail; docs/DESIGN.md §4)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, nkv, d, L = cache.shape
     pos = jnp.asarray(pos, jnp.int32)
     # SCALAR pos (plain generate's scan: every row at the same
-    # position): batch-chunked blocks instead of the (b,) grid — b
-    # launches per call x16 calls/step. Even chunked, the 16 calls
-    # cost a fixed ~0.33 ms/step, part of the short-cache launch-
-    # bound regime where seq-minor trades away the plen-16 corner
-    # (DESIGN.md "decode HBM budget"); the win is everywhere the
-    # cache is the bound.
+    # position): batch-chunked blocks instead of the (b,) grid, so a
+    # call is a few launches and not b of them. The bytes are the
+    # same: one 128-lane block a row.
     per_row = pos.ndim != 0
     pos = jnp.full((b,), pos) if pos.ndim == 0 else pos.reshape(b)
     # shard_map vma alignment: a replicated pos/row must carry the
@@ -759,6 +833,17 @@ def _cache_block(ib, ik, pos_ref, T: int, bk: int, n_k: int, b: int):
             jnp.where(ahead, 0, jnp.minimum(ik, last)))
 
 
+def _tail_block(ib, ik, b: int):
+    """The batch row whose kk tail rows grid step (ib, ik) presents:
+    its own at the row's first step, where _decode_kernel folds the
+    tail in; the NEXT row's from the second step on, so that the fetch
+    (kk small strided pieces) lands behind this row's cache tiles
+    rather than in front of the next row's first step. One fetch a
+    row either way: the index repeats over the rest of the row and
+    into the next row's first step."""
+    return jnp.where((ik > 0) & (ib + 1 < b), ib + 1, ib)
+
+
 def _block_fits_vmem(L: int, d: int, nkv: int, r: int, T: int,
                      itemsize: int, block_k: int = _BLOCK_K,
                      tile_bytes: int = _TILE_BYTES) -> bool:
@@ -771,22 +856,25 @@ def _block_fits_vmem(L: int, d: int, nkv: int, r: int, T: int,
 
 def flash_decode(q, k_cache, v_cache, pos, scale, k_scale=None,
                  v_scale=None, *, block_k: Optional[int] = None,
-                 interpret: Optional[bool] = None, v_dim: int = 0):
+                 interpret: Optional[bool] = None, v_dim: int = 0,
+                 tail=None):
     """Fused decode attention. ``q`` is (b, 1, n_heads, head_dim) (the
     _attend_cache caller layout); caches head-leading as in
     models.generate. ``pos`` scalar or (b,). Returns
     (b, 1, n_heads, head_dim) f32. A latent cache passes ``v_cache``
-    None and ``v_dim`` (see flash_block_decode)."""
+    None and ``v_dim``; ``tail`` is a round's write-behind rows (both:
+    see flash_block_decode)."""
     assert q.shape[1] == 1, q.shape  # single query; flash_block_decode for T>1
     return flash_block_decode(q, k_cache, v_cache, pos, scale,
                               k_scale=k_scale, v_scale=v_scale,
                               block_k=block_k, interpret=interpret,
-                              v_dim=v_dim)
+                              v_dim=v_dim, tail=tail)
 
 
 def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
                        v_scale=None, *, block_k: Optional[int] = None,
-                       interpret: Optional[bool] = None, v_dim: int = 0):
+                       interpret: Optional[bool] = None, v_dim: int = 0,
+                       tail=None):
     """Fused T-query block decode attention (the speculative-decoding
     verify shape): ``q`` is (b, T, n_heads, head_dim) where row b's
     query t sits at sequence position ``pos0[b] + t`` and attends
@@ -800,7 +888,18 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
 
     A LATENT cache (``v_cache`` None, ``v_dim`` > 0): ``k_cache`` is
     (b, 1, d, max_len), every head attends it, and the values are its
-    leading ``v_dim`` features; returns (b, T, n_heads, v_dim)."""
+    leading ``v_dim`` features; returns (b, T, n_heads, v_dim).
+
+    A write-behind ``tail`` (T = 1, no int8): ``(tk, tv, newest)`` with
+    tk/tv (kk, b, kv_heads, head_dim) token-major in the cache's dtype
+    (tv None for a latent cache) and ``newest`` an int32 scalar. Row
+    b's tail row t is the key/value of position pos0_b + 1 + t, which
+    the cache does not hold yet; the query attends cache positions
+    <= pos0_b AND tail rows 0..newest, one softmax over both (the tail
+    is folded in at a row's first grid step, ahead of the cache tiles).
+    Tail positions at or past max_len are left out, as the per-step
+    write drops them. Without a tail the kernel is, to the bit, the
+    one it was."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, T, nh, d = q.shape
@@ -820,6 +919,17 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
             f"{d}, head_dim a 16-multiple); got v_dim={v_dim}, "
             f"v_cache {'absent' if latent else 'given'}")
     dv = v_dim or d
+    n_tail = 0
+    if tail is not None:
+        tk, tv, newest = tail
+        n_tail = tk.shape[0]
+        if (T != 1 or quant or (tv is None) != latent
+                or tk.shape != (n_tail, b, nkv, d)):
+            raise ValueError(
+                f"flash_block_decode: a tail goes with one query a row "
+                f"and an unquantized cache, as (kk, b, kv_heads, "
+                f"head_dim) rows (and no V rows for a latent cache); "
+                f"got T={T}, int8={quant}, tail {tk.shape}")
     # bk comes from the T=1 budget — identical for every T, or the
     # verify kernel's tile partition (and so its accumulation order)
     # would differ from plain decode's, breaking the shared-numerics
@@ -852,35 +962,46 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     # pos is scalar-prefetched: the cache index maps read it, so the
     # grid steps past a row's live context name a block that is
     # already in VMEM or on its way (_cache_block; no copy is issued
-    # for a repeated block index) and the kernel body skips them
-    q_spec = pl.BlockSpec((1, nkv, R, d),
-                          lambda ib, ik, pos_ref: (ib, 0, 0, 0))
-    cache_map = lambda ib, ik, pos_ref: _cache_block(  # noqa: E731
-        ib, ik, pos_ref, T, bk, n_k, b)
+    # for a repeated block index) and the kernel body skips them.
+    # (A tail brings a second prefetched scalar, ``newest``, that no
+    # index map reads.)
+    row_map = lambda ib, ik, pos_ref, _newest=None: (  # noqa: E731
+        ib, 0, 0, 0)
+    cache_map = lambda ib, ik, pos_ref, _newest=None: (  # noqa: E731
+        _cache_block(ib, ik, pos_ref, T, bk, n_k, b))
     kv_spec = pl.BlockSpec((1, nkv, d, bk), cache_map)
-    in_specs = [q_spec, kv_spec]
+    in_specs = [pl.BlockSpec((1, nkv, R, d), row_map), kv_spec]
     args = [qg, k_cache]
     if not latent:
         in_specs += [kv_spec]
         args += [v_cache]
-    o_spec = pl.BlockSpec((1, nkv, R, dv),
-                          lambda ib, ik, pos_ref: (ib, 0, 0, 0))
     if quant:
         # scales reshaped (b, kvh, 1, L): the (1, bk) trailing block
         # dims satisfy Mosaic's tiling rule for any bk multiple of 128
         s_spec = pl.BlockSpec((1, nkv, 1, bk), cache_map)
         in_specs += [s_spec, s_spec]
         args += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
+    scalars = [posv]
+    if n_tail:
+        t_spec = pl.BlockSpec(
+            (n_tail, 1, nkv, d),
+            lambda ib, ik, pos_ref, _newest: (
+                0, _tail_block(ib, ik, b), 0, 0))
+        for rows in (tk,) if latent else (tk, tv):
+            in_specs += [t_spec]
+            args += [vary_like(rows.astype(k_cache.dtype), k_cache)]
+        scalars += [vary_like(
+            jnp.asarray(newest, jnp.int32).reshape(1), k_cache)]
 
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(scalars),
         grid=(b, n_k),
         in_specs=in_specs,
-        out_specs=o_spec,
+        out_specs=pl.BlockSpec((1, nkv, R, dv), row_map),
         scratch_shapes=[pltpu.VMEM((nkv, R), jnp.float32),
                         pltpu.VMEM((nkv, R), jnp.float32),
                         pltpu.VMEM((nkv, R, dv), jnp.float32)],
@@ -888,7 +1009,7 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale), n_k=n_k,
                           bk=bk, max_len=L, quant=quant, r=r, T=T,
-                          v_dim=v_dim),
+                          v_dim=v_dim, n_tail=n_tail),
         grid_spec=grid_spec,
         out_shape=out_struct((b, nkv, R, dv), jnp.float32, q, k_cache),
         interpret=interpret,
@@ -896,6 +1017,6 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
         # T>1 extend/verify block are told apart in program text
         name="flash_decode" if T == 1 else "flash_block_decode",
         **kwargs,
-    )(posv, *args)
+    )(*scalars, *args)
     return (out.reshape(b, nkv, T, r, dv).transpose(0, 2, 1, 3, 4)
             .reshape(b, T, nh, dv))

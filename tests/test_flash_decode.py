@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from rlo_tpu.models.generate import (_attend_cache, _attend_cache_block,
                                      _quantize_kv)
@@ -477,3 +478,229 @@ def test_write_row_oob_pos_is_dropped():
     pos = jnp.asarray([256, 300, 10_000], jnp.int32)
     got = np.asarray(write_kv_row(cache, row, pos, interpret=True))
     np.testing.assert_array_equal(got, np.asarray(cache))
+
+
+# -- the write-behind tail (PR 28): a round's new K/V rows wait in a
+# token-major tail; the attend covers cache positions <= pos and tail
+# rows 0..newest (row t = position pos + 1 + t), the fold writes all
+# rows into the cache at once.
+TAIL_KK, TAIL_L = 8, 256    # two cache tiles of 128
+# row by row: an empty cache, mid-cache, a tail that crosses max_len
+# part-way, a cache that ends exactly where the tail begins to drop,
+# and a retired slot already past max_len
+TAIL_POS0 = [0, 130, TAIL_L - 3, TAIL_L, TAIL_L + 5]
+TAIL_KINDS = {"mha": (4, 4, 64, 0), "gqa": (8, 4, 64, 0),
+              "latent": (8, 1, 576, 512)}   # nh, nkv, d, v_dim
+
+
+def _tail_case(kind, seed=0):
+    nh, nkv, d, v_dim = TAIL_KINDS[kind]
+    nb = len(TAIL_POS0)
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    q = draw(nb, 1, nh, d)
+    kc = draw(nb, nkv, d, TAIL_L)
+    tk = draw(TAIL_KK, nb, nkv, d)
+    vc, tv = (None, None) if v_dim else (draw(nb, nkv, d, TAIL_L),
+                                         draw(TAIL_KK, nb, nkv, d))
+    return q, kc, vc, tk, tv, v_dim, 1.0 / np.sqrt(d)
+
+
+def _written(cache, tail, pos0, upto):
+    """``cache`` after the per-step path stored tail rows 0..upto: row t
+    at column pos0_b + t, dropped at or past max_len (plain numpy)."""
+    if cache is None:
+        return None
+    out = np.asarray(cache).copy()
+    for bi, p in enumerate(pos0):
+        for t in range(upto + 1):
+            if p + t < out.shape[3]:
+                out[bi, :, :, p + t] = np.asarray(tail)[t, bi]
+    return jnp.asarray(out)
+
+
+@pytest.mark.parametrize("path", ["kernel", "einsum"])
+@pytest.mark.parametrize("newest", [0, 3, TAIL_KK - 1])
+@pytest.mark.parametrize("kind", sorted(TAIL_KINDS))
+def test_tail_attend_is_write_then_attend(kind, newest, path):
+    """Attending cache and tail in one softmax == writing rows
+    0..newest into the cache and attending it at pos0 + newest (the
+    float32 einsum oracle), for ragged pos0 up to and past max_len."""
+    q, kc, vc, tk, tv, v_dim, scale = _tail_case(kind)
+    pos0 = jnp.asarray(TAIL_POS0, jnp.int32)
+    tail = (tk, tv, jnp.int32(newest))
+    if path == "kernel":
+        got = flash_decode(q, kc, vc, pos0 - 1, scale, interpret=True,
+                           block_k=128, v_dim=v_dim, tail=tail)
+    else:
+        got = _attend_cache(q, kc, vc, pos0 - 1, scale, use_flash=False,
+                            v_dim=v_dim, tail=tail)
+    assert np.isfinite(np.asarray(got)).all()
+    want = _attend_cache(q, _written(kc, tk, TAIL_POS0, newest),
+                         _written(vc, tv, TAIL_POS0, newest),
+                         pos0 + newest, scale, use_flash=False,
+                         v_dim=v_dim)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_tail_block_rule():
+    """The tail's index map: a row's own kk rows at its first grid step
+    (where the kernel folds them in), the next row's from the second
+    on, so one fetch a row lands behind the row's cache tiles; the
+    last row has no next."""
+    b, n_k = 3, 4
+    got = [[int(decode_mod._tail_block(jnp.int32(ib), jnp.int32(ik), b))
+            for ik in range(n_k)] for ib in range(b)]
+    assert got == [[0, 1, 1, 1], [1, 2, 2, 2], [2, 2, 2, 2]]
+    flat = [r for row in got for r in row]
+    changes = sum(x != y for x, y in zip(flat, flat[1:]))
+    assert changes == b - 1         # one fetch a row after the first
+
+
+def test_tail_refuses_what_it_cannot_hold(data):
+    q, kc, vc, scale = data
+    tk = jnp.zeros((4, B, NKV, D), jnp.float32)
+    with pytest.raises(ValueError, match="a tail goes with one query"):
+        flash_block_decode(jnp.zeros((B, 2, NH, D)), kc, vc, 3, scale,
+                           interpret=True, tail=(tk, tk, 0))
+    qk, ks = _quant_seqminor(kc)
+    with pytest.raises(ValueError, match="a tail goes with one query"):
+        flash_decode(q, qk, qk, 3, scale, ks, ks, interpret=True,
+                     tail=(tk, tk, 0))
+
+
+@pytest.mark.parametrize("path", ["kernel", "scatter"])
+@pytest.mark.parametrize("kind", ["per-head", "latent"])
+def test_tail_fold_drops_what_row_writes_drop(kind, path, monkeypatch):
+    """Folding kk tail rows == kk successive write_kv_row calls, bit
+    for bit: rows that cross a 128-lane block, cross max_len part-way
+    (the columns below it land, the rest are dropped), start at
+    max_len and lie far past it."""
+    from rlo_tpu.models import generate
+    from rlo_tpu.pallas.decode import write_kv_row, write_kv_tail
+    kk, L = 32, 256
+    nkv, d = (1, 48) if kind == "latent" else (NKV, D)
+    pos0 = jnp.asarray([5, 120, L - 10, L, 10_000], jnp.int32)
+    rng = np.random.default_rng(41)
+    cache = jnp.asarray(rng.standard_normal((5, nkv, d, L)), jnp.float32)
+    tail = jnp.asarray(rng.standard_normal((kk, 5, nkv, d)), jnp.float32)
+    want = cache
+    for t in range(kk):
+        want = write_kv_row(want, tail[t], pos0 + t, interpret=True)
+    if path == "kernel":
+        got = write_kv_tail(cache, tail, pos0, interpret=True)
+    else:       # off the tpu backend the gate is shut: the XLA scatter
+        got = generate.fold_kv_tail([{"k": cache}], [{"k": tail}],
+                                    pos0)[0]["k"]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.array_equal(np.asarray(got)[2], np.asarray(cache)[2])
+    np.testing.assert_array_equal(np.asarray(got)[3:],
+                                  np.asarray(cache)[3:])
+
+
+# the kernel as PR 27 left it, before it learnt the tail, verbatim but
+# for comments: what "no tail => today's kernel" is held to
+def _pr27_decode_kernel(pos_ref, q_ref, k_ref, *rest, scale: float,
+                   n_k: int, bk: int, max_len: int, quant: bool,
+                   r: int, T: int, v_dim: int = 0):
+    if not v_dim:       # a V operand; a latent cache has none
+        v_ref, rest = rest[0], rest[1:]
+    if quant:
+        ks_ref, vs_ref, o_ref, m_s, l_s, o_s = rest
+    else:
+        o_ref, m_s, l_s, o_s = rest
+    ib = pl.program_id(0)
+    ik = pl.program_id(1)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_s[...] = jnp.full_like(m_s[...], decode_mod._NEG)
+        l_s[...] = jnp.zeros_like(l_s[...])
+        o_s[...] = jnp.zeros_like(o_s[...])
+
+    pos = pos_ref[ib]
+
+    @pl.when(ik <= decode_mod._last_live_tile(pos, T, bk, n_k))
+    def _attend():
+        dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
+        q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
+        k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
+        v = (k[:, :v_dim, :] if v_dim
+             else v_ref[0].astype(dot_dt))               # (g, d, BK)
+        base = ik * bk
+        row = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+        qoff = jax.lax.broadcasted_iota(jnp.int32, (1, T * r, 1), 1) // r
+        mask_row = (row <= pos + qoff) & (row < max_len)  # (1, T*r, BK)
+        mask_col = (row <= pos + (T - 1)) & (row < max_len)  # (1, 1, BK)
+
+        s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * ks_ref[0]                            # (g, 1, BK)
+        s = jnp.where(mask_row, s, decode_mod._NEG)                 # (g, T*r, BK)
+        v = jnp.where(mask_col, v, jnp.zeros((), dot_dt))
+
+        m = m_s[...]                                     # (g, T*r)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(mask_row, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        m_s[...] = m_new
+        l_s[...] = l_s[...] * corr + p.sum(axis=-1)
+        pv = jnp.where(mask_row, p * vs_ref[0], 0.0) if quant else p
+        o_s[...] = o_s[...] * corr[..., None] + jax.lax.dot_general(
+            pv.astype(dot_dt), v, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(ik == n_k - 1)
+    def _flush():
+        o_ref[0] = o_s[...] / l_s[...][..., None]
+
+
+NO_TAIL_CASES = {
+    # name: (T, pos0, int8, latent, block_k)
+    "T1": (1, [0, L - 1, _EDGE], False, False, _EDGE),
+    "T4-verify": (4, [_EDGE - 3, 0, L - 4], False, False, _EDGE),
+    "T1-int8": (1, [31, 40, 0], True, False, 32),
+    "T4-int8": (4, [_EDGE - 1, 2 * _EDGE, 3], True, False, _EDGE),
+    "T1-padded-tail-tile": (1, [32, L - 1, 5], False, False, 32),
+    "T1-latent": (1, [0, L - 1, _EDGE], False, True, None),
+    "T4-latent": (4, [_EDGE - 3, 0, L - 4], False, True, None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NO_TAIL_CASES))
+def test_no_tail_is_bitwise_the_parent_kernel(data, name, monkeypatch):
+    """Every caller that passes no tail (generate's scan, the
+    speculative verify, the long-prompt extend, decode_step as the
+    benchmark's check calls it) runs the kernel it ran before."""
+    T, pos0, quant, latent, block_k = NO_TAIL_CASES[name]
+    _, kc, vc, scale = data
+    rng = np.random.default_rng(sorted(NO_TAIL_CASES).index(name))
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    if latent:
+        q = jnp.asarray(rng.standard_normal((B, T, NH, 144)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((B, 1, 144, L)), jnp.float32)
+        args, kw = (q, k, None, pos0, scale), {"v_dim": 128}
+    elif quant:
+        q = jnp.asarray(rng.standard_normal((B, T, NH, D)), jnp.float32)
+        qk, ks = _quant_seqminor(kc)
+        qv, vs = _quant_seqminor(vc)
+        args, kw = (q, qk, qv, pos0, scale, ks, vs), {}
+    else:
+        q = jnp.asarray(rng.standard_normal((B, T, NH, D)), jnp.float32)
+        args, kw = (q, kc, vc, pos0, scale), {}
+    got = np.asarray(flash_block_decode(*args, interpret=True,
+                                        block_k=block_k, **kw))
+
+    def parent(*refs, n_tail, **static):
+        assert n_tail == 0
+        return _pr27_decode_kernel(*refs, **static)
+
+    monkeypatch.setattr(decode_mod, "_decode_kernel", parent)
+    want = np.asarray(flash_block_decode(*args, interpret=True,
+                                         block_k=block_k, **kw))
+    np.testing.assert_array_equal(got, want)

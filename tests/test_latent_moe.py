@@ -380,3 +380,9 @@ def test_server_counts_expert_work_and_matches_generate(params):
         c["serve.steps"] * n_moe * CFG.experts_held)
     width = CFG.kv_lora_rank + CFG.qk_rope_head_dim
     assert g["serve.cache_bytes_per_token"] == width * 4 * CFG.n_layers
+    # every round kept its latent rows in the write-behind tail: one
+    # tensor a layer, two 128-lane blocks a slot in the fold
+    assert c["serve.kv_tail.rounds"] == c["serve.rounds"]
+    assert c["serve.kv_tail.rows"] == c["serve.slot_steps"]
+    assert c["serve.kv_flush_blocks"] == (
+        c["serve.rounds"] * 2 * 3 * CFG.n_layers)

@@ -529,52 +529,65 @@ CFG_HD64 = TransformerConfig(vocab=64, d_model=64, n_heads=1, n_layers=1,
                              d_ff=64, dtype="float32")
 
 
+@pytest.mark.parametrize("cache", ["tail", "int8"])
 @pytest.mark.parametrize("near_edge", [-2, 0])
 def test_attend_tile_counters_equal_the_hand_count(monkeypatch,
-                                                   near_edge):
+                                                   near_edge, cache):
     """``serve.attend_tiles`` / ``serve.attend_tiles_live`` against a
-    count by hand from pos, kk and the kernel's exported tile width:
-    step s of a round attends positions <= pos + s, so a row reaches
-    tiles 0 .. (pos + s) // bk of the n_k in the grid. Every slot
-    counts, the never-admitted and the finished too (the kernel runs
-    them; their pos advances with the round). The server is told it
-    is on the tpu backend so that it counts at all; the model still
-    attends through the einsum here, and the count needs only pos."""
+    count by hand from pos, kk and the kernel's exported tile width.
+    With the write-behind tail every step of a round attends the cache
+    as the round found it, positions < pos: tiles 0 .. (pos - 1) // bk
+    of the n_k in the grid, the same in all kk steps (the round's own
+    positions are in the tail). An int8 cache keeps the write of every
+    step: step s attends positions <= pos + s, tiles
+    0 .. (pos + s) // bk. Every slot counts, the never-admitted and
+    the finished too (the kernel runs them; their pos advances with
+    the round). The server is told it is on the tpu backend so that it
+    counts at all; the model still attends through the einsum here,
+    and the count needs only pos."""
     from rlo_tpu.models import serve as serve_mod
     from rlo_tpu.pallas.decode import flash_decode_tile
     from rlo_tpu.utils.metrics import Registry
 
     monkeypatch.setattr(serve_mod, "_on_tpu", lambda: True)
+    cfg = (CFG_HD64 if cache == "tail" else
+           dataclasses.replace(CFG_HD64, kv_cache_dtype="int8"))
     max_len, kk, n_slots = 1024, 4, 3
     bk = flash_decode_tile(jax.ShapeDtypeStruct(
-        (n_slots, 1, CFG_HD64.head_dim, max_len), jnp.float32), 1)
+        (n_slots, 1, cfg.head_dim, max_len), jnp.float32), 1)
     n_k = max_len // bk
     assert n_k >= 2     # else there is no tile to skip at this size
-    params = init_params(jax.random.PRNGKey(3), CFG_HD64)
+    params = init_params(jax.random.PRNGKey(3), cfg)
     reg = Registry()
-    srv = DecodeServer(params, CFG_HD64, n_slots=n_slots,
+    srv = DecodeServer(params, cfg, n_slots=n_slots,
                        max_len=max_len, round_len=kk,
                        prompt_buckets=(8, 1024), metrics=reg)
     assert srv._attend_tiling == (bk, n_k)
+    assert srv._kv_tail == (cache == "tail")
     rng = np.random.default_rng(26)
     # one short row; one whose context crosses (or starts on) the
     # first tile edge inside its first round; slot 2 stays free
     plens, outs = [5, bk + near_edge], [9, 6]
     for plen, out in zip(plens, outs):
-        srv.submit(rng.integers(0, CFG_HD64.vocab, (plen,)), out)
+        srv.submit(rng.integers(0, cfg.vocab, (plen,)), out)
     srv.run()
     rounds = srv.rounds_run
     assert rounds == 2      # 9 tokens: one at admission, 8 / kk rounds
     live = 0
     for slot_pos0 in plens + [0]:           # pos at the first round
         for step in range(rounds * kk):     # pos advances every step
-            live += min((slot_pos0 + step) // bk, n_k - 1) + 1
+            if cache == "tail":             # the cache at the round's start
+                held = slot_pos0 + step // kk * kk - 1
+            else:
+                held = slot_pos0 + step
+            live += min(max(held, 0) // bk, n_k - 1) + 1
     c = srv.stats()["counters"]
     assert c["serve.attend_tiles"] == rounds * kk * n_slots * n_k
     assert c["serve.attend_tiles_live"] == live
-    # the short rows reach one tile a step, the long one two from the
-    # step its context passes the edge
-    crossed = rounds * kk - max(0, -near_edge)
+    # the short rows reach one tile a step. The long one reaches two
+    # from the step its context passes the edge — or, with the tail,
+    # from the first round that FINDS it past the edge: the second
+    crossed = kk if cache == "tail" else rounds * kk - max(0, -near_edge)
     assert live == 3 * rounds * kk + crossed
 
 
@@ -597,3 +610,110 @@ def test_attend_tile_counters_absent_on_the_einsum_path(setup):
     srv._count_attend_tiles(4)
     assert not any(k.startswith("serve.attend_tiles")
                    for k in srv.stats()["counters"])
+
+
+
+# -- the write-behind tail (PR 28): a dense round keeps its new K/V rows
+# in a token-major tail and folds them into the cache when it ends
+def _latent_cfg():
+    import json
+    from pathlib import Path
+    path = (Path(__file__).resolve().parent.parent / "perf" / "configs"
+            / "deepseek-v3-ep16.json")
+    model = json.loads(path.read_text())["tiny"]["model"]
+    return TransformerConfig(**dict(model, dtype="float32",
+                                    param_dtype="float32", n_layers=2))
+
+
+TAIL_CFGS = {
+    "mha": lambda: CFG,
+    "gqa_rope": lambda: dataclasses.replace(CFG, n_kv_heads=2,
+                                            pos_encoding="rope"),
+    "latent": _latent_cfg,      # one latent row a token, expert layer
+}
+
+
+@pytest.mark.parametrize("name,round_len,clip", [
+    ("mha", 1, False), ("mha", 5, False), ("mha", 5, True),
+    ("mha", 32, False), ("gqa_rope", 5, False), ("latent", 8, False),
+    ("latent", 32, True)])
+def test_tail_rounds_match_dense_and_leave_the_per_step_cache(
+        name, round_len, clip):
+    """Token for token what per-request generate() yields — across
+    round boundaries, slot reuse after retirement, clipped rounds, free
+    and retired slots whose pos runs to max_len and past it — and after
+    EVERY round the cache, pos and last_tok the per-step path leaves: a
+    second server is held to the write of every step (as an int8 cache
+    is) and fed the same requests. Layer 0's rows depend on the tokens
+    alone, so they are equal to the bit (placement, and the columns
+    dropped at max_len); deeper rows saw attends that sum the round's
+    last positions in another order. The counters against their hand
+    count."""
+    from rlo_tpu.utils.metrics import Registry
+    cfg = TAIL_CFGS[name]()
+    params = init_params(jax.random.PRNGKey(5), cfg)
+    n_slots, max_len = 2, 64
+
+    def server(reg):
+        return DecodeServer(params, cfg, n_slots=n_slots, max_len=max_len,
+                            round_len=round_len, prompt_buckets=(8, 16),
+                            clip_rounds=clip, metrics=reg)
+
+    reg = Registry()
+    srv, per_step = server(reg), server(Registry())
+    assert srv._kv_tail and per_step._kv_tail
+    per_step._kv_tail = False       # read when the round is traced
+    rng = np.random.default_rng(28)
+    reqs = [(rng.integers(0, cfg.vocab, (int(rng.integers(3, 15)),)),
+             int(rng.integers(2, 21))) for _ in range(5)]
+    reqs.append((rng.integers(0, cfg.vocab, (14,)), max_len - 14))
+    for p, m in reqs:
+        srv.submit(p, m)
+        per_step.submit(p, m)
+    rows = 0
+    while srv.has_work():
+        steps = srv.steps_run
+        srv.step_round()
+        per_step.step_round()
+        rows += (srv.steps_run - steps) * n_slots
+        np.testing.assert_array_equal(srv.pos, per_step.pos)
+        np.testing.assert_array_equal(srv.last_tok, per_step.last_tok)
+        for i, (got, want) in enumerate(zip(srv.cache, per_step.cache)):
+            for key in want:
+                if i == 0:
+                    np.testing.assert_array_equal(got[key], want[key])
+                else:
+                    np.testing.assert_allclose(got[key], want[key],
+                                               rtol=1e-5, atol=1e-5)
+    if round_len >= 5 and not clip:     # a round ran past the cache's end
+        assert srv.pos.max() >= max_len
+    for (p, m), got in zip(reqs, srv.run()):
+        np.testing.assert_array_equal(got, dense_oracle(params, cfg, p, m))
+    c = reg.snapshot()["counters"]
+    assert c["serve.kv_tail.rounds"] == c["serve.rounds"] == srv.rounds_run
+    assert c["serve.kv_tail.rows"] == c["serve.slot_steps"] == rows
+    tensors = 1 if cfg.mla else 2
+    assert c["serve.kv_flush_blocks"] == (
+        srv.rounds_run * 2 * n_slots * cfg.n_layers * tensors)
+    assert not any(k.startswith("serve.kv_")
+                   for k in per_step.stats()["counters"])
+
+
+def test_int8_cache_keeps_the_write_of_every_step(setup):
+    """Its scale sidecars have no tail: the round takes the per-step
+    path (test_variants holds its tokens to generate) and the tail's
+    counters stay untouched."""
+    from rlo_tpu.models.generate import init_kv_cache, init_kv_tail
+    from rlo_tpu.utils.metrics import Registry
+    cfg = dataclasses.replace(CFG, kv_cache_dtype="int8")
+    reg = Registry()
+    srv = DecodeServer(init_params(jax.random.PRNGKey(3), cfg), cfg,
+                       n_slots=2, max_len=64, round_len=3,
+                       prompt_buckets=(8, 16), metrics=reg)
+    assert not srv._kv_tail
+    srv.submit(np.arange(5), 6)
+    srv.run()
+    assert not any(k.startswith("serve.kv_")
+                   for k in reg.snapshot()["counters"])
+    with pytest.raises(ValueError, match="no write-behind tail"):
+        init_kv_tail(init_kv_cache(cfg, 2, 64), 4)

@@ -1,8 +1,9 @@
 """The serving kernels compiled by the real TPU compiler at the benchmark
 cells' shapes (gpt2-medium: decode attention at 96 rows x max_len 1024;
 deepseek-v3-ep16: the latent attend at 128 rows x 576 x 4096 and the
-grouped expert FFN over 16 held experts at 7168 x 2048), without a
-chip: Mosaic's refusals (block shapes, scoped VMEM, scalar-prefetch index
+grouped expert FFN over 16 held experts at 7168 x 2048; both cells'
+round: the attend with a 32-row write-behind tail and the tail's fold),
+without a chip: Mosaic's refusals (block shapes, scoped VMEM, scalar-prefetch index
 maps) show up here, numerics and times do not. The topology is described
 inside a fixture, never at import (only one process may load libtpu, and
 xdist workers all import this file); keep such tests in this one file."""
@@ -12,10 +13,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from rlo_tpu.pallas.decode import flash_block_decode, write_kv_row
+from rlo_tpu.pallas.decode import (flash_block_decode, write_kv_row,
+                                   write_kv_tail)
 from rlo_tpu.pallas.expert_ffn import buffer_rows, expert_ffn
 
 B, NH, D, L = 96, 16, 64, 1024
+L_GPT2 = L
 
 
 @pytest.fixture(scope="module")
@@ -73,6 +76,46 @@ def test_latent_attend_and_row_write_compile_for_v5e(one_chip):
         shape((SLOTS,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
     assert "flash_decode" in text and "write_kv_row" in text
+
+
+ROUND_LEN = 32      # DecodeServer's default: rows in a round's tail
+
+
+@pytest.mark.parametrize("cell", ["gpt2m-decode-sat",
+                                  "dsv3-ep16-reason-sat"])
+def test_round_kernels_with_a_tail_compile_for_v5e(one_chip, cell):
+    """What a dense round runs since PR 28: the attend over the cache
+    and the round's token-major tail (a step of the scan), then the
+    fold of the tail into the cache (once a round). The benchmark looks
+    for both kernels in the round's program by these names."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    latent = cell == "dsv3-ep16-reason-sat"
+    b, nh, nkv, d, L = ((SLOTS, HEADS, 1, LATENT, MAX_LEN) if latent
+                        else (B, NH, NH, D, L_GPT2))
+    bf16 = jnp.bfloat16
+    rows = shape((ROUND_LEN, b, nkv, d), bf16)
+
+    def step_and_fold(q, pos, newest, *kv):
+        caches, tails = kv[:len(kv) // 2], kv[len(kv) // 2:]
+        out = flash_block_decode(
+            q, caches[0], None if latent else caches[1], pos - 1, 0.13,
+            v_dim=V_DIM if latent else 0, interpret=False,
+            tail=(tails[0], None if latent else tails[1], newest))
+        return out, [write_kv_tail(c, t, pos, interpret=False)
+                     for c, t in zip(caches, tails)]
+
+    from rlo_tpu.utils import hlo
+    n = 1 if latent else 2
+    lowered = jax.jit(step_and_fold).lower(
+        shape((b, 1, nh, d), bf16), shape((b,), jnp.int32),
+        shape((), jnp.int32), *[shape((b, nkv, d, L), bf16)] * n,
+        *[rows] * n)
+    # the benchmark's own reading of the lowered text (perf/kinds/serve.py)
+    assert hlo.mosaic_kernels(lowered.as_text()) == {
+        "flash_decode": 1, "write_kv_row": n}
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 1 + n
 
 
 @pytest.mark.parametrize("tokens,tile", [(128, 16), (256, 16), (1024, 64)])
