@@ -16,9 +16,15 @@ slot b lives at physical page table[b, ik]. int8 caches carry
 (n_pages, kv_heads, page_size) f32 scale sidecar pools at the same
 page indexes.
 
-Three entry points mirror models.generate exactly (the layer math IS
-apply_layer via the same attention-hook pattern, so paged decode can
-never drift from dense decode by construction):
+Three entry points mirror models.generate. The two step functions run
+models.generate's own layer loop and head (_forward, _head: embedding,
+apply_layer with the attention hooked, final norm at cfg.norm_eps, the
+tied or untied head) and take their new columns from models.kvcache, so
+a model fact is stated once for the dense and the paged step; what is
+theirs is the page arithmetic, the pool write and the table attend.
+(Through PR 28 they carried copies of that loop, which had drifted: a
+fixed norm eps, the tied head, 1/sqrt(head_dim) — tests/test_kvcache.py
+holds the two paths together at a configuration where those differ.)
 
   - ``paged_decode_step``: one token per slot through all layers;
     writes go to page table[b, pos_b // ps] (inactive slots write
@@ -39,15 +45,15 @@ in the exact class of the dense path.
 
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
-from rlo_tpu.models.generate import (_attend_cache_block, _decode_cfg,
-                                     _quantize_kv)
-from rlo_tpu.models.transformer import (TransformerConfig, apply_layer,
-                                        embed_tokens, _rmsnorm)
+from rlo_tpu.models.generate import (_cache_hook, _decode_cfg, _forward,
+                                     _head)
+from rlo_tpu.models.kvcache import (_attend_cache_block, _on_axis,
+                                    _tensors, _through_hd_view,
+                                    new_block, new_row)
+from rlo_tpu.models.transformer import TransformerConfig
 from rlo_tpu.pallas.reduce import kernel_gate
 
 
@@ -88,111 +94,74 @@ def paged_view(entry, table):
     Unmapped table entries point at the null page (zeros)."""
     b, mp = table.shape
 
-    def g(x):                              # (P, kvh, hd, ps)
-        got = x[table]                     # (b, mp, kvh, hd, ps)
-        got = jnp.moveaxis(got, 1, 3)      # (b, kvh, hd, mp, ps)
-        return got.reshape(b, x.shape[1], x.shape[2],
-                           mp * x.shape[3])
+    def g(x):                              # (P, kvh, [hd,] ps)
+        got = x[table]                     # (b, mp, kvh, [hd,] ps)
+        got = jnp.moveaxis(got, 1, -2)     # (b, kvh, [hd,] mp, ps)
+        return got.reshape(b, *x.shape[1:-1], mp * x.shape[-1])
 
-    def gs(x):                             # (P, kvh, ps)
-        got = x[table]                     # (b, mp, kvh, ps)
-        got = jnp.moveaxis(got, 1, 2)      # (b, kvh, mp, ps)
-        return got.reshape(b, x.shape[1], mp * x.shape[2])
-
-    ks = gs(entry["ks"]) if "ks" in entry else None
-    vs = gs(entry["vs"]) if "vs" in entry else None
+    ks = g(entry["ks"]) if "ks" in entry else None
+    vs = g(entry["vs"]) if "vs" in entry else None
     return g(entry["k"]), g(entry["v"]), ks, vs
 
 
-def paged_write_rows(entry, k_row, v_row, ks_new, vs_new, page, off):
-    """Write one (kvh, hd) K/V row per slot into its pool page:
-    ``page``/``off`` are (b,) int32, row b lands at
-    [page_b, :, :, off_b]. An off of page_size (the DROP sentinel —
-    inactive or masked slots) drops the write entirely. Slots never
-    share a writable page (the COW invariant), so the scatter indexes
-    are disjoint."""
+def _scatter_lanes(entry, new, page, lane):
+    """The XLA arm of both page writes: update i of ``new[name]``
+    (n, kv_heads[, head_dim]) lands at [page_i, :, :, lane_i] of the
+    tensor's pool; a lane of page_size (the DROP sentinel) drops it.
+    Tensors of one rank share the index arrays."""
+    out, index = {}, {}
+    for name, pool in _tensors(entry):
+        rank = pool.ndim - 1
+        if rank not in index:
+            heads_dims = tuple(
+                _on_axis(jnp.arange(n), axis, rank)
+                for axis, n in enumerate(pool.shape[1:-1], 1))
+            index[rank] = ((_on_axis(page, 0, rank),) + heads_dims
+                           + (_on_axis(lane, 0, rank),))
+        out[name] = pool.at[index[rank]].set(
+            new[name].astype(pool.dtype), mode="drop")
+    return out
+
+
+def paged_write_rows(entry, row, page, off):
+    """Write one new row per slot (models.kvcache.new_row's, by tensor
+    name) into its pool page: ``page``/``off`` are (b,) int32, row b
+    lands at [page_b, :, :, off_b]. An off of page_size (the DROP
+    sentinel — inactive or masked slots) drops the write entirely.
+    Slots never share a writable page (the COW invariant), so the
+    scatter indexes are disjoint."""
     ps = entry["k"].shape[3]
-    kvh, hd = entry["k"].shape[1], entry["k"].shape[2]
-    quant = ks_new is not None
-    store_dt = entry["k"].dtype
     if kernel_gate(ps % 128 == 0, f"page row write (page_size={ps})"):
         from rlo_tpu.pallas.decode import write_kv_page_row
-        kc = write_kv_page_row(entry["k"], k_row, page, off)
-        vc = write_kv_page_row(entry["v"], v_row, page, off)
-        out = {"k": kc, "v": vc}
-        if quant:
-            # sidecars (P, kvh, ps) ride the same kernel via the free
-            # (P, kvh, 1, ps) view (the write_kv_row trick)
-            out["ks"] = write_kv_page_row(
-                entry["ks"][:, :, None, :], ks_new[:, :, None],
-                page, off)[:, :, 0, :]
-            out["vs"] = write_kv_page_row(
-                entry["vs"][:, :, None, :], vs_new[:, :, None],
-                page, off)[:, :, 0, :]
-        return out
-    heads = jnp.arange(kvh)[None, :, None]
-    dims = jnp.arange(hd)[None, None, :]
-    idx = (page[:, None, None], heads, dims, off[:, None, None])
-    out = {"k": entry["k"].at[idx].set(k_row.astype(store_dt),
-                                       mode="drop"),
-           "v": entry["v"].at[idx].set(v_row.astype(store_dt),
-                                       mode="drop")}
-    if quant:
-        sidx = (page[:, None], jnp.arange(kvh)[None, :],
-                off[:, None])
-        out["ks"] = entry["ks"].at[sidx].set(ks_new, mode="drop")
-        out["vs"] = entry["vs"].at[sidx].set(vs_new, mode="drop")
-    return out
+        return {name: _through_hd_view(write_kv_page_row, pool,
+                                       row[name], page, off)
+                for name, pool in _tensors(entry)}
+    return _scatter_lanes(entry, row, page, off)
 
 
-def paged_write_chunk(entry, kt, vt, ks_new, vs_new, page, off0,
-                      n_valid):
-    """Write one slot's prefill chunk: ``kt``/``vt`` (kvh, hd, T)
-    seq-minor, token t landing at [page, :, :, off0 + t] for
-    t < n_valid (pads dropped). The chunk never crosses a page
-    boundary (off0 + n_valid <= page_size, caller-scheduled), so ONE
-    page takes every lane — which is what makes the aliased TPU block
-    write legal (a single program owns the block)."""
+def paged_write_chunk(entry, block, page, off0, n_valid):
+    """Write one slot's prefill chunk: ``block`` (models.kvcache.
+    new_block's at batch 1, by tensor name: (1, kvh, hd, T) seq-minor),
+    token t landing at [page, :, :, off0 + t] for t < n_valid (pads
+    dropped). The chunk never crosses a page boundary (off0 + n_valid
+    <= page_size, caller-scheduled), so ONE page takes every lane —
+    which is what makes the aliased TPU block write legal (a single
+    program owns the block)."""
     ps = entry["k"].shape[3]
-    kvh = entry["k"].shape[1]
-    T = kt.shape[2]
-    store_dt = entry["k"].dtype
-    quant = ks_new is not None
     if kernel_gate(ps % 128 == 0, f"page chunk write (page_size={ps})"):
         from rlo_tpu.pallas.decode import write_kv_page_block
-        kc = write_kv_page_block(entry["k"], kt, page, off0, n_valid)
-        vc = write_kv_page_block(entry["v"], vt, page, off0, n_valid)
-        out = {"k": kc, "v": vc}
-        if quant:
-            out["ks"] = write_kv_page_block(
-                entry["ks"][:, :, None, :], ks_new[:, None, :],
-                page, off0, n_valid)[:, :, 0, :]
-            out["vs"] = write_kv_page_block(
-                entry["vs"][:, :, None, :], vs_new[:, None, :],
-                page, off0, n_valid)[:, :, 0, :]
-        return out
+        return {name: _through_hd_view(write_kv_page_block, pool,
+                                       block[name][0], page, off0,
+                                       n_valid, axis=1)
+                for name, pool in _tensors(entry)}
     # the scatter path: T updates into one page, pads dropped via the
     # page_size offset sentinel
-    t = jnp.arange(T)
-    offs = jnp.where(t < n_valid, off0 + t, ps)         # (T,)
-    pagev = jnp.full((T,), page)
-    heads = jnp.arange(kvh)[None, :, None]
-    dims = jnp.arange(entry["k"].shape[2])[None, None, :]
-    idx = (pagev[:, None, None], heads, dims, offs[:, None, None])
-    krows = jnp.moveaxis(kt, 2, 0)                      # (T, kvh, hd)
-    vrows = jnp.moveaxis(vt, 2, 0)
-    out = {"k": entry["k"].at[idx].set(krows.astype(store_dt),
-                                       mode="drop"),
-           "v": entry["v"].at[idx].set(vrows.astype(store_dt),
-                                       mode="drop")}
-    if quant:
-        sidx = (pagev[:, None], jnp.arange(kvh)[None, :],
-                offs[:, None])
-        out["ks"] = entry["ks"].at[sidx].set(
-            jnp.moveaxis(ks_new, 1, 0), mode="drop")
-        out["vs"] = entry["vs"].at[sidx].set(
-            jnp.moveaxis(vs_new, 1, 0), mode="drop")
-    return out
+    t = jnp.arange(block["k"].shape[3])
+    offs = jnp.where(t < n_valid, off0 + t, ps)             # (T,)
+    return _scatter_lanes(
+        entry, {name: jnp.moveaxis(x[0], -1, 0)             # token-major
+                for name, x in block.items()},
+        jnp.full(t.shape, page), offs)
 
 
 def _paged_attend(q, entry, table, pos_q, scale):
@@ -229,9 +198,7 @@ def paged_decode_step(params: dict, token, pos, pools, table, active,
     cache attend swapped in — the same single-source structure as
     models.generate.decode_step."""
     cfg = _decode_cfg(cfg)
-    dt = cfg.act_dtype
     posv = jnp.asarray(pos, jnp.int32)
-    b = token.shape[0]
     ps = pools[0]["k"].shape[3]
     mp = table.shape[1]
     page_i = jnp.clip(posv // ps, 0, mp - 1)
@@ -240,29 +207,15 @@ def paged_decode_step(params: dict, token, pos, pools, table, active,
     page = jnp.where(ok, page, 0)
     off = jnp.where(ok, posv % ps, ps)     # ps = the drop sentinel
     pos_arr = posv[:, None]
-    x = embed_tokens(params["embed"], token[:, None], pos_arr, cfg)
-    scale = 1.0 / (cfg.head_dim ** 0.5)
-    new_pools = []
-    for layer, lc in zip(params["layers"], pools):
-        def attend(q, k, v, lc=lc):
-            quant = "ks" in lc
-            k_row, v_row = k[:, 0], v[:, 0]          # (b, kvh, hd)
-            ks_new = vs_new = None
-            if quant:
-                k_row, ks_new = _quantize_kv(k_row)
-                v_row, vs_new = _quantize_kv(v_row)
-            entry = paged_write_rows(lc, k_row, v_row, ks_new,
-                                     vs_new, page, off)
-            new_pools.append(entry)
-            return _paged_attend(q, entry, table, pos_arr,
-                                 scale).astype(dt)
 
-        x, _ = apply_layer(x, layer, cfg, attention=attend,
-                           pos=pos_arr)
-    x = _rmsnorm(x, params["ln_f"]["g"])
-    logits = (x[:, 0, :] @ params["embed"].T.astype(dt)) \
-        .astype(jnp.float32)
-    return logits, new_pools
+    def step(lc, k, v):
+        entry = paged_write_rows(lc, new_row(lc, k, v), page, off)
+        return entry, lambda q: _paged_attend(q, entry, table, pos_arr,
+                                              cfg.attn_scale)
+
+    x, new_pools = _forward(params, token[:, None], pos_arr, pools, cfg,
+                            _cache_hook(cfg, step))
+    return _head(params, x, cfg, 0), new_pools
 
 
 def paged_prefill_chunk(params: dict, tokens, pos0, n_valid, pools,
@@ -282,8 +235,7 @@ def paged_prefill_chunk(params: dict, tokens, pos0, n_valid, pools,
     the same mask as models.generate.block_decode. MoE configs route
     drop-free (pads must be inert), the ragged-prefill rule."""
     cfg = _decode_cfg(cfg)
-    dt = cfg.act_dtype
-    b, T = tokens.shape
+    T = tokens.shape[1]
     ps = pools[0]["k"].shape[3]
     mp = table.shape[1]
     pos0 = jnp.asarray(pos0, jnp.int32)
@@ -291,50 +243,22 @@ def paged_prefill_chunk(params: dict, tokens, pos0, n_valid, pools,
     page = table[0, jnp.clip(pos0 // ps, 0, mp - 1)]
     off0 = pos0 % ps
     pos_arr = pos0 + jnp.arange(T, dtype=jnp.int32)[None, :]  # (1, T)
-    x = embed_tokens(params["embed"], tokens, pos_arr, cfg)
-    scale = 1.0 / (cfg.head_dim ** 0.5)
-    new_pools = []
-    for layer, lc in zip(params["layers"], pools):
-        def attend(q, k, v, lc=lc):
-            quant = "ks" in lc
-            kt = k[0].transpose(1, 2, 0)             # (kvh, hd, T)
-            vt = v[0].transpose(1, 2, 0)
-            ks_new = vs_new = None
-            if quant:
-                # quantize over hd per position BEFORE the seq-minor
-                # flip (the block_decode ordering)
-                kq, ks_new = _quantize_kv(k[0])      # (T, kvh, hd)
-                vq, vs_new = _quantize_kv(v[0])
-                kt = kq.transpose(1, 2, 0)
-                vt = vq.transpose(1, 2, 0)
-                ks_new = ks_new.transpose(1, 0)      # (kvh, T)
-                vs_new = vs_new.transpose(1, 0)
-            entry = paged_write_chunk(lc, kt, vt, ks_new, vs_new,
-                                      page, off0, n_valid)
-            new_pools.append(entry)
-            return _paged_attend(q, entry, table, pos_arr,
-                                 scale).astype(dt)
 
-        x, _ = apply_layer(x, layer, cfg, attention=attend,
-                           pos=pos_arr)
-    x = _rmsnorm(x, params["ln_f"]["g"])
-    idx = jnp.clip(n_valid - 1, 0, T - 1)[None, None, None]
-    xl = jnp.take_along_axis(
-        x, jnp.broadcast_to(idx, (b, 1, x.shape[-1])), axis=1)[:, 0]
-    logits = (xl @ params["embed"].T.astype(dt)).astype(jnp.float32)
-    return logits, new_pools
+    def step(lc, k, v):
+        entry = paged_write_chunk(lc, new_block(lc, k, v), page, off0,
+                                  n_valid)
+        return entry, lambda q: _paged_attend(q, entry, table, pos_arr,
+                                              cfg.attn_scale)
+
+    x, new_pools = _forward(params, tokens, pos_arr, pools, cfg,
+                            _cache_hook(cfg, step))
+    return _head(params, x, cfg,
+                 jnp.clip(n_valid - 1, 0, T - 1)), new_pools
 
 
 def copy_page(pools, src, dst):
     """The COW primitive: dst := src across every layer's pools (K, V
     and the int8 scale sidecars). Jit with donated pools so the copy
     is in-place at the XLA level."""
-    out = []
-    for entry in pools:
-        e = {"k": entry["k"].at[dst].set(entry["k"][src]),
-             "v": entry["v"].at[dst].set(entry["v"][src])}
-        if "ks" in entry:
-            e["ks"] = entry["ks"].at[dst].set(entry["ks"][src])
-            e["vs"] = entry["vs"].at[dst].set(entry["vs"][src])
-        out.append(e)
-    return out
+    return [{name: pool.at[dst].set(pool[src])
+             for name, pool in _tensors(entry)} for entry in pools]

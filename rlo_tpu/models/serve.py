@@ -56,13 +56,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from rlo_tpu.models.generate import (block_decode, decode_step,
-                                     fold_kv_tail, init_kv_cache,
-                                     init_kv_tail, prefill, _decode_cfg)
-from rlo_tpu.models import moe
+from rlo_tpu.models import kvcache, moe
+from rlo_tpu.models.generate import (block_decode, decode_step, prefill,
+                                     _decode_cfg)
+from rlo_tpu.models.kvcache import (fold_kv_tail, init_kv_cache,
+                                    init_kv_tail)
 from rlo_tpu.models.transformer import TransformerConfig
 from rlo_tpu.observe.spans import Stage
-from rlo_tpu.pallas.decode import can_flash_decode, flash_decode_tile
 from rlo_tpu.pallas.reduce import _on_tpu
 from rlo_tpu.utils.metrics import Registry, SERVING
 from rlo_tpu.utils.tracing import annotate
@@ -211,21 +211,14 @@ class DecodeServer:
                 f"no prompt bucket fits max_len {max_len} "
                 f"(buckets {tuple(sorted(prompt_buckets))})")
         self.cache = init_kv_cache(cfg, n_slots, max_len)
+        self.metrics.gauge("serve.cache_bytes_per_token").set(
+            kvcache.bytes_per_position(self.cache))
         # the decode kernel's tiling of the cache axis as (tile width,
         # tiles), for the attend-tile counters; None where decode_step
         # attends through the einsum (off the tpu backend, or a shape
         # can_flash_decode refuses): nothing is tiled, nothing counted
-        k = self.cache[0]["k"]
-        self.metrics.gauge("serve.cache_bytes_per_token").set(
-            sum(a.size * a.dtype.itemsize
-                for a in jax.tree.leaves(self.cache))
-            // (n_slots * k.shape[3]))
-        latent = cfg.kv_lora_rank if cfg.mla else 0
-        self._attend_tiling = None
-        if _on_tpu() and can_flash_decode(k.shape[3], k.shape[2],
-                                          v_dim=latent):
-            bk = flash_decode_tile(k, cfg.n_heads, latent=cfg.mla)
-            self._attend_tiling = (bk, -(-k.shape[3] // bk))
+        self._attend_tiling = (kvcache.attend_tiling(self.cache, cfg)
+                               if _on_tpu() else None)
         # 'sigmoid_group' expert layers report their routing counts
         # (models.moe.STATS): the round then carries their sum over its
         # steps and layers and returns it as a fifth output
@@ -235,7 +228,7 @@ class DecodeServer:
         # between, so the new K/V rows wait in a write-behind tail and
         # reach the seq-minor cache once a round (init_kv_tail). An
         # int8 cache has no tail: it keeps the write of every step.
-        self._kv_tail = cfg.kv_cache_dtype is None
+        self._kv_tail = kvcache.keeps_tail(self.cache)
 
         def round_fn(params, cache, last_tok, pos, kk):
             tail = self._kv_tail        # read when the round is traced
@@ -298,11 +291,9 @@ class DecodeServer:
         self._extend = jax.jit(extend_chunk, donate_argnums=(1,))
 
         def scatter_slot(cache, row, slot):
-            def put(big, small):
-                return lax.dynamic_update_slice(
-                    big, small.astype(big.dtype),
-                    (slot,) + (0,) * (big.ndim - 1))
-            return jax.tree.map(put, cache, row)
+            # this server's own function: jit keeps its trace cache,
+            # which _observe_round counts, by function
+            return kvcache.scatter_slot(cache, row, slot)
 
         self._scatter = jax.jit(scatter_slot, donate_argnums=(0,))
         # jitted function -> trace-cache entries it was built to hold

@@ -88,13 +88,13 @@ class TransformerConfig:
     #   None   — cache in the activation dtype (exact decode)
     #   'int8' — per-(position, head) symmetric quantization: HALF the
     #            cache memory and HBM bytes of bf16, error one
-    #            quantization half-step per read. With the flash-decode
-    #            kernel (pallas/decode.py) dequantizing tiles in VMEM,
-    #            measured 1.17-1.43x decode tok/s (across windows)
-    #            at batch 32 / plen 1024
-    #            on v5e (interleaved paired ratio,
-    #            benchmarks/decode_bench.py --compare-kv); also 2x the
-    #            servable batch x context per chip.
+    #            quantization half-step per read; 2x the servable
+    #            batch x context per chip. The flash-decode kernel
+    #            (pallas/decode.py) dequantizes tiles in VMEM. No cell
+    #            of the benchmark runs it (ROADMAP D12); a builder's
+    #            run of 2026-08-01, from before the ledger
+    #            (BENCH_extra.json, decode_bench.py --compare-kv, v5e,
+    #            batch 32, plen 1024): 1.17-1.43x decode tok/s.
     kv_cache_dtype: Optional[str] = None
     # rematerialize each layer in the backward pass (jax.checkpoint):
     # trades ~one extra forward of FLOPs for O(layers) less activation

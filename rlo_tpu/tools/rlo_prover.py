@@ -110,6 +110,7 @@ P4_FILES = ("rlo_tpu/ops/tpu_collectives.py",
             "rlo_tpu/ops/ring_attention.py", "rlo_tpu/ops/ulysses.py",
             "rlo_tpu/models/transformer.py", "rlo_tpu/models/moe.py",
             "rlo_tpu/models/pipeline.py", "rlo_tpu/models/generate.py",
+            "rlo_tpu/models/kvcache.py",
             "rlo_tpu/parallel/consensus.py", "rlo_tpu/backend.py")
 SERVE_PY = "rlo_tpu/models/serve.py"
 PAGED_PY = "rlo_tpu/models/paged.py"
