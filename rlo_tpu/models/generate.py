@@ -179,14 +179,18 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
     cfg = _decode_cfg(cfg)
     posv = jnp.asarray(pos)
     v_dim = cfg.kv_lora_rank if cfg.mla else 0
+    # upto: the last cache position a query attends
     if tail is None:
-        tails = [None] * len(cache)
+        tails, upto = [None] * len(cache), posv
     else:
         tails, newest = tail
         newest = jnp.asarray(newest, jnp.int32)
-        before = posv - newest - 1      # the last position the cache holds
+        upto = posv - newest - 1        # the last position the cache holds
     # (1,) shared positions, or (b, 1) per-row, for embed/rope
     pos_arr = posv[:, None] if posv.ndim == 1 else posv[None]
+
+    # the attend kernel's work list up to there: one for all layers
+    work = kvcache.attend_work(cache, cfg, upto, tp_axis=tp_axis)
 
     def step(lc_tl, k, v):
         lc, tl = lc_tl
@@ -194,10 +198,11 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
         if tl is None:
             entry = kvcache.write_row(lc, row, posv)
             return entry, lambda q: kvcache.attend(
-                q, entry, posv, cfg.attn_scale, v_dim=v_dim)
+                q, entry, upto, cfg.attn_scale, v_dim=v_dim, work=work)
         tl = kvcache.store_tail_row(tl, row, newest)
         return tl, lambda q: kvcache.attend(
-            q, lc, before, cfg.attn_scale, v_dim=v_dim, tail=(tl, newest))
+            q, lc, upto, cfg.attn_scale, v_dim=v_dim, tail=(tl, newest),
+            work=work)
 
     x, new = _forward(params, token[:, None], pos_arr,
                       list(zip(cache, tails)), cfg, _cache_hook(cfg, step),
@@ -223,11 +228,14 @@ def block_decode(params: dict, tokens, pos0, cache,
     pos_arr = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)  # (b, T)
     v_dim = cfg.kv_lora_rank if cfg.mla else 0
 
+    work = kvcache.attend_work(cache, cfg, pos0, T, tp_axis=tp_axis)
+
     def step(lc, k, v):
         entry = kvcache.write_block(lc, kvcache.new_block(lc, k, v),
                                     pos0, pos_arr)
         return entry, lambda q: kvcache.attend_block(
-            q, entry, pos_arr, cfg.attn_scale, pos0=pos0, v_dim=v_dim)
+            q, entry, pos_arr, cfg.attn_scale, pos0=pos0, v_dim=v_dim,
+            work=work)
 
     x, new = _forward(params, tokens, pos_arr, cache, cfg,
                       _cache_hook(cfg, step), tp_axis=tp_axis,

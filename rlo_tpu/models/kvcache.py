@@ -18,7 +18,8 @@ become columns of an entry (``new_row``, ``new_block``), how they are
 written (``write_row``, ``write_block``, ``store_prompt``; the round's
 write-behind tail: ``init_kv_tail``, ``store_tail_row``,
 ``fold_kv_tail``), how an entry is attended (``attend``,
-``attend_block``) and what a server asks of a cache (``scatter_slot``,
+``attend_block``, and ``attend_work``: what all layers of a step
+share) and what a server asks of a cache (``scatter_slot``,
 ``bytes_per_position``, ``keeps_tail``, ``attend_tiling``) — is one
 function here that LOOPS OVER THE ENTRY'S TENSORS; a scale sidecar
 rides the write kernels through its free (batch, kv_heads, 1, max_len)
@@ -38,7 +39,8 @@ from jax import lax
 
 from rlo_tpu.models.transformer import TransformerConfig
 from rlo_tpu.ops.ring_attention import _NEG
-from rlo_tpu.pallas.reduce import KernelFallbackWarning, kernel_gate
+from rlo_tpu.pallas.reduce import (KernelFallbackWarning, _on_tpu,
+                                   kernel_gate)
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -365,27 +367,52 @@ def store_tail_row(tail, row, newest):
         for name, rows in tail.items()}
 
 
-def attend(q, entry, pos, scale, *, v_dim: int = 0, tail=None):
+def attend_work(cache, cfg: TransformerConfig, pos, T: int = 1,
+                tp_axis: Optional[str] = None):
+    """What the attends of ONE step share, built once for all layers:
+    the flash-decode kernel's work list (pallas.decode.decode_work_list)
+    for T queries a row from position ``pos`` (a scalar or (b,)) on —
+    the list is a function of ``pos``, T and the cache's shape, and
+    every layer's entry has the same shape. Handed to ``attend`` /
+    ``attend_block`` as ``work``. None where the attend is the einsum
+    (off the tpu backend, or a shape can_flash_decode refuses)."""
+    from rlo_tpu.pallas.decode import decode_work_list
+    ntp = lax.axis_size(tp_axis) if tp_axis is not None else 1
+    tiling = (attend_tiling(cache, cfg, cfg.n_heads // ntp)
+              if _on_tpu() else None)
+    if tiling is None:
+        return None
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
+                           cache[0]["k"].shape[:1])
+    return decode_work_list(pos, T, *tiling)
+
+
+def attend(q, entry, pos, scale, *, v_dim: int = 0, tail=None,
+           work=None):
     """One query a row, q (b, 1, H, head_dim), against ``entry``'s
     positions <= pos with the entry's own scales (_attend_cache).
     ``v_dim``: latent attention's kv_lora_rank — a latent entry cannot
     say where its values end. ``tail`` ``(rows, newest)``: one layer's
-    write-behind rows, attended beside the cache."""
+    write-behind rows, attended beside the cache. ``work``: the step's
+    attend_work, for this ``pos``."""
     if tail is not None:
         rows, newest = tail
         tail = (rows["k"], rows.get("v"), newest)
     return _attend_cache(q, entry["k"], entry.get("v"), pos, scale,
                          k_scale=entry.get("ks"),
-                         v_scale=entry.get("vs"), v_dim=v_dim, tail=tail)
+                         v_scale=entry.get("vs"), v_dim=v_dim, tail=tail,
+                         work=work)
 
 
-def attend_block(q, entry, pos_q, scale, *, pos0, v_dim: int = 0):
+def attend_block(q, entry, pos_q, scale, *, pos0, v_dim: int = 0,
+                 work=None):
     """T queries a row, q (b, T, H, head_dim), query i at position
-    pos_q[b, i] = pos0_b + i (_attend_cache_block)."""
+    pos_q[b, i] = pos0_b + i (_attend_cache_block). ``work``: the
+    step's attend_work, for ``pos0`` and this T."""
     return _attend_cache_block(q, entry["k"], entry.get("v"), pos_q,
                                scale, k_scale=entry.get("ks"),
                                v_scale=entry.get("vs"), pos0=pos0,
-                               v_dim=v_dim)
+                               v_dim=v_dim, work=work)
 
 
 # ---- what a server asks of a cache ------------------------------------
@@ -409,16 +436,18 @@ def bytes_per_position(cache) -> int:
                                                     * k.shape[3])
 
 
-def attend_tiling(cache, cfg: TransformerConfig):
+def attend_tiling(cache, cfg: TransformerConfig,
+                  n_heads: Optional[int] = None):
     """flash_decode's tiling of the cache axis as (tile width, tiles);
     None for a shape can_flash_decode refuses (the attend is the
-    einsum: nothing is tiled)."""
+    einsum: nothing is tiled). ``n_heads``: the query heads beside
+    this cache, where a shard holds fewer than cfg.n_heads."""
     from rlo_tpu.pallas.decode import can_flash_decode, flash_decode_tile
     k = cache[0]["k"]
     latent = cfg.kv_lora_rank if cfg.mla else 0
     if not can_flash_decode(k.shape[3], k.shape[2], v_dim=latent):
         return None
-    bk = flash_decode_tile(k, cfg.n_heads, latent=cfg.mla)
+    bk = flash_decode_tile(k, n_heads or cfg.n_heads, latent=cfg.mla)
     return bk, -(-k.shape[3] // bk)
 
 
@@ -426,7 +455,7 @@ def attend_tiling(cache, cfg: TransformerConfig):
 
 def _attend_cache(q, k_cache, v_cache, pos, scale,
                   k_scale=None, v_scale=None, use_flash=None,
-                  v_dim: int = 0, tail=None):
+                  v_dim: int = 0, tail=None, work=None):
     """q (b, 1, H, hd) against the cache prefix [0, pos]: full-length
     matmul over the static cache, masked beyond the position. ``pos``
     is a scalar (all rows at the same position) or a (b,) vector
@@ -452,7 +481,11 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
     attends cache positions <= pos AND tail rows 0..newest — row t is
     position pos + 1 + t, which the cache does not hold yet — in one
     softmax; tail positions at or past max_len are left out, as the
-    per-step write drops them."""
+    per-step write drops them.
+
+    ``work``: the kernel's work list for this ``pos``, where the
+    caller built one for all its layers (attend_work); the einsum has
+    no use for it."""
     b, one, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
     if use_flash is None:
@@ -468,7 +501,8 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
         # pass — rlo_tpu.pallas.decode
         from rlo_tpu.pallas.decode import flash_decode
         return flash_decode(q, k_cache, v_cache, pos, scale,
-                            k_scale, v_scale, v_dim=v_dim, tail=tail)
+                            k_scale, v_scale, v_dim=v_dim, tail=tail,
+                            work=work)
     # the einsum path IS the T=1 case of the block attend — one
     # implementation, so a dequant/mask/dtype fix can never diverge
     # decode_step from block_decode (speculative decoding's
@@ -483,7 +517,8 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
 
 def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                         k_scale=None, v_scale=None, pos0=None,
-                        use_flash=None, v_dim: int = 0, tail=None):
+                        use_flash=None, v_dim: int = 0, tail=None,
+                        work=None):
     """Block variant of the cache attend: q (b, T, nh, hd) where query
     i of row b sits at position pos_q[b, i] and attends cache
     positions <= pos_q[b, i]. Because the block's own K/V rows are
@@ -502,7 +537,8 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
     needs their argmaxes to agree).
 
     ``tail`` (T = 1, the einsum path only; see _attend_cache): the
-    tail's scores join the cache's before the softmax."""
+    tail's scores join the cache's before the softmax. ``work``: see
+    _attend_cache (for ``pos0`` and this T)."""
     b, T, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
     if use_flash is None:
@@ -534,7 +570,8 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
     if use_flash:
         from rlo_tpu.pallas.decode import flash_block_decode
         return flash_block_decode(q, k_cache, v_cache, pos0, scale,
-                                  k_scale, v_scale, v_dim=v_dim)
+                                  k_scale, v_scale, v_dim=v_dim,
+                                  work=work)
     if v_dim:  # latent: the values are the stream's leading features
         v_cache = k_cache[:, :, :v_dim]
     rep = nh // nkv

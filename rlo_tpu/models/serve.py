@@ -120,9 +120,10 @@ class DecodeServer:
     ``serve.admissions``, ``serve.prefill_tokens`` against
     ``serve.prefill_padded_tokens``, ``serve.slot_steps`` against
     ``serve.slot_steps_useful``, ``serve.attend_tiles`` against
-    ``serve.attend_tiles_live`` (dense scheduler, flash_decode path:
-    cache tiles in the round's grid, and those a row's live context
-    reaches — the rest the kernel skips), the gauge
+    ``serve.attend_tiles_live`` and ``serve.attend_steps`` (dense
+    scheduler, flash_decode path: cache tiles of a grid over max_len,
+    those a row's live context reaches, and the grid steps the kernel
+    runs — its work list holds the live tiles alone), the gauge
     ``serve.cache_bytes_per_token`` (dense scheduler: what one position
     of one slot holds over all layers), ``serve.moe.<count>`` for
     'sigmoid_group' expert layers (models.moe.STATS, summed over the
@@ -834,16 +835,20 @@ class DecodeServer:
             2 * self.n_slots * len(jax.tree.leaves(self.cache)))
 
     def _count_attend_tiles(self, kk: int) -> None:
-        """How often flash_decode's skip engages, from the host's own
-        ``pos``: ``serve.attend_tiles`` is every (row, step, cache
-        tile) of the round's grid, ``serve.attend_tiles_live`` those a
-        row's context reaches; the rest are neither fetched nor
-        computed. With the tail every step of the round attends the
-        cache as the round found it, positions < pos: tiles
-        0 .. (pos - 1) // bk, the same in every step (tile 0 alone for
-        an empty row). Without it step s attends positions <= pos + s.
-        Every slot counts, free and finished ones too: the kernel runs
-        them."""
+        """What flash_decode's grid walks, from the host's own ``pos``
+        by the kernel's rule (its tile width, _last_live_tile's
+        clip): ``serve.attend_tiles`` is every (row, step, cache tile)
+        of a grid over max_len, the denominator of the live share;
+        ``serve.attend_tiles_live`` those a row's context reaches;
+        ``serve.attend_steps`` the grid steps the round's attends run.
+        The grid is the work list of live (row, tile) pairs
+        (decode_work_list), so steps == tiles_live says it engages; a
+        grid over max_len would read == tiles. With the tail every
+        step of the round attends the cache as the round found it,
+        positions < pos: tiles 0 .. (pos - 1) // bk, the same in every
+        step (tile 0 alone for an empty row). Without it step s
+        attends positions <= pos + s. Every slot counts, free and
+        finished ones too: the kernel runs them."""
         if self._attend_tiling is None:
             return
         bk, n_k = self._attend_tiling
@@ -852,10 +857,11 @@ class DecodeServer:
         else:
             last = np.minimum((self.pos[:, None] + np.arange(kk)) // bk,
                               n_k - 1)
+        live = int(last.sum()) + kk * self.n_slots
         self.metrics.counter("serve.attend_tiles").inc(
             kk * self.n_slots * n_k)
-        self.metrics.counter("serve.attend_tiles_live").inc(
-            int(last.sum()) + kk * self.n_slots)
+        self.metrics.counter("serve.attend_tiles_live").inc(live)
+        self.metrics.counter("serve.attend_steps").inc(live)
 
     def _observe_round(self, dt: float, kk: int) -> None:
         self._hist("serve.round_usec").observe(dt * 1e6)

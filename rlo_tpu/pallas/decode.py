@@ -2,7 +2,7 @@
 
 Decode attention is the other half of the serving HBM story: each step
 reads every row's live cache prefix (and, tile-rounded, no more: see
-the grid paragraph), and the XLA einsum path
+the work-list paragraph), and the XLA einsum path
 (models.generate._attend_cache) was measured 2-4x off the
 weight+cache streaming bound at batch 32 / plen 1024 on v5e — and,
 worse, de-optimized the int8 cache (XLA materializes the dequantized
@@ -18,25 +18,35 @@ The work per position is tiny (a (r, d) x (d, BK) dot), so the grid
 must be coarse or per-program launch overhead dominates — the first
 cut ran one program per (batch, kv-head, tile) and measured 2x SLOWER
 than the einsum at batch 32 (12k programs/step of ~100 ns of useful
-bandwidth each). The shipped grid is (batch, cache-tiles) with ALL kv
+bandwidth each). A grid step is one (row, cache-tile) with ALL kv
 heads resident per program (a batched dot over the head axis), two
 orders of magnitude fewer launches, each streaming kvh*BK*d cache
 bytes.
 
-The grid spans max_len, but a row reads only the tiles its live
-context reaches. ``pos`` is scalar-prefetched and the K/V (and int8
-scale) index maps (_cache_block) present a row's own tiles up to the
-one holding position pos + T - 1; on the grid steps past it — the
-dead steps — they present tile 0 of the NEXT row, and the kernel body
-does not run. Pallas issues no copy for a block index that repeats,
-so a row's dead tiles cost neither HBM bytes nor compute, only an
-empty grid step each (~0.3 us), and the next row's first tile is
-already under way when the row's own steps begin. A wholly masked
-tile contributes p = 0 and corr = 1, so the skip changes no bit of
-the output. The tile width BK is therefore the granularity of the
-skip as well as of the stream (_pick_bk). A server's rows sit at a
-third of max_len on average, which is what this buys (PERF.md, PR 26:
-the attend of a 96-row gpt2-medium decode step 0.62 -> 0.33 ms).
+The grid is a WORK LIST, as long as the round's contexts and no
+longer. A row reads only the tiles its live context reaches — tile 0
+up to the one holding position pos + T - 1 (_last_live_tile) — and
+only those are grid steps: decode_work_list turns ``pos`` into the
+live (row, tile) pairs, rows in order, a row's tiles ascending, and the
+kernel runs a 1-D grid over them whose bound, n_work, is read on the
+device. ``row_of`` and ``tile_of`` are scalar-prefetched (beside
+``pos``); the K/V (and int8 scale) index maps are (row_of[i], 0, 0,
+tile_of[i]), q's, the tail's and the output's (row_of[i], 0, 0, 0). A
+wholly masked tile would contribute p = 0 and corr = 1, so leaving it
+out changes no bit of the output, and with no dead steps between them
+the pipeline's ordinary look-ahead has the next row's first tile, its
+q and its tail rows under way behind a row's last tile. (The grid used
+to span (batch, max_len / BK) with the dead steps skipped in the body:
+an empty grid step cost 0.2-0.35 us, 58% of the steps of a server's
+mix at BK 256; PERF.md, PR 30.) The tile width BK is therefore the
+granularity of a row's last, partly dead tile as well as of the
+stream (_pick_bk). The list depends on ``pos``, T and the cache's
+shape alone, so a step builds it once for all its layers
+(models.kvcache.attend_work) and a lone call builds its own. The grid
+is sequential ('arbitrary'): the accumulators cross its steps. A v5e
+has one TensorCore, so the 'parallel' batch axis of the old grid
+bought nothing there; a chip with two would want the list split in
+two halves of equal work, one a core.
 
 Shapes (GQA-grouped, head-leading, SEQ-MINOR — models.generate
 stores the cache with max_len as the minor dim so HBM tiles stream at
@@ -49,8 +59,10 @@ decode, so both paths share one kernel and its numerics:
   q        (b, kv_heads, T*r, head_dim)  r = n_heads / kv_heads
   k/v      (b, kv_heads, head_dim, max_len)  act dtype or int8
   ks/vs    (b, kv_heads, max_len) f32 scales (int8 caches only)
-  pos      (b,) int32, scalar-prefetched (SMEM) — query t of row b
-           masks prefix [0, pos_b + t]
+  row_of, tile_of  (b * n_k + 1,) int32, scalar-prefetched (SMEM):
+           the work list, n_work live entries then a sentinel
+  pos      (b,) int32, scalar-prefetched — query t of row b masks
+           prefix [0, pos_b + t]
   out      (b, kv_heads, T*r, head_dim) f32
   scratch  m/l (kv_heads, T*r), o (kv_heads, T*r, head_dim) f32
 
@@ -62,24 +74,25 @@ serves scores and values, for all heads (r = n_heads query rows):
   q        (b, 1, T*n_heads, d)     d = kv_lora + rope dims (576)
   k        (b, 1, d, max_len)       v = k[:, :, :v_dim]  (512)
   out      (b, 1, T*n_heads, v_dim) f32
-Tile picking, the live-tile skip and the ``pos`` prefetch are the same
-code (_pick_bk with its own bytes-a-step constant, _cache_block).
+Tile picking and the work list are the same code (_pick_bk with its
+own bytes-a-step constant, decode_work_list).
 
 A WRITE-BEHIND TAIL (T = 1; DecodeServer's round, docs/DESIGN.md §4):
 the rows a round has produced so far wait token-major beside the cache
 and reach it once a round (write_kv_tail), because one new column costs
 a whole 128-lane block a row to store. The kernel takes them as
   tk/tv    (kk, b, kv_heads, head_dim)  cache dtype; no tv if latent
-  newest   (1,) int32, prefetched beside pos — rows 0..newest are live,
+  newest   (1,) int32, prefetched after pos — rows 0..newest are live,
            row t is position pos_b + 1 + t
-and folds them into the same softmax at a row's first grid step.
+and folds them into the same softmax at a row's first grid step (its
+tile 0, which every row has).
 
 Dots run in bf16 with f32 accumulation (int8 -> bf16 is lossless;
 f32 caches keep f32 dots — their tiles are smaller than VMEM allows
-anyway). The cache axis is innermost and sequential ('arbitrary'),
-accumulating (m, l, o) in VMEM scratch — initialised at a row's first
-grid step and flushed at its last, both outside the skip; the padded
-tail block past max_len is masked (and V zeroed under the mask, so
+anyway). (m, l, o) accumulate in VMEM scratch — initialised at a
+row's first grid step (tile_of[i] == 0) and flushed at its last
+(row_of[i + 1] is another row, or the sentinel that ends the list);
+the padded tail block past max_len is masked (and V zeroed under the mask, so
 out-of-range garbage can never ride a 0*NaN into the accumulator).
 """
 
@@ -105,10 +118,10 @@ _BLOCK_K = 512
 _SUBLANES = 16
 
 
-def _decode_kernel(pos_ref, *refs, scale: float,
-                   n_k: int, bk: int, max_len: int, quant: bool,
+def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
+                   bk: int, max_len: int, quant: bool,
                    r: int, T: int, v_dim: int = 0, n_tail: int = 0):
-    if n_tail:          # a second prefetched scalar: the tail's newest row
+    if n_tail:          # a fourth prefetched scalar: the tail's newest row
         newest_ref, refs = refs[0], refs[1:]
     q_ref, k_ref, *rest = refs
     if not v_dim:       # a V operand; a latent cache has none
@@ -121,8 +134,10 @@ def _decode_kernel(pos_ref, *refs, scale: float,
         ks_ref, vs_ref, o_ref, m_s, l_s, o_s = rest
     else:
         o_ref, m_s, l_s, o_s = rest
-    ib = pl.program_id(0)
-    ik = pl.program_id(1)
+    # grid step i of the work list (decode_work_list): tile ik of row ib
+    i = pl.program_id(0)
+    ib = row_ref[i]
+    ik = tile_ref[i]
     # dots in bf16 (f32 accumulate): int8 -> bf16 is lossless, bf16 is
     # the MXU-native width, and an f32 cast would materialize 4x the
     # tile bytes in VMEM. f32 caches keep f32 (exactness; their tiles
@@ -140,10 +155,10 @@ def _decode_kernel(pos_ref, *refs, scale: float,
         # 0..newest of this round, token-major, row t at position
         # pos + 1 + t. It is one more tile of the same online softmax,
         # with K arriving (kk, d) instead of (d, BK), and it goes
-        # FIRST — it starts the accumulators where zeros would — so
-        # that its block is free from the row's second grid step on
-        # and the next row's can be fetched behind this row's cache
-        # tiles (_tail_block). Rows past ``newest`` are the zeros the
+        # FIRST — it starts the accumulators where zeros would — at
+        # the row's first grid step, its block fetched a step ahead
+        # like any other (behind the previous row's last cache tile).
+        # Rows past ``newest`` are the zeros the
         # tail was made of; a position at or past max_len is one the
         # per-step write would have dropped; a row with neither
         # leaves m = _NEG, l = 0, o = 0, as no tail does.
@@ -169,61 +184,62 @@ def _decode_kernel(pos_ref, *refs, scale: float,
 
     pos = pos_ref[ib]
 
-    # a tile that starts past the last position any query of this row
-    # attends (pos + T - 1) is masked to p = 0, m unchanged, corr = 1:
-    # skipping it is bit-identical to computing it. Its K/V were not
-    # fetched either: on exactly these steps k_ref/v_ref hold the
-    # next row's first tile (_cache_block, the same rule).
-    @pl.when(ik <= _last_live_tile(pos, T, bk, n_k))
-    def _attend():
-        # g = kvh heads batched per program. The query axis holds
-        # T*r rows, t-major: row t*r+rr is block token t, group-member rr,
-        # at sequence position pos + t (T=1 recovers single-token decode).
-        q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
-        k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
-        # latent: the values are the key stream's leading features
-        v = (k[:, :v_dim, :] if v_dim
-             else v_ref[0].astype(dot_dt))               # (g, d, BK)
-        # masks built >=2-D from iota: Mosaic cannot insert a minor dim on
-        # sub-32-bit (bool) values, so never reshape a 1-D mask
-        base = ik * bk
-        row = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
-        # per-query causal position: query row t*r+rr masks at pos + t
-        qoff = jax.lax.broadcasted_iota(jnp.int32, (1, T * r, 1), 1) // r
-        mask_row = (row <= pos + qoff) & (row < max_len)  # (1, T*r, BK)
-        # V zeroing: any key a query of this block may attend (<= pos+T-1)
-        # — seq-minor V masks over its LAST axis
-        mask_col = (row <= pos + (T - 1)) & (row < max_len)  # (1, 1, BK)
+    # every step of the list is a live tile: one that starts at or
+    # before the last position a query of this row attends
+    # (pos + T - 1). The tiles past it would be masked to p = 0, m
+    # unchanged, corr = 1 — leaving them out is bit-identical to
+    # computing them — and they are not in the list.
 
-        # batched over the head axis, contracting head_dim — the seq-minor
-        # cache arrives as the MXU-native (d, BK) operand
-        s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * scale
-        if quant:
-            s = s * ks_ref[0]                            # (g, 1, BK)
-        s = jnp.where(mask_row, s, _NEG)                 # (g, T*r, BK)
-        # zero V under the mask: a padded tail tile may hold uninitialized
-        # VMEM, and 0 * NaN would poison the accumulator
-        v = jnp.where(mask_col, v, jnp.zeros((), dot_dt))
+    # g = kvh heads batched per program. The query axis holds
+    # T*r rows, t-major: row t*r+rr is block token t, group-member rr,
+    # at sequence position pos + t (T=1 recovers single-token decode).
+    q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
+    k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
+    # latent: the values are the key stream's leading features
+    v = (k[:, :v_dim, :] if v_dim
+         else v_ref[0].astype(dot_dt))               # (g, d, BK)
+    # masks built >=2-D from iota: Mosaic cannot insert a minor dim on
+    # sub-32-bit (bool) values, so never reshape a 1-D mask
+    base = ik * bk
+    row = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+    # per-query causal position: query row t*r+rr masks at pos + t
+    qoff = jax.lax.broadcasted_iota(jnp.int32, (1, T * r, 1), 1) // r
+    mask_row = (row <= pos + qoff) & (row < max_len)  # (1, T*r, BK)
+    # V zeroing: any key a query of this block may attend (<= pos+T-1)
+    # — seq-minor V masks over its LAST axis
+    mask_col = (row <= pos + (T - 1)) & (row < max_len)  # (1, 1, BK)
 
-        m = m_s[...]                                     # (g, T*r)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.where(mask_row, jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.exp(m - m_new)
-        m_s[...] = m_new
-        l_s[...] = l_s[...] * corr + p.sum(axis=-1)
-        # fold the v dequant into the probabilities (f32, no relayout of
-        # v) — AFTER the l accumulation (the softmax denominator must sum
-        # the unscaled probabilities) and re-masked: the padded tail's vs
-        # tile is uninitialized VMEM and p's zeros would ride 0*NaN into
-        # the accumulator, the same hazard v is zeroed for above
-        pv = jnp.where(mask_row, p * vs_ref[0], 0.0) if quant else p
-        # p (g, R, BK) x v (g, d, BK), contracting BK
-        o_s[...] = o_s[...] * corr[..., None] + jax.lax.dot_general(
-            pv.astype(dot_dt), v, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+    # batched over the head axis, contracting head_dim — the seq-minor
+    # cache arrives as the MXU-native (d, BK) operand
+    s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32) * scale
+    if quant:
+        s = s * ks_ref[0]                            # (g, 1, BK)
+    s = jnp.where(mask_row, s, _NEG)                 # (g, T*r, BK)
+    # zero V under the mask: a padded tail tile may hold uninitialized
+    # VMEM, and 0 * NaN would poison the accumulator
+    v = jnp.where(mask_col, v, jnp.zeros((), dot_dt))
 
-    @pl.when(ik == n_k - 1)
+    m = m_s[...]                                     # (g, T*r)
+    m_new = jnp.maximum(m, s.max(axis=-1))
+    p = jnp.where(mask_row, jnp.exp(s - m_new[..., None]), 0.0)
+    corr = jnp.exp(m - m_new)
+    m_s[...] = m_new
+    l_s[...] = l_s[...] * corr + p.sum(axis=-1)
+    # fold the v dequant into the probabilities (f32, no relayout of
+    # v) — AFTER the l accumulation (the softmax denominator must sum
+    # the unscaled probabilities) and re-masked: the padded tail's vs
+    # tile is uninitialized VMEM and p's zeros would ride 0*NaN into
+    # the accumulator, the same hazard v is zeroed for above
+    pv = jnp.where(mask_row, p * vs_ref[0], 0.0) if quant else p
+    # p (g, R, BK) x v (g, d, BK), contracting BK
+    o_s[...] = o_s[...] * corr[..., None] + jax.lax.dot_general(
+        pv.astype(dot_dt), v, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+    # a row's last step: the next entry is another row's, or the
+    # sentinel that ends the list
+    @pl.when(row_ref[i + 1] != ib)
     def _flush():
         o_ref[0] = o_s[...] / l_s[...][..., None]
 
@@ -740,23 +756,32 @@ def can_flash_decode(max_len: int, head_dim: int,
 
 
 #: K bytes (kv_heads x head_dim x BK x itemsize) one grid step should
-#: stream at least. The tile is the granularity at which a row's dead
-#: context is skipped, so narrower skips more — until a grid step's
-#: fixed cost shows. Measured at 96 rows x 16 heads x 64 x 1024 bf16 on
-#: a v5e (PERF.md, PR 26), whole decode step on a server's mix of
-#: contexts / one call with every row at max_len: BK 512 (1 MiB)
-#: 17.6 ms / 0.556 ms; 256 (512 KiB) 15.8 / 0.588; 128 (256 KiB)
-#: 16.0 / 0.696.
+#: stream at least. The tile is the granularity at which a row's
+#: context is rounded up (its steps are its live tiles,
+#: decode_work_list), so narrower reads less — until a grid step's
+#: fixed cost, ~0.25 us on top of the tile's bytes, shows. Measured at
+#: 96 rows x 16 heads x 64 x 1024 bf16 on a v5e (PERF.md, PR 30), one
+#: call with a 32-row tail on a server's mix of contexts (mean 320) /
+#: with every row at max_len: BK 512 (1 MiB) 0.321 / 0.550 ms; 256
+#: (512 KiB) 0.276 / 0.597; 128 (256 KiB) 0.300 / 0.723. (On the
+#: (batch, max_len / BK) grid before it, whose dead tiles were empty
+#: steps: 0.380 / 0.550, 0.334 / 0.599, 0.387 / 0.746.)
 _TILE_BYTES = 512 << 10
 
 #: the same for a latent cache (one stream of d = 576 rows a tile, 128
 #: query rows, compute at the ridge), and the widest tile it may take.
-#: Measured on a v5e at 128 rows x 576 x 4096 bf16 (PERF.md, PR 27), one
-#: call on a reasoning server's contexts (mean 928) / every row at pos
-#: 300 / at max_len: BK 256 0.842 / 0.544 / 2.530 ms; 512 0.585 / 0.338
-#: / 1.633; 1024 (1152 KiB) 0.529 / 0.402 / 1.307; 2048 0.641 / 0.603 /
-#: 1.133. A row is ONE stream, so a grid step moves a ninth of what a
-#: 16-head step does at equal width and the empty steps weigh more.
+#: Measured on a v5e at 128 rows x 576 x 4096 bf16 with a 32-row tail
+#: (PERF.md, PR 30), one call on a reasoning server's contexts (mean
+#: 1137) / every row at pos 300 / at max_len: BK 256 1.015 / 0.546 /
+#: 2.776 ms; 512 0.786 / 0.432 / 1.866; 1024 (1152 KiB) 0.755 / 0.547 /
+#: 1.504; 2048 0.849 / 0.775 / 1.322; and in the cell itself (contexts
+#: that grow from the prompts, mean ~0.9k) 512 0.634, 1024 0.611. A
+#: step here costs ~0.9 us before its bytes (max_len / steps: 1.36,
+#: 1.82, 2.94, 5.16 us a step), four times a 16-head step's, so
+#: halving the tile buys less over-read than it pays in steps unless
+#: the contexts are short. (On the (batch, max_len / BK) grid before
+#: it: 1.292 / 0.874 / 2.838, 0.931 / 0.603 / 1.898, 0.831 / 0.633 /
+#: 1.518, 0.893 / 0.827 / 1.328; the cell 0.656 at 1024.)
 _LATENT_TILE_BYTES = 1152 << 10
 _LATENT_BLOCK_K = 1024
 
@@ -799,9 +824,10 @@ def _pick_bk(L: int, d: int, nkv: int, r: int, itemsize: int,
 def flash_decode_tile(k_cache, n_heads: int, latent: bool = False) -> int:
     """The cache-tile width flash_decode / flash_block_decode run a
     (b, kv_heads, head_dim, max_len) cache at (anything with its
-    ``shape`` and ``dtype``): the granularity at which a row's dead
-    context is skipped. For callers that count tiles (DecodeServer's
-    ``serve.attend_tiles``); the rule lives here."""
+    ``shape`` and ``dtype``): the granularity at which a row's live
+    context is rounded up. For callers that count tiles (DecodeServer's
+    ``serve.attend_tiles``, kvcache.attend_work); the rule lives
+    here."""
     _, nkv, d, L = k_cache.shape
     itemsize = 4 if k_cache.dtype == jnp.float32 else 2
     return _pick_bk(L, d, nkv, n_heads // nkv, itemsize,
@@ -815,33 +841,34 @@ def _last_live_tile(pos, T: int, bk: int, n_k: int):
     return jnp.clip((pos + (T - 1)) // bk, 0, n_k - 1)
 
 
-def _cache_block(ib, ik, pos_ref, T: int, bk: int, n_k: int, b: int):
-    """Block index (row, 0, 0, tile) of the K/V tile — and of the
-    int8 scale tile, same rank — that grid step (ib, ik) presents:
-    its own tile up to the row's last live one; past it — the dead
-    steps, which _decode_kernel does not compute — tile 0 of the NEXT
-    row. Pallas issues no copy when a block index repeats, so a row's
-    dead tiles move no bytes, and the next row's first tile is in
-    flight from the first dead step on (when that row begins, the
-    index repeats again and the tile is already in VMEM) instead of
-    being requested at the row's last grid step with nothing left to
-    hide it behind. The last row has no next: its dead steps stay on
-    its last live tile."""
-    last = _last_live_tile(pos_ref[ib], T, bk, n_k)
-    ahead = (ik > last) & (ib + 1 < b)
-    return (jnp.where(ahead, ib + 1, ib), 0, 0,
-            jnp.where(ahead, 0, jnp.minimum(ik, last)))
+def decode_work_list(pos, T: int, bk: int, n_k: int):
+    """The grid of one flash_decode / flash_block_decode call: its live
+    (row, tile) pairs, in the order the kernel walks them. From ``pos``
+    (b,) and _last_live_tile's rule, row r owns ``last_r + 1`` steps —
+    never 0: tile 0 is always a row's, which gives the tail its step
+    and every output block its write — rows in order, a row's tiles
+    ascending. Returns ``(row_of, tile_of, n_work)``: two int32 arrays
+    of the static length b * n_k + 1 and the number of pairs
+    (b <= n_work <= b * n_k), the grid's dynamic bound. The entries
+    from n_work on are a sentinel, ``row_of = b`` and ``tile_of = 0``:
+    the kernel reads ``row_of[i + 1]`` to see a row end, and the index
+    maps clamp the row when the pipeline looks one step ahead.
 
-
-def _tail_block(ib, ik, b: int):
-    """The batch row whose kk tail rows grid step (ib, ik) presents:
-    its own at the row's first step, where _decode_kernel folds the
-    tail in; the NEXT row's from the second step on, so that the fetch
-    (kk small strided pieces) lands behind this row's cache tiles
-    rather than in front of the next row's first step. One fetch a
-    row either way: the index repeats over the rest of the row and
-    into the next row's first step."""
-    return jnp.where((ik > 0) & (ib + 1 < b), ib + 1, ib)
+    Compares and sums over (b * n_k + 1, b), no sort and no loop. All
+    layers of a step share ``pos`` and the cache's shape, so a caller
+    with many layers builds the list once (kvcache.attend_work) and
+    hands it to each call (``work=``)."""
+    b = pos.shape[0]
+    count = _last_live_tile(pos, T, bk, n_k) + 1            # (b,)
+    ends = jnp.cumsum(count)
+    step = jnp.arange(b * n_k + 1, dtype=jnp.int32)
+    # done[i, r]: row r's steps all come before step i
+    done = step[:, None] >= ends[None, :]
+    row_of = done.sum(axis=1, dtype=jnp.int32)
+    first = jnp.where(done, count[None, :], 0).sum(axis=1,
+                                                    dtype=jnp.int32)
+    tile_of = jnp.where(row_of < b, step - first, 0)
+    return row_of, tile_of, ends[b - 1]
 
 
 def _block_fits_vmem(L: int, d: int, nkv: int, r: int, T: int,
@@ -857,24 +884,25 @@ def _block_fits_vmem(L: int, d: int, nkv: int, r: int, T: int,
 def flash_decode(q, k_cache, v_cache, pos, scale, k_scale=None,
                  v_scale=None, *, block_k: Optional[int] = None,
                  interpret: Optional[bool] = None, v_dim: int = 0,
-                 tail=None):
+                 tail=None, work=None):
     """Fused decode attention. ``q`` is (b, 1, n_heads, head_dim) (the
     _attend_cache caller layout); caches head-leading as in
     models.generate. ``pos`` scalar or (b,). Returns
     (b, 1, n_heads, head_dim) f32. A latent cache passes ``v_cache``
-    None and ``v_dim``; ``tail`` is a round's write-behind rows (both:
-    see flash_block_decode)."""
+    None and ``v_dim``; ``tail`` is a round's write-behind rows;
+    ``work`` a prebuilt work list (all three: see
+    flash_block_decode)."""
     assert q.shape[1] == 1, q.shape  # single query; flash_block_decode for T>1
     return flash_block_decode(q, k_cache, v_cache, pos, scale,
                               k_scale=k_scale, v_scale=v_scale,
                               block_k=block_k, interpret=interpret,
-                              v_dim=v_dim, tail=tail)
+                              v_dim=v_dim, tail=tail, work=work)
 
 
 def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
                        v_scale=None, *, block_k: Optional[int] = None,
                        interpret: Optional[bool] = None, v_dim: int = 0,
-                       tail=None):
+                       tail=None, work=None):
     """Fused T-query block decode attention (the speculative-decoding
     verify shape): ``q`` is (b, T, n_heads, head_dim) where row b's
     query t sits at sequence position ``pos0[b] + t`` and attends
@@ -899,7 +927,13 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     is folded in at a row's first grid step, ahead of the cache tiles).
     Tail positions at or past max_len are left out, as the per-step
     write drops them. Without a tail the kernel is, to the bit, the
-    one it was."""
+    one it was.
+
+    The grid is the call's work list (decode_work_list): one step a
+    live (row, tile) pair, ``n_work`` steps in all, a bound read on
+    the device. ``work`` is that list built by the caller for this
+    ``pos0``, T and cache shape (every layer of a step shares them);
+    with none the call builds its own."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, T, nh, d = q.shape
@@ -952,23 +986,25 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
           .reshape(b, nkv, R, d))
     posv = jnp.asarray(pos0, jnp.int32)
     posv = jnp.full((b,), posv) if posv.ndim == 0 else posv.reshape(b)
-    # inside shard_map (vma typing) every kernel operand must carry
-    # the same varying-axes set: a replicated pos rides along with the
-    # tp-sharded q/cache
-    from rlo_tpu.parallel.mesh import vary_like
-    posv = vary_like(posv, q)
-    posv = vary_like(posv, k_cache)
-
-    # pos is scalar-prefetched: the cache index maps read it, so the
-    # grid steps past a row's live context name a block that is
-    # already in VMEM or on its way (_cache_block; no copy is issued
-    # for a repeated block index) and the kernel body skips them.
-    # (A tail brings a second prefetched scalar, ``newest``, that no
-    # index map reads.)
-    row_map = lambda ib, ik, pos_ref, _newest=None: (  # noqa: E731
-        ib, 0, 0, 0)
-    cache_map = lambda ib, ik, pos_ref, _newest=None: (  # noqa: E731
-        _cache_block(ib, ik, pos_ref, T, bk, n_k, b))
+    if work is None:
+        work = decode_work_list(posv, T, bk, n_k)
+    row_of, tile_of, n_work = work
+    if row_of.shape != (b * n_k + 1,) or tile_of.shape != row_of.shape:
+        raise ValueError(
+            f"flash_block_decode: a work list for {b} rows of {n_k} "
+            f"tiles has {b * n_k + 1} entries, got {row_of.shape} and "
+            f"{tile_of.shape}")
+    # the list, then pos, are scalar-prefetched: the index maps read
+    # step i's (row, tile) from it, so the pipeline's look-ahead has
+    # step i + 1's tile — the next row's first tile, its q and its
+    # tail rows included — under way behind step i. Past the last
+    # pair it reads the sentinel, clamped to a block that exists.
+    # (A tail brings one more prefetched scalar, ``newest``; no index
+    # map reads it or pos.)
+    row_map = lambda i, row_ref, tile_ref, pos_ref, _newest=None: (  # noqa: E731
+        jnp.minimum(row_ref[i], b - 1), 0, 0, 0)
+    cache_map = lambda i, row_ref, tile_ref, pos_ref, _newest=None: (  # noqa: E731
+        jnp.minimum(row_ref[i], b - 1), 0, 0, tile_ref[i])
     kv_spec = pl.BlockSpec((1, nkv, d, bk), cache_map)
     in_specs = [pl.BlockSpec((1, nkv, R, d), row_map), kv_spec]
     args = [qg, k_cache]
@@ -981,25 +1017,30 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
         s_spec = pl.BlockSpec((1, nkv, 1, bk), cache_map)
         in_specs += [s_spec, s_spec]
         args += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
-    scalars = [posv]
+    # inside shard_map (vma typing) every kernel operand must carry
+    # the same varying-axes set: the replicated scalars (the list,
+    # pos, the grid's bound) ride along with the tp-sharded q/cache
+    from rlo_tpu.parallel.mesh import vary_like
+    scalars = [row_of, tile_of, posv]
     if n_tail:
         t_spec = pl.BlockSpec(
             (n_tail, 1, nkv, d),
-            lambda ib, ik, pos_ref, _newest: (
-                0, _tail_block(ib, ik, b), 0, 0))
+            lambda i, row_ref, tile_ref, pos_ref, _newest: (
+                0, jnp.minimum(row_ref[i], b - 1), 0, 0))
         for rows in (tk,) if latent else (tk, tv):
             in_specs += [t_spec]
             args += [vary_like(rows.astype(k_cache.dtype), k_cache)]
-        scalars += [vary_like(
-            jnp.asarray(newest, jnp.int32).reshape(1), k_cache)]
+        scalars += [jnp.asarray(newest, jnp.int32).reshape(1)]
+    scalars = [vary_like(vary_like(x, q), k_cache) for x in scalars]
+    n_work = vary_like(vary_like(n_work, q), k_cache)
 
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+            dimension_semantics=("arbitrary",))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, n_k),
+        grid=(n_work,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, nkv, R, dv), row_map),
         scratch_shapes=[pltpu.VMEM((nkv, R), jnp.float32),
@@ -1007,7 +1048,7 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
                         pltpu.VMEM((nkv, R, dv), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=float(scale), n_k=n_k,
+        functools.partial(_decode_kernel, scale=float(scale),
                           bk=bk, max_len=L, quant=quant, r=r, T=T,
                           v_dim=v_dim, n_tail=n_tail),
         grid_spec=grid_spec,

@@ -859,22 +859,45 @@ def _elemwise(op, a, b):
         shape = _broadcast(a, b)
         if shape is None:
             return OPAQUE
-        da = a.data if isinstance(a, ArrayVal) else None
-        db = b.data if isinstance(b, ArrayVal) else None
         dt = a.dtype if isinstance(a, ArrayVal) else b.dtype
-        # data survives only scalar<->array combinations (enough for
-        # the clamp/offset chains the scalar operands go through)
-        if isinstance(a, ArrayVal) and isinstance(b, (int, float)) \
-                and da is not None and a.shape == shape:
-            return ArrayVal(shape, [op(v, b) for v in da], dt)
-        if isinstance(b, ArrayVal) and isinstance(a, (int, float)) \
-                and db is not None and b.shape == shape:
-            return ArrayVal(shape, [op(a, v) for v in db], dt)
-        if da is not None and db is not None and a.shape == b.shape:
-            return ArrayVal(shape, [op(x, y) for x, y in zip(da, db)],
-                            dt)
-        return ArrayVal(shape, None, dt)
+        # data survives where both sides carry it (a Python scalar
+        # does): the clamp/offset chains the scalar operands go
+        # through, and the compares and sums a work list is built from
+        da, db = _flat_broadcast(a, shape), _flat_broadcast(b, shape)
+        if da is None or db is None:
+            return ArrayVal(shape, None, dt)
+        return ArrayVal(shape, [op(x, y) for x, y in zip(da, db)], dt)
     return OPAQUE
+
+
+def _flat_broadcast(x, shape):
+    """The flat values of ``x`` broadcast to ``shape``; None where it
+    carries none."""
+    n = math.prod(shape or (1,))
+    if isinstance(x, (int, float)):
+        return [x] * n
+    if not isinstance(x, ArrayVal) or x.data is None:
+        return None
+    if x.shape == tuple(shape):
+        return x.data
+    dims = (1,) * (len(shape) - x.ndim) + x.shape
+    strides, acc = [], 1
+    for d in reversed(dims):
+        strides.append(0 if d == 1 else acc)
+        acc *= d
+    strides.reverse()
+    return [x.data[sum(i * st for i, st in zip(idx, strides))]
+            for idx in itertools.product(*(range(d) for d in shape))]
+
+
+def _sum_last_axis(arr: "ArrayVal"):
+    """``arr.sum(axis=-1)`` with its values, where it carries them."""
+    data = None
+    if arr.data is not None and arr.ndim >= 1:
+        w = arr.shape[-1]
+        data = [sum(arr.data[i:i + w])
+                for i in range(0, arr.size, w)] if w else None
+    return ArrayVal(arr.shape[:-1], data, arr.dtype)
 
 
 class StubModule:
@@ -1067,7 +1090,19 @@ def _jnp_where(cond, a, b):
                         b if isinstance(b, ArrayVal) else ArrayVal(()))
     dt = a.dtype if isinstance(a, ArrayVal) else \
         (b.dtype if isinstance(b, ArrayVal) else _dt("float32"))
-    return ArrayVal(shape2 or shape, None, dt)
+    shape = shape2 or shape
+    flat = [_flat_broadcast(x, shape) for x in (cond, a, b)]
+    data = None if any(x is None for x in flat) else \
+        [y if c else z for c, y, z in zip(*flat)]
+    return ArrayVal(shape, data, dt)
+
+
+def _jnp_cumsum(x, *_a, **_k):
+    if not isinstance(x, ArrayVal) or x.ndim != 1:
+        return OPAQUE
+    data = None if x.data is None else \
+        list(itertools.accumulate(x.data))
+    return ArrayVal(x.shape, data, x.dtype)
 
 
 def _jnp_concatenate(arrs, axis=0, **_k):
@@ -1094,6 +1129,7 @@ _JNP_FNS = {
     "arange": _jnp_arange, "where": _jnp_where,
     "concatenate": _jnp_concatenate, "exp": _jnp_elemwise1,
     "zeros_like": _jnp_elemwise1, "abs": _jnp_elemwise1,
+    "cumsum": _jnp_cumsum,
 }
 
 
@@ -1228,6 +1264,10 @@ class Interp:
                ast.BitOr: lambda a, b: a | b,
                ast.BitXor: lambda a, b: a ^ b}
 
+    _CMPOPS = {ast.Eq: lambda a, b: a == b, ast.NotEq: lambda a, b: a != b,
+               ast.Lt: lambda a, b: a < b, ast.LtE: lambda a, b: a <= b,
+               ast.Gt: lambda a, b: a > b, ast.GtE: lambda a, b: a >= b}
+
     def _binop(self, op, a, b):
         fn = self._BINOPS.get(type(op))
         if fn is None:
@@ -1273,9 +1313,12 @@ class Interp:
                 if node.attr in ("reshape", "transpose", "astype"):
                     return getattr(base, node.attr)
                 if node.attr == "sum":
-                    return lambda *a, **k: ArrayVal(
-                        base.shape[:-1] if a and a[0] in (-1,)
-                        else (), None, base.dtype)
+                    def _sum(*a, axis=None, **_k):
+                        axis = a[0] if a else axis
+                        if axis in (-1, base.ndim - 1):
+                            return _sum_last_axis(base)
+                        return ArrayVal((), None, base.dtype)
+                    return _sum
                 return OPAQUE
             if isinstance(base, DTypeVal) and node.attr == "itemsize":
                 return base.itemsize
@@ -1339,6 +1382,11 @@ class Interp:
         if isinstance(a, ArrayVal) or isinstance(b, ArrayVal):
             if isinstance(op, (ast.Is, ast.IsNot)):
                 return isinstance(op, ast.IsNot)
+            fn = self._CMPOPS.get(type(op))
+            out = _elemwise(lambda x, y: int(fn(x, y)), a, b) \
+                if fn is not None else OPAQUE
+            if isinstance(out, ArrayVal):
+                return out
             shape = _broadcast(a if isinstance(a, ArrayVal)
                                else ArrayVal(()),
                                b if isinstance(b, ArrayVal)
@@ -1631,17 +1679,22 @@ def _p3_probes() -> List[Probe]:
                  ks_pool=A((8, 4, 128)), vs_pool=A((8, 4, 128)),
                  interpret=True),
         ], sites=1),
+        # the grid is the call's own work list (decode_work_list),
+        # built here from these positions: n_work grounds the grid,
+        # and every step's (row, tile) must name a block that exists
         Probe("rlo_tpu/pallas/decode.py", "flash_block_decode", [
+            # T = 2; the LAST row short: the list ends on its tile 0
             dict(q=A((2, 2, 8, 64)), k_cache=A((2, 4, 64, 1024)),
-                 v_cache=A((2, 4, 64, 1024)), pos0=A((2,), [0, 800]),
+                 v_cache=A((2, 4, 64, 1024)), pos0=A((2,), [800, 0]),
                  scale=0.125, k_scale=None, v_scale=None,
                  interpret=True),
+            # int8: the scale tiles follow the K/V index map
             dict(q=A((2, 1, 8, 64)), k_cache=A((2, 4, 64, 1024)),
                  v_cache=A((2, 4, 64, 1024)), pos0=A((2,), [1023, 512]),
                  scale=0.125, k_scale=A((2, 4, 1024)),
                  v_scale=A((2, 4, 1024)), interpret=True),
-            # the LAST row short: its dead grid steps have no next
-            # row to present, and a retired slot's pos past max_len
+            # a retired slot's pos past max_len (every tile, no more),
+            # beside a row of one tile
             dict(q=A((2, 1, 8, 64)), k_cache=A((2, 4, 64, 1024)),
                  v_cache=A((2, 4, 64, 1024)), pos0=A((2,), [4096, 3]),
                  scale=0.125, k_scale=None, v_scale=None,

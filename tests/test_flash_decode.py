@@ -7,16 +7,19 @@ precise than the einsum path (f32 accumulation vs the bf16 matmul), so
 the quantized comparison targets the dequantized-f32 reference.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from rlo_tpu.models.generate import (_attend_cache, _attend_cache_block,
                                      _quantize_kv)
 from rlo_tpu.pallas import decode as decode_mod
-from rlo_tpu.pallas.decode import (_cache_block, can_flash_decode,
+from rlo_tpu.pallas.decode import (can_flash_decode, decode_work_list,
                                    flash_block_decode, flash_decode,
                                    flash_decode_tile)
 
@@ -209,8 +212,8 @@ def test_block_T1_is_flash_decode(data):
 
 
 # -- dead-tile skip: tiles wholly past a row's last attended position
-# are neither fetched (_live_tile clamps the index maps) nor computed
-# (the body runs under the same rule). L = 48: block_k 16 gives three
+# are not in the call's work list (decode_work_list), so they are
+# neither fetched nor computed nor a grid step. L = 48: block_k 16 gives three
 # tiles with edges at 16 and 32; block_k 32 two, the second padded.
 _EDGE = 16
 SKIP_CASES = {
@@ -270,55 +273,94 @@ def test_skip_matches_oracle(data, name):
 def test_skip_is_bitwise_the_unskipped_kernel(data, name, monkeypatch):
     """A wholly masked tile adds p = 0 with corr = 1, so leaving it
     out changes no bit. The unskipped evaluation is the same kernel
-    with the rule switched off: every grid step presents and computes
-    its own tile, masked, as before the skip existed."""
+    with the rule switched off: the work list then holds every tile
+    of every row, each presented and computed, masked, as before the
+    skip existed."""
     q, cache, _, pos0, scale, block_k = _skip_case(data, name)
     got = _run_kernel(q, cache, pos0, scale, block_k)
     monkeypatch.setattr(decode_mod, "_last_live_tile",
-                        lambda pos, T, bk, n_k: n_k - 1)
+                        lambda pos, T, bk, n_k: jnp.full_like(pos, n_k - 1))
     want = _run_kernel(q, cache, pos0, scale, block_k)
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("T", [1, 4])
-@pytest.mark.parametrize("bk,n_k", [(16, 3), (32, 2), (512, 2), (128, 8)])
-def test_cache_block_rule(bk, n_k, T):
-    """The index-map rule itself (interpret mode would hide a tile
-    that is fetched for nothing, the mask zeroes it either way). For
-    every (ik, pos, T): a live step presents its own tile; a dead
-    step — past the tile holding position pos + T - 1 — presents
-    tile 0 of the next row (so that row's first fetch is under way),
-    or on the last row stays on its last live tile. Whatever pos, a
-    retired slot's past max_len too, the block exists; and a row's
-    dead steps all present ONE block, so they cost one copy at most."""
+def _work_rows(pos, T, bk, n_k):
+    """decode_work_list's answer for ``pos`` as plain ints."""
+    row_of, tile_of, n_work = decode_work_list(
+        jnp.asarray(pos, jnp.int32), T, bk, n_k)
+    return ([int(x) for x in row_of], [int(x) for x in tile_of],
+            int(n_work))
+
+
+# (bk, n_k, T, before): ``before`` = 1 is the tail's rule — a round
+# attends the cache as it found it, positions < pos, so the list is
+# built from pos - 1 (-1 for an empty row: tile 0 alone)
+@pytest.mark.parametrize("bk,n_k,T,before", [
+    (bk, n_k, T, 0) for bk, n_k in [(16, 3), (32, 2), (512, 2), (128, 8)]
+    for T in (1, 4)] + [(128, 8, 1, 1)])
+def test_work_list_rule(bk, n_k, T, before):
+    """The list the grid walks and the index maps read (interpret mode
+    would hide a tile fetched for nothing: the mask zeroes it either
+    way). For ``pos`` at every tile edge +- {T, 1, 0}, at 0, and a
+    retired slot's far past max_len, in every row of a batch of three:
+    n_work is the sum of the rows' live tiles; rows come in order, each
+    at least once, a row's tiles 0..last ascending; from n_work on the
+    entries are the sentinel; and every block an index map names, at a
+    step of the grid or one past it, exists."""
     L, b = bk * n_k, 3
     edges = {e + d for e in range(0, L + bk, bk) for d in (-T, -1, 0, 1)}
     for pos in sorted(p for p in edges | {0, 2 * L, 10 * L} if p >= 0):
-        last = min((pos + T - 1) // bk, n_k - 1)
         for ib in range(b):
-            pos_ref = jnp.asarray([7, 7, 7], jnp.int32).at[ib].set(pos)
-            got = [tuple(int(x) for x in _cache_block(
-                jnp.int32(ib), jnp.int32(ik), pos_ref, T, bk, n_k, b))
-                for ik in range(n_k)]
-            for ik, (row, z1, z2, tile) in enumerate(got):
-                assert (z1, z2) == (0, 0)
-                assert 0 <= row < b and 0 <= tile < n_k
-                live = ik * bk <= min(pos + T - 1, L - 1)
-                assert live == (ik <= last)
-                if live:
-                    assert (row, tile) == (ib, ik), (ib, ik, pos)
-                elif ib + 1 < b:
-                    assert (row, tile) == (ib + 1, 0), (ib, ik, pos)
-                else:
-                    assert (row, tile) == (ib, last), (ib, ik, pos)
-            assert len(set(got[last + 1:])) <= 1
+            posv = np.asarray([7, 7, 7])
+            posv[ib] = pos
+            posv = posv - before
+            last = [min(max((int(p) + T - 1) // bk, 0), n_k - 1)
+                    for p in posv]
+            row_of, tile_of, n_work = _work_rows(posv, T, bk, n_k)
+            assert len(row_of) == len(tile_of) == b * n_k + 1
+            assert n_work == sum(x + 1 for x in last)
+            assert b <= n_work <= b * n_k
+            want = [(r, t) for r in range(b) for t in range(last[r] + 1)]
+            assert list(zip(row_of, tile_of))[:n_work] == want, (posv, T)
+            assert set(row_of[:n_work]) == set(range(b))
+            # a live tile starts at or before the row's last attended
+            # position (or is tile 0)
+            assert all(t == 0 or t * bk <= min(posv[r] + T - 1, L - 1)
+                       for r, t in want)
+            assert row_of[n_work:] == [b] * (b * n_k + 1 - n_work)
+            assert tile_of[n_work:] == [0] * (b * n_k + 1 - n_work)
+            # the index maps: (min(row_of[i], b - 1), 0, 0, tile_of[i])
+            for i in range(n_work + 1):
+                assert 0 <= min(row_of[i], b - 1) < b
+                assert 0 <= tile_of[i] < n_k
+            # _flush's test: a row ends where the next entry differs
+            ends = [i for i in range(n_work) if row_of[i + 1] != row_of[i]]
+            assert len(ends) == b and ends[-1] == n_work - 1
+
+
+def test_work_list_is_checked_and_reusable(data):
+    """A caller's own list (kvcache.attend_work builds one a step for
+    all layers) gives the bits the call's own would; one built for
+    another tiling is refused."""
+    q, kc, vc, scale = data
+    pos = jnp.asarray([3, _EDGE, L - 1], jnp.int32)
+    want = flash_decode(q, kc, vc, pos, scale, interpret=True,
+                        block_k=_EDGE)
+    work = decode_work_list(pos, 1, _EDGE, L // _EDGE)
+    got = jax.jit(lambda w: flash_decode(
+        q, kc, vc, pos, scale, interpret=True, block_k=_EDGE, work=w))(
+            work)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="a work list for 3 rows"):
+        flash_decode(q, kc, vc, pos, scale, interpret=True, block_k=32,
+                     work=work)
 
 
 def test_skip_with_poisoned_dead_context(data):
     """NaN in K and inf in V at every position past the rows'
     contexts: the live tile's own tail is masked as before, and a row
-    whose later grid steps were all skipped still flushes its output
-    (a dead step's refs hold the next row's tile, not its own)."""
+    whose later tiles are not in the work list still flushes its
+    output, at its last live tile."""
     q, kc, vc, scale = data
     posv = np.asarray([3, _EDGE, L - 1], np.int32)
     col = np.arange(L)[None, None, None, :]
@@ -547,20 +589,6 @@ def test_tail_attend_is_write_then_attend(kind, newest, path):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_tail_block_rule():
-    """The tail's index map: a row's own kk rows at its first grid step
-    (where the kernel folds them in), the next row's from the second
-    on, so one fetch a row lands behind the row's cache tiles; the
-    last row has no next."""
-    b, n_k = 3, 4
-    got = [[int(decode_mod._tail_block(jnp.int32(ib), jnp.int32(ik), b))
-            for ik in range(n_k)] for ib in range(b)]
-    assert got == [[0, 1, 1, 1], [1, 2, 2, 2], [2, 2, 2, 2]]
-    flat = [r for row in got for r in row]
-    changes = sum(x != y for x, y in zip(flat, flat[1:]))
-    assert changes == b - 1         # one fetch a row after the first
-
-
 def test_tail_refuses_what_it_cannot_hold(data):
     q, kc, vc, scale = data
     tk = jnp.zeros((4, B, NKV, D), jnp.float32)
@@ -602,31 +630,61 @@ def test_tail_fold_drops_what_row_writes_drop(kind, path, monkeypatch):
                                   np.asarray(cache)[3:])
 
 
-# the kernel as PR 27 left it, before it learnt the tail, verbatim but
-# for comments: what "no tail => today's kernel" is held to
-def _pr27_decode_kernel(pos_ref, q_ref, k_ref, *rest, scale: float,
+# the call as PR 29 left it, before the grid became a work list: the
+# kernel (verbatim but for comments), its (b, n_k) grid over max_len
+# and the index maps that parked a row's dead steps on the next row's
+# first tile. What "same work, same answers" is held to.
+def _pr29_decode_kernel(pos_ref, *refs, scale: float,
                    n_k: int, bk: int, max_len: int, quant: bool,
-                   r: int, T: int, v_dim: int = 0):
+                   r: int, T: int, v_dim: int = 0, n_tail: int = 0):
+    if n_tail:          # a second prefetched scalar: the tail's newest row
+        newest_ref, refs = refs[0], refs[1:]
+    q_ref, k_ref, *rest = refs
     if not v_dim:       # a V operand; a latent cache has none
         v_ref, rest = rest[0], rest[1:]
+    if n_tail:          # the round's own rows, after the cache operands
+        tk_ref, rest = rest[0], rest[1:]
+        if not v_dim:
+            tv_ref, rest = rest[0], rest[1:]
     if quant:
         ks_ref, vs_ref, o_ref, m_s, l_s, o_s = rest
     else:
         o_ref, m_s, l_s, o_s = rest
     ib = pl.program_id(0)
     ik = pl.program_id(1)
+    dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
 
     @pl.when(ik == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s[...], decode_mod._NEG)
-        l_s[...] = jnp.zeros_like(l_s[...])
-        o_s[...] = jnp.zeros_like(o_s[...])
+        if not n_tail:
+            m_s[...] = jnp.full_like(m_s[...], decode_mod._NEG)
+            l_s[...] = jnp.zeros_like(l_s[...])
+            o_s[...] = jnp.zeros_like(o_s[...])
+            return
+        q = q_ref[0].astype(dot_dt)                      # (g, r, d)
+        if v_dim:
+            kt = tk_ref[:, 0, 0, :][None].astype(dot_dt)  # (1, kk, d)
+            vt = kt[:, :, :v_dim]
+        else:                                 # (kk, g, d) -> (g, kk, d)
+            kt = jnp.swapaxes(tk_ref[:, 0], 0, 1).astype(dot_dt)
+            vt = jnp.swapaxes(tv_ref[:, 0], 0, 1).astype(dot_dt)
+        t = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_tail), 2)
+        live = (t <= newest_ref[0]) & (pos_ref[ib] + 1 + t < max_len)
+        s = jax.lax.dot_general(q, kt, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(live, s, decode_mod._NEG)                     # (g, r, kk)
+        m = s.max(axis=-1)
+        p = jnp.where(live, jnp.exp(s - m[..., None]), 0.0)
+        m_s[...] = m
+        l_s[...] = p.sum(axis=-1)
+        o_s[...] = jax.lax.dot_general(
+            p.astype(dot_dt), vt, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
 
     pos = pos_ref[ib]
 
     @pl.when(ik <= decode_mod._last_live_tile(pos, T, bk, n_k))
     def _attend():
-        dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
         q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
         k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
         v = (k[:, :v_dim, :] if v_dim
@@ -660,7 +718,69 @@ def _pr27_decode_kernel(pos_ref, q_ref, k_ref, *rest, scale: float,
         o_ref[0] = o_s[...] / l_s[...][..., None]
 
 
-NO_TAIL_CASES = {
+def _pr29_flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
+                             v_scale=None, *, block_k=None, v_dim=0,
+                             tail=None):
+    b, T, nh, d = q.shape
+    nkv, L_ = k_cache.shape[1], k_cache.shape[3]
+    r, R, dv = nh // nkv, T * (nh // nkv), v_dim or d
+    quant, latent = k_scale is not None, v_cache is None
+    widest, tile_bytes = decode_mod._tile_rule(latent)
+    itemsize = 4 if k_cache.dtype == jnp.float32 else 2
+    bk = decode_mod._pick_bk(L_, d, nkv, r, itemsize, block_k or widest,
+                             tile_bytes)
+    n_k = -(-L_ // bk)
+    qg = (q.reshape(b, T, nkv, r, d).transpose(0, 2, 1, 3, 4)
+          .reshape(b, nkv, R, d))
+    posv = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32), (b,))
+
+    def cache_block(ib, ik, pos_ref, _newest=None):
+        last = decode_mod._last_live_tile(pos_ref[ib], T, bk, n_k)
+        ahead = (ik > last) & (ib + 1 < b)
+        return (jnp.where(ahead, ib + 1, ib), 0, 0,
+                jnp.where(ahead, 0, jnp.minimum(ik, last)))
+
+    def row_map(ib, ik, pos_ref, _newest=None):
+        return ib, 0, 0, 0
+
+    kv_spec = pl.BlockSpec((1, nkv, d, bk), cache_block)
+    in_specs = [pl.BlockSpec((1, nkv, R, d), row_map), kv_spec]
+    args, scalars, n_tail = [qg, k_cache], [posv], 0
+    if not latent:
+        in_specs += [kv_spec]
+        args += [v_cache]
+    if quant:
+        s_spec = pl.BlockSpec((1, nkv, 1, bk), cache_block)
+        in_specs += [s_spec, s_spec]
+        args += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
+    if tail is not None:
+        tk, tv, newest = tail
+        n_tail = tk.shape[0]
+        t_spec = pl.BlockSpec(
+            (n_tail, 1, nkv, d), lambda ib, ik, pos_ref, _newest: (
+                0, jnp.where((ik > 0) & (ib + 1 < b), ib + 1, ib), 0, 0))
+        for rows in (tk,) if latent else (tk, tv):
+            in_specs += [t_spec]
+            args += [rows.astype(k_cache.dtype)]
+        scalars += [jnp.asarray(newest, jnp.int32).reshape(1)]
+    out = pl.pallas_call(
+        functools.partial(_pr29_decode_kernel, scale=float(scale), n_k=n_k,
+                          bk=bk, max_len=L_, quant=quant, r=r, T=T,
+                          v_dim=v_dim, n_tail=n_tail),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(b, n_k),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, nkv, R, dv), row_map),
+            scratch_shapes=[pltpu.VMEM((nkv, R), jnp.float32),
+                            pltpu.VMEM((nkv, R), jnp.float32),
+                            pltpu.VMEM((nkv, R, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, R, dv), jnp.float32),
+        interpret=True)(*scalars, *args)
+    return (out.reshape(b, nkv, T, r, dv).transpose(0, 2, 1, 3, 4)
+            .reshape(b, T, nh, dv))
+
+
+PARENT_CASES = {
     # name: (T, pos0, int8, latent, block_k)
     "T1": (1, [0, L - 1, _EDGE], False, False, _EDGE),
     "T4-verify": (4, [_EDGE - 3, 0, L - 4], False, False, _EDGE),
@@ -669,17 +789,19 @@ NO_TAIL_CASES = {
     "T1-padded-tail-tile": (1, [32, L - 1, 5], False, False, 32),
     "T1-latent": (1, [0, L - 1, _EDGE], False, True, None),
     "T4-latent": (4, [_EDGE - 3, 0, L - 4], False, True, None),
+    "T1-retired-slot": (1, [2 * L, 3, 10 * L], False, False, _EDGE),
 }
 
 
-@pytest.mark.parametrize("name", sorted(NO_TAIL_CASES))
-def test_no_tail_is_bitwise_the_parent_kernel(data, name, monkeypatch):
-    """Every caller that passes no tail (generate's scan, the
-    speculative verify, the long-prompt extend, decode_step as the
-    benchmark's check calls it) runs the kernel it ran before."""
-    T, pos0, quant, latent, block_k = NO_TAIL_CASES[name]
+@pytest.mark.parametrize("name", sorted(PARENT_CASES))
+def test_is_bitwise_the_parent_call(data, name):
+    """At an unchanged tile width a row's tiles arrive in the same
+    order into the same accumulators: every caller (generate's scan,
+    the speculative verify, the long-prompt extend, int8 and latent
+    caches) gets the bits the (b, n_k) grid gave."""
+    T, pos0, quant, latent, block_k = PARENT_CASES[name]
     _, kc, vc, scale = data
-    rng = np.random.default_rng(sorted(NO_TAIL_CASES).index(name))
+    rng = np.random.default_rng(sorted(PARENT_CASES).index(name))
     pos0 = jnp.asarray(pos0, jnp.int32)
     if latent:
         q = jnp.asarray(rng.standard_normal((B, T, NH, 144)), jnp.float32)
@@ -695,12 +817,21 @@ def test_no_tail_is_bitwise_the_parent_kernel(data, name, monkeypatch):
         args, kw = (q, kc, vc, pos0, scale), {}
     got = np.asarray(flash_block_decode(*args, interpret=True,
                                         block_k=block_k, **kw))
-
-    def parent(*refs, n_tail, **static):
-        assert n_tail == 0
-        return _pr27_decode_kernel(*refs, **static)
-
-    monkeypatch.setattr(decode_mod, "_decode_kernel", parent)
-    want = np.asarray(flash_block_decode(*args, interpret=True,
-                                         block_k=block_k, **kw))
+    want = np.asarray(_pr29_flash_block_decode(*args, block_k=block_k,
+                                               **kw))
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("newest", [0, TAIL_KK - 1])
+@pytest.mark.parametrize("kind", sorted(TAIL_KINDS))
+def test_tail_is_bitwise_the_parent_call(kind, newest):
+    """The same with a round's tail, for ragged pos0 up to and past
+    max_len (two tiles of 128: rows of one and of two live tiles)."""
+    q, kc, vc, tk, tv, v_dim, scale = _tail_case(kind, seed=3)
+    pos0 = jnp.asarray(TAIL_POS0, jnp.int32)
+    tail = (tk, tv, jnp.int32(newest))
+    got = flash_decode(q, kc, vc, pos0 - 1, scale, interpret=True,
+                       block_k=128, v_dim=v_dim, tail=tail)
+    want = _pr29_flash_block_decode(q, kc, vc, pos0 - 1, scale,
+                                    block_k=128, v_dim=v_dim, tail=tail)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -546,7 +546,7 @@ def test_attend_tile_counters_equal_the_hand_count(monkeypatch,
     counts at all; the model still attends through the einsum here,
     and the count needs only pos."""
     from rlo_tpu.models import serve as serve_mod
-    from rlo_tpu.pallas.decode import flash_decode_tile
+    from rlo_tpu.pallas.decode import decode_work_list, flash_decode_tile
     from rlo_tpu.utils.metrics import Registry
 
     monkeypatch.setattr(serve_mod, "_on_tpu", lambda: True)
@@ -584,6 +584,17 @@ def test_attend_tile_counters_equal_the_hand_count(monkeypatch,
     c = srv.stats()["counters"]
     assert c["serve.attend_tiles"] == rounds * kk * n_slots * n_k
     assert c["serve.attend_tiles_live"] == live
+    # the kernel's grid is its work list: as many steps as live tiles
+    # (a grid over max_len would have read == serve.attend_tiles), by
+    # the list's own length for every step's positions
+    assert c["serve.attend_steps"] == c["serve.attend_tiles_live"]
+    steps = 0
+    for step in range(rounds * kk):
+        held = np.asarray(plens + [0]) + (
+            step // kk * kk - 1 if cache == "tail" else step)
+        steps += int(decode_work_list(jnp.asarray(held, jnp.int32), 1,
+                                      bk, n_k)[2])
+    assert c["serve.attend_steps"] == steps
     # the short rows reach one tile a step. The long one reaches two
     # from the step its context passes the edge — or, with the tail,
     # from the first round that FINDS it past the edge: the second
@@ -594,7 +605,7 @@ def test_attend_tile_counters_equal_the_hand_count(monkeypatch,
 def test_attend_tile_counters_absent_on_the_einsum_path(setup):
     """Off the tpu backend (here) decode_step attends through the
     einsum: nothing is tiled, so the server holds no tiling, never
-    touches the two counters and stats() does not show them."""
+    touches the three counters and stats() does not show them."""
     from rlo_tpu.utils.metrics import Registry
 
     reg = Registry()
@@ -605,10 +616,10 @@ def test_attend_tile_counters_absent_on_the_einsum_path(setup):
     srv.run()
     c = srv.stats()["counters"]
     assert c["serve.slot_steps"] > 0
-    assert not any(k.startswith("serve.attend_tiles") for k in c)
+    assert not any(k.startswith("serve.attend_") for k in c)
     # a shape the kernel's gate refuses holds none on the chip either
     srv._count_attend_tiles(4)
-    assert not any(k.startswith("serve.attend_tiles")
+    assert not any(k.startswith("serve.attend_")
                    for k in srv.stats()["counters"])
 
 

@@ -2,7 +2,8 @@
 cells' shapes (gpt2-medium: decode attention at 96 rows x max_len 1024;
 deepseek-v3-ep16: the latent attend at 128 rows x 576 x 4096 and the
 grouped expert FFN over 16 held experts at 7168 x 2048; both cells'
-round: the attend with a 32-row write-behind tail and the tail's fold),
+round: the attend with a 32-row write-behind tail and the tail's fold;
+every attend on its work-list grid, a 1-D grid with a dynamic bound),
 without a chip: Mosaic's refusals (block shapes, scoped VMEM, scalar-prefetch index
 maps) show up here, numerics and times do not. The topology is described
 inside a fixture, never at import (only one process may load libtpu, and
@@ -13,7 +14,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from rlo_tpu.pallas.decode import (flash_block_decode, write_kv_row,
+from rlo_tpu.pallas.decode import (decode_work_list, flash_block_decode,
+                                   flash_decode_tile, write_kv_row,
                                    write_kv_tail)
 from rlo_tpu.pallas.expert_ffn import buffer_rows, expert_ffn
 
@@ -76,6 +78,32 @@ def test_latent_attend_and_row_write_compile_for_v5e(one_chip):
         shape((SLOTS,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
     assert "flash_decode" in text and "write_kv_row" in text
+
+
+LATENT_TILE = 1024      # _LATENT_BLOCK_K, as re-measured in PR 30
+
+
+def test_latent_attend_on_a_shared_work_list_compiles_for_v5e(one_chip):
+    """The latent attend at the tile the rule picks, as a decode step
+    runs it: the work list built once from pos (a dynamic grid bound
+    and two prefetched lists) and handed to every layer's call."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cache = shape((SLOTS, 1, LATENT, MAX_LEN), jnp.bfloat16)
+    bk = flash_decode_tile(cache, HEADS, latent=True)
+    assert bk == LATENT_TILE
+
+    def step(q, c0, c1, pos):
+        work = decode_work_list(pos, 1, bk, MAX_LEN // bk)
+        return [flash_block_decode(q, c, None, pos, 0.135, v_dim=V_DIM,
+                                   interpret=False, work=work)
+                for c in (c0, c1)]
+
+    text = jax.jit(step).lower(
+        shape((SLOTS, 1, HEADS, LATENT), jnp.bfloat16), cache, cache,
+        shape((SLOTS,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2 and "flash_decode" in text
 
 
 ROUND_LEN = 32      # DecodeServer's default: rows in a round's tail
