@@ -36,9 +36,9 @@ from rlo_tpu.models.kvcache import (  # noqa: F401
     _attend_cache, _attend_cache_block, _quantize_kv, fold_kv_tail,
     init_kv_cache, init_kv_tail, kv_cache_pspecs)
 from rlo_tpu.models.transformer import (TransformerConfig, apply_layer,
-                                        embed_tokens, head_weights,
-                                        mla_unabsorbed, _local_attention,
-                                        _rmsnorm)
+                                        check_unselected, embed_tokens,
+                                        head_weights, mla_unabsorbed,
+                                        _local_attention, _rmsnorm)
 
 
 def _decode_cfg(cfg: TransformerConfig) -> TransformerConfig:
@@ -85,7 +85,8 @@ def _forward(params: dict, tokens, pos, caches, cfg: TransformerConfig,
     attention swapped for ``hook(layer, lc, *args) -> (attended, new
     entry)``: ``lc`` is the layer's item of ``caches``, ``args`` what
     apply_layer hands an attention hook ((q, k, v); under latent
-    attention (q_nope, q_rope, latent)). Then the final norm. Returns
+    attention (q_nope, q_rope, latent, index)). Then the final norm.
+    Returns
     (x (b, T, d), the layers' new entries)."""
     x = embed_tokens(params["embed"], tokens, pos, cfg)
     entries = []
@@ -123,19 +124,20 @@ def _head(params: dict, x, cfg: TransformerConfig, at=None):
 
 def _cache_hook(cfg: TransformerConfig, step):
     """_forward's ``hook`` for a step through a cache, from the part
-    that is the step function's own: ``step(lc, k, v) -> (new entry,
-    attend)`` puts the layer's new keys and values where they go and
-    says how a query ``q`` then attends (``attend(q)``). Under latent
-    attention ``k`` is the latent rows, ``v`` None, and the attend runs
-    in the absorbed form (_mla_absorbed)."""
+    that is the step function's own: ``step(lc, k, v, index) -> (new
+    entry, attend)`` puts the layer's new keys and values where they go
+    and says how a query ``q`` then attends (``attend(q)``). Under
+    latent attention ``k`` is the latent rows, ``v`` None, ``index`` a
+    token selector's projections (None without one: the other layers'
+    too), and the attend runs in the absorbed form (_mla_absorbed)."""
     def hook(layer, lc, *args):
         if cfg.mla:
-            q_nope, q_rope, latent = args
-            entry, attend = step(lc, latent, None)
+            q_nope, q_rope, latent, index = args
+            entry, attend = step(lc, latent, None, index)
             return _mla_absorbed(q_nope, q_rope, layer, cfg,
                                  attend), entry
         q, k, v = args
-        entry, attend = step(lc, k, v)
+        entry, attend = step(lc, k, v, None)
         return attend(q).astype(cfg.act_dtype), entry
 
     return hook
@@ -145,7 +147,8 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
                 tp_axis: Optional[str] = None,
                 ep_axis: Optional[str] = None,
                 moe_info: Optional[list] = None,
-                tail: Optional[tuple] = None
+                tail: Optional[tuple] = None,
+                dsa_info: Optional[list] = None
                 ) -> Tuple[jax.Array, list]:
     """One token (b,) int32 at position ``pos`` through all layers
     using the K/V cache. Returns (logits (b, vocab) f32, new cache).
@@ -167,7 +170,12 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
     Latent attention (``cfg.mla``): the cache holds one latent row a
     token and layer; the step writes it like any other row
     (kvcache.write_row, or the tail) and attends in the absorbed form
-    (_mla_absorbed). ``moe_info``: see apply_layer.
+    (_mla_absorbed). ``moe_info``: see apply_layer. With a token
+    selector (``cfg.dsa``) the row's index key is written with it, the
+    selector picks among the positions the cache then holds
+    (kvcache.select_tokens) and the attend runs over its choice;
+    ``dsa_info`` receives each layer's scores and choice, and how the
+    attend read it (kvcache.select_tokens).
 
     ``tail`` ``(rows, newest)``: step ``newest`` of a loop that keeps
     its new K/V rows in a write-behind tail (init_kv_tail; the loop
@@ -191,14 +199,26 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
 
     # the attend kernel's work list up to there: one for all layers
     work = kvcache.attend_work(cache, cfg, upto, tp_axis=tp_axis)
+    # the same for the selector's score kernel, and its positions
+    iwork = upto_q = None
+    if cfg.dsa:
+        iwork = kvcache.select_work(cache, cfg, upto)
+        upto_q = jnp.broadcast_to(upto, token.shape)[:, None]
 
-    def step(lc_tl, k, v):
+    def step(lc_tl, k, v, index):
         lc, tl = lc_tl
-        row = kvcache.new_row(lc, k, v)
+        row = kvcache.new_row(lc, k, v, index)
         if tl is None:
             entry = kvcache.write_row(lc, row, posv)
+            select = None if index is None else kvcache.select_tokens(
+                index, entry, upto_q, cfg.index_topk, work=iwork,
+                info=dsa_info)
+            # this layer's record: the attend adds how it read the set
+            record = (dsa_info[-1] if dsa_info is not None
+                      and select is not None else None)
             return entry, lambda q: kvcache.attend(
-                q, entry, upto, cfg.attn_scale, v_dim=v_dim, work=work)
+                q, entry, upto, cfg.attn_scale, v_dim=v_dim, work=work,
+                select=select, info=record)
         tl = kvcache.store_tail_row(tl, row, newest)
         return tl, lambda q: kvcache.attend(
             q, lc, upto, cfg.attn_scale, v_dim=v_dim, tail=(tl, newest),
@@ -213,7 +233,9 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
 def block_decode(params: dict, tokens, pos0, cache,
                  cfg: TransformerConfig,
                  tp_axis: Optional[str] = None,
-                 ep_axis: Optional[str] = None):
+                 ep_axis: Optional[str] = None,
+                 moe_info: Optional[list] = None,
+                 dsa_info: Optional[list] = None):
     """Process T tokens (b, T) through the cache in ONE forward: row
     b's token i sits at position pos0[b] + i. Returns
     (logits (b, T, vocab) f32, cache). The verify step of speculative
@@ -221,7 +243,9 @@ def block_decode(params: dict, tokens, pos0, cache,
     a building block for chunked cache extension. Write-then-attend
     with per-(row, i) masks, so rejected drafts' cache entries are
     simply garbage beyond the accepted position — masked out and
-    overwritten by later writes, exactly like ragged decode."""
+    overwritten by later writes, exactly like ragged decode. A token
+    selector chooses query by query (decode_step). ``moe_info``,
+    ``dsa_info``: see decode_step."""
     cfg = _decode_cfg(cfg)
     b, T = tokens.shape
     pos0 = jnp.asarray(pos0, jnp.int32).reshape(b)
@@ -230,16 +254,18 @@ def block_decode(params: dict, tokens, pos0, cache,
 
     work = kvcache.attend_work(cache, cfg, pos0, T, tp_axis=tp_axis)
 
-    def step(lc, k, v):
-        entry = kvcache.write_block(lc, kvcache.new_block(lc, k, v),
-                                    pos0, pos_arr)
+    def step(lc, k, v, index):
+        entry = kvcache.write_block(
+            lc, kvcache.new_block(lc, k, v, index), pos0, pos_arr)
+        select = None if index is None else kvcache.select_tokens(
+            index, entry, pos_arr, cfg.index_topk, info=dsa_info)
         return entry, lambda q: kvcache.attend_block(
             q, entry, pos_arr, cfg.attn_scale, pos0=pos0, v_dim=v_dim,
-            work=work)
+            work=work, select=select)
 
     x, new = _forward(params, tokens, pos_arr, cache, cfg,
                       _cache_hook(cfg, step), tp_axis=tp_axis,
-                      ep_axis=ep_axis)
+                      ep_axis=ep_axis, moe_info=moe_info)
     return _head(params, x, cfg), new
 
 
@@ -282,18 +308,21 @@ def prefill(params: dict, tokens, cache, cfg: TransformerConfig,
     form (every head's keys and values decompressed from the latent:
     mla_unabsorbed) and the hook stashes the LATENT rows, the same rows
     decode_step writes and then attends absorbed. ``moe_info``: see
-    apply_layer.
+    apply_layer. A token selector's index keys are stored beside the
+    rows and nothing is selected: a prompt block is no longer than
+    index_topk (longer prompts go on through block_decode).
     """
     if last_index is not None:
         cfg = _decode_cfg(cfg)  # ragged MoE: padding must be inert
+    check_unselected(cfg, tokens.shape[1])
 
     def hook(layer, lc, *args):
         # the COMPACT K/V block goes into the cache on the way through
         # (head-leading and SEQ-MINOR; rope keys rotated); the block
         # itself is attended causally, as in training
         if cfg.mla:
-            q_nope, q_rope, latent = args
-            entry, _, _ = kvcache.store_prompt(lc, latent)
+            q_nope, q_rope, latent, index = args
+            entry, _, _ = kvcache.store_prompt(lc, latent, index=index)
             return mla_unabsorbed(q_nope, q_rope, latent, layer,
                                   cfg), entry
         q, k, v = args

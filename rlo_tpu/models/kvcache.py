@@ -11,7 +11,11 @@ kinds the code tells apart by looking at it:
     (``cfg.kv_cache_dtype='int8'``);
   - ``{"k"}``: latent attention's one row [c_kv | rotated key dims] a
     token, (batch, 1, width, max_len); the values are the row's leading
-    kv_lora_rank features.
+    kv_lora_rank features;
+  - ``{"k", "ik"}``: the same beside a token selector's index keys
+    (batch, 1, index_head_dim, max_len) — two kinds of state of
+    different width in one entry (``cfg.dsa``). ``select_tokens``
+    scores them and picks the positions a query attends.
 
 Everything that depends on that — how a step's new keys and values
 become columns of an entry (``new_row``, ``new_block``), how they are
@@ -35,6 +39,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from rlo_tpu.models.transformer import TransformerConfig
@@ -84,8 +89,11 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
         # sequence-minor layout; no "v" (the values are the row's
         # leading kv_lora_rank features)
         width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-        return [{"k": jnp.zeros((batch, 1, width, max_len),
-                                cfg.act_dtype)}
+        widths = {"k": width}
+        if cfg.dsa:     # a token selector's index key beside the row
+            widths["ik"] = cfg.index_head_dim
+        return [{name: jnp.zeros((batch, 1, w, max_len), cfg.act_dtype)
+                 for name, w in widths.items()}
                 for _ in range(cfg.n_layers)]
     shape = (batch, kvh, cfg.head_dim, max_len)
     # DISTINCT buffers per entry: sharing one zeros array across k/v/
@@ -127,15 +135,18 @@ def _tensors(entry):
     """(name, tensor) over an entry: values, then their scales — the
     order every write keeps, however the dict was put together (a jit
     boundary rebuilds it with sorted keys)."""
-    return [(name, entry[name]) for name in ("k", "v", "ks", "vs")
+    return [(name, entry[name]) for name in ("k", "v", "ks", "vs", "ik")
             if name in entry]
 
 
 def keeps_tail(cache) -> bool:
     """Whether a loop that owns its steps may keep this cache's new
     rows in a write-behind tail (init_kv_tail): an int8 cache, whose
-    rows come with scale sidecars, keeps the write of every step."""
-    return not any("ks" in lc for lc in cache)
+    rows come with scale sidecars, keeps the write of every step, and
+    so does one with index keys, whose newest rows a selector scores
+    with the others (a step's write of such an entry is 704 features
+    x one 128-lane block a row, PERF.md PR 31)."""
+    return not any("ks" in lc or "ik" in lc for lc in cache)
 
 
 def init_kv_tail(cache, n: int):
@@ -151,7 +162,8 @@ def init_kv_tail(cache, n: int):
     into the cache once. int8 caches (scale sidecars) keep the
     per-step write."""
     if not keeps_tail(cache):
-        raise ValueError("an int8 cache has no write-behind tail")
+        raise ValueError("an int8 cache, or one with index keys, has "
+                         "no write-behind tail")
     return [{name: jnp.zeros((n,) + a.shape[:3], a.dtype)
              for name, a in lc.items()} for lc in cache]
 
@@ -206,16 +218,24 @@ def _as_stored(entry, k, v):
     return {"k": kq, "v": vq, "ks": ks, "vs": vs}
 
 
-def new_row(entry, k, v=None):
+def _latent_tensors(k, index):
+    """A latent entry's new (b, T, width) rows by tensor name: the
+    latent rows and, with a token selector, its index keys."""
+    return {"k": k} if index is None else {"k": k, "ik": index["k"]}
+
+
+def new_row(entry, k, v=None, index=None):
     """One step's new keys and values as ``entry`` stores them, by
     tensor name. apply_layer's hook hands ``k`` and ``v`` (b, 1,
     kv_heads, head_dim) — rope keys arrive rotated and are cached
     rotated — or, under latent attention, the (b, 1, width) latent
-    rows as ``k`` alone. Returns (b, kv_heads | 1, head_dim | width)
-    rows; an int8 entry's are quantized at append, with their
-    (b, kv_heads) scales."""
+    rows as ``k`` alone (and ``index``, a token selector's
+    projections, whose key rides in the same entry). Returns
+    (b, kv_heads | 1, head_dim | width) rows; an int8 entry's are
+    quantized at append, with their (b, kv_heads) scales."""
     if v is None:
-        return {"k": k[:, 0][:, None, :]}
+        return {name: x[:, 0][:, None, :]
+                for name, x in _latent_tensors(k, index).items()}
     return _as_stored(entry, k[:, 0], v[:, 0])
 
 
@@ -234,13 +254,14 @@ def _seq_minor(x):
     return x.transpose(0, 1, 3, 2) if x.ndim == 4 else x
 
 
-def new_block(entry, k, v=None):
+def new_block(entry, k, v=None, index=None):
     """T tokens' new keys and values (new_row's arguments, T in place
     of 1) as ``entry`` stores them: by tensor name the seq-minor
     (b, kv_heads | 1, head_dim | width, T) block, beside an int8
     entry's its (b, kv_heads, T) scales."""
     if v is None:
-        return {"k": k.transpose(0, 2, 1)[:, None]}
+        return {name: x.transpose(0, 2, 1)[:, None]
+                for name, x in _latent_tensors(k, index).items()}
     return {name: _seq_minor(x)
             for name, x in _head_major(entry, k, v).items()}
 
@@ -286,15 +307,14 @@ def write_row(entry, row, pos):
             out[name] = _through_hd_view(write_kv_row, big, new, pos)
         elif pos.ndim:
             # seq-minor: the new row lands in ONE lane per (b, head,
-            # dim); tensors of one rank share the index arrays
-            rank = big.ndim - 1
-            if rank not in lanes:
-                ahead = [jnp.arange(n) for n in big.shape[:-1]]
-                lanes[rank] = tuple(
-                    _on_axis(a, axis, rank)
-                    for axis, a in enumerate(ahead)) + (
+            # dim); tensors of one shape share the index arrays
+            ahead, rank = big.shape[:-1], big.ndim - 1
+            if ahead not in lanes:
+                lanes[ahead] = tuple(
+                    _on_axis(jnp.arange(n), axis, rank)
+                    for axis, n in enumerate(ahead)) + (
                     _on_axis(pos, 0, rank),)
-            out[name] = big.at[lanes[rank]].set(new.astype(big.dtype))
+            out[name] = big.at[lanes[ahead]].set(new.astype(big.dtype))
         else:
             out[name] = lax.dynamic_update_slice(
                 big, new[..., None].astype(big.dtype),
@@ -313,26 +333,30 @@ def write_block(entry, block, pos0, cols):
     # ~1.2 ms PER VERIFY at batch 1 (block_decode 1.65 ms vs 0.46 ms
     # decode step; builder's run on a v5e, 2026-08) — the aliased
     # pallas block write replaces it
+    # (a block wider than the kernel's 128 columns goes in pieces)
     use_kernel = kernel_gate(
-        can_write_block(max_len) and T <= 128,
+        can_write_block(max_len),
         f"cache block write (max_len={max_len}, T={T})")
     out, lanes = {}, {}
     for name, big in _tensors(entry):
         new = block[name].astype(big.dtype)
         if use_kernel:
-            out[name] = _through_hd_view(write_kv_block, big, new, pos0)
+            for t0 in range(0, T, 128):
+                big = _through_hd_view(write_kv_block, big,
+                                       new[..., t0:t0 + 128], pos0 + t0)
+            out[name] = big
             continue
-        rank = big.ndim
-        if rank not in lanes:
-            lanes[rank] = tuple(
+        ahead, rank = big.shape[:-1], big.ndim
+        if ahead not in lanes:
+            lanes[ahead] = tuple(
                 _on_axis(jnp.arange(n), axis, rank)
-                for axis, n in enumerate(big.shape[:-1])) + (
+                for axis, n in enumerate(ahead)) + (
                 jnp.expand_dims(cols, list(range(1, rank - 1))),)
-        out[name] = big.at[lanes[rank]].set(new)
+        out[name] = big.at[lanes[ahead]].set(new)
     return out
 
 
-def store_prompt(entry, k, v=None):
+def store_prompt(entry, k, v=None, index=None):
     """``entry`` with a whole prompt's keys and values (new_block's
     arguments) in columns 0..plen-1, and the ``(k, v)`` the causal
     attend over the prompt block must see — what decode will read
@@ -344,7 +368,8 @@ def store_prompt(entry, k, v=None):
                                         (0,) * big.ndim)
 
     if v is None:
-        return {"k": put(entry["k"], new_block(entry, k)["k"])}, k, v
+        return {name: put(entry[name], x) for name, x in new_block(
+            entry, k, index=index).items()}, k, v
     rows = _head_major(entry, k, v)
     out = {name: put(big, _seq_minor(rows[name]))
            for name, big in _tensors(entry)}
@@ -388,31 +413,214 @@ def attend_work(cache, cfg: TransformerConfig, pos, T: int = 1,
 
 
 def attend(q, entry, pos, scale, *, v_dim: int = 0, tail=None,
-           work=None):
+           work=None, select=None, info: Optional[dict] = None):
     """One query a row, q (b, 1, H, head_dim), against ``entry``'s
     positions <= pos with the entry's own scales (_attend_cache).
     ``v_dim``: latent attention's kv_lora_rank — a latent entry cannot
     say where its values end. ``tail`` ``(rows, newest)``: one layer's
     write-behind rows, attended beside the cache. ``work``: the step's
-    attend_work, for this ``pos``."""
+    attend_work, for this ``pos``. ``select`` (b, 1, max_len) bool:
+    select_tokens' choice, the positions the query attends among
+    those. ``info``: select_tokens' record of that choice; the attend
+    adds how it read it (select_counts' ``reads``)."""
     if tail is not None:
         rows, newest = tail
         tail = (rows["k"], rows.get("v"), newest)
     return _attend_cache(q, entry["k"], entry.get("v"), pos, scale,
                          k_scale=entry.get("ks"),
                          v_scale=entry.get("vs"), v_dim=v_dim, tail=tail,
-                         work=work)
+                         work=work, select=select, info=info)
 
 
 def attend_block(q, entry, pos_q, scale, *, pos0, v_dim: int = 0,
-                 work=None):
+                 work=None, select=None):
     """T queries a row, q (b, T, H, head_dim), query i at position
     pos_q[b, i] = pos0_b + i (_attend_cache_block). ``work``: the
-    step's attend_work, for ``pos0`` and this T."""
+    step's attend_work, for ``pos0`` and this T. ``select``
+    (b, T, max_len) bool: select_tokens' choice, query by query."""
     return _attend_cache_block(q, entry["k"], entry.get("v"), pos_q,
                                scale, k_scale=entry.get("ks"),
                                v_scale=entry.get("vs"), pos0=pos0,
-                               v_dim=v_dim, work=work)
+                               v_dim=v_dim, work=work, select=select)
+
+
+# ---- the token selector ------------------------------------------------
+
+#: what a server counts of one layer's selections and attends
+#: (select_counts)
+SELECT_STATS = ("keys_scored", "rows_attended", "dense_row_steps",
+                "latent_rows_read")
+
+
+#: how an attend read a selection (select_counts' ``reads``): the whole
+#: live context under the selection as a mask, or the selected rows
+#: alone. Small ints, so that a record that holds one may leave a jit
+READS_CONTEXT, READS_SELECTION = 0, 1
+
+
+def select_counts(ctx, topk: int, reads: int) -> dict:
+    """SELECT_STATS of one layer, on the host, by select_tokens' own
+    rule: ``ctx`` (rows, calls) int, the positions each query of each
+    call may attend. ``keys_scored``: the contexts the selector scored
+    — every row of a call in which ANY row has more than ``topk``
+    positions, none of a call in which none has (select_tokens' one
+    branch). ``rows_attended``: min(ctx, topk), what the attends had to
+    read. ``dense_row_steps``: queries at ctx <= topk, whose selection
+    is the identity. ``latent_rows_read``: what the attend that ran
+    did read, by the form it reported when it was traced (``reads``,
+    which _attend_cache writes into select_tokens' record):
+    READS_CONTEXT or READS_SELECTION."""
+    ctx = np.asarray(ctx, np.int64)
+    kept = np.minimum(ctx, topk)
+    scored = (ctx > topk).any(axis=0)                   # per call
+    return {"keys_scored": int(ctx[:, scored].sum()),
+            "rows_attended": int(kept.sum()),
+            "dense_row_steps": int((ctx <= topk).sum()),
+            "latent_rows_read": int({READS_CONTEXT: ctx,
+                                     READS_SELECTION: kept}[reads].sum())}
+
+
+#: context tile of the blocked XLA forms (index scores, the latent
+#: block attend): no (heads, T, max_len) tensor is ever formed
+_CTX_TILE = 1024
+
+#: f32 score bytes up to which a block attend may form the whole
+#: (heads, T, max_len) tensor in one einsum: twice what a 128-token
+#: chunk of 128 heads forms over a cache of 4096 positions (256 MiB)
+_EINSUM_SCORE_BYTES = 512 << 20
+
+
+def _ctx_tiles(cache, pos_q):
+    """The blocked XLA forms' walk over ``cache`` (b, 1, d, max_len):
+    (tile width, tiles that hold a position <= max pos_q, dot dtype).
+    A cache axis that _CTX_TILE does not divide is one tile."""
+    L = cache.shape[3]
+    bt = _CTX_TILE if L % _CTX_TILE == 0 else L
+    dot_dt = jnp.float32 if cache.dtype == jnp.float32 else jnp.bfloat16
+    return bt, jnp.clip(jnp.max(pos_q) // bt + 1, 1, L // bt), dot_dt
+
+
+def _index_scores(index, ik_cache, pos_q):
+    """The selector's scores in plain XLA: index["q"] (b, T, heads, d),
+    index["w"] (b, T, heads) against ``ik_cache`` (b, 1, d, max_len) ->
+    (b, T, max_len) f32, ``sum_j w_j relu(q_j . k_s)`` at s <= pos_q
+    and -inf past it. Context tile by context tile (the (b, T, heads,
+    tile) products of one tile at a time), live tiles only."""
+    q, w = index["q"], index["w"].astype(jnp.float32)
+    b, T = q.shape[:2]
+    L = ik_cache.shape[3]
+    bt, n_live, dot_dt = _ctx_tiles(ik_cache, pos_q)
+
+    def tile(i, out):
+        k = lax.dynamic_slice_in_dim(ik_cache[:, 0], i * bt, bt, axis=2)
+        s = jnp.einsum("bthd,bdk->bthk", q.astype(dot_dt),
+                       k.astype(dot_dt),
+                       preferred_element_type=jnp.float32)
+        s = jnp.einsum("bthk,bth->btk", jnp.maximum(s, 0.0), w)
+        return lax.dynamic_update_slice_in_dim(out, s, i * bt, axis=2)
+
+    out = lax.fori_loop(0, n_live, tile,
+                        jnp.zeros((b, T, L), jnp.float32))
+    live = jnp.arange(L)[None, None, :] <= pos_q[:, :, None]
+    return jnp.where(live, out, -jnp.inf)
+
+
+def topk_mask(scores, k: int):
+    """EXACT top-``k`` of ``scores`` (..., n) f32 as a bool mask: the k
+    largest, ties to the lowest position; every position where n <= k.
+    No sort: the k-th largest value is found bit by bit (32 counts over
+    the row, on the order-preserving integer image of the floats), then
+    the ties at that value are taken in position order. -inf entries
+    rank last and are taken only when fewer than k others exist; mask
+    them off afterwards. (Settling 2 / 4 / 8 bits a pass, one count
+    against 3 / 15 / 255 candidates, measured 0.139 / 0.200 / 2.77 ms
+    against 0.134 at 32 x 24576 on a v5e: PERF.md, PR 31.)"""
+    n = scores.shape[-1]
+    if n <= k:
+        return jnp.ones(scores.shape, bool)
+    raw = lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32)
+    # float order -> unsigned integer order
+    key = jnp.where(raw < 0, ~raw, raw | jnp.int32(-2 ** 31))
+    key = lax.bitcast_convert_type(key, jnp.uint32)
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(key >= cand[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, t)
+
+    kth = lax.fori_loop(0, 32, bit,
+                        jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = key > kth[..., None]
+    ties = key == kth[..., None]
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    return above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32)
+                            <= room[..., None]))
+
+
+def select_work(cache, cfg: TransformerConfig, pos):
+    """index_score's work list for one step (what attend_work is to the
+    attend): built once from ``pos`` for all layers. None where the
+    scores come from XLA (off the tpu backend, a refused shape) or the
+    configuration selects nothing."""
+    from rlo_tpu.pallas.decode import (can_index_score, decode_work_list,
+                                       index_score_tile)
+    if "ik" not in cache[0] or not _on_tpu():
+        return None
+    L = cache[0]["ik"].shape[3]
+    if L <= cfg.index_topk or not can_index_score(L, cfg.index_head_dim):
+        return None
+    bk = index_score_tile(L)
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
+                           cache[0]["ik"].shape[:1])
+    return decode_work_list(pos, 1, bk, L // bk)
+
+
+def select_tokens(index, entry, pos_q, topk: int, *, work=None,
+                  info: Optional[list] = None):
+    """The positions each query attends, as a (b, T, max_len) bool
+    mask: ``index`` (transformer.index_project) scores ``entry``'s
+    index keys at positions <= pos_q (b, T) — which the entry already
+    holds: write, then select, then attend — and the ``topk`` best
+    stay, exactly, ties to the lowest position; a query with no more
+    than ``topk`` positions keeps them all. None (attend as without a
+    selector) where the cache itself is no longer than ``topk``. While
+    NO query of the call has more than ``topk`` positions, nothing is
+    scored (one branch on the largest position). ``work``: the step's
+    select_work. ``info``: a list that receives {"scores", "select",
+    "keys_scored"} (the live contexts this call scored: 0 where it
+    scored nothing) for callers that check them; the attend that
+    follows adds how it read the choice (_attend_cache)."""
+    from rlo_tpu.pallas.decode import can_index_score, index_score
+    ik = entry["ik"]
+    b, T = pos_q.shape
+    L = ik.shape[3]
+    if L <= topk:
+        return None
+    live = jnp.arange(L)[None, None, :] <= pos_q[:, :, None]
+    ctx = jnp.minimum(pos_q + 1, L)
+
+    def chosen():
+        with jax.named_scope("dsa.score"):
+            if T == 1 and kernel_gate(
+                    can_index_score(L, ik.shape[2]),
+                    f"index score (max_len={L}, dim={ik.shape[2]})"):
+                scores = index_score(index["q"][:, 0], index["w"][:, 0],
+                                     ik, pos_q[:, 0], work=work)[:, None]
+            else:
+                scores = _index_scores(index, ik, pos_q)
+        with jax.named_scope("dsa.select"):
+            return scores, topk_mask(scores, topk) & live, jnp.sum(ctx)
+
+    def everything():
+        return jnp.where(live, 0.0, -jnp.inf), live, jnp.int32(0)
+
+    scores, select, scored = lax.cond(jnp.max(pos_q) < topk, everything,
+                                      chosen)
+    if info is not None:
+        info.append({"scores": scores, "select": select,
+                     "keys_scored": scored})
+    return select
 
 
 # ---- what a server asks of a cache ------------------------------------
@@ -455,7 +663,8 @@ def attend_tiling(cache, cfg: TransformerConfig,
 
 def _attend_cache(q, k_cache, v_cache, pos, scale,
                   k_scale=None, v_scale=None, use_flash=None,
-                  v_dim: int = 0, tail=None, work=None):
+                  v_dim: int = 0, tail=None, work=None, select=None,
+                  info: Optional[dict] = None):
     """q (b, 1, H, hd) against the cache prefix [0, pos]: full-length
     matmul over the static cache, masked beyond the position. ``pos``
     is a scalar (all rows at the same position) or a (b,) vector
@@ -485,9 +694,17 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
 
     ``work``: the kernel's work list for this ``pos``, where the
     caller built one for all its layers (attend_work); the einsum has
-    no use for it."""
+    no use for it. ``select`` (b, 1, max_len) bool: a token selector's
+    choice among the positions <= pos (select_tokens); the kernel
+    takes it as a mask over the tiles it streams whole. ``info``: the
+    selection's record, which receives the form this attend read it
+    in (select_counts' ``reads``)."""
     b, one, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
+    if info is not None:
+        # the MASKED form, kernel and einsum alike: every row's whole
+        # live context is read, selected or not
+        info["reads"] = READS_CONTEXT
     if use_flash is None:
         from rlo_tpu.pallas.decode import can_flash_decode
         use_flash = kernel_gate(
@@ -500,9 +717,10 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
         # materializing the dequant at batch 32), online softmax, one
         # pass — rlo_tpu.pallas.decode
         from rlo_tpu.pallas.decode import flash_decode
-        return flash_decode(q, k_cache, v_cache, pos, scale,
-                            k_scale, v_scale, v_dim=v_dim, tail=tail,
-                            work=work)
+        return flash_decode(
+            q, k_cache, v_cache, pos, scale, k_scale, v_scale,
+            v_dim=v_dim, tail=tail, work=work,
+            select=None if select is None else select[:, 0])
     # the einsum path IS the T=1 case of the block attend — one
     # implementation, so a dequant/mask/dtype fix can never diverge
     # decode_step from block_decode (speculative decoding's
@@ -512,13 +730,13 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
              else posv.reshape(b, 1))
     return _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                                k_scale=k_scale, v_scale=v_scale,
-                               v_dim=v_dim, tail=tail)
+                               v_dim=v_dim, tail=tail, select=select)
 
 
 def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                         k_scale=None, v_scale=None, pos0=None,
                         use_flash=None, v_dim: int = 0, tail=None,
-                        work=None):
+                        work=None, select=None):
     """Block variant of the cache attend: q (b, T, nh, hd) where query
     i of row b sits at position pos_q[b, i] and attends cache
     positions <= pos_q[b, i]. Because the block's own K/V rows are
@@ -538,40 +756,59 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
 
     ``tail`` (T = 1, the einsum path only; see _attend_cache): the
     tail's scores join the cache's before the softmax. ``work``: see
-    _attend_cache (for ``pos0`` and this T)."""
+    _attend_cache (for ``pos0`` and this T).
+
+    ``select`` (b, T, max_len) bool: a token selector's choice, query
+    by query, one more mask (the XLA forms only: the T > 1 kernel takes
+    none). A LATENT block whose (heads, T, max_len) f32 scores would
+    pass _EINSUM_SCORE_BYTES — admission's wide chunks of a long
+    prompt — is attended context tile by context tile instead
+    (_attend_latent_blocked), on the chip and off it."""
     b, T, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
+    blocked = bool(v_dim) and tail is None and (
+        4 * b * nh * T * max_len > _EINSUM_SCORE_BYTES)
+    if select is not None and T > 1:
+        use_flash = False       # a mask a query: the XLA forms
     if use_flash is None:
         from rlo_tpu.pallas.decode import (_block_fits_vmem,
                                            _tile_rule,
                                            can_flash_decode)
         itemsize = 4 if k_cache.dtype == jnp.float32 else 2
+        # where the kernel does not run, which XLA form does
+        path = (f"the latent attend blocked over context tiles of "
+                f"{_CTX_TILE} (online softmax)" if blocked
+                else "the einsum over the whole cache")
         gate = pos0 is not None and kernel_gate(
             can_flash_decode(max_len, hd, v_dim=v_dim),
             f"block attend (max_len={max_len}, head_dim={hd}, "
-            f"v_dim={v_dim})")
+            f"v_dim={v_dim}; XLA form: {path})")
         fits = gate and _block_fits_vmem(
             max_len, hd, nkv, nh // nkv, T, itemsize,
             *_tile_rule(bool(v_dim)))
         if gate and not fits:
             # T=1 would flash but this block cannot share its tiling:
-            # the einsum fallback DIVERGES numerically from the flash
+            # the XLA fallback DIVERGES numerically from the flash
             # decode step, so speculative greedy parity degrades to
             # near-tie class in this regime — warn, don't hide it
             import warnings
             warnings.warn(
                 f"block attend T={T} exceeds the VMEM budget at the "
                 f"T=1 flash tiling (nkv={nkv}, head_dim={hd}, "
-                f"max_len={max_len}); falling back to einsum — verify "
+                f"max_len={max_len}); running {path} in XLA — verify "
                 f"numerics will NOT match the flash decode step "
                 f"(use a smaller gamma for exact speculative parity)",
                 KernelFallbackWarning, stacklevel=2)
         use_flash = fits
     if use_flash:
         from rlo_tpu.pallas.decode import flash_block_decode
-        return flash_block_decode(q, k_cache, v_cache, pos0, scale,
-                                  k_scale, v_scale, v_dim=v_dim,
-                                  work=work)
+        return flash_block_decode(
+            q, k_cache, v_cache, pos0, scale, k_scale, v_scale,
+            v_dim=v_dim, work=work,
+            select=None if select is None else select[:, 0])  # T == 1
+    if blocked:
+        return _attend_latent_blocked(q, k_cache, pos_q, scale, v_dim,
+                                      select)
     if v_dim:  # latent: the values are the stream's leading features
         v_cache = k_cache[:, :, :v_dim]
     rep = nh // nkv
@@ -586,6 +823,8 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
     if k_scale is not None:
         s = s * k_scale[:, :, None, None, :]
     mask = jnp.arange(max_len)[None, None, :] <= pos_q[:, :, None]
+    if select is not None:
+        mask = mask & select
     s = jnp.where(mask[:, None, None, :, :], s, _NEG)
     if tail is not None:
         tk, tv, newest = tail
@@ -612,3 +851,45 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                                preferred_element_type=jnp.float32)
     return out.astype(jnp.float32).reshape(b, T, nh, v_dim or hd)
 
+
+
+def _attend_latent_blocked(q, k_cache, pos_q, scale, v_dim: int,
+                           select=None):
+    """_attend_cache_block's einsum for a latent cache with NO
+    (heads, T, max_len) tensor: the live context tiles of width
+    _CTX_TILE one after another, (m, l, o) carried through an online
+    softmax. q (b, T, H, d), k_cache (b, 1, d, max_len), pos_q (b, T),
+    ``select`` (b, T, max_len) bool or None; returns (b, T, H, v_dim)
+    f32. What a (heads, T, tile) f32 block costs is the caller's
+    choice of T."""
+    b, T, nh, d = q.shape
+    bt, n_live, dot_dt = _ctx_tiles(k_cache, pos_q)
+    qh = q.transpose(0, 2, 1, 3).astype(dot_dt)          # (b, H, T, d)
+
+    def tile(i, carry):
+        m, l, o = carry
+        k = lax.dynamic_slice_in_dim(k_cache[:, 0], i * bt, bt,
+                                     axis=2).astype(dot_dt)  # (b, d, bt)
+        s = jnp.einsum("bhtd,bdk->bhtk", qh, k,
+                       preferred_element_type=jnp.float32) * scale
+        at = i * bt + jnp.arange(bt)
+        mask = at[None, None, :] <= pos_q[:, :, None]     # (b, T, bt)
+        if select is not None:
+            mask = mask & lax.dynamic_slice_in_dim(select, i * bt, bt,
+                                                   axis=2)
+        mask = mask[:, None]
+        s = jnp.where(mask, s, _NEG)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + p.sum(axis=-1)
+        o = o * corr[..., None] + jnp.einsum(
+            "bhtk,bck->bhtc", p.astype(dot_dt), k[:, :v_dim],
+            preferred_element_type=jnp.float32)
+        return m_new, l, o
+
+    init = (jnp.full((b, nh, T), _NEG, jnp.float32),
+            jnp.zeros((b, nh, T), jnp.float32),
+            jnp.zeros((b, nh, T, v_dim), jnp.float32))
+    m, l, o = lax.fori_loop(0, n_live, tile, init)
+    return (o / l[..., None]).transpose(0, 2, 1, 3)

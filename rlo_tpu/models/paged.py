@@ -208,7 +208,7 @@ def paged_decode_step(params: dict, token, pos, pools, table, active,
     off = jnp.where(ok, posv % ps, ps)     # ps = the drop sentinel
     pos_arr = posv[:, None]
 
-    def step(lc, k, v):
+    def step(lc, k, v, index=None):
         entry = paged_write_rows(lc, new_row(lc, k, v), page, off)
         return entry, lambda q: _paged_attend(q, entry, table, pos_arr,
                                               cfg.attn_scale)
@@ -244,7 +244,7 @@ def paged_prefill_chunk(params: dict, tokens, pos0, n_valid, pools,
     off0 = pos0 % ps
     pos_arr = pos0 + jnp.arange(T, dtype=jnp.int32)[None, :]  # (1, T)
 
-    def step(lc, k, v):
+    def step(lc, k, v, index=None):
         entry = paged_write_chunk(lc, new_block(lc, k, v), page, off0,
                                   n_valid)
         return entry, lambda q: _paged_attend(q, entry, table, pos_arr,

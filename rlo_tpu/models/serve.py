@@ -23,7 +23,11 @@ TPU-shaped design decisions:
     then the row's cache is scattered into the pool cache at the slot
     index. Prompts longer than the largest bucket extend past it in
     jitted ``block_decode`` chunks — admission never rejects a prompt
-    that fits ``max_len - max_new``.
+    that fits ``max_len - max_new`` — of 128 tokens, or of twice that
+    for a prompt that runs further than one such chunk past the bucket
+    (extend_widths: half the passes over the weights; a latent cache's
+    attend of such a chunk goes context tile by context tile,
+    models.kvcache._attend_latent_blocked).
   - PAGED mode (``paged=True``, docs/DESIGN.md §12): the per-slot
     dense cache becomes a global pool of ``page_size``-token seq-minor
     pages plus a per-slot int32 page table
@@ -82,6 +86,40 @@ class Request:
     eos_id: Optional[int] = None
 
 
+#: the dense scheduler's prompt buckets, unless the caller names others
+PROMPT_BUCKETS = (64, 256, 1024)
+
+
+def prompt_buckets_for(cfg: TransformerConfig, max_len: int,
+                       prompt_buckets: Tuple[int, ...]) -> Tuple[int, ...]:
+    """The dense scheduler's prefill buckets: those of
+    ``prompt_buckets`` that fit the cache. A prompt block is attended
+    whole, before any selection, so a token selector's buckets stop
+    at what it would keep anyway."""
+    widest = min(max_len, cfg.index_topk) if cfg.dsa else max_len
+    buckets = tuple(b for b in sorted(prompt_buckets) if b <= widest)
+    if not buckets:
+        raise ValueError(
+            f"no prompt bucket fits max_len {max_len} "
+            f"(buckets {tuple(sorted(prompt_buckets))})")
+    return buckets
+
+
+def extend_widths(buckets: Tuple[int, ...]) -> Tuple[int, int]:
+    """(chunk, long chunk): the widths in which a prompt longer than
+    the widest bucket extends past it (jitted block_decode chunks). A
+    prompt that runs further than one long chunk past the bucket takes
+    the long width for all of its chunks, any other the short one: two
+    shapes of the extend program. The long width is two short ones, 256
+    tokens at the default buckets: a v5e admits 8k-18k-token prompts of
+    a latent cache at 5.6 / 6.1 / 5.5 / 4.9 / 4.4 k tokens a second in
+    chunks of 128 / 256 / 512 / 1024 / 2048 (PERF.md, PR 31: a chunk's
+    pass over the weights against a block attend that slows as its
+    (heads, T, tile) scores grow)."""
+    chunk = min(128, buckets[-1])
+    return chunk, 2 * chunk
+
+
 def _bucket(plen: int, buckets: Tuple[int, ...]) -> int:
     for b in buckets:
         if plen <= b:
@@ -125,8 +163,14 @@ class DecodeServer:
     those a row's live context reaches, and the grid steps the kernel
     runs — its work list holds the live tiles alone), the gauge
     ``serve.cache_bytes_per_token`` (dense scheduler: what one position
-    of one slot holds over all layers), ``serve.moe.<count>`` for
-    'sigmoid_group' expert layers (models.moe.STATS, summed over the
+    of one slot holds over all layers), ``serve.dsa.keys_scored``,
+    ``serve.dsa.rows_attended``, ``serve.dsa.dense_row_steps`` and
+    ``serve.dsa.latent_rows_read`` for a token selector
+    (_count_dsa: once a round from the host's ``pos``, by the rule
+    kvcache.select_counts keeps beside the program's own, the attend's
+    form as the traced program reported it),
+    ``serve.moe.<count>`` for 'sigmoid_group' expert layers
+    (models.moe.STATS, summed over the
     round's steps and layers on the device and read back with the
     tokens: ``tokens``, ``assignments_held``, ``rows_computed`` — tile
     padding included —, ``experts_hit``, ``dropped``, which stays 0),
@@ -155,7 +199,7 @@ class DecodeServer:
 
     def __init__(self, params, cfg: TransformerConfig, *,
                  n_slots: int, max_len: int, round_len: int = 32,
-                 prompt_buckets: Tuple[int, ...] = (64, 256, 1024),
+                 prompt_buckets: Tuple[int, ...] = PROMPT_BUCKETS,
                  metrics: Optional[Registry] = None,
                  # rlo-prover: lane-pinned (one 128-lane cache block)
                  paged: bool = False, page_size: int = 128,
@@ -205,12 +249,7 @@ class DecodeServer:
                              else clip_rounds)
             return
         self.clip_rounds = bool(clip_rounds)
-        self.buckets = tuple(b for b in sorted(prompt_buckets)
-                             if b <= max_len)
-        if not self.buckets:
-            raise ValueError(
-                f"no prompt bucket fits max_len {max_len} "
-                f"(buckets {tuple(sorted(prompt_buckets))})")
+        self.buckets = prompt_buckets_for(cfg, max_len, prompt_buckets)
         self.cache = init_kv_cache(cfg, n_slots, max_len)
         self.metrics.gauge("serve.cache_bytes_per_token").set(
             kvcache.bytes_per_position(self.cache))
@@ -230,6 +269,13 @@ class DecodeServer:
         # reach the seq-minor cache once a round (init_kv_tail). An
         # int8 cache has no tail: it keeps the write of every step.
         self._kv_tail = kvcache.keeps_tail(self.cache)
+        # a token selector's threshold, where the cache is long
+        # enough for it to choose (_count_dsa); else 0. How its attends
+        # read a selection is what the round's program says of itself
+        # when it is traced (kvcache.select_counts' ``reads``)
+        self._dsa_topk = cfg.index_topk if cfg.dsa and \
+            max_len > cfg.index_topk else 0
+        self._dsa_reads = None
 
         def round_fn(params, cache, last_tok, pos, kk):
             tail = self._kv_tail        # read when the round is traced
@@ -237,13 +283,17 @@ class DecodeServer:
             def body(carry, s):
                 tok, kv, *stats = carry
                 info = []
+                dsa = [] if self._dsa_topk else None
                 # kv is the tail (the cache is closed over, read only)
                 # or, without one, the cache itself
                 logits, kv = decode_step(
                     params, tok, pos + s, cache if tail else kv, cfg_d,
-                    moe_info=info, tail=(kv, s) if tail else None)
+                    moe_info=info, tail=(kv, s) if tail else None,
+                    dsa_info=dsa)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 stats = [n + sum(i["stats"] for i in info) for n in stats]
+                if dsa:         # traced: the form the attends took
+                    self._dsa_reads = dsa[0]["reads"]
                 return (tok, kv, *stats), tok
 
             stats = ([jnp.zeros((len(moe.STATS),), jnp.int32)]
@@ -276,7 +326,7 @@ class DecodeServer:
         # bucket-prefilled row cache through jitted block_decode
         # chunks — the chunked-prefill unit on the dense path, so
         # admission never rejects a prompt that fits max_len - max_new
-        self._chunk_w = min(128, self.buckets[-1])
+        self._chunk_w, self._long_w = extend_widths(self.buckets)
 
         def extend_chunk(params, row, toks, pos0, n_valid):
             logits, row = block_decode(params, toks,
@@ -303,7 +353,8 @@ class DecodeServer:
         # instance
         self._jits = {"_round": (self._round, 1),
                       "_prefill": (self._prefill, len(self.buckets)),
-                      "_extend": (self._extend, 1),
+                      # the 128-token chunk and the long prompts' own
+                      "_extend": (self._extend, 2),
                       "_scatter": (self._scatter, 1)}
 
     # ---- paged mode (docs/DESIGN.md §12) -----------------------------
@@ -462,16 +513,18 @@ class DecodeServer:
             # chunk's last-position logits seed the first token)
             off = head
             ran = bucket  # positions the prefill ran, padding included
+            width = (self._long_w if plen - head > self._long_w
+                     else self._chunk_w)
             while off < plen:
-                n = min(self._chunk_w, plen - off)
-                toks = np.zeros((1, self._chunk_w), np.int32)
+                n = min(width, plen - off)
+                toks = np.zeros((1, width), np.int32)
                 toks[0, :n] = req.prompt[off:off + n]
                 with self._span("admit.extend", rid):
                     first, row = self._extend(
                         self.params, row, jnp.asarray(toks),
                         jnp.int32(off), jnp.int32(n))
                 off += n
-                ran += self._chunk_w
+                ran += width
             with self._span("admit.scatter_dispatch", rid):
                 self.cache = self._scatter(self.cache, row,
                                            jnp.int32(slot))
@@ -776,6 +829,7 @@ class DecodeServer:
             self.cache = cache
         self._count_kv_tail(kk)       # while the device runs the round
         self._count_attend_tiles(kk)
+        self._count_dsa(kk)
         with self._span("round.wait"):
             # the host blocks here for the device's whole round
             toks = np.asarray(toks)
@@ -862,6 +916,26 @@ class DecodeServer:
             kk * self.n_slots * n_k)
         self.metrics.counter("serve.attend_tiles_live").inc(live)
         self.metrics.counter("serve.attend_steps").inc(live)
+
+    def _count_dsa(self, kk: int) -> None:
+        """A token selector's work in the round just launched
+        (kvcache.SELECT_STATS), from the host's own ``pos``: step s of
+        row r has ctx = pos_r + s + 1 positions to choose from (the row
+        writes, then selects, then attends), cut at max_len. The rule
+        is kvcache.select_counts', kept beside select_tokens; how the
+        attend read a selection is what the traced round reported.
+        Summed over the layers, but for ``dense_row_steps``, which are
+        row-steps. Every slot counts, free and finished ones too: the
+        program runs them."""
+        if not self._dsa_topk:
+            return
+        ctx = np.minimum(self.pos[:, None].astype(np.int64) + 1
+                         + np.arange(kk), self.max_len)    # (rows, kk)
+        counts = kvcache.select_counts(ctx, self._dsa_topk,
+                                       self._dsa_reads)
+        for name, n in counts.items():
+            self.metrics.counter("serve.dsa." + name).inc(
+                n if name == "dense_row_steps" else n * self.cfg.n_layers)
 
     def _observe_round(self, dt: float, kk: int) -> None:
         self._hist("serve.round_usec").observe(dt * 1e6)

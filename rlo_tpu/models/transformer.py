@@ -159,10 +159,23 @@ class TransformerConfig:
     routed_scale: float = 1.0
     expert_first: int = 0
     n_experts_held: int = 0
+    # learned sparse attention over a latent cache (the "lightning
+    # indexer"), selected by index_topk > 0: index_n_heads small query
+    # heads of index_head_dim score every cached token against ONE
+    # index key a token (cached beside the latent row), and latent
+    # attention runs over the index_topk best alone (mla_project,
+    # models.kvcache.select_tokens). 0 changes nothing.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     @property
     def mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def dsa(self) -> bool:
+        return self.index_topk > 0
 
     @property
     def head_dim(self) -> int:
@@ -263,6 +276,15 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             layer["wuk"] = norm(k4, (kl, cfg.n_heads, nope), kl ** -0.5)
             layer["wuv"] = norm(k5, (kl, cfg.n_heads, cfg.v_head_dim),
                                 kl ** -0.5)
+            if cfg.dsa:     # its own keys: the others draw what they drew
+                hi, di = cfg.index_n_heads, cfg.index_head_dim
+                k6, k7, k8 = jax.random.split(
+                    jax.random.fold_in(keys[k], 1), 3)
+                layer["wiq"] = norm(k6, (ql, hi * di), ql ** -0.5)
+                layer["wik"] = norm(k7, (d, di), d ** -0.5)
+                layer["ik_norm"] = {"g": ones(di),
+                                    "b": jnp.zeros((di,), pdt)}
+                layer["wiw"] = norm(k8, (d, hi), d ** -0.5)
         elif cfg.kv_heads == cfg.n_heads:
             layer["wqkv"] = norm(keys[k], (d, 3, d), d ** -0.5)
         else:  # GQA: smaller K/V projections, separate q
@@ -543,12 +565,55 @@ def _local_attention(q, k, v, use_flash=None, interpret=None,
     return out.reshape(L, b, nh, hd).transpose(1, 0, 2, 3)[..., :vd]
 
 
+def _layernorm(x, g, b, eps: float):
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(var + eps) * g.astype(jnp.float32)
+            + b.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope_leading(t, pos, cfg: TransformerConfig):
+    """``t`` (b, blk, heads, width) with its first qk_rope_head_dim
+    features rotated at ``pos``; the others pass."""
+    rope = cfg.qk_rope_head_dim
+    return jnp.concatenate(
+        [_rope_cfg(t[..., :rope], pos, cfg), t[..., rope:]], -1)
+
+
+def index_project(h, c_q, layer: dict, cfg: TransformerConfig, pos):
+    """The token selector's projections of the normed activation ``h``
+    (b, blk, d) and of latent attention's query latent ``c_q``: ``q``
+    (b, blk, index heads, index dim), ``k`` (b, blk, index dim) — ONE
+    key a token for all the index heads, LayerNormed — both with their
+    first qk_rope_head_dim features rotated at ``pos``, and ``w``
+    (b, blk, index heads) f32, a query's weight of each head's score,
+    times heads^-0.5 and dim^-0.5. A token's score of a key is
+    sum_j w_j relu(q_j . k) (kvcache.select_tokens); the decode cache
+    stores ``k`` beside the latent row."""
+    b, blk, _ = h.shape
+    dt = h.dtype
+    hi, di = cfg.index_n_heads, cfg.index_head_dim
+    with jax.named_scope("dsa.index_q"):
+        q = _rope_leading((c_q @ layer["wiq"].astype(dt)).reshape(
+            b, blk, hi, di), pos, cfg)
+        w = jnp.einsum("btd,dh->bth", h, layer["wiw"].astype(dt),
+                       preferred_element_type=jnp.float32) * (
+                           hi ** -0.5 * di ** -0.5)
+    with jax.named_scope("dsa.index_k"):
+        k = _layernorm(h @ layer["wik"].astype(dt), layer["ik_norm"]["g"],
+                       layer["ik_norm"]["b"], cfg.norm_eps)
+        k = _rope_leading(k[:, :, None, :], pos, cfg)[:, :, 0]
+    return {"q": q, "k": k, "w": w}
+
+
 def mla_project(h, layer: dict, cfg: TransformerConfig, pos):
     """Latent attention's projections of the normed activation ``h``
     (b, blk, d): (q_nope (b, blk, H, nope), q_rope (b, blk, H, rope)
-    rotated, latent (b, blk, kv_lora + rope)). The latent row is
+    rotated, latent (b, blk, kv_lora + rope), index). The latent row is
     [rms(c_kv) | rope(k_r)]: what the decode cache stores, once per
-    token and layer, whatever the number of heads."""
+    token and layer, whatever the number of heads. ``index`` is the
+    token selector's (index_project), None without one."""
     b, blk, _ = h.shape
     dt = h.dtype
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -564,7 +629,19 @@ def mla_project(h, layer: dict, cfg: TransformerConfig, pos):
         q = (c_q @ layer["wuq"].astype(dt)).reshape(
             b, blk, cfg.n_heads, nope + rope)
         q_rope = _rope_cfg(q[..., nope:], pos, cfg)
-    return q[..., :nope], q_rope, jnp.concatenate([c_kv, k_r], -1)
+    index = index_project(h, c_q, layer, cfg, pos) if cfg.dsa else None
+    return (q[..., :nope], q_rope, jnp.concatenate([c_kv, k_r], -1),
+            index)
+
+
+def check_unselected(cfg: TransformerConfig, blk: int) -> None:
+    """A causal block attended whole (mla_unabsorbed) is the model only
+    while no query has more than index_topk positions to choose from."""
+    if cfg.dsa and blk > cfg.index_topk:
+        raise ValueError(
+            f"a block of {blk} tokens attended without selection "
+            f"exceeds index_topk {cfg.index_topk}: extend it through "
+            f"the cache (models.generate.block_decode)")
 
 
 def mla_unabsorbed(q_nope, q_rope, latent, layer: dict,
@@ -633,9 +710,10 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
     is materialized anywhere on the training path.
 
     Latent attention (``cfg.mla``): the hook is ``attention(q_nope,
-    q_rope, latent)`` (mla_project's outputs) and returns
-    (b, blk, heads, v_head_dim); None attends the block in the plain
-    form (mla_unabsorbed). ``moe_info``: a list that each
+    q_rope, latent, index)`` (mla_project's outputs; ``index`` None
+    without a token selector) and returns (b, blk, heads, v_head_dim);
+    None attends the block in the plain form (mla_unabsorbed), which
+    selects nothing: a block longer than index_topk is refused. ``moe_info``: a list that each
     'sigmoid_group' expert layer appends its routing record to
     (models.moe.routed_ffn), for callers that count or check it."""
     b, blk, _ = x.shape
@@ -662,10 +740,11 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
             raise ValueError("latent attention runs unsharded so far "
                              "(no sp_axis / tp_axis)")
         assert pos is not None, "rope needs per-layer positions"
-        q_nope, q_rope, latent = mla_project(h, layer, cfg, pos)
+        q_nope, q_rope, latent, index = mla_project(h, layer, cfg, pos)
         if attention is not None:
-            att = attention(q_nope, q_rope, latent)
+            att = attention(q_nope, q_rope, latent, index)
         else:
+            check_unselected(cfg, blk)
             att = mla_unabsorbed(q_nope, q_rope, latent, layer, cfg)
         with jax.named_scope("mla.out"):
             x = x + att.reshape(b, blk, -1).astype(dt) @ layer[

@@ -87,6 +87,14 @@ a whole 128-lane block a row to store. The kernel takes them as
 and folds them into the same softmax at a row's first grid step (its
 tile 0, which every row has).
 
+A SELECTION (T = 1; a token selector's choice, models.kvcache
+.select_tokens) rides the same kernel as one more operand, (b, 1, 1,
+max_len) f32 ones and zeros streamed tile by tile beside the cache and
+ANDed into the causal mask: the masked form of a sparse attend, which
+reads every live tile whole. The selector's own kernel, index_score
+(at the end of this file), walks the same kind of work list at its own
+tile width and writes one f32 score a cached token.
+
 Dots run in bf16 with f32 accumulation (int8 -> bf16 is lossless;
 f32 caches keep f32 dots — their tiles are smaller than VMEM allows
 anyway). (m, l, o) accumulate in VMEM scratch — initialised at a
@@ -120,7 +128,8 @@ _SUBLANES = 16
 
 def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
                    bk: int, max_len: int, quant: bool,
-                   r: int, T: int, v_dim: int = 0, n_tail: int = 0):
+                   r: int, T: int, v_dim: int = 0, n_tail: int = 0,
+                   selected: bool = False):
     if n_tail:          # a fourth prefetched scalar: the tail's newest row
         newest_ref, refs = refs[0], refs[1:]
     q_ref, k_ref, *rest = refs
@@ -130,6 +139,8 @@ def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
         tk_ref, rest = rest[0], rest[1:]
         if not v_dim:
             tv_ref, rest = rest[0], rest[1:]
+    if selected:        # the positions this row may attend, 1.0 or 0.0
+        sel_ref, rest = rest[0], rest[1:]
     if quant:
         ks_ref, vs_ref, o_ref, m_s, l_s, o_s = rest
     else:
@@ -205,6 +216,8 @@ def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
     # per-query causal position: query row t*r+rr masks at pos + t
     qoff = jax.lax.broadcasted_iota(jnp.int32, (1, T * r, 1), 1) // r
     mask_row = (row <= pos + qoff) & (row < max_len)  # (1, T*r, BK)
+    if selected:        # a token selector's choice, every head the same
+        mask_row = mask_row & (sel_ref[0] > 0.0)     # (1, 1, BK)
     # V zeroing: any key a query of this block may attend (<= pos+T-1)
     # — seq-minor V masks over its LAST axis
     mask_col = (row <= pos + (T - 1)) & (row < max_len)  # (1, 1, BK)
@@ -884,25 +897,26 @@ def _block_fits_vmem(L: int, d: int, nkv: int, r: int, T: int,
 def flash_decode(q, k_cache, v_cache, pos, scale, k_scale=None,
                  v_scale=None, *, block_k: Optional[int] = None,
                  interpret: Optional[bool] = None, v_dim: int = 0,
-                 tail=None, work=None):
+                 tail=None, work=None, select=None):
     """Fused decode attention. ``q`` is (b, 1, n_heads, head_dim) (the
     _attend_cache caller layout); caches head-leading as in
     models.generate. ``pos`` scalar or (b,). Returns
     (b, 1, n_heads, head_dim) f32. A latent cache passes ``v_cache``
     None and ``v_dim``; ``tail`` is a round's write-behind rows;
-    ``work`` a prebuilt work list (all three: see
-    flash_block_decode)."""
+    ``work`` a prebuilt work list; ``select`` a token selector's choice
+    (all four: see flash_block_decode)."""
     assert q.shape[1] == 1, q.shape  # single query; flash_block_decode for T>1
     return flash_block_decode(q, k_cache, v_cache, pos, scale,
                               k_scale=k_scale, v_scale=v_scale,
                               block_k=block_k, interpret=interpret,
-                              v_dim=v_dim, tail=tail, work=work)
+                              v_dim=v_dim, tail=tail, work=work,
+                              select=select)
 
 
 def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
                        v_scale=None, *, block_k: Optional[int] = None,
                        interpret: Optional[bool] = None, v_dim: int = 0,
-                       tail=None, work=None):
+                       tail=None, work=None, select=None):
     """Fused T-query block decode attention (the speculative-decoding
     verify shape): ``q`` is (b, T, n_heads, head_dim) where row b's
     query t sits at sequence position ``pos0[b] + t`` and attends
@@ -933,7 +947,15 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     live (row, tile) pair, ``n_work`` steps in all, a bound read on
     the device. ``work`` is that list built by the caller for this
     ``pos0``, T and cache shape (every layer of a step shares them);
-    with none the call builds its own."""
+    with none the call builds its own.
+
+    ``select`` (T = 1, no int8): (b, max_len) bool, the cache positions
+    a row's query may attend beside the causal mask — a token
+    selector's choice (models.kvcache.select_tokens), the same for
+    every head. It streams with the cache as one f32 lane a position;
+    the tiles are read whole, selected or not: the MASKED form of a
+    sparse attend. With every live position selected the result is,
+    to the bit, the call's without it."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, T, nh, d = q.shape
@@ -942,6 +964,13 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     R = T * r
     quant = k_scale is not None
     latent = v_cache is None
+    if select is not None and (T != 1 or quant or tail is not None
+                               or select.shape != (b, L)):
+        raise ValueError(
+            f"flash_block_decode: a selection goes with one query a "
+            f"row, an unquantized cache and no tail, as (b, max_len); "
+            f"got T={T}, int8={quant}, tail {tail is not None}, "
+            f"select {select.shape}")
     widest, tile_bytes = _tile_rule(latent)
     if block_k is None:
         block_k = widest
@@ -1031,6 +1060,10 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
             in_specs += [t_spec]
             args += [vary_like(rows.astype(k_cache.dtype), k_cache)]
         scalars += [jnp.asarray(newest, jnp.int32).reshape(1)]
+    if select is not None:
+        in_specs += [pl.BlockSpec((1, 1, 1, bk), cache_map)]
+        args += [vary_like(select.astype(jnp.float32)[:, None, None, :],
+                           k_cache)]
     scalars = [vary_like(vary_like(x, q), k_cache) for x in scalars]
     n_work = vary_like(vary_like(n_work, q), k_cache)
 
@@ -1050,7 +1083,8 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale),
                           bk=bk, max_len=L, quant=quant, r=r, T=T,
-                          v_dim=v_dim, n_tail=n_tail),
+                          v_dim=v_dim, n_tail=n_tail,
+                          selected=select is not None),
         grid_spec=grid_spec,
         out_shape=out_struct((b, nkv, R, dv), jnp.float32, q, k_cache),
         interpret=interpret,
@@ -1061,3 +1095,104 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     )(*scalars, *args)
     return (out.reshape(b, nkv, T, r, dv).transpose(0, 2, 1, 3, 4)
             .reshape(b, T, nh, dv))
+
+
+# ---- the token selector's score ----------------------------------------
+
+#: cache-axis tile of index_score: (index dim 128) x 2048 bf16 is 512 KiB
+#: of keys a grid step, against a step's fixed cost of ~0.3 us
+_INDEX_BLOCK_K = 2048
+
+
+def _index_score_kernel(row_ref, tile_ref, q_ref, w_ref, k_ref, o_ref):
+    # grid step i of the work list: tile tile_ref[i] of row row_ref[i];
+    # the index maps did the addressing
+    q = q_ref[0]                                     # (heads, d)
+    k = k_ref[0, 0]                                  # (d, BK)
+    s = jax.lax.dot_general(q, k.astype(q.dtype), (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = jnp.maximum(s, 0.0) * w_ref[0]               # (heads, BK) x (heads, 1)
+    o_ref[0] = s.sum(axis=0, keepdims=True)          # (1, BK)
+
+
+def index_score_tile(max_len: int, block_k: int = _INDEX_BLOCK_K) -> int:
+    """index_score's tile of the cache axis: at most ``block_k``, a
+    128-multiple that divides max_len (or the whole axis)."""
+    bk = min(block_k, max_len)
+    while bk > 128 and max_len % bk:
+        bk -= 128
+    return bk
+
+
+def can_index_score(max_len: int, head_dim: int) -> bool:
+    """Shape gate: whole 128-lane blocks of the cache axis, whole bf16
+    sublane groups of the index key."""
+    return (max_len >= 128 and max_len % 128 == 0
+            and head_dim % _SUBLANES == 0)
+
+
+def index_score(q, w, k_cache, pos, *, work=None,
+                block_k: Optional[int] = None,
+                interpret: Optional[bool] = None):
+    """A token selector's scores of a row's cached index keys, for one
+    query a row: ``q`` (b, heads, d), ``w`` (b, heads) f32, ``k_cache``
+    (b, 1, d, max_len) seq-minor (one key a token, shared by the
+    heads), ``pos`` (b,). Returns (b, max_len) f32,
+    ``sum_j w_j relu(q_j . k_s)`` at positions s <= pos_b and -inf past
+    them. A row's keys stream ONCE, in tiles, over the live (row, tile)
+    pairs of decode_work_list (``work``: the list for T = 1 at
+    index_score_tile's width; with none the call builds its own); per
+    tile (heads, d) x (d, BK) on the MXU in the cache's dtype with f32
+    accumulation, ReLU, the heads' weighted sum. XLA's form of it
+    writes and re-reads a (b, heads, max_len) f32 tensor, twice the
+    keys' own bytes. Tiles past a row's context are not visited: the
+    mask that follows covers what they hold."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, nh, d = q.shape
+    L = k_cache.shape[3]
+    if block_k is None:
+        block_k = _INDEX_BLOCK_K
+    bk = index_score_tile(L, block_k)
+    n_k = -(-L // bk)
+    posv = jnp.asarray(pos, jnp.int32)
+    posv = jnp.full((b,), posv) if posv.ndim == 0 else posv.reshape(b)
+    if work is None:
+        work = decode_work_list(posv, 1, bk, n_k)
+    row_of, tile_of, n_work = work
+    if row_of.shape != (b * n_k + 1,):
+        raise ValueError(
+            f"index_score: a work list for {b} rows of {n_k} tiles has "
+            f"{b * n_k + 1} entries, got {row_of.shape}")
+    row_map = lambda i, row_ref, tile_ref: (  # noqa: E731
+        jnp.minimum(row_ref[i], b - 1), 0, 0)
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_work,),
+        in_specs=[
+            pl.BlockSpec((1, nh, d), row_map),
+            pl.BlockSpec((1, nh, 1), row_map),
+            pl.BlockSpec((1, 1, d, bk),
+                         lambda i, row_ref, tile_ref: (
+                             jnp.minimum(row_ref[i], b - 1), 0, 0,
+                             tile_ref[i])),
+        ],
+        out_specs=pl.BlockSpec(
+            (1, 1, bk), lambda i, row_ref, tile_ref: (
+                jnp.minimum(row_ref[i], b - 1), 0, tile_ref[i])),
+    )
+    out = pl.pallas_call(
+        _index_score_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, L), jnp.float32),
+        interpret=interpret,
+        name="index_score",
+        **kwargs,
+    )(row_of, tile_of, q.astype(k_cache.dtype),
+      w.astype(jnp.float32)[..., None], k_cache)
+    live = jnp.arange(L)[None, :] <= posv[:, None]
+    return jnp.where(live, out[:, 0], -jnp.inf)

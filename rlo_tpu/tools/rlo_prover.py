@@ -1700,6 +1700,17 @@ def _p3_probes() -> List[Probe]:
                  scale=0.125, k_scale=None, v_scale=None,
                  interpret=True),
         ], sites=1),
+        # a token selector's scores on its own work list: the index
+        # keys' tiles and the score blocks follow (row, tile)
+        Probe("rlo_tpu/pallas/decode.py", "index_score", [
+            dict(q=A((2, 8, 128)), w=A((2, 8)),
+                 k_cache=A((2, 1, 128, 4096)), pos=A((2,), [3000, 0]),
+                 work=None, block_k=None, interpret=True),
+            # a retired slot's pos past max_len beside a row of one tile
+            dict(q=A((2, 8, 128)), w=A((2, 8)),
+                 k_cache=A((2, 1, 128, 4096)), pos=A((2,), [9000, 5]),
+                 work=None, block_k=1024, interpret=True),
+        ], sites=1),
         Probe("rlo_tpu/pallas/flash.py", "_flash_fwd_call", [
             dict(q=A((8, 1024, 128)), k=A((8, 2048, 128)),
                  v=A((8, 2048, 128)), m=A((8, 1, 1024)),

@@ -167,3 +167,53 @@ def test_expert_ffn_compiles_for_v5e(one_chip, tokens, tile):
         shape((n_rows // tile,), jnp.int32),
         shape((), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text and "expert_ffn" in text
+
+
+# deepseek-v3.2-ep32 x longctx-decode: 32 slots of 24576 positions, the
+# token selector's 64 heads of 128 over one 128-wide key a token, the
+# attend over the 2048 positions it keeps
+DSA_SLOTS, DSA_LEN, INDEX_HEADS, INDEX_DIM, INDEX_TOPK = 32, 24576, 64, 128, 2048
+
+
+def test_index_score_and_selected_attend_compile_for_v5e(one_chip):
+    """A decode step of the sparse-attention cell, a layer's worth: the
+    row write of both tensors of the entry, index_score on its own
+    work list, the exact top-2048 as a mask, and the latent attend
+    with that mask streamed beside the cache."""
+    from rlo_tpu.models.kvcache import topk_mask
+    from rlo_tpu.pallas.decode import index_score, index_score_tile
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    bf16 = jnp.bfloat16
+    latent = shape((DSA_SLOTS, 1, LATENT, DSA_LEN), bf16)
+    keys = shape((DSA_SLOTS, 1, INDEX_DIM, DSA_LEN), bf16)
+    bk = flash_decode_tile(latent, HEADS, latent=True)
+    ibk = index_score_tile(DSA_LEN)
+    assert (bk, ibk) == (LATENT_TILE, 2048)
+
+    def step(q, qi, w, row, irow, cache, ik, pos):
+        cache = write_kv_row(cache, row, pos, interpret=False)
+        ik = write_kv_row(ik, irow, pos, interpret=False)
+        scores = index_score(
+            qi, w, ik, pos, interpret=False,
+            work=decode_work_list(pos, 1, ibk, DSA_LEN // ibk))
+        select = topk_mask(scores, INDEX_TOPK)
+        out = flash_block_decode(
+            q, cache, None, pos, 0.135, v_dim=V_DIM, interpret=False,
+            work=decode_work_list(pos, 1, bk, DSA_LEN // bk),
+            select=select)
+        return out, cache, ik
+
+    from rlo_tpu.utils import hlo
+    lowered = jax.jit(step).lower(
+        shape((DSA_SLOTS, 1, HEADS, LATENT), bf16),
+        shape((DSA_SLOTS, INDEX_HEADS, INDEX_DIM), bf16),
+        shape((DSA_SLOTS, INDEX_HEADS), jnp.float32),
+        shape((DSA_SLOTS, 1, LATENT), bf16),
+        shape((DSA_SLOTS, 1, INDEX_DIM), bf16), latent, keys,
+        shape((DSA_SLOTS,), jnp.int32))
+    assert hlo.mosaic_kernels(lowered.as_text()) == {
+        "flash_decode": 1, "index_score": 1, "write_kv_row": 2}
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 4
