@@ -21,7 +21,10 @@ TPU-shaped design decisions:
   - DENSE mode (the original): a fresh request prefills into its slot
     with the blockwise prefill (one forward at a padded prompt bucket),
     then the row's cache is scattered into the pool cache at the slot
-    index. Prompts longer than the largest bucket extend past it in
+    index. The requests one admission pass takes of one bucket are ONE
+    launch — a device loop over them, a row at a time — and one read
+    of their first tokens (DecodeServer._admit). Prompts longer than
+    the largest bucket extend past it in
     jitted ``block_decode`` chunks — admission never rejects a prompt
     that fits ``max_len - max_new`` — of 128 tokens, or of twice that
     for a prompt that runs further than one such chunk past the bucket
@@ -150,12 +153,18 @@ class DecodeServer:
     ``perf.serve.<stage>`` on the profiler's clock, and its elapsed
     time in the counters ``serve.<stage>_ns`` / ``_n``. Stages:
     ``step_round``; ``admit`` with ``admit.stage_input``,
-    ``admit.prefill_dispatch``, ``admit.extend``,
-    ``admit.scatter_dispatch``, ``admit.first_token_sync`` (paged:
-    ``admit.map_pages``, ``admit.prefill_chunk``, ``page_gauges``);
-    ``round.dispatch``, ``round.wait``, ``round.readback``;
-    ``distribute``. Work counters sit at the same boundaries:
-    ``serve.admissions``, ``serve.prefill_tokens`` against
+    ``admit.prefill_dispatch`` and ``admit.first_token_sync`` — dense
+    scheduler: once a LAUNCH, which admits a pass's requests of one
+    prompt bucket, so a group's requests share one sync and TTFT ends
+    there; once a request for a prompt past the widest bucket, which
+    also runs ``admit.extend`` and ``admit.scatter_dispatch`` —
+    (paged: ``admit.map_pages``, ``admit.prefill_chunk``,
+    ``page_gauges``); ``round.dispatch``, ``round.wait``,
+    ``round.readback``; ``distribute``. Work counters sit at the same
+    boundaries: ``serve.admissions``, ``serve.admit.launches``
+    (admission programs launched) and ``serve.admit.batched_rows``
+    (requests admitted through them: every one but the long prompts),
+    ``serve.prefill_tokens`` against
     ``serve.prefill_padded_tokens``, ``serve.slot_steps`` against
     ``serve.slot_steps_useful``, ``serve.attend_tiles`` against
     ``serve.attend_tiles_live`` and ``serve.attend_steps`` (dense
@@ -320,7 +329,37 @@ class DecodeServer:
             first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return row, first
 
-        self._prefill = jax.jit(prefill_slot)
+        # the one-row prefill as a program of its own: a prompt past the
+        # widest bucket starts with it, and an admission program calls
+        # it, a row at a time
+        one_row = self._prefill = jax.jit(prefill_slot)
+
+        def admit_rows(params, cache, staged):
+            # one (pass, bucket) group in ONE launch: row i of
+            # ``staged`` is [prompt padded to the bucket | length |
+            # slot], live rows first (a live row's length is >= 1). A
+            # device loop over the live rows alone runs, for each, the
+            # one-row bucket prefill above and the scatter into its
+            # slot: what the device computes for a request is what a
+            # launch of its own would, and a padded row costs nothing.
+            bucket = staged.shape[1] - 2
+            live = jnp.sum(staged[:, bucket] > 0)
+
+            def body(i, carry):
+                cache, firsts = carry
+                r = lax.dynamic_slice_in_dim(staged, i, 1)
+                row, first = one_row(params, r[:, :bucket], r[:, bucket])
+                cache = kvcache.scatter_slot(cache, row, r[0, bucket + 1])
+                return cache, lax.dynamic_update_slice(firsts, first, (i,))
+
+            return lax.fori_loop(
+                0, live, body,
+                (cache, jnp.zeros((staged.shape[0],), jnp.int32)))
+
+        self._admit_rows = jax.jit(admit_rows, donate_argnums=(1,))
+        # buckets whose admission program is built: a bucket's is built
+        # when a request of the bucket first arrives (_launch_group)
+        self._admit_built: set = set()
 
         # long prompts (plen > the largest bucket) extend the
         # bucket-prefilled row cache through jitted block_decode
@@ -352,7 +391,12 @@ class DecodeServer:
         # the jitted object: callers may wrap the attributes on the
         # instance
         self._jits = {"_round": (self._round, 1),
-                      "_prefill": (self._prefill, len(self.buckets)),
+                      # one admission program a bucket, built when a
+                      # request of the bucket first arrives
+                      "_admit_rows": (self._admit_rows,
+                                      len(self.buckets)),
+                      # the widest bucket's, for long prompts
+                      "_prefill": (self._prefill, 1),
                       # the 128-token chunk and the long prompts' own
                       "_extend": (self._extend, 2),
                       "_scatter": (self._scatter, 1)}
@@ -452,13 +496,14 @@ class DecodeServer:
         return rid
 
     def _span(self, stage: str, rid: Optional[int] = None,
-              fabric_stage: Optional[int] = None) -> annotate:
+              fabric_stage: Optional[int] = None, **ids) -> annotate:
         """The span around one stage of the host loop: the profiler
         annotation ``perf.serve.<stage>`` (with ``rid`` when the stage
-        belongs to a request) and the counters ``serve.<stage>_ns`` /
-        ``_n``. A stage that carries a ``fabric_stage`` is also emitted
-        as that ``Ev.SPAN`` when the fabric attached a recorder AND the
-        fabric-level request is sampled."""
+        belongs to a request, and any further ``ids``) and the counters
+        ``serve.<stage>_ns`` / ``_n``. A stage that carries a
+        ``fabric_stage`` is also emitted as that ``Ev.SPAN`` when the
+        fabric attached a recorder AND the fabric-level request is
+        sampled."""
         emit = None
         recorder = self.spans
         if (fabric_stage is not None and recorder is not None
@@ -467,7 +512,8 @@ class DecodeServer:
             if frid is not None and recorder.sampled(frid):
                 emit = functools.partial(recorder.emit, frid,
                                          fabric_stage)
-        ids = {} if rid is None else {"rid": rid}
+        if rid is not None:
+            ids["rid"] = rid
         return annotate("perf.serve." + stage, self.metrics,
                         "serve." + stage, emit, **ids)
 
@@ -478,8 +524,19 @@ class DecodeServer:
         """Fill every free slot from the queue; returns the number of
         requests that COMPLETED during admission (max_new=1 or an
         immediate eos retires the slot at once — the freed slot is
-        re-offered to the queue in the same pass, and the completion
-        count keeps step_round truthful about progress)."""
+        re-offered to the queue in the same admission, and the
+        completion count keeps step_round truthful about progress).
+
+        Dense scheduler: the queue's head goes to the lowest free slot,
+        the next to the next, as many as there are free slots: one
+        PASS. Its requests that fit a prompt bucket are admitted a
+        bucket at a time: one launch (``_admit_rows``) and one read of
+        all its first tokens a (pass, bucket) GROUP, every group
+        launched before any is read, so a request's TTFT ends at its
+        group's sync and a group of one runs the program a group of
+        nine does. A prompt past the widest bucket is admitted alone,
+        after the groups (``_admit_long``). Slots that came free in a
+        pass are offered again in the next."""
         with self._span("admit"):
             if self.paged:
                 return self._admit_paged()
@@ -487,75 +544,126 @@ class DecodeServer:
 
     def _admit_dense(self) -> int:
         completed = 0
-        slot = 0
-        while slot < self.n_slots:
-            if self.req_of_slot[slot] is not None or not self._queue:
-                slot += 1
-                continue
-            rid, req = self._queue.pop(0)
-            t_sub = self._submit_ts.pop(rid, None)
-            now = time.perf_counter()
-            if t_sub is not None:
-                self._hist("serve.queue_wait_usec").observe(
-                    (now - t_sub) * 1e6)
-            plen = len(req.prompt)
-            head = min(plen, self.buckets[-1])
-            bucket = _bucket(head, self.buckets)
-            with self._span("admit.stage_input", rid):
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :head] = req.prompt[:head]
-                prompt = jnp.asarray(padded)
-                length = jnp.asarray([head], jnp.int32)
-            with self._span("admit.prefill_dispatch", rid):
-                row, first = self._prefill(self.params, prompt, length)
-            # long prompt: extend the row past the bucket in jitted
-            # block_decode chunks (write-then-attend; the final
-            # chunk's last-position logits seed the first token)
-            off = head
-            ran = bucket  # positions the prefill ran, padding included
-            width = (self._long_w if plen - head > self._long_w
-                     else self._chunk_w)
-            while off < plen:
-                n = min(width, plen - off)
-                toks = np.zeros((1, width), np.int32)
-                toks[0, :n] = req.prompt[off:off + n]
-                with self._span("admit.extend", rid):
-                    first, row = self._extend(
-                        self.params, row, jnp.asarray(toks),
-                        jnp.int32(off), jnp.int32(n))
-                off += n
-                ran += width
-            with self._span("admit.scatter_dispatch", rid):
-                self.cache = self._scatter(self.cache, row,
-                                           jnp.int32(slot))
-            with self._span("admit.first_token_sync", rid):
-                # the host blocks here until this request's prefill
-                # and scatter finished on the device
-                first = int(np.asarray(first).reshape(-1)[0])
-            if t_sub is not None:
-                # first token is materialized on the host here: TTFT
-                # = submit (or due) -> first token, queue wait included
-                self._hist("serve.ttft_usec").observe(
-                    (time.perf_counter() - t_sub) * 1e6)
-            count = self.metrics.counter
-            count("serve.admissions").inc()
-            count("serve.prefill_tokens").inc(plen)
-            count("serve.prefill_padded_tokens").inc(ran)
-            count("serve.tokens_out").inc()
+        while True:
+            free = [s for s in range(self.n_slots)
+                    if self.req_of_slot[s] is None]
+            groups: Dict[int, list] = {}    # bucket -> its requests
+            long = []
+            for slot in free[:len(self._queue)]:
+                rid, req = self._queue.pop(0)
+                t_sub = self._submit_ts.pop(rid, None)
+                if t_sub is not None:
+                    self._hist("serve.queue_wait_usec").observe(
+                        (time.perf_counter() - t_sub) * 1e6)
+                taken = (slot, rid, req, t_sub)
+                if len(req.prompt) > self.buckets[-1]:
+                    long.append(taken)
+                else:
+                    groups.setdefault(_bucket(len(req.prompt),
+                                              self.buckets),
+                                      []).append(taken)
+            if not (groups or long):
+                return completed
             self.metrics.gauge("serve.queue_depth").set(len(self._queue))
-            self.req_of_slot[slot] = rid
-            self._out[rid] = [first]
-            self.pos[slot] = plen
-            self.last_tok[slot] = first
-            self.budget[slot] = req.max_new - 1
-            if req.eos_id is not None and first == req.eos_id:
-                self.budget[slot] = 0
-            self._retire_if_done(slot)
-            if self.req_of_slot[slot] is None:
-                completed += 1  # retired at admission: re-offer slot
-            else:
-                slot += 1
-        return completed
+            launched = [(bucket, rows, self._launch_group(bucket, rows))
+                        for bucket, rows in sorted(groups.items())]
+            for bucket, rows, firsts in launched:
+                with self._span("admit.first_token_sync", rows=len(rows)):
+                    # the host blocks here until the group's prefills
+                    # and scatters finished on the device
+                    firsts = np.asarray(firsts)
+                for taken, first in zip(rows, firsts):
+                    completed += self._seat(*taken, int(first), bucket)
+            for taken in long:
+                completed += self._admit_long(*taken)
+
+    def _launch_group(self, bucket: int, rows: list):
+        """Stage the in-bucket requests ``rows`` of one pass into one
+        host array and launch their admission; returns the first
+        tokens, on the device, a row each in ``rows``' order."""
+        with self._span("admit.stage_input", rows=len(rows)):
+            staged = np.zeros((self.n_slots, bucket + 2), np.int32)
+            for i, (slot, _, req, _) in enumerate(rows):
+                plen = len(req.prompt)
+                staged[i, :plen] = req.prompt
+                staged[i, bucket:] = plen, slot
+            staged = jnp.asarray(staged)
+        with self._span("admit.prefill_dispatch", rows=len(rows)):
+            if bucket not in self._admit_built:
+                # The layers are traced HERE, where no other trace is
+                # open: jit keeps the one-row function's jaxpr, and the
+                # loop's body, traced next, finds it. The same trace
+                # made inside the body cost a 24-layer server 6.7 s
+                # more of set-up on the chip's host (PERF.md §6, PR 33).
+                self._admit_built.add(bucket)
+                self._jits["_prefill"][0].trace(
+                    self.params,
+                    jax.ShapeDtypeStruct((1, bucket), jnp.int32),
+                    jax.ShapeDtypeStruct((1,), jnp.int32))
+            self.cache, firsts = self._admit_rows(self.params, self.cache,
+                                                  staged)
+        self.metrics.counter("serve.admit.launches").inc()
+        self.metrics.counter("serve.admit.batched_rows").inc(len(rows))
+        return firsts
+
+    def _admit_long(self, slot: int, rid: int, req: Request,
+                    t_sub: Optional[float]) -> int:
+        """Admit one prompt longer than the widest bucket: the bucket
+        prefill of its head, then jitted block_decode chunks
+        (write-then-attend; the final chunk's last-position logits
+        seed the first token), the scatter and a sync of its own."""
+        plen = len(req.prompt)
+        head = self.buckets[-1]
+        with self._span("admit.stage_input", rid):
+            prompt = jnp.asarray(req.prompt[None, :head])
+            length = jnp.asarray([head], jnp.int32)
+        with self._span("admit.prefill_dispatch", rid):
+            row, first = self._prefill(self.params, prompt, length)
+        off = head
+        ran = head      # positions the prefill ran, padding included
+        width = (self._long_w if plen - head > self._long_w
+                 else self._chunk_w)
+        while off < plen:
+            n = min(width, plen - off)
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :n] = req.prompt[off:off + n]
+            with self._span("admit.extend", rid):
+                first, row = self._extend(
+                    self.params, row, jnp.asarray(toks),
+                    jnp.int32(off), jnp.int32(n))
+            off += n
+            ran += width
+        with self._span("admit.scatter_dispatch", rid):
+            self.cache = self._scatter(self.cache, row, jnp.int32(slot))
+        with self._span("admit.first_token_sync", rid):
+            # the host blocks here until this request's prefill,
+            # chunks and scatter finished on the device
+            first = int(np.asarray(first).reshape(-1)[0])
+        return self._seat(slot, rid, req, t_sub, first, ran)
+
+    def _seat(self, slot: int, rid: int, req: Request,
+              t_sub: Optional[float], first: int, ran: int) -> int:
+        """``req`` holds ``slot`` from here: its first token is on the
+        host (TTFT = submit, or due, -> here, queue wait included), its
+        prefill ran ``ran`` positions, padding included. Returns 1 if
+        the request completed at once (the slot is free again)."""
+        if t_sub is not None:
+            self._hist("serve.ttft_usec").observe(
+                (time.perf_counter() - t_sub) * 1e6)
+        count = self.metrics.counter
+        count("serve.admissions").inc()
+        count("serve.prefill_tokens").inc(len(req.prompt))
+        count("serve.prefill_padded_tokens").inc(ran)
+        count("serve.tokens_out").inc()
+        self.req_of_slot[slot] = rid
+        self._out[rid] = [first]
+        self.pos[slot] = len(req.prompt)
+        self.last_tok[slot] = first
+        self.budget[slot] = req.max_new - 1
+        if req.eos_id is not None and first == req.eos_id:
+            self.budget[slot] = 0
+        self._retire_if_done(slot)
+        return int(self.req_of_slot[slot] is None)
 
     # ---- paged admission / chunked prefill ---------------------------
     def _try_map(self, slot: int, req: Request) -> bool:

@@ -333,7 +333,9 @@ def test_span_and_work_counters_balance(setup, paged):
     """After a whole run: one admission per request, every token is
     either a prefill's first token or a useful slot-step, every child
     stage's time fits inside its parent's, and each stage's ``_n`` is
-    the number of times the stage ran."""
+    the number of times the stage ran: once a request in the paged
+    scheduler and for a prompt past the widest bucket, once a LAUNCH
+    for the dense scheduler's in-bucket groups."""
     from rlo_tpu.utils.metrics import Registry
 
     params = setup
@@ -359,8 +361,8 @@ def test_span_and_work_counters_balance(setup, paged):
     for stage in ("round.dispatch", "round.wait", "round.readback",
                   "distribute"):
         assert c[f"serve.{stage}_n"] == c["serve.rounds"]
-    assert c["serve.admit.first_token_sync_n"] == len(reqs)
     if paged:
+        assert c["serve.admit.first_token_sync_n"] == len(reqs)
         assert c["serve.admit.map_pages_n"] >= len(reqs)
         assert c["serve.admit.prefill_chunk_n"] == \
             c["serve.prefill_chunks"]
@@ -372,9 +374,15 @@ def test_span_and_work_counters_balance(setup, paged):
                     if len(p) > 16)
         assert n_ext > 0      # the stream reaches past the last bucket
         assert c["serve.admit.extend_n"] == n_ext
+        n_long = sum(len(p) > 16 for p, _ in reqs)
+        assert c["serve.admit.scatter_dispatch_n"] == n_long
+        assert c["serve.admit.batched_rows"] == len(reqs) - n_long
+        # two slots: a launch admits one or two requests
+        launches = c["serve.admit.launches"]
+        assert (len(reqs) - n_long) / 2 <= launches <= len(reqs) - n_long
         for stage in ("stage_input", "prefill_dispatch",
-                      "scatter_dispatch"):
-            assert c[f"serve.admit.{stage}_n"] == len(reqs)
+                      "first_token_sync"):
+            assert c[f"serve.admit.{stage}_n"] == launches + n_long
     for parent in ("step_round", "admit"):
         kids = sum(c.get(f"serve.{k}_ns", 0)
                    for k, par in SPAN_PARENT.items() if par == parent)
@@ -386,7 +394,10 @@ def test_span_and_work_counters_balance(setup, paged):
 def test_profiler_trace_holds_nested_serve_spans(setup, tmp_path):
     """Under a jax.profiler session on the CPU two step_round() calls
     leave ``perf.serve.*`` events on ``/host:CPU`` that nest as
-    SPAN_PARENT says, and every admission stage carries its ``rid``."""
+    SPAN_PARENT says; an admission stage carries the ``rid`` of its
+    request (a prompt past the widest bucket) or the ``rows`` of its
+    group (one launch a bucket), and between them they hold every
+    request admitted."""
     import glob
     import warnings
 
@@ -426,7 +437,9 @@ def test_profiler_trace_holds_nested_serve_spans(setup, tmp_path):
         parent = SPAN_PARENT.get(name)
         for a, b, stats in spans:
             if name.startswith("admit."):
-                admitted.add(int(stats["rid"]))
+                assert ("rid" in stats) != ("rows" in stats), name
+                if "rid" in stats:
+                    admitted.add(int(stats["rid"]))
             if parent is not None:
                 assert any(pa <= a and b <= pb
                            for pa, pb, _ in by_stage[parent]), name
@@ -435,6 +448,10 @@ def test_profiler_trace_holds_nested_serve_spans(setup, tmp_path):
             "round.dispatch", "round.readback",
             "distribute"} <= set(by_stage)
     assert admitted and admitted <= {0, 1, 2, 3}
+    for stage in ("stage_input", "prefill_dispatch", "first_token_sync"):
+        spans = by_stage["admit." + stage]
+        assert len(admitted) < sum(
+            int(st.get("rows", 1)) for _, _, st in spans) <= 4, stage
 
 
 @pytest.mark.parametrize("paged", [False, True],
@@ -494,6 +511,141 @@ def test_retraces_counts_shapes_the_server_was_not_built_for(setup):
     assert c2["serve.retraces"] == c2["serve.retraces._round"] >= 1
     assert not any(k.startswith("serve.retraces._")
                    and k != "serve.retraces._round" for k in c2)
+
+
+# -- batched admission (PR 33): one launch and one sync a (pass, bucket)
+# group; what the device computes for a request is its one-row prefill
+ADMIT_CFGS = {
+    "plain": lambda: CFG,
+    "int8": lambda: dataclasses.replace(CFG, kv_cache_dtype="int8"),
+    "latent": lambda: _latent_cfg(),
+}
+
+
+def _one_row_reference(params, cfg, buckets, max_len):
+    """``reference(prompt, max_new, eos_id=None)``: a request's tokens by
+    the plainest route — generate.prefill of its one padded row (its
+    bucket's width; a prompt past the widest bucket whole, at its own
+    length), then generate.decode_step a token at a time."""
+    from rlo_tpu.models.generate import (_decode_cfg, decode_step,
+                                         init_kv_cache, prefill)
+    first = jax.jit(lambda p, t, n: prefill(
+        p, t, init_kv_cache(cfg, 1, max_len), cfg, last_index=n - 1))
+    step = jax.jit(lambda p, t, n, c: decode_step(p, t, n, c,
+                                                  _decode_cfg(cfg)))
+
+    def reference(prompt, max_new, eos_id=None):
+        plen = len(prompt)
+        width = plen if plen > buckets[-1] else _bucket(plen, buckets)
+        padded = np.zeros((1, width), np.int32)
+        padded[0, :plen] = prompt
+        logits, cache = first(params, jnp.asarray(padded),
+                              jnp.asarray([plen], jnp.int32))
+        toks = [int(np.argmax(np.asarray(logits)[0]))]
+        while len(toks) < max_new and toks[-1] != eos_id:
+            logits, cache = step(
+                params, jnp.asarray(toks[-1:], jnp.int32),
+                jnp.asarray([plen + len(toks) - 1], jnp.int32), cache)
+            toks.append(int(np.argmax(np.asarray(logits)[0])))
+        return np.asarray(toks, np.int32)
+
+    return reference
+
+
+@pytest.mark.parametrize("entry", sorted(ADMIT_CFGS))
+def test_batched_admission_matches_one_row_reference(entry):
+    """A mixed queue through three slots: both buckets in one pass,
+    more requests than free slots, one ``max_new == 1`` and one whose
+    first token is its ``eos_id`` (both retire at admission: their
+    slots are offered again in the same admission, a second launch),
+    one prompt past the widest bucket (admitted alone). Every request's
+    tokens equal the one-row reference's, and the counters say which
+    path each took."""
+    from rlo_tpu.utils.metrics import Registry
+    cfg = ADMIT_CFGS[entry]()
+    params = init_params(jax.random.PRNGKey(33), cfg)
+    buckets, max_len = (8, 16), 64
+    rng = np.random.default_rng(33)
+    shape = [(5, 6), (12, 1), (7, 5), (21, 4), (16, 7), (3, 3), (9, 2),
+             (8, 5)]                        # (prompt length, max_new)
+    reqs = [[rng.integers(0, cfg.vocab, (plen,)), m, None]
+            for plen, m in shape]
+    reference = _one_row_reference(params, cfg, buckets, max_len)
+    reqs[2][2] = int(reference(reqs[2][0], 1)[0])
+    reg = Registry()
+    srv = DecodeServer(params, cfg, n_slots=3, max_len=max_len,
+                       round_len=4, prompt_buckets=buckets, metrics=reg)
+    for prompt, max_new, eos in reqs:
+        srv.submit(prompt, max_new, eos_id=eos)
+    assert srv._admit() == 2        # requests 1 and 2 retired at once
+    # pass 1: [0, 2] and [1]; pass 2: 3 alone (long) and [4]
+    c = reg.snapshot()["counters"]
+    assert c["serve.admit.launches"] == 3
+    assert c["serve.admit.batched_rows"] == 4
+    assert c["serve.admissions"] == 5
+    assert srv.slot_ownership() == (0, 3, 4)
+    outs = srv.run()
+    assert len(outs[2]) == 1 and len(outs[1]) == 1
+    for (prompt, max_new, eos), got in zip(reqs, outs):
+        np.testing.assert_array_equal(got, reference(prompt, max_new, eos))
+    c = reg.snapshot()["counters"]
+    assert c["serve.admissions"] == len(reqs)
+    assert c["serve.admit.batched_rows"] == len(reqs) - 1
+    assert c["serve.admit.scatter_dispatch_n"] == 1     # the long one
+    assert c["serve.admit.first_token_sync_n"] == \
+        c["serve.admit.launches"] + 1
+    assert c["serve.prefill_tokens"] == sum(p for p, _ in shape)
+    assert c["serve.prefill_padded_tokens"] == \
+        8 * 4 + 16 * 3 + (16 + 16)      # the long one: bucket + a chunk
+    assert c.get("serve.retraces", 0) == 0
+    assert reg.histogram("serve.ttft_usec").count == len(reqs) == \
+        reg.histogram("serve.queue_wait_usec").count
+
+
+def test_first_admission_traces_each_layer_once_a_bucket(setup,
+                                                         monkeypatch):
+    """The set-up cost of admission, without a chip: a fresh server's
+    first admission of a two-bucket queue runs the layer body (python)
+    ``n_layers`` times a bucket USED and not at all for the bucket no
+    request needs; a second, differently sized pass traces nothing, and
+    ``_jits`` holds one admission entry a bucket with no re-trace."""
+    from rlo_tpu.models import generate as generate_mod
+    from rlo_tpu.utils.metrics import Registry
+
+    calls = []
+    inner = generate_mod.apply_layer
+
+    def counted(x, *a, **kw):
+        calls.append(x.shape)
+        return inner(x, *a, **kw)
+
+    monkeypatch.setattr(generate_mod, "apply_layer", counted)
+    params = setup
+    reg = Registry()
+    srv = DecodeServer(params, CFG, n_slots=4, max_len=96, round_len=4,
+                       prompt_buckets=(8, 16, 32), metrics=reg)
+    rng = np.random.default_rng(15)
+    for plen in (3, 12, 8, 16):             # buckets 8 and 16, not 32
+        srv.submit(rng.integers(0, CFG.vocab, (plen,)), 9)
+    srv._admit()
+    assert sorted(calls) == sorted(
+        [(1, 8, CFG.d_model)] * CFG.n_layers
+        + [(1, 16, CFG.d_model)] * CFG.n_layers)
+    fn, built_for = srv._jits["_admit_rows"]
+    assert built_for == 3 and fn._cache_size() == 2
+    assert srv._jits["_prefill"][0]._cache_size() == 0
+    srv.cancel(0), srv.cancel(2), srv.cancel(3)
+    del calls[:]
+    srv.submit(rng.integers(0, CFG.vocab, (5,)), 3)    # a group of one
+    srv.submit(rng.integers(0, CFG.vocab, (9,)), 3)
+    srv.submit(rng.integers(0, CFG.vocab, (16,)), 3)   # and one of two
+    srv._admit()
+    assert calls == [] and fn._cache_size() == 2
+    srv.run()
+    c = reg.snapshot()["counters"]
+    assert c.get("serve.retraces", 0) == 0
+    assert c["serve.admit.launches"] == 4
+    assert c["serve.admit.batched_rows"] == c["serve.admissions"] == 7
 
 
 def test_fabric_recorder_still_gets_prefill_chunk_spans(setup):

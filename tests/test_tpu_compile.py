@@ -3,7 +3,9 @@ cells' shapes (gpt2-medium: decode attention at 96 rows x max_len 1024;
 deepseek-v3-ep16: the latent attend at 128 rows x 576 x 4096 and the
 grouped expert FFN over 16 held experts at 7168 x 2048; both cells'
 round: the attend with a 32-row write-behind tail and the tail's fold;
-every attend on its work-list grid, a 1-D grid with a dynamic bound),
+every attend on its work-list grid, a 1-D grid with a dynamic bound;
+gpt2-medium's admission program, a device loop around the one-row
+prefill with the pool cache in its carry),
 without a chip: Mosaic's refusals (block shapes, scoped VMEM, scalar-prefetch index
 maps) show up here, numerics and times do not. The topology is described
 inside a fixture, never at import (only one process may load libtpu, and
@@ -217,3 +219,41 @@ def test_index_score_and_selected_attend_compile_for_v5e(one_chip):
     assert hlo.mosaic_kernels(lowered.as_text()) == {
         "flash_decode": 1, "index_score": 1, "write_kv_row": 2}
     assert lowered.compile().as_text().count("tpu_custom_call") >= 4
+
+
+def test_admission_program_runs_its_loop_in_place_for_v5e(one_chip,
+                                                          monkeypatch):
+    """gpt2-medium x decode-sat's admission program (PR 33: one launch a
+    prompt bucket, a device loop over the group's rows) at the cell's 96
+    slots x 1024 positions, two layers deep: the TPU compiler takes the
+    while loop with the flash kernel in its body, and the pool cache
+    rides the loop's carry IN PLACE — all of it aliased to the donated
+    input, temporaries a hundredth of it. A carry that were copied would
+    double the cache's 9.7 GB on the chip."""
+    from rlo_tpu.models.kvcache import init_kv_cache
+    from rlo_tpu.models.serve import DecodeServer
+    from rlo_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig(vocab=1024, d_model=1024, n_heads=NH,
+                            n_layers=2, d_ff=4096, dtype="bfloat16")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    srv = DecodeServer(params, cfg, n_slots=2, max_len=L_GPT2)
+    # from here the model's kernel gates see the chip being compiled for
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    cache = abstract(jax.eval_shape(
+        lambda: init_kv_cache(cfg, B, L_GPT2)))
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    compiled = srv._admit_rows.lower(
+        abstract(params), cache, jax.ShapeDtypeStruct(
+            (B, 256 + 2), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "while" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == cache_bytes
+    assert memory.temp_size_in_bytes < cache_bytes // 100
