@@ -245,7 +245,14 @@ def block_decode(params: dict, tokens, pos0, cache,
     simply garbage beyond the accepted position — masked out and
     overwritten by later writes, exactly like ragged decode. A token
     selector chooses query by query (decode_step). ``moe_info``,
-    ``dsa_info``: see decode_step."""
+    ``dsa_info``: see decode_step.
+
+    Under ``cfg.block_len`` (generation by diffusion over blocks) the
+    mask is block-causal: the T tokens are whole blocks, ``pos0`` a
+    multiple of block_len, and every query attends all of its own
+    block — a denoise or commit PASS over a row's current block (T =
+    block_len: each of the block's positions sees positions
+    < pos0 + T), and a long prompt's chunk alike."""
     cfg = _decode_cfg(cfg)
     b, T = tokens.shape
     pos0 = jnp.asarray(pos0, jnp.int32).reshape(b)
@@ -261,7 +268,7 @@ def block_decode(params: dict, tokens, pos0, cache,
             index, entry, pos_arr, cfg.index_topk, info=dsa_info)
         return entry, lambda q: kvcache.attend_block(
             q, entry, pos_arr, cfg.attn_scale, pos0=pos0, v_dim=v_dim,
-            work=work, select=select)
+            work=work, select=select, block_len=cfg.block_len)
 
     x, new = _forward(params, tokens, pos_arr, cache, cfg,
                       _cache_hook(cfg, step), tp_axis=tp_axis,
@@ -272,9 +279,14 @@ def block_decode(params: dict, tokens, pos0, cache,
 def prefill(params: dict, tokens, cache, cfg: TransformerConfig,
             tp_axis: Optional[str] = None,
             ep_axis: Optional[str] = None,
-            last_index=None, moe_info: Optional[list] = None):
+            last_index=None, moe_info: Optional[list] = None,
+            need_logits: bool = True):
     """Fill the cache with the whole prompt in ONE forward pass.
-    Returns (logits of the last prompt position, filled cache).
+    Returns (logits of the last prompt position, filled cache);
+    ``need_logits=False`` skips the head and returns (None, cache): a
+    model that generates by diffusion over blocks takes no token from
+    its prompt's logits. Under ``cfg.block_len`` the block is attended
+    block-causally (_local_attention).
     ``last_index`` (b,) selects a PER-ROW logits position instead of
     the final one (ragged prompts: row i's prompt ends at
     last_index[i]; positions beyond it hold padding whose cache
@@ -327,13 +339,44 @@ def prefill(params: dict, tokens, cache, cfg: TransformerConfig,
                                   cfg), entry
         q, k, v = args
         entry, k, v = kvcache.store_prompt(lc, k, v)
-        return _local_attention(q, k, v).astype(cfg.act_dtype), entry
+        return _local_attention(
+            q, k, v, block_len=cfg.block_len).astype(cfg.act_dtype), entry
 
     x, new = _forward(params, tokens, jnp.arange(tokens.shape[1]), cache,
                       cfg, hook, tp_axis=tp_axis, ep_axis=ep_axis,
                       moe_info=moe_info)
+    if not need_logits:
+        return None, new
     return _head(params, x, cfg,
                  -1 if last_index is None else last_index), new
+
+
+# ---- generation by diffusion over blocks --------------------------------
+
+def denoise_update(logits, block, masked, threshold: float):
+    """The unmask rule of one denoise pass ('low_confidence_dynamic',
+    greedy): ``logits`` (b, B, vocab) f32 of a pass over ``block``
+    (b, B) int32 whose positions ``masked`` (b, B) bool still hold the
+    mask id. At each masked position the confidence is the largest
+    softmax probability, in float32, and the candidate its argmax;
+    every masked position whose confidence passes ``threshold`` takes
+    its candidate, or, if none does, the one with the largest
+    confidence (the lowest position on a tie). A row with no mask left
+    (its pass was a commit) is returned as it came. Returns
+    (block, masked, unmasked (b, B) bool)."""
+    with jax.named_scope("diff.unmask"):
+        logits = logits.astype(jnp.float32)
+        top = jnp.max(logits, axis=-1)
+        conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+        cand = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        sure = masked & (conf > threshold)
+        # argmax takes the first of equal confidences: the lowest position
+        best = jnp.argmax(jnp.where(masked, conf, -1.0), axis=-1)
+        lone = (jnp.arange(block.shape[1])[None, :] == best[:, None]) \
+            & masked & ~jnp.any(sure, axis=-1, keepdims=True)
+        unmasked = sure | lone
+        return (jnp.where(unmasked, cand, block), masked & ~unmasked,
+                unmasked)
 
 
 def prefill_scan(params: dict, tokens, cache, cfg: TransformerConfig,

@@ -433,15 +433,18 @@ def attend(q, entry, pos, scale, *, v_dim: int = 0, tail=None,
 
 
 def attend_block(q, entry, pos_q, scale, *, pos0, v_dim: int = 0,
-                 work=None, select=None):
+                 work=None, select=None, block_len: int = 0):
     """T queries a row, q (b, T, H, head_dim), query i at position
     pos_q[b, i] = pos0_b + i (_attend_cache_block). ``work``: the
     step's attend_work, for ``pos0`` and this T. ``select``
-    (b, T, max_len) bool: select_tokens' choice, query by query."""
+    (b, T, max_len) bool: select_tokens' choice, query by query.
+    ``block_len`` > 0: the block-causal mask (whole blocks, ``pos0`` a
+    multiple of it): a query attends every position of its block."""
     return _attend_cache_block(q, entry["k"], entry.get("v"), pos_q,
                                scale, k_scale=entry.get("ks"),
                                v_scale=entry.get("vs"), pos0=pos0,
-                               v_dim=v_dim, work=work, select=select)
+                               v_dim=v_dim, work=work, select=select,
+                               block_len=block_len)
 
 
 # ---- the token selector ------------------------------------------------
@@ -736,7 +739,7 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
 def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                         k_scale=None, v_scale=None, pos0=None,
                         use_flash=None, v_dim: int = 0, tail=None,
-                        work=None, select=None):
+                        work=None, select=None, block_len: int = 0):
     """Block variant of the cache attend: q (b, T, nh, hd) where query
     i of row b sits at position pos_q[b, i] and attends cache
     positions <= pos_q[b, i]. Because the block's own K/V rows are
@@ -763,9 +766,23 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
     none). A LATENT block whose (heads, T, max_len) f32 scores would
     pass _EINSUM_SCORE_BYTES — admission's wide chunks of a long
     prompt — is attended context tile by context tile instead
-    (_attend_latent_blocked), on the chip and off it."""
+    (_attend_latent_blocked), on the chip and off it.
+
+    ``block_len`` > 0: generation by diffusion over blocks. Query i
+    attends every position of its own block of ``block_len`` as well
+    (write-then-attend has put them there): positions <= pos_q
+    rounded up to its block's last (``pos0`` is a multiple of
+    block_len, T a whole number of blocks). The kernel and the einsum
+    take the same mask; per-head K/V caches only."""
     b, T, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
+    if block_len:
+        if v_dim or tail is not None or select is not None or T % block_len:
+            raise ValueError(
+                "a block-causal attend takes whole blocks of a per-head "
+                "K/V cache, without tail or selection")
+        # the last position a query attends: its block's last
+        pos_q = pos_q - pos_q % block_len + (block_len - 1)
     blocked = bool(v_dim) and tail is None and (
         4 * b * nh * T * max_len > _EINSUM_SCORE_BYTES)
     if select is not None and T > 1:
@@ -804,7 +821,7 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
         from rlo_tpu.pallas.decode import flash_block_decode
         return flash_block_decode(
             q, k_cache, v_cache, pos0, scale, k_scale, v_scale,
-            v_dim=v_dim, work=work,
+            v_dim=v_dim, work=work, block_len=block_len,
             select=None if select is None else select[:, 0])  # T == 1
     if blocked:
         return _attend_latent_blocked(q, k_cache, pos_q, scale, v_dim,

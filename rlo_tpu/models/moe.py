@@ -33,7 +33,11 @@ path: sigmoid scores in float32, a bias that moves the selection only,
 the top ``experts_per_tok`` experts among the best ``topk_group`` of
 ``n_group`` groups, their scores normalised to sum 1 and times
 ``routed_scale``, ``n_shared_experts`` always-on experts, gated FFNs,
-and NO dropped token at any skew. The layer is told which experts it
+and NO dropped token at any skew. ``moe_router='softmax_topk'`` is the
+same layer behind the other common router (``route_softmax``): a
+softmax over all experts in float32, the ``experts_per_tok`` largest
+stay, their weights divided by their sum; no groups, no bias. The
+layer is told which experts it
 holds (``expert_first``, ``n_experts_held``): it routes over all
 ``n_experts`` and adds the terms of its own experts and of the shared
 expert — one chip's share of an expert-parallel layer. On one chip
@@ -151,10 +155,13 @@ def moe_ffn(params: dict, h, n_experts: int, *,
 STATS = ("tokens", "assignments_held", "rows_computed", "experts_hit",
          "dropped")
 
+#: the ``moe_router`` settings routed_ffn serves
+ROUTED = ("sigmoid_group", "softmax_topk")
+
 
 def init_routed_params(rng: jax.Array, cfg) -> dict:
-    """Router (d, n_experts) and its selection bias ``br``, the held
-    experts' gated FFNs — ``wg``/``wu`` (held, d, f), ``wd``
+    """Router (d, n_experts) and ('sigmoid_group') its selection bias
+    ``br``, the held experts' gated FFNs — ``wg``/``wu`` (held, d, f), ``wd``
     (held, f, d) — and the shared expert's (``swg``/``swu``/``swd``,
     width f x n_shared_experts), stored in ``cfg.param_dtype``. The
     bias is drawn at 0.01, twice the median gap between the last expert
@@ -171,11 +178,12 @@ def init_routed_params(rng: jax.Array, cfg) -> dict:
 
     out_scale = (2 * f * cfg.n_layers) ** -0.5
     p = {"wr": norm(ks[0], (d, cfg.n_experts), d ** -0.5),
-         "br": jax.random.normal(ks[1], (cfg.n_experts,),
-                                 jnp.float32) * 0.01,
          "wg": norm(ks[2], (held, d, f), d ** -0.5),
          "wu": norm(ks[3], (held, d, f), d ** -0.5),
          "wd": norm(ks[4], (held, f, d), out_scale)}
+    if cfg.moe_router == "sigmoid_group":
+        p["br"] = jax.random.normal(ks[1], (cfg.n_experts,),
+                                    jnp.float32) * 0.01
     if cfg.n_shared_experts:
         fs = f * cfg.n_shared_experts
         p.update(swg=norm(ks[5], (d, fs), d ** -0.5),
@@ -209,6 +217,19 @@ def route(x, wr, br, *, n_group: int, topk_group: int, top_k: int,
     w = jnp.take_along_axis(scores, ids, axis=-1)
     w = w / jnp.sum(w, axis=-1, keepdims=True) * routed_scale
     return ids.astype(jnp.int32), w, choice
+
+
+def route_softmax(x, wr, *, top_k: int):
+    """Tokens ``x`` (T, d) -> (ids (T, k) int32, weights (T, k) f32,
+    choice scores (T, E) f32): ``softmax(x wr)`` over ALL experts in
+    float32 at full matmul precision, the ``top_k`` largest stay (ties
+    to the lowest expert), their probabilities divided by their sum."""
+    scores = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), wr.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    w, ids = lax.top_k(scores, top_k)
+    return (ids.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True),
+            scores)
 
 
 def row_tile(n_assign: int, n_experts: int) -> int:
@@ -305,17 +326,22 @@ def held_experts_ffn(params: dict, x, ids, w, first: int,
 
 
 def routed_ffn(params: dict, h, cfg):
-    """The 'sigmoid_group' expert layer on ``h`` (..., d): routed terms
-    of the held experts plus the shared expert. Returns (out, info);
-    ``info`` = {"ids" (T, k), "choice" (T, E) f32: the scores the
-    selection was made on, "stats" int32 (5,) in STATS order}."""
+    """The routed expert layer (``cfg.moe_router`` in ROUTED) on ``h``
+    (..., d): routed terms of the held experts plus the shared expert,
+    if any. Returns (out, info); ``info`` = {"ids" (T, k), "choice"
+    (T, E) f32: the scores the selection was made on, "stats" int32
+    (5,) in STATS order}."""
     shape, dt = h.shape, h.dtype
     x = h.reshape(-1, shape[-1])
     with jax.named_scope("moe.route"):
-        ids, w, choice = route(
-            x, params["wr"], params["br"], n_group=cfg.n_group,
-            topk_group=cfg.topk_group, top_k=cfg.experts_per_tok,
-            routed_scale=cfg.routed_scale)
+        if cfg.moe_router == "softmax_topk":
+            ids, w, choice = route_softmax(x, params["wr"],
+                                           top_k=cfg.experts_per_tok)
+        else:
+            ids, w, choice = route(
+                x, params["wr"], params["br"], n_group=cfg.n_group,
+                topk_group=cfg.topk_group, top_k=cfg.experts_per_tok,
+                routed_scale=cfg.routed_scale)
     with jax.named_scope("moe.experts"):
         out, stats = held_experts_ffn(params, x, ids, w,
                                       cfg.expert_first)

@@ -44,6 +44,22 @@ TPU-shaped design decisions:
     (their budget exhausted); outputs are truncated to the request's
     max_new, and slot reuse is safe because every attend masks at the
     row's own position and cache writes overwrite in order.
+  - BLOCK DIFFUSION (``cfg.block_len`` > 0, dense scheduler): a model
+    that generates block by block by masked denoising. A slot's state
+    is its current block (``block_len`` token ids, which of them still
+    hold the mask id) at ``pos``, the block's first position; a round
+    is ``round_len`` PASSES in one jitted program, and a pass is one
+    forward of every row's block (generate.block_decode under the
+    block-causal mask, write-then-attend): a row with a mask left
+    DENOISES (generate.denoise_update unmasks 1 to block_len of its
+    positions; ``pos`` stays, the K/V rows it wrote are provisional), a
+    row with none COMMITS (the same forward on the final tokens, whose
+    K/V rows stand; the block is delivered, ``pos`` moves by block_len,
+    a new all-mask block begins). One program serves rows in either
+    phase side by side; a pass yields 0 to block_len tokens a row.
+    Admission prefills the prompt's whole blocks without the head and
+    takes no first token; the leftover ``plen mod block_len`` prompt
+    tokens open the first block, unmasked.
 
 Oracle (tests/test_serve.py, tests/test_paged.py): any stream of
 requests produces, per request, EXACTLY the tokens of its dense
@@ -64,8 +80,8 @@ import numpy as np
 from jax import lax
 
 from rlo_tpu.models import kvcache, moe
-from rlo_tpu.models.generate import (block_decode, decode_step, prefill,
-                                     _decode_cfg)
+from rlo_tpu.models.generate import (block_decode, decode_step,
+                                     denoise_update, prefill, _decode_cfg)
 from rlo_tpu.models.kvcache import (fold_kv_tail, init_kv_cache,
                                     init_kv_tail)
 from rlo_tpu.models.transformer import TransformerConfig
@@ -91,6 +107,12 @@ class Request:
 
 #: the dense scheduler's prompt buckets, unless the caller names others
 PROMPT_BUCKETS = (64, 256, 1024)
+
+#: what a round of block diffusion counts of itself, in this order
+#: (``serve.diffusion.<name>``; DecodeServer's docstring)
+BLOCK_STATS = ("row_passes", "denoise_passes", "commit_passes",
+               "tokens_unmasked", "blocks_committed", "tokens_committed",
+               "surplus_dropped", "leftover_committed")
 
 
 def prompt_buckets_for(cfg: TransformerConfig, max_len: int,
@@ -183,6 +205,17 @@ class DecodeServer:
     round's steps and layers on the device and read back with the
     tokens: ``tokens``, ``assignments_held``, ``rows_computed`` — tile
     padding included —, ``experts_hit``, ``dropped``, which stays 0),
+    ``serve.diffusion.<count>`` under block diffusion (BLOCK_STATS,
+    counted on the device pass by pass over the rows that still owe
+    tokens, summed over the round and read back with its tokens:
+    ``row_passes`` = ``denoise_passes`` + ``commit_passes``,
+    ``tokens_unmasked``, ``blocks_committed``, ``tokens_committed`` =
+    block_len x ``blocks_committed`` - ``surplus_dropped`` -
+    ``leftover_committed``: a last block's positions past what the
+    request owed, computed and dropped, and the prompt's leftover
+    tokens that opened a first block; ``serve.ttft_usec`` then ends at
+    a request's first committed block, and ``serve.steps`` counts
+    passes),
     and ``serve.retraces`` (with
     ``serve.retraces.<fn>``): trace-cache entries of the server's own
     jitted functions beyond the shapes it was built for.
@@ -226,6 +259,12 @@ class DecodeServer:
         self.pos = np.zeros((n_slots,), np.int32)
         self.last_tok = np.zeros((n_slots,), np.int32)
         self.budget = np.zeros((n_slots,), np.int64)  # tokens still due
+        # block diffusion: a slot's current block at ``pos``, which of
+        # its positions still hold the mask id, and how many of its
+        # leading positions are the prompt's leftover tokens
+        self.blk = np.zeros((n_slots, cfg.block_len), np.int32)
+        self.masked = np.zeros((n_slots, cfg.block_len), bool)
+        self.given = np.zeros((n_slots,), np.int32)
         self.req_of_slot: List[Optional[int]] = [None] * n_slots
         self._queue: List[Tuple[int, Request]] = []
         self._out: List[Optional[List[int]]] = []
@@ -252,6 +291,9 @@ class DecodeServer:
 
         cfg_d = _decode_cfg(cfg)
         if paged:
+            if cfg.block_len:
+                raise ValueError("block diffusion runs on the dense "
+                                 "scheduler so far (paged=False)")
             self._init_paged(cfg_d, page_size, n_pages,
                              prefill_budget, prefix_cache,
                              True if clip_rounds is None
@@ -259,6 +301,10 @@ class DecodeServer:
             return
         self.clip_rounds = bool(clip_rounds)
         self.buckets = prompt_buckets_for(cfg, max_len, prompt_buckets)
+        if cfg.block_len and any(b % cfg.block_len for b in self.buckets):
+            raise ValueError(
+                f"prompt buckets {self.buckets} must be whole blocks of "
+                f"{cfg.block_len}: a long prompt goes on from the widest")
         self.cache = init_kv_cache(cfg, n_slots, max_len)
         self.metrics.gauge("serve.cache_bytes_per_token").set(
             kvcache.bytes_per_position(self.cache))
@@ -267,11 +313,12 @@ class DecodeServer:
         # attends through the einsum (off the tpu backend, or a shape
         # can_flash_decode refuses): nothing is tiled, nothing counted
         self._attend_tiling = (kvcache.attend_tiling(self.cache, cfg)
-                               if _on_tpu() else None)
-        # 'sigmoid_group' expert layers report their routing counts
+                               if _on_tpu() and not cfg.block_len
+                               else None)
+        # routed expert layers report their routing counts
         # (models.moe.STATS): the round then carries their sum over its
-        # steps and layers and returns it as a fifth output
-        moe_stats = cfg.moe_router == "sigmoid_group" and any(
+        # steps and layers and returns it as its last output
+        moe_stats = cfg.moe_router in moe.ROUTED and any(
             "moe" in layer for layer in params["layers"])
         # the round owns its kk steps and nobody reads the cache in
         # between, so the new K/V rows wait in a write-behind tail and
@@ -315,6 +362,8 @@ class DecodeServer:
             return (tok, pos + kk, cache, jnp.transpose(toks),  # (b, kk)
                     *stats)
 
+        if cfg.block_len:   # a round of passes over blocks instead
+            round_fn = self._block_round_fn(cfg_d, moe_stats)
         # donate the pool cache: without aliasing, every round would
         # double-buffer the full n_slots x max_len cache in HBM
         self._round = jax.jit(round_fn, static_argnames=("kk",),
@@ -324,6 +373,13 @@ class DecodeServer:
             # one padded row through the blockwise prefill; returns the
             # row cache + the first generated token
             row = init_kv_cache(cfg, 1, max_len)
+            if cfg.block_len:
+                # no head and no token: the passes generate (the
+                # positions past the prompt's whole blocks hold what
+                # the first pass overwrites)
+                _, row = prefill(params, prompt, row, cfg,
+                                 need_logits=False)
+                return row, jnp.zeros((1,), jnp.int32)
             logits, row = prefill(params, prompt, row, cfg,
                                   last_index=length - 1)
             first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -370,6 +426,8 @@ class DecodeServer:
         def extend_chunk(params, row, toks, pos0, n_valid):
             logits, row = block_decode(params, toks,
                                        pos0[None], row, cfg)
+            if cfg.block_len:   # whole blocks of the prompt; no token
+                return jnp.zeros((1,), jnp.int32), row
             idx = jnp.clip(n_valid - 1, 0,
                            toks.shape[1] - 1)[None, None, None]
             xl = jnp.take_along_axis(
@@ -400,6 +458,72 @@ class DecodeServer:
                       # the 128-token chunk and the long prompts' own
                       "_extend": (self._extend, 2),
                       "_scatter": (self._scatter, 1)}
+
+    # ---- block diffusion ----------------------------------------------
+    def _block_round_fn(self, cfg_d: TransformerConfig, moe_stats: bool):
+        """The round of a model that generates by diffusion over blocks:
+        ``kk`` passes in one program. ``blk`` / ``masked`` (b, B) are
+        the rows' current blocks at ``pos`` (b,), ``owed`` (b,) the
+        tokens a row still owes (0: the slot is free or done, and its
+        state stands still) and ``given`` (b,) how many leading
+        positions of a first block are prompt tokens. A pass runs every
+        row's block through the model; a row with a mask left takes
+        the unmask rule, a row with none commits. Returns (cache, blk,
+        masked, pos, given, tokens (kk, b, B): the block each pass ran,
+        commits (kk, b) bool: the passes that delivered theirs,
+        BLOCK_STATS' counts[, models.moe.STATS' counts])."""
+        cfg = self.cfg
+        B = cfg.block_len
+
+        def round_fn(params, cache, blk, masked, pos, owed, given, kk):
+            def body(carry, _):
+                blk, masked, pos, owed, given, cache, counts, *stats = carry
+                info = []
+                logits, cache = block_decode(params, blk, pos, cache,
+                                             cfg_d, moe_info=info)
+                live = owed > 0
+                commit = live & ~jnp.any(masked, axis=-1)
+                # a commit's row has no mask left: the rule leaves it
+                new_blk, new_masked, unmasked = denoise_update(
+                    logits, blk, masked, cfg.confidence)
+                with jax.named_scope("diff.commit"):
+                    fresh = B - given       # positions that are not prompt
+                    delivered = jnp.minimum(fresh, owed)
+
+                    def at_commit(x):
+                        return jnp.sum(jnp.where(commit, x, 0))
+
+                    counts = counts + jnp.stack([
+                        jnp.sum(live), jnp.sum(live & ~commit),
+                        jnp.sum(commit),
+                        jnp.sum(unmasked & live[:, None]),
+                        jnp.sum(commit), at_commit(delivered),
+                        at_commit(fresh - delivered),
+                        at_commit(given)]).astype(jnp.int32)
+                    ran = blk
+                    denoise = (live & ~commit)[:, None]
+                    blk = jnp.where(commit[:, None], jnp.int32(cfg.mask_id),
+                                    jnp.where(denoise, new_blk, blk))
+                    masked = commit[:, None] | jnp.where(
+                        denoise, new_masked, masked)
+                    pos = pos + jnp.where(commit, B, 0)
+                    owed = owed - jnp.where(commit, delivered, 0)
+                    given = jnp.where(commit, 0, given)
+                stats = [n + sum(i["stats"] for i in info) for n in stats]
+                return ((blk, masked, pos, owed, given, cache, counts,
+                         *stats), (ran, commit))
+
+            stats = ([jnp.zeros((len(moe.STATS),), jnp.int32)]
+                     if moe_stats else [])
+            counts = jnp.zeros((len(BLOCK_STATS),), jnp.int32)
+            (blk, masked, pos, owed, given, cache, counts, *stats), (
+                toks, commits) = lax.scan(
+                    body, (blk, masked, pos, owed, given, cache, counts,
+                           *stats), None, length=kk)
+            return (cache, blk, masked, pos, given, toks, commits, counts,
+                    *stats)
+
+        return round_fn
 
     # ---- paged mode (docs/DESIGN.md §12) -----------------------------
     def _init_paged(self, cfg_d, page_size, n_pages, prefill_budget,
@@ -478,6 +602,13 @@ class DecodeServer:
             raise ValueError(
                 f"prompt {len(prompt)} + max_new {max_new} exceeds "
                 f"max_len {self.max_len}")
+        B = self.cfg.block_len
+        if B and -(-(len(prompt) + max_new) // B) * B > self.max_len:
+            # the last block's surplus positions are attended by the
+            # tokens that are kept: the cache has to hold them
+            raise ValueError(
+                f"prompt {len(prompt)} + max_new {max_new}, rounded up "
+                f"to whole blocks of {B}, exceeds max_len {self.max_len}")
         if self.paged:
             need = -(-(len(prompt) + max_new) // self.page_size)
             if need > self.n_pages - 1:
@@ -568,6 +699,13 @@ class DecodeServer:
             launched = [(bucket, rows, self._launch_group(bucket, rows))
                         for bucket, rows in sorted(groups.items())]
             for bucket, rows, firsts in launched:
+                if self.cfg.block_len:
+                    # no first token to read: the rows are seated while
+                    # the device prefills them, and the round follows
+                    for slot, rid, req, _ in rows:
+                        completed += self._seat_block(slot, rid, req,
+                                                      bucket)
+                    continue
                 with self._span("admit.first_token_sync", rows=len(rows)):
                     # the host blocks here until the group's prefills
                     # and scatters finished on the device
@@ -613,6 +751,9 @@ class DecodeServer:
         (write-then-attend; the final chunk's last-position logits
         seed the first token), the scatter and a sync of its own."""
         plen = len(req.prompt)
+        B = self.cfg.block_len
+        if B:   # the cache takes the prompt's whole blocks (_seat_block)
+            plen -= plen % B
         head = self.buckets[-1]
         with self._span("admit.stage_input", rid):
             prompt = jnp.asarray(req.prompt[None, :head])
@@ -635,6 +776,8 @@ class DecodeServer:
             ran += width
         with self._span("admit.scatter_dispatch", rid):
             self.cache = self._scatter(self.cache, row, jnp.int32(slot))
+        if B:
+            return self._seat_block(slot, rid, req, ran)
         with self._span("admit.first_token_sync", rid):
             # the host blocks here until this request's prefill,
             # chunks and scatter finished on the device
@@ -662,6 +805,31 @@ class DecodeServer:
         self.budget[slot] = req.max_new - 1
         if req.eos_id is not None and first == req.eos_id:
             self.budget[slot] = 0
+        self._retire_if_done(slot)
+        return int(self.req_of_slot[slot] is None)
+
+    def _seat_block(self, slot: int, rid: int, req: Request,
+                    ran: int) -> int:
+        """_seat under block diffusion: the prompt's whole blocks are in
+        the cache (or on their way: nothing was read), its leftover
+        tokens open the slot's first block, unmasked, before the mask
+        ids. No token exists yet: TTFT ends at the first committed
+        block (_distribute_blocks)."""
+        B = self.cfg.block_len
+        plen = len(req.prompt)
+        left = plen % B
+        count = self.metrics.counter
+        count("serve.admissions").inc()
+        count("serve.prefill_tokens").inc(plen - left)
+        count("serve.prefill_padded_tokens").inc(ran)
+        self.req_of_slot[slot] = rid
+        self._out[rid] = []
+        self.pos[slot] = plen - left
+        self.blk[slot] = self.cfg.mask_id
+        self.blk[slot, :left] = req.prompt[plen - left:]
+        self.masked[slot] = np.arange(B) >= left
+        self.given[slot] = left
+        self.budget[slot] = req.max_new
         self._retire_if_done(slot)
         return int(self.req_of_slot[slot] is None)
 
@@ -924,6 +1092,8 @@ class DecodeServer:
         completed = self._admit()
         if all(r is None for r in self.req_of_slot):
             return completed > 0
+        if self.cfg.block_len:
+            return self._step_round_blocks()
         kk = self.round_len
         if self.clip_rounds:
             kk = max(1, min(kk, int(min(
@@ -945,11 +1115,42 @@ class DecodeServer:
             self.last_tok = np.asarray(tok).copy()
             self.pos = np.asarray(pos).copy()
             for counts in stats:    # expert layers' counts, if any
-                for name, n in zip(moe.STATS, np.asarray(counts)):
-                    self.metrics.counter("serve.moe." + name).inc(int(n))
+                self._count_named("serve.moe.", moe.STATS, counts)
         dt = time.perf_counter() - t0  # toks materialized: round done
         self._observe_round(dt, kk)
         self._distribute(toks, kk)
+        return True
+
+    def _step_round_blocks(self):
+        """A round of block diffusion: ``round_len`` passes over every
+        slot's current block in one launch (_block_round_fn), then the
+        committed blocks to their requests."""
+        kk = self.round_len
+        occupied = np.array([r is not None for r in self.req_of_slot])
+        owed = np.where(occupied, np.maximum(self.budget, 0), 0)
+        given = self.given
+        t0 = time.perf_counter()
+        with self._span("round.dispatch"):
+            (self.cache, blk, masked, pos, given_now, toks, commits,
+             counts, *stats) = self._round(
+                self.params, self.cache, jnp.asarray(self.blk),
+                jnp.asarray(self.masked), jnp.asarray(self.pos),
+                jnp.asarray(owed, jnp.int32), jnp.asarray(given), kk)
+        with self._span("round.wait"):
+            # the host blocks here for the device's whole round
+            toks = np.asarray(toks)
+        with self._span("round.readback"):
+            commits = np.asarray(commits)
+            self.blk = np.asarray(blk).copy()
+            self.masked = np.asarray(masked).copy()
+            self.pos = np.asarray(pos).copy()
+            self.given = np.asarray(given_now).copy()
+            self._count_named("serve.diffusion.", BLOCK_STATS, counts)
+            for moe_counts in stats:
+                self._count_named("serve.moe.", moe.STATS, moe_counts)
+        dt = time.perf_counter() - t0
+        self._observe_round(dt, kk)
+        self._distribute_blocks(toks, commits, given)
         return True
 
     def _step_round_paged(self):
@@ -981,6 +1182,11 @@ class DecodeServer:
         self._distribute(toks, kk, only_active=True)
         self._page_gauges()
         return True
+
+    def _count_named(self, prefix: str, names, counts) -> None:
+        """Counts a round summed on the device, into ``prefix<name>``."""
+        for name, n in zip(names, np.asarray(counts)):
+            self.metrics.counter(prefix + name).inc(int(n))
 
     def _count_kv_tail(self, kk: int) -> None:
         """A dense round that ran with the write-behind tail:
@@ -1087,6 +1293,39 @@ class DecodeServer:
             # asked for; the rest decoded past a row's end
             self.metrics.counter("serve.tokens_out").inc(kept)
             self.metrics.counter("serve.slot_steps_useful").inc(kept)
+
+    def _distribute_blocks(self, toks, commits, given) -> None:
+        """The blocks a round committed to their requests: ``toks``
+        (kk, n_slots, B) is the block each pass ran, ``commits``
+        (kk, n_slots) the passes that delivered theirs, ``given`` the
+        leading prompt positions of a slot's first block as the round
+        found it. A request gets exactly what it owes: the positions
+        past that are the last block's surplus. TTFT ends here, at a
+        request's first committed block."""
+        with self._span("distribute"):
+            kept = 0
+            now = time.perf_counter()
+            for slot in range(self.n_slots):
+                rid = self.req_of_slot[slot]
+                passes = np.flatnonzero(commits[:, slot])
+                if rid is None or not passes.size:
+                    continue
+                seq = toks[passes, slot].reshape(-1)[given[slot]:]
+                seq = seq[:int(self.budget[slot])].tolist()
+                eos = self._eos[rid]
+                if eos is not None and eos in seq:
+                    seq = seq[:seq.index(eos) + 1]
+                    self.budget[slot] = 0
+                else:
+                    self.budget[slot] -= len(seq)
+                t_sub = self._accept_ts.get(rid)
+                if seq and not self._out[rid] and t_sub is not None:
+                    self._hist("serve.ttft_usec").observe(
+                        (now - t_sub) * 1e6)
+                self._out[rid].extend(seq)
+                kept += len(seq)
+                self._retire_if_done(slot)
+            self.metrics.counter("serve.tokens_out").inc(kept)
 
     def run(self) -> List[np.ndarray]:
         """Drive rounds until every submitted request completes."""

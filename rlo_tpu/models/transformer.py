@@ -145,7 +145,10 @@ class TransformerConfig:
     # sigmoid scores, a selection-only bias, top experts_per_tok among
     # the topk_group best of n_group groups, normalised weights times
     # routed_scale, n_shared_experts always-on experts, no token
-    # dropped. The first n_dense_layers layers keep the dense FFN. The
+    # dropped; 'softmax_topk' is the same layer behind a softmax over
+    # all experts in float32 whose experts_per_tok largest stay, their
+    # weights divided by their sum (no groups, no bias). The first
+    # n_dense_layers layers keep the dense FFN. The
     # layer holds experts [expert_first, expert_first + n_experts_held)
     # of the n_experts it routes over (0 = all of them) and computes
     # their part of the result.
@@ -168,6 +171,21 @@ class TransformerConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # width of a query/key/value head where the model sets it apart from
+    # d_model // n_heads (0): wq is (d, heads x width), wo its transpose
+    attn_head_dim: int = 0
+    # a gain-only RMS norm over each head's width on q and on k, before
+    # the rotation (``q_norm`` / ``k_norm`` (head_dim,) a layer)
+    qk_norm: bool = False
+    # generation by diffusion over blocks, selected by block_len > 0:
+    # position i attends position j iff j // block_len <= i // block_len
+    # (prefill and block_decode alike), and a server generates block by
+    # block by masked denoising (models.generate.denoise_update): a
+    # block starts as ``mask_id``s and a pass unmasks every masked
+    # position whose confidence passes ``confidence``, or the best one
+    block_len: int = 0
+    mask_id: int = 0
+    confidence: float = 0.9
 
     @property
     def mla(self) -> bool:
@@ -181,6 +199,8 @@ class TransformerConfig:
     def head_dim(self) -> int:
         if self.mla:  # width of a query/key head
             return self.qk_nope_head_dim + self.qk_rope_head_dim
+        if self.attn_head_dim:
+            return self.attn_head_dim
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -256,7 +276,8 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
         params["head"] = norm(keys[1], (cfg.vocab, d), d ** -0.5)
     k = 2
     for i in range(cfg.n_layers):
-        att_out = cfg.n_heads * cfg.v_head_dim if cfg.mla else d
+        att_out = cfg.n_heads * (cfg.v_head_dim if cfg.mla
+                                 else cfg.head_dim)
         layer = {
             "ln1": {"g": ones(d)},
             "wo": norm(keys[k + 1], (att_out, d),
@@ -286,13 +307,16 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
                                     "b": jnp.zeros((di,), pdt)}
                 layer["wiw"] = norm(k8, (d, hi), d ** -0.5)
         elif cfg.kv_heads == cfg.n_heads:
-            layer["wqkv"] = norm(keys[k], (d, 3, d), d ** -0.5)
+            layer["wqkv"] = norm(keys[k], (d, 3, att_out), d ** -0.5)
         else:  # GQA: smaller K/V projections, separate q
             dkv = cfg.kv_heads * cfg.head_dim
             kq, kkv = jax.random.split(keys[k])
-            layer["wq"] = norm(kq, (d, d), d ** -0.5)
+            layer["wq"] = norm(kq, (d, att_out), d ** -0.5)
             layer["wkv"] = norm(kkv, (d, 2, dkv), d ** -0.5)
-        if cfg.layer_is_moe(i) and cfg.moe_router == "sigmoid_group":
+        if cfg.qk_norm and not cfg.mla:
+            layer["q_norm"] = {"g": ones(cfg.head_dim)}
+            layer["k_norm"] = {"g": ones(cfg.head_dim)}
+        if cfg.layer_is_moe(i) and cfg.moe_router in moe.ROUTED:
             layer["moe"] = moe.init_routed_params(keys[k + 2], cfg)
         elif cfg.layer_is_moe(i):
             layer["moe"] = moe.init_moe_params(keys[k + 2], d, f,
@@ -332,7 +356,7 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: Optional[str] = None,
     params argument."""
     from jax.sharding import PartitionSpec as P
     if cfg.mla or cfg.moe_router != "switch" or cfg.ffn != "gelu" \
-            or not cfg.tie_embeddings:
+            or not cfg.tie_embeddings or cfg.qk_norm:
         raise ValueError(
             "param_pspecs knows the MHA/GQA block with a gelu FFN and "
             "switch experts; latent attention, gated FFNs, an untied "
@@ -510,10 +534,14 @@ def _rope(t, pos, scaling: Optional[str] = None, scale: float = 1.0,
 
 
 def _local_attention(q, k, v, use_flash=None, interpret=None,
-                     scale: Optional[float] = None):
+                     scale: Optional[float] = None, block_len: int = 0):
     """Unsharded causal attention: q (b, L, H, D); k/v (b, L, Hkv, D)
     with Hkv ≤ H (grouped-query attention — query head h attends K/V
-    head h // (H/Hkv)). ``scale`` defaults to D^-0.5. ``v`` may be
+    head h // (H/Hkv)). ``scale`` defaults to D^-0.5. ``block_len`` > 0
+    makes the mask BLOCK-causal: query i attends every position of its
+    own block of ``block_len`` too (j // block_len <= i // block_len) —
+    to the kernel and to the oracle alike, a query that attends until
+    the last position of its block instead of until its own. ``v`` may be
     narrower than q and k (latent attention: 128 beside 192): the
     kernel has one width, so v rides zero-padded to D and the output is
     cut back — half again the PV products and V bytes it needs (PERF.md
@@ -541,6 +569,11 @@ def _local_attention(q, k, v, use_flash=None, interpret=None,
     # per-program overhead (the round-4 MFU-cliff mechanism; measured
     # bq 1024 = 1.14x bq 256 at 128 folded heads)
     bq = auto_block_q(g * L, L, hd)
+    # the last position each query attends: its own, or its block's
+    until = None
+    if block_len:
+        until = (jnp.arange(L, dtype=jnp.int32) // block_len * block_len
+                 + (block_len - 1))
     if use_flash is None:
         ok = can_flash(L, L, hd, block_q=bq, groups=g)
         use_flash = ok if interpret else kernel_gate(
@@ -550,7 +583,7 @@ def _local_attention(q, k, v, use_flash=None, interpret=None,
             k = jnp.repeat(k, g, axis=2)
             v = jnp.repeat(v, g, axis=2)
         return jax.vmap(lambda q_, k_, v_: full_attention(
-            q_, k_, v_, causal=True, scale=scale))(q, k, v)
+            q_, k_, v_, causal=True, scale=scale, q_until=until))(q, k, v)
     from rlo_tpu.pallas.flash import flash_attention
     vd = v.shape[-1]
     if vd != hd:
@@ -561,7 +594,8 @@ def _local_attention(q, k, v, use_flash=None, interpret=None,
         return t.transpose(1, 0, 2, 3).reshape(L, b * n, hd)
 
     out = flash_attention(fold(q), fold(k), fold(v), causal=True,
-                          scale=scale, block_q=bq, interpret=interpret)
+                          scale=scale, block_q=bq, interpret=interpret,
+                          q_until=until)
     return out.reshape(L, b, nh, hd).transpose(1, 0, 2, 3)[..., :vd]
 
 
@@ -671,7 +705,7 @@ def _ffn(h, layer: dict, cfg: TransformerConfig, tp_sum, ep_axis,
     expert layers share one model). Returns (out, aux)."""
     dt = h.dtype
     zero = jnp.zeros((), jnp.float32)
-    if "moe" in layer and cfg.moe_router == "sigmoid_group":
+    if "moe" in layer and cfg.moe_router in moe.ROUTED:
         out, info = moe.routed_ffn(layer["moe"], h, cfg)
         if moe_info is not None:
             moe_info.append(info)
@@ -713,9 +747,10 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
     q_rope, latent, index)`` (mla_project's outputs; ``index`` None
     without a token selector) and returns (b, blk, heads, v_head_dim);
     None attends the block in the plain form (mla_unabsorbed), which
-    selects nothing: a block longer than index_topk is refused. ``moe_info``: a list that each
-    'sigmoid_group' expert layer appends its routing record to
-    (models.moe.routed_ffn), for callers that count or check it."""
+    selects nothing: a block longer than index_topk is refused.
+    ``moe_info``: a list that each routed expert layer (models.moe
+    .ROUTED) appends its routing record to (models.moe.routed_ffn), for
+    callers that count or check it."""
     b, blk, _ = x.shape
     dt = x.dtype
     ntp = lax.axis_size(tp_axis) if tp_axis is not None else 1
@@ -769,6 +804,10 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
 
     q = heads(q, nh_local)
     k, v = heads(k, nkv_local), heads(v, nkv_local)
+    if cfg.qk_norm:
+        with jax.named_scope("attn.qk_norm"):
+            q = _rmsnorm(q, layer["q_norm"]["g"], cfg.norm_eps)
+            k = _rmsnorm(k, layer["k_norm"]["g"], cfg.norm_eps)
     if cfg.pos_encoding == "rope":
         assert pos is not None, "rope needs per-layer positions"
         q = _rope_cfg(q, pos, cfg)
@@ -784,7 +823,10 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
     if attention is not None:
         att = attention(q, k, v)
     elif sp_axis is None:
-        att = _local_attention(q, k, v)
+        att = _local_attention(q, k, v, block_len=cfg.block_len)
+    elif cfg.block_len:
+        raise ValueError("the block-causal mask runs unsharded so far "
+                         "(no sp_axis)")
     elif cfg.sp_attention == "ulysses":
         from rlo_tpu.ops.ulysses import ulysses_attention
         att = jax.vmap(lambda q_, k_, v_: ulysses_attention(

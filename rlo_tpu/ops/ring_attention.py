@@ -233,8 +233,10 @@ def ring_attention(q, k, v, axis: str, *, causal: bool = False,
 
 
 def full_attention(q, k, v, *, causal: bool = False,
-                   scale: Optional[float] = None):
-    """Unsharded reference implementation (the test oracle)."""
+                   scale: Optional[float] = None, q_until=None):
+    """Unsharded reference implementation (the test oracle).
+    ``q_until`` (Lq,): with ``causal``, the last key position each
+    query attends, in place of its own (a block-causal mask)."""
     qn, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -243,7 +245,8 @@ def full_attention(q, k, v, *, causal: bool = False,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         kn = k.shape[0]
-        mask = jnp.arange(kn)[None, :] <= jnp.arange(qn)[:, None]
+        until = jnp.arange(qn) if q_until is None else q_until
+        mask = jnp.arange(kn)[None, :] <= until[:, None]
         s = jnp.where(mask[None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("hqk,khd->qhd", p, v.astype(jnp.float32))

@@ -129,7 +129,7 @@ _SUBLANES = 16
 def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
                    bk: int, max_len: int, quant: bool,
                    r: int, T: int, v_dim: int = 0, n_tail: int = 0,
-                   selected: bool = False):
+                   selected: bool = False, block_len: int = 0):
     if n_tail:          # a fourth prefetched scalar: the tail's newest row
         newest_ref, refs = refs[0], refs[1:]
     q_ref, k_ref, *rest = refs
@@ -213,8 +213,11 @@ def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
     # sub-32-bit (bool) values, so never reshape a 1-D mask
     base = ik * bk
     row = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
-    # per-query causal position: query row t*r+rr masks at pos + t
+    # per-query causal position: query row t*r+rr masks at pos + t —
+    # or, block-causal, at the last position of block token t's block
     qoff = jax.lax.broadcasted_iota(jnp.int32, (1, T * r, 1), 1) // r
+    if block_len:
+        qoff = qoff // block_len * block_len + (block_len - 1)
     mask_row = (row <= pos + qoff) & (row < max_len)  # (1, T*r, BK)
     if selected:        # a token selector's choice, every head the same
         mask_row = mask_row & (sel_ref[0] > 0.0)     # (1, 1, BK)
@@ -916,7 +919,8 @@ def flash_decode(q, k_cache, v_cache, pos, scale, k_scale=None,
 def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
                        v_scale=None, *, block_k: Optional[int] = None,
                        interpret: Optional[bool] = None, v_dim: int = 0,
-                       tail=None, work=None, select=None):
+                       tail=None, work=None, select=None,
+                       block_len: int = 0):
     """Fused T-query block decode attention (the speculative-decoding
     verify shape): ``q`` is (b, T, n_heads, head_dim) where row b's
     query t sits at sequence position ``pos0[b] + t`` and attends
@@ -955,10 +959,23 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     every head. It streams with the cache as one f32 lane a position;
     the tiles are read whole, selected or not: the MASKED form of a
     sparse attend. With every live position selected the result is,
-    to the bit, the call's without it."""
+    to the bit, the call's without it.
+
+    ``block_len`` > 0 (a divisor of T; ``pos0`` a multiple of it): the
+    BLOCK-causal mask of generation by diffusion over blocks — query t
+    attends every position of its own block too, positions <=
+    pos0 + t // block_len * block_len + block_len - 1, all of which
+    write-then-attend has put in the cache. With T = block_len every
+    query of the call attends positions < pos0 + T. The tiles and the
+    work list are the causal call's: the last position any query
+    attends is pos0 + T - 1 either way."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, T, nh, d = q.shape
+    if block_len and T % block_len:
+        raise ValueError(
+            f"flash_block_decode: a block-causal call takes whole "
+            f"blocks, got T={T} at block_len={block_len}")
     nkv, L = k_cache.shape[1], k_cache.shape[3]
     r = nh // nkv
     R = T * r
@@ -1084,7 +1101,8 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
         functools.partial(_decode_kernel, scale=float(scale),
                           bk=bk, max_len=L, quant=quant, r=r, T=T,
                           v_dim=v_dim, n_tail=n_tail,
-                          selected=select is not None),
+                          selected=select is not None,
+                          block_len=block_len),
         grid_spec=grid_spec,
         out_shape=out_struct((b, nkv, R, dv), jnp.float32, q, k_cache),
         interpret=interpret,

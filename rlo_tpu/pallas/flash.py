@@ -604,7 +604,7 @@ def flash_block_update_hld(q, k, v, m, l, o, q_pos, k_pos, *,
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None, block_q: int = 256,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None, q_until=None):
     """Whole attention as ONE fused block update from the initial
     (m, l, o) state — the communication-free quadratic part of Ulysses
     sequence parallelism (each shard holds full sequences of its local
@@ -613,7 +613,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
     attends K/V head h // (H/Hkv); the compact K/V is what streams
     from HBM); positions are the global 0..L ranges. The K/V axis is
     tiled by ``block_k``, so arbitrarily long sequences stream through
-    VMEM (per-step working set ~ block_q x block_k)."""
+    VMEM (per-step working set ~ block_q x block_k). ``q_until`` (Lq,)
+    int32: with ``causal``, the last key position each query attends,
+    in place of its own — the kernel's mask is ``k_pos <= q_pos`` on
+    the positions it is handed (a block-causal mask hands every query
+    its block's last position)."""
     from rlo_tpu.parallel.mesh import vary_like
 
     lq, h, d = q.shape
@@ -623,7 +627,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     m0 = vary_like(jnp.full((h, 1, lq), _NEG, jnp.float32), q)
     l0 = vary_like(jnp.zeros((h, 1, lq), jnp.float32), q)
     o0 = vary_like(jnp.zeros((h, lq, d), jnp.float32), q)
-    qp = vary_like(jnp.arange(lq, dtype=jnp.int32).reshape(1, lq), q)
+    qp = jnp.arange(lq, dtype=jnp.int32) if q_until is None else q_until
+    qp = vary_like(qp.astype(jnp.int32).reshape(1, lq), q)
     kp = vary_like(jnp.arange(lk, dtype=jnp.int32).reshape(1, lk), q)
     # pallas_fast: the l-normalization below makes the dropped max-
     # routing term exactly zero analytically (see _pallas_bwd)

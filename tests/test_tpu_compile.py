@@ -5,7 +5,10 @@ grouped expert FFN over 16 held experts at 7168 x 2048; both cells'
 round: the attend with a 32-row write-behind tail and the tail's fold;
 every attend on its work-list grid, a 1-D grid with a dynamic bound;
 gpt2-medium's admission program, a device loop around the one-row
-prefill with the pool cache in its carry),
+prefill with the pool cache in its carry;
+sdar-30b-a3b-6l: a pass's block write and block-causal attend of 4
+queries at 192 rows x 32 / 4 heads x 128 x 1536, and the grouped expert
+FFN over 128 held experts at 2048 x 768),
 without a chip: Mosaic's refusals (block shapes, scoped VMEM, scalar-prefetch index
 maps) show up here, numerics and times do not. The topology is described
 inside a fixture, never at import (only one process may load libtpu, and
@@ -17,8 +20,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from rlo_tpu.pallas.decode import (decode_work_list, flash_block_decode,
-                                   flash_decode_tile, write_kv_row,
-                                   write_kv_tail)
+                                   flash_decode_tile, write_kv_block,
+                                   write_kv_row, write_kv_tail)
 from rlo_tpu.pallas.expert_ffn import buffer_rows, expert_ffn
 
 B, NH, D, L = 96, 16, 64, 1024
@@ -166,6 +169,74 @@ def test_expert_ffn_compiles_for_v5e(one_chip, tokens, tile):
         shape((HELD, D_MODEL, D_EXPERT), jnp.bfloat16),
         shape((HELD, D_MODEL, D_EXPERT), jnp.bfloat16),
         shape((HELD, D_EXPERT, D_MODEL), jnp.bfloat16),
+        shape((n_rows // tile,), jnp.int32),
+        shape((), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "expert_ffn" in text
+
+
+# sdar-30b-a3b-6l x blockgen-sat: 192 slots of 1536 positions, blocks of
+# 4, 32 query heads on 4 K/V heads of 128; all 128 experts of 2048 x 768
+SDAR_SLOTS, SDAR_LEN, SDAR_BLOCK = 192, 1536, 4
+SDAR_HEADS, SDAR_KV, SDAR_HD = 32, 4, 128
+SDAR_D, SDAR_F, SDAR_EXPERTS = 2048, 768, 128
+
+
+def test_block_pass_write_and_attend_compile_for_v5e(one_chip):
+    """A pass of generation by diffusion over blocks, a layer's worth:
+    the block's 4 provisional K/V columns written into the cache, then
+    its 4 x 32 queries against everything before the block and the block
+    itself (the block-causal mask), on a work list built once."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    bf16 = jnp.bfloat16
+    cache = shape((SDAR_SLOTS, SDAR_KV, SDAR_HD, SDAR_LEN), bf16)
+    rows = shape((SDAR_SLOTS, SDAR_KV, SDAR_HD, SDAR_BLOCK), bf16)
+    bk = flash_decode_tile(cache, SDAR_HEADS)
+
+    def step(q, k, v, k_new, v_new, pos0):
+        k = write_kv_block(k, k_new, pos0, interpret=False)
+        v = write_kv_block(v, v_new, pos0, interpret=False)
+        work = decode_work_list(pos0, SDAR_BLOCK, bk, SDAR_LEN // bk)
+        return flash_block_decode(
+            q, k, v, pos0, SDAR_HD ** -0.5, interpret=False, work=work,
+            block_len=SDAR_BLOCK), k, v
+
+    from rlo_tpu.utils import hlo
+    lowered = jax.jit(step, donate_argnums=(1, 2)).lower(
+        shape((SDAR_SLOTS, SDAR_BLOCK, SDAR_HEADS, SDAR_HD), bf16),
+        cache, cache, rows, rows, shape((SDAR_SLOTS,), jnp.int32))
+    # the benchmark's own reading of the lowered text
+    # (perf/kinds/serve_diffusion.py)
+    assert hlo.mosaic_kernels(lowered.as_text()) == {
+        "flash_block_decode": 1, "write_kv_block": 2}
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("tokens", [768, 64, 256, 1024])
+def test_small_expert_ffn_compiles_for_v5e(one_chip, tokens):
+    """All 128 experts of 2048 x 768 held: a pass of 192 rows x 4
+    positions (48 rows an expert, tiles of 128) and the prefill buckets,
+    every (token, choice) pair here in the worst case, at the tile
+    models.moe.row_tile picks."""
+    from rlo_tpu.models.moe import row_tile
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    tile = row_tile(tokens * 8, SDAR_EXPERTS)
+    n_rows = buffer_rows(tokens * 8, SDAR_EXPERTS, tile)
+
+    def ffn(x, wg, wu, wd, tile_expert, n_live):
+        return expert_ffn(x, wg, wu, wd, tile_expert, n_live, tile=tile,
+                          interpret=False)
+
+    bf16 = jnp.bfloat16
+    text = jax.jit(ffn).lower(
+        shape((n_rows, SDAR_D), bf16),
+        shape((SDAR_EXPERTS, SDAR_D, SDAR_F), bf16),
+        shape((SDAR_EXPERTS, SDAR_D, SDAR_F), bf16),
+        shape((SDAR_EXPERTS, SDAR_F, SDAR_D), bf16),
         shape((n_rows // tile,), jnp.int32),
         shape((), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text and "expert_ffn" in text
