@@ -86,6 +86,7 @@ from rlo_tpu.models.kvcache import (fold_kv_tail, init_kv_cache,
                                     init_kv_tail)
 from rlo_tpu.models.transformer import TransformerConfig
 from rlo_tpu.observe.spans import Stage
+from rlo_tpu.pallas import expert_ffn
 from rlo_tpu.pallas.reduce import _on_tpu
 from rlo_tpu.utils.metrics import Registry, SERVING
 from rlo_tpu.utils.tracing import annotate
@@ -204,7 +205,10 @@ class DecodeServer:
     (models.moe.STATS, summed over the
     round's steps and layers on the device and read back with the
     tokens: ``tokens``, ``assignments_held``, ``rows_computed`` — tile
-    padding included —, ``experts_hit``, ``dropped``, which stays 0),
+    padding included —, ``experts_hit``, ``dropped``, which stays 0)
+    and the gauge ``serve.moe.ffn_steps_per_tile`` (grid steps a live
+    tile of pallas.expert_ffn takes at the routed layers' shape: what
+    its byte rule chose),
     ``serve.diffusion.<count>`` under block diffusion (BLOCK_STATS,
     counted on the device pass by pass over the rows that still owe
     tokens, summed over the round and read back with its tokens:
@@ -318,8 +322,14 @@ class DecodeServer:
         # routed expert layers report their routing counts
         # (models.moe.STATS): the round then carries their sum over its
         # steps and layers and returns it as its last output
-        moe_stats = cfg.moe_router in moe.ROUTED and any(
-            "moe" in layer for layer in params["layers"])
+        routed = [layer["moe"] for layer in params["layers"]
+                  if "moe" in layer]
+        moe_stats = cfg.moe_router in moe.ROUTED and bool(routed)
+        if moe_stats:
+            # what pallas.expert_ffn's byte rule chose for their shape
+            _, d, f = routed[0]["wg"].shape
+            self.metrics.gauge("serve.moe.ffn_steps_per_tile").set(
+                expert_ffn.steps_per_tile(d, f, cfg.act_dtype.itemsize))
         # the round owns its kk steps and nobody reads the cache in
         # between, so the new K/V rows wait in a write-behind tail and
         # reach the seq-minor cache once a round (init_kv_tail). An

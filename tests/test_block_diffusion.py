@@ -324,6 +324,7 @@ def test_ragged_outputs_late_admission_and_a_reused_slot(params):
     assert hist["serve.ttft_usec"]["count"] == 7
     assert srv.stats()["gauges"]["serve.cache_bytes_per_token"] == (
         CFG.n_layers * 2 * CFG.kv_heads * CFG.head_dim * 4)
+    assert srv.stats()["gauges"]["serve.moe.ffn_steps_per_tile"] == 1
     assert srv.slot_ownership() == (None, None)
 
 

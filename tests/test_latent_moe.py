@@ -23,8 +23,9 @@ from rlo_tpu.models.transformer import (TransformerConfig, _rope_cfg,
                                         _yarn_freqs, forward, init_params)
 from rlo_tpu.pallas.decode import (can_flash_decode, flash_block_decode,
                                    flash_decode, flash_decode_tile)
+from rlo_tpu.pallas import expert_ffn as ek
 from rlo_tpu.pallas.expert_ffn import (buffer_rows, can_expert_ffn,
-                                       expert_ffn)
+                                       expert_ffn, steps_per_tile)
 from rlo_tpu.utils.metrics import Registry
 
 PERF = Path(__file__).resolve().parent.parent / "perf"
@@ -263,19 +264,16 @@ def test_shares_add_up_to_the_uncut_layer(params):
 
 # ---- kernels, interpreted ----------------------------------------------
 
-@pytest.mark.parametrize("n_live,skew", [(3, False), (8, True), (0, False)])
-def test_expert_ffn_kernel_against_einsum(n_live, skew):
-    d, f, held, tile = 256, 384, 4, 16
-    ks = jax.random.split(jax.random.PRNGKey(n_live), 4)
-    n_rows = buffer_rows(64, held, tile)
+def _ffn_against_einsum(te, n_live, seed=0, d=256, f=384, held=4, tile=16):
+    """expert_ffn, interpreted, on the first ``n_live`` of the tiles
+    whose experts ``te`` names, against the einsum over each row's own
+    expert."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    n_rows = len(te) * tile
     x = jax.random.normal(ks[0], (n_rows, d))
     wg = jax.random.normal(ks[1], (held, d, f)) * d ** -0.5
     wu = jax.random.normal(ks[2], (held, d, f)) * d ** -0.5
     wd = jax.random.normal(ks[3], (held, f, d)) * f ** -0.5
-    n_tiles = n_rows // tile
-    # skew: one expert owns every live tile but the last
-    te = np.where(np.arange(n_tiles) < 7, 1, 3) if skew else \
-        np.minimum(np.arange(n_tiles), held - 1)
     out = expert_ffn(x, wg, wu, wd, jnp.asarray(te, jnp.int32), n_live,
                      tile=tile, interpret=True)
     assert out.shape == x.shape
@@ -286,6 +284,62 @@ def test_expert_ffn_kernel_against_einsum(n_live, skew):
             "rd,rdf->rf", xs, wu[e]), wd[e])
     if n_live:
         _close(out[:n_live * tile], want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("n_live,skew", [(3, False), (8, True), (0, False)])
+def test_expert_ffn_kernel_against_einsum(n_live, skew):
+    held, tile = 4, 16
+    n_tiles = buffer_rows(64, held, tile) // tile
+    # skew: one expert owns every live tile but the last
+    te = np.where(np.arange(n_tiles) < 7, 1, 3) if skew else \
+        np.minimum(np.arange(n_tiles), held - 1)
+    _ffn_against_einsum(te, n_live, seed=n_live)
+
+
+@pytest.mark.parametrize("d,f,itemsize,chunks,steps", [
+    (2048, 768, 2, (2048, 768), 1),     # sdar-30b-a3b: whole, one step
+    (7168, 2048, 2, (1024, 256), 15),   # deepseek-v3: 4 and 3.5 MiB blocks
+    (7168, 2048, 4, (512, 128), 30),
+    (256, 384, 4, (256, 384), 1),       # this file's toy shapes
+    (128, 128, 4, (128, 128), 1)])
+def test_expert_ffn_chunks_follow_the_byte_budget(d, f, itemsize, chunks,
+                                                  steps):
+    """The blocks a grid step presents: the widest 128-multiple
+    divisors whose weight blocks fit ``_STEP_BYTES``, whole where the
+    matrices fit (one step a tile then), never 0 where the gate says
+    yes."""
+    assert can_expert_ffn(d, f, 16)
+    dk, fb = ek._chunks(d, f, itemsize)
+    assert (dk, fb) == chunks
+    assert steps_per_tile(d, f, itemsize) == steps
+    for c, n, row in ((dk, d, f), (fb, f, d)):
+        assert c and c % 128 == 0 and n % c == 0
+        assert c * row * itemsize <= ek._STEP_BYTES
+        wider = [w for w in range(c + 128, n + 1, 128) if n % w == 0]
+        assert all(w * row * itemsize > ek._STEP_BYTES for w in wider)
+
+
+# tiles of 16 rows over 4 held experts: expert 0 owns three tiles,
+# expert 1 none, 2 and 3 one each; the last two tiles are dead
+_SKEWED = np.array([0, 0, 0, 2, 3, 3, 3])
+
+
+@pytest.mark.parametrize("d,f,budget,chunks", [
+    (256, 384, None, (256, 384)),           # whole: the one-step body
+    (256, 384, 128 * 384 * 4, (128, 128)),  # 2 + 3 steps a tile
+    (512, 256, 256 * 256 * 4, (256, 128))])  # 2 + 2
+@pytest.mark.parametrize("te,n_live", [(_SKEWED, 5), (np.arange(4), 4)],
+                         ids=["skewed", "one_tile_each"])
+def test_expert_ffn_kernel_at_each_chunking(monkeypatch, d, f, budget,
+                                            chunks, te, n_live):
+    """Both bodies, and the index maps that keep a fetch in flight, give
+    the einsum's result whatever the budget chose."""
+    if budget:
+        monkeypatch.setattr(ek, "_STEP_BYTES", budget)
+    assert ek._chunks(d, f, 4) == chunks
+    assert steps_per_tile(d, f, 4) == (
+        1 if budget is None else d // chunks[0] + f // chunks[1])
+    _ffn_against_einsum(te, n_live, seed=7, d=d, f=f)
 
 
 def test_expert_ffn_refuses_shapes_outside_its_gate():
@@ -380,6 +434,9 @@ def test_server_counts_expert_work_and_matches_generate(params):
         c["serve.steps"] * n_moe * CFG.experts_held)
     width = CFG.kv_lora_rank + CFG.qk_rope_head_dim
     assert g["serve.cache_bytes_per_token"] == width * 4 * CFG.n_layers
+    # the expert kernel's byte rule at the routed layers' shape: whole
+    assert g["serve.moe.ffn_steps_per_tile"] == steps_per_tile(
+        CFG.d_model, CFG.moe_d_ff, 4) == 1
     # every round kept its latent rows in the write-behind tail: one
     # tensor a layer, two 128-lane blocks a slot in the fold
     assert c["serve.kv_tail.rounds"] == c["serve.rounds"]

@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 from rlo_tpu.pallas.decode import (decode_work_list, flash_block_decode,
                                    flash_decode_tile, write_kv_block,
                                    write_kv_row, write_kv_tail)
-from rlo_tpu.pallas.expert_ffn import buffer_rows, expert_ffn
+from rlo_tpu.pallas.expert_ffn import (buffer_rows, expert_ffn,
+                                       steps_per_tile)
 
 B, NH, D, L = 96, 16, 64, 1024
 L_GPT2 = L
@@ -154,7 +155,11 @@ def test_round_kernels_with_a_tail_compile_for_v5e(one_chip, cell):
 @pytest.mark.parametrize("tokens,tile", [(128, 16), (256, 16), (1024, 64)])
 def test_expert_ffn_compiles_for_v5e(one_chip, tokens, tile):
     """A decode step of 128 rows, and prefill buckets of 256 and 1024:
-    every (token, choice) pair here in the worst case."""
+    every (token, choice) pair here in the worst case. The byte rule's
+    choice at 7168 x 2048 in bf16 is 7 + 8 steps a tile, blocks of 4 and
+    3.5 MiB in two buffers: a wider one has to fit scoped VMEM HERE."""
+    assert steps_per_tile(D_MODEL, D_EXPERT, 2) == 15
+
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
@@ -218,8 +223,10 @@ def test_small_expert_ffn_compiles_for_v5e(one_chip, tokens):
     """All 128 experts of 2048 x 768 held: a pass of 192 rows x 4
     positions (48 rows an expert, tiles of 128) and the prefill buckets,
     every (token, choice) pair here in the worst case, at the tile
-    models.moe.row_tile picks."""
+    models.moe.row_tile picks. An expert's three 3 MiB matrices are one
+    block each: ONE step a tile."""
     from rlo_tpu.models.moe import row_tile
+    assert steps_per_tile(SDAR_D, SDAR_F, 2) == 1
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
