@@ -129,41 +129,8 @@ TINY = SmokeConfig(
 
 
 # ---------------------------------------------------------------------------
-# scaffolding: compile accounting, kernel accounting, comparisons
+# scaffolding: kernel accounting, comparisons
 # ---------------------------------------------------------------------------
-
-class CompileMeter:
-    """Seconds JAX spent tracing, lowering and obtaining executables
-    (compiling, or reading the persistent cache), and the persistent
-    cache's hits and misses — from JAX's own monitoring events, so a
-    phase's compile share needs no second run to measure."""
-
-    _DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax
-        self.secs = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, secs: float, **_kw) -> None:
-        if event in self._DURATIONS:
-            self.secs += secs
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self) -> Tuple[float, int, int]:
-        return self.secs, self.hits, self.misses
-
 
 def check_kernels(cfg: SmokeConfig, what: str, jitted, args: Sequence,
                   expect: Dict[str, int], static: Optional[dict] = None
@@ -817,19 +784,28 @@ def run(cfg: SmokeConfig) -> dict:
     import jax
 
     from rlo_tpu.pallas.reduce import KernelFallbackWarning
+    from rlo_tpu.utils.tracing import BUILDS, build_totals
     warnings.simplefilter("error", KernelFallbackWarning)
-    meter = CompileMeter()
+    BUILDS.arm()
+
+    def built() -> Tuple[float, int, int]:
+        # seconds tracing, lowering and obtaining executables, each
+        # once, and the persistent cache's hits and misses so far
+        t = build_totals(BUILDS.records)
+        return (1e-9 * sum(n for key, n in t.items() if key.endswith("_ns")),
+                t["cache_hits"], t["cache_misses"])
+
     n_dev = len(jax.devices())
     phases = list(ONE_CHIP)
     if n_dev >= WS:
         phases += FOUR_CHIPS
     results: dict = {}
     for name, fn in phases:
-        c0, h0, m0 = meter.snapshot()
+        c0, h0, m0 = built()
         t0 = time.perf_counter()
         facts = fn(cfg)
         wall = time.perf_counter() - t0
-        c1, h1, m1 = meter.snapshot()
+        c1, h1, m1 = built()
         results[name] = facts
         print(f"phase {name}: ok wall={wall:.1f}s compile={c1 - c0:.1f}s "
               f"cache_hits={h1 - h0} cache_misses={m1 - m0} "

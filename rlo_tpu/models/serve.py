@@ -89,7 +89,11 @@ from rlo_tpu.observe.spans import Stage
 from rlo_tpu.pallas import expert_ffn
 from rlo_tpu.pallas.reduce import _on_tpu
 from rlo_tpu.utils.metrics import Registry, SERVING
-from rlo_tpu.utils.tracing import annotate
+from rlo_tpu.utils.tracing import BUILDS, annotate, build_table
+
+# the programs of a start that precede its server (a caller's weights)
+# are in the build log too, under no span
+BUILDS.arm()
 
 #: newest samples kept beside the log2 buckets of the four latency
 #: histograms, so stats() percentiles are exact (8 bytes a sample)
@@ -175,7 +179,9 @@ class DecodeServer:
     (``utils.tracing.annotate``, always on): a profiler annotation
     ``perf.serve.<stage>`` on the profiler's clock, and its elapsed
     time in the counters ``serve.<stage>_ns`` / ``_n``. Stages:
-    ``step_round``; ``admit`` with ``admit.stage_input``,
+    ``init`` (the constructor: cache allocation, the jitted functions,
+    whatever runs eagerly); ``step_round``; ``admit`` with
+    ``admit.stage_input``,
     ``admit.prefill_dispatch`` and ``admit.first_token_sync`` — dense
     scheduler: once a LAUNCH, which admits a pass's requests of one
     prompt bucket, so a group's requests share one sync and TTFT ends
@@ -220,9 +226,14 @@ class DecodeServer:
     tokens that opened a first block; ``serve.ttft_usec`` then ends at
     a request's first committed block, and ``serve.steps`` counts
     passes),
-    and ``serve.retraces`` (with
+    ``serve.retraces`` (with
     ``serve.retraces.<fn>``): trace-cache entries of the server's own
-    jitted functions beyond the shapes it was built for.
+    jitted functions beyond the shapes it was built for,
+    and ``serve.build.<count>`` (utils.tracing.BUILD_COUNTS): the
+    programs JAX obtained while a stage of this server was open and the
+    nanoseconds it spent tracing, lowering and compiling them, each
+    once, from the process's build log; in a steady round they stand
+    still. ``stats()["build"]`` is the same log by function.
 
     PAGED mode adds the page-pool telemetry (docs/DESIGN.md §12):
     ``serve.pages_in_use`` / ``serve.pages_free`` gauges, prefix-cache
@@ -294,16 +305,26 @@ class DecodeServer:
         self._retraced: Dict[str, int] = {}
 
         cfg_d = _decode_cfg(cfg)
-        if paged:
-            if cfg.block_len:
-                raise ValueError("block diffusion runs on the dense "
-                                 "scheduler so far (paged=False)")
-            self._init_paged(cfg_d, page_size, n_pages,
-                             prefill_budget, prefix_cache,
-                             True if clip_rounds is None
-                             else clip_rounds)
-            return
-        self.clip_rounds = bool(clip_rounds)
+        if paged and cfg.block_len:
+            raise ValueError("block diffusion runs on the dense "
+                             "scheduler so far (paged=False)")
+        # what the server allocates, builds and runs eagerly before its
+        # first request is a stage like any other: its seconds, and the
+        # programs JAX obtains in them, are the server's (BUILDS)
+        with self._span("init"):
+            if paged:
+                self._init_paged(cfg_d, page_size, n_pages,
+                                 prefill_budget, prefix_cache,
+                                 True if clip_rounds is None
+                                 else clip_rounds)
+            else:
+                self._init_dense(cfg_d, prompt_buckets, bool(clip_rounds))
+
+    def _init_dense(self, cfg_d: TransformerConfig,
+                    prompt_buckets: Tuple[int, ...], clip_rounds: bool):
+        params, cfg = self.params, self.cfg
+        n_slots, max_len = self.n_slots, self.max_len
+        self.clip_rounds = clip_rounds
         self.buckets = prompt_buckets_for(cfg, max_len, prompt_buckets)
         if cfg.block_len and any(b % cfg.block_len for b in self.buckets):
             raise ValueError(
@@ -1356,10 +1377,16 @@ class DecodeServer:
         the server keeps them on, log2 estimates for any other,
         metrics.hist_summary) — dashboards read quantiles, not raw
         28-bucket dumps. The bucket layout stays available through
-        ``self.metrics.snapshot()`` for anyone who wants it. Paged
-        servers add the allocator's own counters under ``pages``."""
+        ``self.metrics.snapshot()`` for anyone who wants it. ``build``
+        is a row a function JAX traced, lowered or compiled under a
+        span of this registry, the costliest first
+        (utils.tracing.build_table). Paged servers add the allocator's
+        own counters under ``pages``."""
         snap = self.metrics.snapshot()
         snap["histograms"] = self.metrics.summaries()
+        # a copy: another thread's build may append meanwhile
+        snap["build"] = build_table(r for r in tuple(BUILDS.records)
+                                    if r.metrics is self.metrics)
         if self.paged:
             snap["pages"] = self.allocator.stats()
             if self.trie is not None:

@@ -15,7 +15,10 @@ This is the rebuild's replacement:
     launches show up named in TPU profiles, on the profiler's clock)
     that also totals its elapsed time into a metrics Registry and can
     hand its bracket to a fabric SpanRecorder; `profile(logdir)` wraps
-    jax.profiler.trace for a capture window.
+    jax.profiler.trace for a capture window;
+  - `BUILDS`, the process's build log: every trace, lowering and
+    compile JAX makes, one record a root with nested time apart, under
+    the `annotate` span that was open when it began.
 
 The native C core has the same facility (rlo_trace_* in rlo_core.h);
 tests assert both sides emit the same event sequence for the same
@@ -26,11 +29,12 @@ from __future__ import annotations
 
 import contextlib
 import json
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
 from enum import IntEnum
-from typing import Deque, Dict, Iterator, List, Optional
+from typing import Deque, Dict, Iterable, Iterator, List, Optional
 
 
 class Ev(IntEnum):
@@ -178,6 +182,10 @@ TRACER = Tracer()
 
 _TraceAnnotation = None  # jax.profiler.TraceAnnotation, on first use
 
+#: per thread: ``spans``, the open ``annotate`` spans, outermost first,
+#: and the build log's open events (``BuildLog``)
+_LOCAL = threading.local()
+
 
 class annotate:
     """The span helper: ``with annotate(name, ...):`` around a stage.
@@ -191,10 +199,13 @@ class annotate:
     (``counter`` defaults to ``name``; no ``metrics``, no counters),
     and calls ``emit(t0, t1)`` with the bracket in
     ``time.perf_counter()`` seconds when one is given — how a fabric's
-    SpanRecorder gets the same stage as an ``Ev.SPAN``. Always on: no
-    flag arms it. The jax import is lazy (the engine stack imports
-    this module without JAX)."""
-    __slots__ = ("_ann", "_metrics", "_counter", "_emit", "_t0")
+    SpanRecorder gets the same stage as an ``Ev.SPAN``. While it is
+    open it is the innermost of its thread's open spans: a program
+    that JAX builds meanwhile is recorded in ``BUILDS`` under its name
+    and counted into its ``metrics`` (``Build``). Always on: no flag
+    arms it. The jax import is lazy (the engine stack imports this
+    module without JAX)."""
+    __slots__ = ("name", "metrics", "counter", "_ann", "_emit", "_t0")
 
     def __init__(self, name: str, metrics=None,
                  counter: Optional[str] = None, emit=None, **ids):
@@ -202,25 +213,215 @@ class annotate:
         if _TraceAnnotation is None:
             from jax.profiler import TraceAnnotation
             _TraceAnnotation = TraceAnnotation
+            BUILDS.arm()
+        self.name = name
+        self.metrics = metrics
+        self.counter = name if counter is None else counter
         self._ann = _TraceAnnotation(name, **ids)
-        self._metrics = metrics
-        self._counter = name if counter is None else counter
         self._emit = emit
 
     def __enter__(self) -> "annotate":
         self._ann.__enter__()
+        try:
+            _LOCAL.spans.append(self)
+        except AttributeError:      # the thread's first span
+            _LOCAL.spans = [self]
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
+        _LOCAL.spans.pop()
         self._ann.__exit__(*exc)
-        if self._metrics is not None:
-            self._metrics.counter(self._counter + "_ns").inc(
+        if self.metrics is not None:
+            self.metrics.counter(self.counter + "_ns").inc(
                 t1 - self._t0)
-            self._metrics.counter(self._counter + "_n").inc()
+            self.metrics.counter(self.counter + "_n").inc()
         if self._emit is not None:
             self._emit(self._t0 * 1e-9, t1 * 1e-9)
+
+
+# ---------------------------------------------------------------------------
+# The build log: what JAX traced, lowered and compiled, and for whom
+# ---------------------------------------------------------------------------
+
+#: JAX's monitoring events around the three phases of obtaining a
+#: program. Each fires on entry (a scalar: its start) and on exit (a
+#: time span), both with ``fun_name=``; the events of programs built
+#: inside a phase (a jitted callee's trace in its caller's, a
+#: primitive's in a lowering rule) fire inside its interval.
+_BUILD_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+#: the persistent cache's answer, fired inside a compile's interval
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+#: what a ``Build`` counts, and the counters ``<prefix>.build.<key>`` it
+#: adds to its span's registry: programs obtained (compiled, or read
+#: from the persistent cache), nanoseconds by phase, each once
+#: (``trace_ns`` in a trace that is a root, ``trace_nested_ns`` in
+#: traces opened inside another event's interval), and the persistent
+#: cache's answers
+BUILD_COUNTS = ("programs", "trace_ns", "trace_nested_ns", "lower_ns",
+                "compile_ns", "cache_hits", "cache_misses")
+#: phase -> where an event's own nanoseconds go: as a root, nested
+_PHASE_NS = {"trace": ("trace_ns", "trace_nested_ns"),
+             "lower": ("lower_ns", "lower_ns"),
+             "compile": ("compile_ns", "compile_ns")}
+
+
+class Build:
+    """One root of the build log: a trace, lowering or compile that
+    began with no other open on its thread. ``fun_name`` is the
+    function's name as JAX gives it (``jit(f)`` read as ``f``),
+    ``phase`` the root's own, ``t0`` / ``t1`` its interval on
+    ``time.perf_counter_ns()``, ``span`` the name of the innermost
+    ``annotate`` open when it began (None: none was), ``metrics`` that
+    span's registry and ``prefix`` what its counters there start with
+    (the span's counter up to its first dot, then ``.build.``).
+    ``counts`` holds BUILD_COUNTS over the whole interval: an event
+    opened inside it is folded in as it closes, its own time (its
+    duration less what its children cover) under its phase, so the
+    four ``*_ns`` add up to ``t1 - t0`` and no nanosecond is counted
+    twice."""
+    __slots__ = ("fun_name", "phase", "span", "metrics", "prefix", "t0",
+                 "t1", "counts")
+
+    def __init__(self, fun_name: str, phase: str,
+                 span: Optional[annotate], t0: int):
+        if fun_name.startswith("jit(") and fun_name.endswith(")"):
+            fun_name = fun_name[4:-1]
+        self.fun_name = fun_name
+        self.phase = phase
+        self.span = self.metrics = self.prefix = None
+        if span is not None:
+            self.span, self.metrics = span.name, span.metrics
+            self.prefix = span.counter.partition(".")[0] + ".build."
+        self.t0 = self.t1 = t0
+        self.counts = dict.fromkeys(BUILD_COUNTS, 0)
+
+
+class BuildLog:
+    """Every program this process obtained, as ``records``: one
+    ``Build`` a root, the newest ``capacity`` of them. It listens to
+    JAX's own monitoring events, so it sees what any caller builds,
+    jitted or eager, and keeps a true stack a thread (``_LOCAL.builds``:
+    the open events as [phase, start ns, ns its children cover];
+    ``_LOCAL.root``: the record of the outermost). A listener call is
+    an append or a pop, and memory is the nesting depth plus one record
+    a root: a 24-layer server's start fires 10^4-10^5 events and keeps
+    a few hundred records. Times are stamped as the events arrive, on
+    the clock ``annotate`` totals on. ``events`` counts the listener
+    calls that found a phase (what the log costs is that many times a
+    call)."""
+
+    def __init__(self, capacity: int = 16384):
+        self.records: Deque[Build] = deque(maxlen=capacity)
+        self.events = 0
+        self._armed = False
+
+    def arm(self) -> None:
+        """Register the listeners, once. ``annotate`` does on its first
+        use; a main that wants the programs built before its first
+        span calls it itself."""
+        if self._armed:
+            return
+        self._armed = True
+        from jax import monitoring
+        monitoring.register_scalar_listener(self._on_enter)
+        monitoring.register_event_time_span_listener(self._on_exit)
+        monitoring.register_event_listener(self._on_cache)
+
+    def _on_enter(self, event: str, _start, fun_name: str = "",
+                  **_kw) -> None:
+        phase = _BUILD_PHASES.get(event)
+        if phase is None:
+            return
+        now = time.perf_counter_ns()
+        self.events += 1
+        open_ = getattr(_LOCAL, "builds", None)
+        if open_ is None:
+            open_ = _LOCAL.builds = []
+        if not open_:
+            spans = getattr(_LOCAL, "spans", None)
+            _LOCAL.root = Build(fun_name, phase,
+                                spans[-1] if spans else None, now)
+        open_.append([phase, now, 0])
+
+    def _on_exit(self, event: str, _start, _end, **_kw) -> None:
+        phase = _BUILD_PHASES.get(event)
+        if phase is None:
+            return
+        now = time.perf_counter_ns()
+        open_ = getattr(_LOCAL, "builds", None)
+        if not open_:
+            return      # armed inside this event: it was never pushed
+        self.events += 1
+        _, t0, covered = open_.pop()
+        root = _LOCAL.root
+        root.counts[_PHASE_NS[phase][bool(open_)]] += now - t0 - covered
+        if phase == "compile":
+            root.counts["programs"] += 1
+        if open_:
+            open_[-1][2] += now - t0
+            return
+        root.t1 = now
+        self.records.append(root)
+        if root.metrics is not None:
+            for key, n in root.counts.items():
+                root.metrics.counter(root.prefix + key).inc(n)
+
+    def _on_cache(self, event: str, **_kw) -> None:
+        key = _CACHE_EVENTS.get(event)
+        if key is not None and getattr(_LOCAL, "builds", None):
+            _LOCAL.root.counts[key] += 1
+
+
+#: the process-wide build log
+BUILDS = BuildLog()
+
+
+def build_totals(records: Iterable[Build]) -> Dict[str, int]:
+    """BUILD_COUNTS summed over ``records``."""
+    total = dict.fromkeys(BUILD_COUNTS, 0)
+    for r in records:
+        for key, n in r.counts.items():
+            total[key] += n
+    return total
+
+
+def build_table(records: Iterable[Build]) -> List[Dict]:
+    """``records`` by ``fun_name``, the costliest first: ``calls`` (the
+    roots of the phase that has most: a jitted call is a trace, a
+    lowering and a compile), seconds of ``trace_s`` (the roots' own),
+    ``trace_nested_s``, ``lower_s`` and ``compile_s``, each once, and
+    their sum ``total_s``, ``programs``, ``cache_hits`` and
+    ``cache_misses``, and ``spans``, the names of the spans its roots
+    began under."""
+    by_name: Dict[str, List[Build]] = {}
+    for r in records:
+        by_name.setdefault(r.fun_name, []).append(r)
+    table = []
+    for fun_name, group in by_name.items():
+        phases = [r.phase for r in group]
+        row = {"fun_name": fun_name,
+               "calls": max(phases.count(p) for p in _PHASE_NS)}
+        total = 0
+        for key, n in build_totals(group).items():
+            if key.endswith("_ns"):
+                row[key[:-2] + "s"] = n / 1e9
+                total += n
+            else:
+                row[key] = n
+        row["total_s"] = total / 1e9
+        row["spans"] = sorted({r.span for r in group} - {None})
+        table.append(row)
+    table.sort(key=lambda row: -row["total_s"])
+    return table
 
 
 @contextlib.contextmanager

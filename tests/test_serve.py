@@ -391,6 +391,88 @@ def test_span_and_work_counters_balance(setup, paged):
     assert "serve.occupancy_pct" not in reg.snapshot()["histograms"]
 
 
+@pytest.mark.parametrize("mode", ["dense", "block", "paged"])
+def test_build_log_names_the_stage_that_paid_for_each_program(setup,
+                                                              mode):
+    """A start as the build log holds it: every program JAX obtained
+    for the server began under one of its spans (the constructor's
+    among them) and is counted once in ``serve.build.*``, as many
+    programs as an independent listener saw compiled; ``stats()``
+    names the round's function and the admission's once each; the
+    dense schedulers' one-row prefill is a trace of its own, a root
+    under ``admit.prefill_dispatch`` before the loop's; and across ten
+    further rounds nothing is built."""
+    from rlo_tpu.utils.metrics import Registry
+    from rlo_tpu.utils.tracing import BUILD_COUNTS, BUILDS
+
+    params = setup
+    cfg = (dataclasses.replace(CFG, block_len=4, mask_id=CFG.vocab - 1)
+           if mode == "block" else CFG)
+    compiles = []
+
+    def listen(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    def wave(srv, rng):
+        # whole rounds only: a paged round clipped to a shorter budget
+        # is another program
+        for _ in range(4):
+            srv.submit(rng.integers(0, CFG.vocab, (int(rng.integers(3, 9)),)),
+                       20 if mode == "block" else 21)
+        while srv.has_work():
+            srv.step_round()
+
+    reg = Registry()
+    rng = np.random.default_rng(21)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        kw = (dict(paged=True, page_size=8) if mode == "paged"
+              else dict(prompt_buckets=(8, 16)))
+        srv = DecodeServer(params, cfg, n_slots=2, max_len=64, round_len=4,
+                           metrics=reg, **kw)
+        wave(srv, rng)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    mine = [r for r in BUILDS.records if r.metrics is reg]
+    assert mine and all(r.span.startswith("perf.serve.") for r in mine)
+    c = reg.snapshot()["counters"]
+    assert c["serve.init_n"] == 1 and c["serve.init_ns"] > 0
+    build = {k: c["serve.build." + k] for k in BUILD_COUNTS}
+    assert build["programs"] == len(compiles) > 0
+    assert build == {k: sum(r.counts[k] for r in mine)
+                     for k in BUILD_COUNTS}
+    assert sum(r.t1 - r.t0 for r in mine) == sum(
+        n for k, n in build.items() if k.endswith("_ns")) <= \
+        c["serve.init_ns"] + c["serve.step_round_ns"]
+
+    table = {row["fun_name"]: row for row in srv.stats()["build"]}
+    admission = "chunk_fn" if mode == "paged" else "admit_rows"
+    assert table["round_fn"]["calls"] == table[admission]["calls"] == 1
+    assert table["round_fn"]["programs"] == 1
+    assert table["round_fn"]["spans"] == ["perf.serve.round.dispatch"]
+    stage = ("perf.serve.admit.prefill_chunk" if mode == "paged"
+             else "perf.serve.admit.prefill_dispatch")
+    assert table[admission]["spans"] == [stage]
+    if mode != "paged":
+        # the layers' trace, made where no other trace is open
+        # (_launch_group): it is no program of its own, and the loop
+        # that calls it is traced after it
+        (one_row,) = [r for r in mine if r.fun_name == "prefill_slot"]
+        assert (one_row.phase, one_row.span) == ("trace", stage)
+        loop = next(r for r in mine if r.fun_name == "admit_rows")
+        assert loop.phase == "trace" and one_row.t1 <= loop.t0
+
+    rounds = c["serve.rounds"]
+    wave(srv, rng), wave(srv, rng)
+    c = reg.snapshot()["counters"]
+    assert c["serve.rounds"] - rounds >= 10
+    assert {k: c["serve.build." + k] for k in BUILD_COUNTS} == build
+    assert c.get("serve.retraces", 0) == 0
+    assert len([r for r in BUILDS.records if r.metrics is reg]) == \
+        len(mine)
+
+
 def test_profiler_trace_holds_nested_serve_spans(setup, tmp_path):
     """Under a jax.profiler session on the CPU two step_round() calls
     leave ``perf.serve.*`` events on ``/host:CPU`` that nest as

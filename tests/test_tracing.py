@@ -260,13 +260,177 @@ def test_annotate_totals_into_a_registry_and_hands_out_its_bracket():
 
 def test_tracing_module_imports_without_jax():
     """The engine stack imports utils/tracing.py without JAX: the
-    profiler import waits for the first span."""
+    profiler import, and the build log's listeners, wait for the first
+    span."""
     import subprocess
     import sys
     code = ("import sys; sys.modules['jax'] = None; "
             "import rlo_tpu.utils.tracing as t; "
             "assert t._TraceAnnotation is None; "
+            "assert not t.BUILDS._armed and not t.BUILDS.records; "
             "t.Tracer().emit(0, t.Ev.VOTE); print('ok')")
     out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+# ---- the build log -------------------------------------------------------
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def test_build_log_folds_nested_events_into_their_root(monkeypatch):
+    """The listeners on a clock worked by hand: a trace with a trace
+    nested two deep and an eager compile inside it is ONE record whose
+    nanoseconds, by phase, add up to its interval; an exit that never
+    entered (the log armed inside it) is dropped."""
+    from rlo_tpu.utils import tracing
+    from rlo_tpu.utils.metrics import Registry
+
+    clock = iter([0, 10, 12, 15, 30, 40, 70, 100, 200, 300, 350])
+    monkeypatch.setattr(tracing.time, "perf_counter_ns",
+                        lambda: next(clock))
+    log = tracing.BuildLog()
+    reg = Registry()
+    span = annotate("perf.x.stage", reg, "x.stage")
+    monkeypatch.setattr(tracing._LOCAL, "spans", [span], raising=False)
+    log._on_exit(_TRACE, 0.0, 0.0, fun_name="never_entered")    # t=0
+    log._on_enter(_TRACE, 0.0, fun_name="outer")                # 10
+    log._on_enter(_TRACE, 0.0, fun_name="inner")                # 12
+    log._on_enter("/jax/other", 0.0)            # not a phase: no clock
+    log._on_enter(_TRACE, 0.0, fun_name="sin")                  # 15
+    log._on_exit(_TRACE, 0.0, 0.0, fun_name="sin")              # 30
+    log._on_exit(_TRACE, 0.0, 0.0, fun_name="inner")            # 40
+    log._on_enter(_COMPILE, 0.0, fun_name="jit(iota)")          # 70
+    log._on_cache("/jax/compilation_cache/cache_misses")
+    log._on_exit(_COMPILE, 0.0, 0.0, fun_name="jit(iota)")      # 100
+    assert not log.records                      # outer is still open
+    log._on_exit(_TRACE, 0.0, 0.0, fun_name="outer")            # 200
+    log._on_cache("/jax/compilation_cache/cache_hits")  # none open
+    log._on_enter(_COMPILE, 0.0, fun_name="jit(outer)")         # 300
+    log._on_cache("/jax/compilation_cache/cache_hits")
+    log._on_exit(_COMPILE, 0.0, 0.0, fun_name="jit(outer)")     # 350
+    first, second = log.records
+    assert (first.fun_name, first.phase, first.span, first.t0,
+            first.t1) == ("outer", "trace", "perf.x.stage", 10, 200)
+    # inner 12..40 less sin's 15 = 13, sin 15; iota 30; the rest outer's
+    assert first.counts == {
+        "programs": 1, "trace_ns": 190 - 28 - 30, "trace_nested_ns": 28,
+        "lower_ns": 0, "compile_ns": 30, "cache_hits": 0,
+        "cache_misses": 1}
+    assert (second.fun_name, second.phase, second.counts["compile_ns"],
+            second.counts["cache_hits"]) == ("outer", "compile", 50, 1)
+    assert log.events == 10
+    c = reg.snapshot()["counters"]
+    assert {k: v for k, v in c.items() if k.startswith("x.build.")} == {
+        "x.build." + k: first.counts[k] + second.counts[k]
+        for k in tracing.BUILD_COUNTS}
+    assert tracing.build_totals(log.records)["programs"] == 2
+    (row,) = tracing.build_table(log.records)
+    assert row == {
+        "fun_name": "outer", "calls": 1, "programs": 2,
+        "trace_s": 132e-9, "trace_nested_s": 28e-9, "lower_s": 0.0,
+        "compile_s": 80e-9, "total_s": 240e-9, "cache_hits": 1,
+        "cache_misses": 1, "spans": ["perf.x.stage"]}
+
+
+def test_build_log_records_one_root_for_a_jit_that_calls_a_jit():
+    """A jitted ``outer`` that calls a jitted ``inner`` in a fori_loop
+    body, under a span: one trace root, ``outer``'s, whose own and
+    nested nanoseconds add up to its interval, the nested part no less
+    than JAX's own duration of ``inner``'s trace; every root lies
+    inside the span, and the span's registry counts the programs an
+    independent listener saw compiled."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from rlo_tpu.utils.metrics import Registry
+    from rlo_tpu.utils.tracing import BUILDS, build_table
+
+    seen = {"inner_s": [], "compiles": 0}
+
+    def listen(event, secs, fun_name="", **_kw):
+        if event == _TRACE and fun_name == "build_log_inner":
+            seen["inner_s"].append(secs)
+        seen["compiles"] += event == _COMPILE
+
+    @jax.jit
+    def build_log_inner(x):
+        return jnp.sin(x) * 2
+
+    @jax.jit
+    def build_log_outer(x):
+        return lax.fori_loop(0, 3, lambda i, c: build_log_inner(c) + 1, x)
+
+    reg = Registry()
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        with annotate("perf.t.build", reg, "t.stage"):
+            x = jnp.ones((4,)) + 1      # eager programs are roots too
+            build_log_outer(x).block_until_ready()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    mine = [r for r in BUILDS.records if r.metrics is reg]
+    assert mine and all(r.span == "perf.t.build" for r in mine)
+    assert all(r.t1 - r.t0 == sum(n for k, n in r.counts.items()
+                                  if k.endswith("_ns")) for r in mine)
+    assert [r.phase for r in mine
+            if r.fun_name == "build_log_outer"] == ["trace", "lower",
+                                                    "compile"]
+    assert not [r for r in mine if r.fun_name == "build_log_inner"]
+    root = next(r for r in mine if r.fun_name == "build_log_outer")
+    assert root.counts["trace_ns"] > 0
+    # the two clocks are read a listener call apart
+    (inner_s,) = seen["inner_s"]
+    assert root.counts["trace_nested_ns"] >= 0.9 * inner_s * 1e9
+    c = reg.snapshot()["counters"]
+    assert sum(r.t1 - r.t0 for r in mine) <= c["t.stage_ns"]
+    assert c["t.build.programs"] == seen["compiles"] >= 2
+    assert c["t.build.trace_ns"] + c["t.build.trace_nested_ns"] \
+        + c["t.build.lower_ns"] + c["t.build.compile_ns"] == \
+        sum(r.t1 - r.t0 for r in mine)
+    table = build_table(mine)
+    row = next(r for r in table if r["fun_name"] == "build_log_outer")
+    assert row["calls"] == 1 and row["programs"] == 1
+    assert row["spans"] == ["perf.t.build"]
+    costs = [r["total_s"] for r in table]
+    assert costs == sorted(costs, reverse=True)
+
+
+def test_build_log_without_a_registry_or_a_span():
+    """A root under a span with no ``metrics`` is recorded under the
+    span's name and writes no counter; a program built by another
+    thread, or with no span open, is under no span."""
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+
+    from rlo_tpu.utils.metrics import Registry
+    from rlo_tpu.utils.tracing import BUILDS
+
+    reg = Registry()
+    n0 = len(BUILDS.records)
+
+    def build(name):
+        def f(x):
+            return x * 3 + 1
+        f.__name__ = name
+        jax.jit(f)(jnp.ones((3,))).block_until_ready()
+
+    with annotate("perf.t.outer", reg):
+        with annotate("perf.t.plain"):
+            build("build_log_plain")
+        worker = threading.Thread(target=build, args=("build_log_thread",))
+        worker.start()
+        worker.join(timeout=120)
+        assert not worker.is_alive()
+    build("build_log_bare")
+    spans = {r.fun_name: (r.span, r.metrics)
+             for r in list(BUILDS.records)[n0:]}
+    assert spans["build_log_plain"] == ("perf.t.plain", None)
+    assert spans["build_log_thread"] == (None, None)
+    assert spans["build_log_bare"] == (None, None)
+    assert not [k for k in reg.snapshot()["counters"] if ".build." in k]
