@@ -199,7 +199,15 @@ class DecodeServer:
     ``serve.attend_tiles_live`` and ``serve.attend_steps`` (dense
     scheduler, flash_decode path: cache tiles of a grid over max_len,
     those a row's live context reaches, and the grid steps the kernel
-    runs — its work list holds the live tiles alone), the gauge
+    runs — its work list holds the live tiles alone),
+    ``serve.attend_lanes_fetched`` against ``serve.attend_lanes_live``
+    (cache positions the kernel's own copies move, a row's last tile
+    rounded up to the copy's granule, and those the contexts hold) and
+    the gauge
+    ``serve.attend_fetch_depth`` (slots of the kernel's K/V ring;
+    absent where the attend is the einsum or a step computes longer
+    than it streams, a latent cache under many heads, and the
+    pipeline fetches whole tiles), the gauge
     ``serve.cache_bytes_per_token`` (dense scheduler: what one position
     of one slot holds over all layers), ``serve.dsa.keys_scored``,
     ``serve.dsa.rows_attended``, ``serve.dsa.dense_row_steps`` and
@@ -337,9 +345,21 @@ class DecodeServer:
         # tiles), for the attend-tile counters; None where decode_step
         # attends through the einsum (off the tpu backend, or a shape
         # can_flash_decode refuses): nothing is tiled, nothing counted
-        self._attend_tiling = (kvcache.attend_tiling(self.cache, cfg)
-                               if _on_tpu() and not cfg.block_len
-                               else None)
+        tiling = kvcache.attend_tiling(self.cache, cfg) if _on_tpu() \
+            else None
+        self._attend_tiling = None if cfg.block_len else tiling
+        # the ring the kernel fetches its own K/V through, where it
+        # does: 0 where a step computes longer than it streams and the
+        # pipeline brings whole tiles
+        self._attend_slots = 0
+        if tiling is not None:
+            from rlo_tpu.pallas.decode import flash_decode_slots
+            self._attend_slots = flash_decode_slots(
+                self.cache[0]["k"], cfg.n_heads,
+                cfg.kv_lora_rank if cfg.mla else 0, cfg.block_len or 1)
+        if self._attend_slots:
+            self.metrics.gauge("serve.attend_fetch_depth").set(
+                self._attend_slots)
         # routed expert layers report their routing counts
         # (models.moe.STATS): the round then carries their sum over its
         # steps and layers and returns it as its last output
@@ -1242,7 +1262,13 @@ class DecodeServer:
         ``serve.attend_steps`` the grid steps the round's attends run.
         The grid is the work list of live (row, tile) pairs
         (decode_work_list), so steps == tiles_live says it engages; a
-        grid over max_len would read == tiles. With the tail every
+        grid over max_len would read == tiles. The kernel copies its
+        own tiles, a row's last only as far as it is live:
+        ``serve.attend_lanes_fetched`` is the cache positions those
+        copies move (decode_lanes_fetched, the kernel's rule: rounded
+        up to the copy's granule at a row's last tile; whole tiles
+        would read tiles_live x bk) and ``serve.attend_lanes_live``
+        the positions the rows' contexts hold. With the tail every
         step of the round attends the cache as the round found it,
         positions < pos: tiles 0 .. (pos - 1) // bk, the same in every
         step (tile 0 alone for an empty row). Without it step s
@@ -1250,17 +1276,23 @@ class DecodeServer:
         finished ones too: the kernel runs them."""
         if self._attend_tiling is None:
             return
+        from rlo_tpu.pallas.decode import decode_lanes_fetched
         bk, n_k = self._attend_tiling
-        if self._kv_tail:
-            last = kk * np.clip((self.pos - 1) // bk, 0, n_k - 1)
-        else:
-            last = np.minimum((self.pos[:, None] + np.arange(kk)) // bk,
-                              n_k - 1)
-        live = int(last.sum()) + kk * self.n_slots
-        self.metrics.counter("serve.attend_tiles").inc(
-            kk * self.n_slots * n_k)
-        self.metrics.counter("serve.attend_tiles_live").inc(live)
-        self.metrics.counter("serve.attend_steps").inc(live)
+        # the last position each attend of the round reaches: (rows, 1)
+        # with the tail, kk times over; (rows, kk) without
+        held = (self.pos[:, None] - 1 if self._kv_tail
+                else self.pos[:, None] + np.arange(kk))
+        times = kk if self._kv_tail else 1
+        live = times * int((np.clip(held // bk, 0, n_k - 1) + 1).sum())
+        count = self.metrics.counter
+        count("serve.attend_tiles").inc(kk * self.n_slots * n_k)
+        count("serve.attend_tiles_live").inc(live)
+        count("serve.attend_steps").inc(live)
+        count("serve.attend_lanes_fetched").inc(times * int(
+            decode_lanes_fetched(held, 1, bk, self.max_len,
+                                 self._attend_slots).sum()))
+        count("serve.attend_lanes_live").inc(times * int(
+            np.clip(held + 1, 0, self.max_len).sum()))
 
     def _count_dsa(self, kk: int) -> None:
         """A token selector's work in the round just launched

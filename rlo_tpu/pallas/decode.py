@@ -30,17 +30,43 @@ only those are grid steps: decode_work_list turns ``pos`` into the
 live (row, tile) pairs, rows in order, a row's tiles ascending, and the
 kernel runs a 1-D grid over them whose bound, n_work, is read on the
 device. ``row_of`` and ``tile_of`` are scalar-prefetched (beside
-``pos``); the K/V (and int8 scale) index maps are (row_of[i], 0, 0,
-tile_of[i]), q's, the tail's and the output's (row_of[i], 0, 0, 0). A
-wholly masked tile would contribute p = 0 and corr = 1, so leaving it
-out changes no bit of the output, and with no dead steps between them
-the pipeline's ordinary look-ahead has the next row's first tile, its
-q and its tail rows under way behind a row's last tile. (The grid used
-to span (batch, max_len / BK) with the dead steps skipped in the body:
-an empty grid step cost 0.2-0.35 us, 58% of the steps of a server's
-mix at BK 256; PERF.md, PR 30.) The tile width BK is therefore the
-granularity of a row's last, partly dead tile as well as of the
-stream (_pick_bk). The list depends on ``pos``, T and the cache's
+``pos``); the int8 scales' and the selection's index maps are
+(row_of[i], 0, 0, tile_of[i]), q's, the tail's and the output's
+(row_of[i], 0, 0, 0). A wholly masked tile would contribute p = 0 and
+corr = 1, so leaving it out changes no bit of the output. (The grid
+used to span (batch, max_len / BK) with the dead steps skipped in the
+body: an empty grid step cost 0.2-0.35 us, 58% of the steps of a
+server's mix at BK 256; PERF.md, PR 30.)
+
+Where a step streams longer than it computes (_ring_slots: one query
+a head, a verify, a block of 4 over grouped heads) K and V are NOT
+BlockSpec operands: they stay in HBM (pl.ANY) and the kernel FETCHES
+ITS OWN TILES (_ring_fetch, PR 37). The BlockSpec pipeline
+double-buffers, one copy a tensor in flight, so at every row end the
+next tile's start-up stood in the stream, and a tile is the unit of
+its fetch, so a row's last tile came whole (Mosaic here refuses
+pl.Buffered(3)). The kernel holds a ring of _RING_SLOTS VMEM slots a
+tensor with a DMA semaphore each; the work list is whole in SMEM, so
+step i can name any later step's (row, tile): it starts the copy of
+step i + _RING_SLOTS - 1 (step 0 primes the ring: _ring_span), then
+waits for its own tile and computes from its slot. Copies run across
+row ends, and the next row's q and tail rows still come a step ahead
+on their BlockSpecs. And a copy is only AS LONG AS THE ROW IS LIVE:
+the kernel knows pos, so a row's last tile is copied up to the next
+granule past its last attended position (_copy_lanes; 128 lanes for
+tiles up to 512, a quarter of a wider one: a DMA's shape is static, so
+one copy a size under a switch of at most four arms, _copy_sizes) and
+the rest of the slot keeps an older tile's lanes, which the masks
+treat like any dead position (scores selected to _NEG, V zeroed,
+never multiplied). The tile width BK is therefore the granularity of
+the STEPS, the granule that of the bytes (_pick_bk;
+decode_lanes_fetched is the rule for callers that count). A step
+that COMPUTES longer than it streams — 128 heads on one latent stream:
+~2.5 us of matmul and softmax on a tile of 1.4 us — hides its bytes
+whoever fetches them and shows every scalar cycle of the fetch's loop
+and switches, so it keeps K (and V) on BlockSpecs with the index map
+(row_of[i], 0, 0, tile_of[i]): same body, same bits, whole tiles. The
+list depends on ``pos``, T and the cache's
 shape alone, so a step builds it once for all its layers
 (models.kvcache.attend_work) and a lone call builds its own. The grid
 is sequential ('arbitrary'): the accumulators cross its steps. A v5e
@@ -64,7 +90,9 @@ decode, so both paths share one kernel and its numerics:
   pos      (b,) int32, scalar-prefetched — query t of row b masks
            prefix [0, pos_b + t]
   out      (b, kv_heads, T*r, head_dim) f32
-  scratch  m/l (kv_heads, T*r), o (kv_heads, T*r, head_dim) f32
+  scratch  m/l (kv_heads, T*r), o (kv_heads, T*r, head_dim) f32;
+           the K and V rings (_RING_SLOTS, kv_heads, head_dim, BK) in
+           the cache's dtype, DMA semaphores (tensors, _RING_SLOTS)
 
 A LATENT cache (latent attention's absorbed decode) is the same kernel
 with no V operand: every head attends ONE stream — k (b, 1, d, max_len),
@@ -100,8 +128,9 @@ f32 caches keep f32 dots — their tiles are smaller than VMEM allows
 anyway). (m, l, o) accumulate in VMEM scratch — initialised at a
 row's first grid step (tile_of[i] == 0) and flushed at its last
 (row_of[i + 1] is another row, or the sentinel that ends the list);
-the padded tail block past max_len is masked (and V zeroed under the mask, so
-out-of-range garbage can never ride a 0*NaN into the accumulator).
+what a slot holds past its copy — a short last tile's stale lanes, the
+end of a cache BK does not divide — is masked (and V zeroed under the
+mask, so garbage can never ride a 0*NaN into the accumulator).
 """
 
 from __future__ import annotations
@@ -111,6 +140,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -126,12 +156,108 @@ _BLOCK_K = 512
 _SUBLANES = 16
 
 
+def _copy_granule(bk: int) -> int:
+    """The lanes a K/V copy is a multiple of: a quarter of the tile,
+    and at least one 128-lane block of the cache axis (so a copy takes
+    one of at most four sizes: _copy_sizes) — or the whole tile where
+    that is no multiple of 128 (interpret mode's small caches)."""
+    return -(-bk // 512) * 128 if bk % 128 == 0 else bk
+
+
+def _copy_lanes(end, tile, bk: int, max_len: int, xp=jnp):
+    """How many lanes of cache tile ``tile`` a row copies whose queries
+    attend positions < ``end``: the tile as far as it is live, rounded
+    up to _copy_granule (never none: an empty row still owns tile 0),
+    and no further than the tile or the cache goes. A row's earlier
+    tiles come out whole; only its last is cut. The kernel's rule and,
+    through decode_lanes_fetched, the host's counters'."""
+    g = _copy_granule(bk)
+    lanes = xp.maximum((end - tile * bk + g - 1) // g, 1) * g
+    return xp.minimum(xp.minimum(lanes, bk), max_len - tile * bk)
+
+
+def _copy_sizes(bk: int, max_len: int):
+    """Every value _copy_lanes can take, ascending: a DMA's shape is
+    static, so the kernel holds one copy a size and picks by the live
+    context (a switch of at most four arms, and one more for the short
+    last tile of a cache that bk does not divide)."""
+    g = _copy_granule(bk)
+    # lists and calls only: the prover evaluates this rule too (P3)
+    return sorted(set(list(range(g, bk, g)) + [bk, max_len % bk or bk]))
+
+
+def _ring_span(i, n_work, n_slots: int, xp=jnp):
+    """The grid steps [lo, hi) whose copies step ``i`` starts, so that
+    n_slots - 1 steps beyond the one being computed are under way: step
+    0 primes the ring with steps 0 .. n_slots - 1, every later step
+    starts step i + n_slots - 1 alone — into the slot step i - 1 has
+    just been computed from — and no step past the list's end."""
+    return (xp.where(i == 0, 0, i + (n_slots - 1)),
+            xp.minimum(i + n_slots, n_work))
+
+
+def _ring_fetch(i, row, tile, row_ref, tile_ref, pos_ref, streams, sem, *,
+                bk: int, max_len: int, T: int):
+    """The kernel's own K/V fetch: bring grid step i's tile (``tile`` of
+    ``row``) into VMEM and return the ring slot that holds it.
+    ``streams`` is one (cache in HBM, ring (n_slots, kv_heads, head_dim,
+    bk) in VMEM) pair a tensor, ``sem`` a DMA semaphore (tensor, slot).
+    The work list is whole in SMEM, so step i names step j's (row,
+    tile) for any j: it starts the copies _ring_span gives it, then
+    waits for its own. So n_slots - 1 copies a tensor are in flight
+    while a step computes, where the BlockSpec pipeline holds one: a
+    copy's start-up no longer stands between two tiles of the stream.
+    A copy is _copy_lanes wide; the lanes of a slot past it hold an
+    older tile's and are masked like any dead position."""
+    n_slots = streams[0][1].shape[0]
+    *short, whole = _copy_sizes(bk, max_len)
+
+    def copies(j, act, row, tile):
+        """``act`` (start or wait) on the copies of step j, tile
+        ``tile`` of row ``row``. The whole tile first: every tile of a
+        row but its last takes that arm and no other compare."""
+        lanes = _copy_lanes(jnp.minimum(pos_ref[row] + T, max_len), tile,
+                            bk, max_len)
+        base = tile * bk
+        if bk % 128 == 0:
+            base = pl.multiple_of(base, 128)
+        slot = j % n_slots
+
+        def arm(n):
+            for s, (hbm, ring) in enumerate(streams):
+                act(pltpu.make_async_copy(
+                    hbm.at[row, :, :, pl.ds(base, n)],
+                    ring.at[slot, :, :, pl.ds(0, n)], sem.at[s, slot]))
+
+        if not short:
+            return arm(whole)
+        pl.when(lanes == whole)(functools.partial(arm, whole))
+
+        @pl.when(lanes != whole)
+        def _a_rows_last_tile():
+            if len(short) == 1:
+                return arm(short[0])
+            for n in short:
+                pl.when(lanes == n)(functools.partial(arm, n))
+
+    def start(j, carry):
+        copies(j, lambda cp: cp.start(), row_ref[j], tile_ref[j])
+        return carry
+
+    jax.lax.fori_loop(*_ring_span(i, pl.num_programs(0), n_slots), start, 0)
+    copies(i, lambda cp: cp.wait(), row, tile)
+    return i % n_slots
+
+
 def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
                    bk: int, max_len: int, quant: bool,
                    r: int, T: int, v_dim: int = 0, n_tail: int = 0,
-                   selected: bool = False, block_len: int = 0):
+                   selected: bool = False, block_len: int = 0,
+                   n_slots: int = 0):
     if n_tail:          # a fourth prefetched scalar: the tail's newest row
         newest_ref, refs = refs[0], refs[1:]
+    # K and V: the caches themselves, in HBM, where the kernel copies
+    # its own tiles (n_slots: the ring's depth); else this step's tiles
     q_ref, k_ref, *rest = refs
     if not v_dim:       # a V operand; a latent cache has none
         v_ref, rest = rest[0], rest[1:]
@@ -142,9 +268,8 @@ def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
     if selected:        # the positions this row may attend, 1.0 or 0.0
         sel_ref, rest = rest[0], rest[1:]
     if quant:
-        ks_ref, vs_ref, o_ref, m_s, l_s, o_s = rest
-    else:
-        o_ref, m_s, l_s, o_s = rest
+        ks_ref, vs_ref, rest = rest[0], rest[1], rest[2:]
+    o_ref, m_s, l_s, o_s, *rings = rest
     # grid step i of the work list (decode_work_list): tile ik of row ib
     i = pl.program_id(0)
     ib = row_ref[i]
@@ -154,6 +279,16 @@ def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
     # tile bytes in VMEM. f32 caches keep f32 (exactness; their tiles
     # fit).
     dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
+    if n_slots:
+        *rings, sem = rings
+        caches = (k_ref,) if v_dim else (k_ref, v_ref)
+        slot = _ring_fetch(i, ib, ik, row_ref, tile_ref, pos_ref,
+                           list(zip(caches, rings)), sem, bk=bk,
+                           max_len=max_len, T=T)
+        k_tile = rings[0].at[slot]
+        v_tile = None if v_dim else rings[1].at[slot]
+    else:
+        k_tile, v_tile = k_ref.at[0], None if v_dim else v_ref.at[0]
 
     @pl.when(ik == 0)
     def _init():
@@ -205,10 +340,10 @@ def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
     # T*r rows, t-major: row t*r+rr is block token t, group-member rr,
     # at sequence position pos + t (T=1 recovers single-token decode).
     q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
-    k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
+    k = k_tile[...].astype(dot_dt)                   # (g, d, BK)
     # latent: the values are the key stream's leading features
     v = (k[:, :v_dim, :] if v_dim
-         else v_ref[0].astype(dot_dt))               # (g, d, BK)
+         else v_tile[...].astype(dot_dt))            # (g, d, BK)
     # masks built >=2-D from iota: Mosaic cannot insert a minor dim on
     # sub-32-bit (bool) values, so never reshape a 1-D mask
     base = ik * bk
@@ -754,52 +889,96 @@ def paged_flash_decode(q, k_pool, v_pool, table, pos0, scale,
 
 def can_flash_decode(max_len: int, head_dim: int,
                      block_k: int = _BLOCK_K, v_dim: int = 0) -> bool:
-    """Shape gate: a lane-friendly head_dim, and a cache tile Mosaic
-    accepts — bk a multiple of 128 (bk ceil-divides max_len; the
-    padded tail is masked) or the whole axis in one tile. A latent
+    """Shape gate: a lane-friendly head_dim, and a cache axis of whole
+    128-lane blocks, tiled in multiples of 128: the kernel's own K/V
+    copies slice that axis, and Mosaic slices it in 128s only. A
+    max_len that is no multiple of 128 (a ragged last tile, or a cache
+    shorter than a block) is refused here, by its shape, and the
+    einsum takes it. A latent
     cache (``v_dim`` > 0: values are the leading v_dim features of the
     key stream) has head_dim as the tile's sublane axis only: whole
     bf16 sublane groups, and a lane-friendly v_dim for the output."""
-    if v_dim:
-        if head_dim % _SUBLANES or v_dim % 128 or v_dim > head_dim:
-            return False
-    elif not (head_dim % 128 == 0 or head_dim == 64):
-        return False
-    if max_len < 1:
+    if not _head_dims_ok(head_dim, v_dim) or max_len < 1:
         return False
     bk = min(block_k, max_len)
-    return bk == max_len or bk % 128 == 0
+    return bk % 128 == 0 and max_len % 128 == 0
+
+
+def _head_dims_ok(head_dim: int, v_dim: int) -> bool:
+    """can_flash_decode's rule for the feature axes alone."""
+    if v_dim:
+        return not (head_dim % _SUBLANES or v_dim % 128
+                    or v_dim > head_dim)
+    return head_dim % 128 == 0 or head_dim == 64
 
 
 #: K bytes (kv_heads x head_dim x BK x itemsize) one grid step should
-#: stream at least. The tile is the granularity at which a row's
-#: context is rounded up (its steps are its live tiles,
-#: decode_work_list), so narrower reads less — until a grid step's
-#: fixed cost, ~0.25 us on top of the tile's bytes, shows. Measured at
-#: 96 rows x 16 heads x 64 x 1024 bf16 on a v5e (PERF.md, PR 30), one
-#: call with a 32-row tail on a server's mix of contexts (mean 320) /
-#: with every row at max_len: BK 512 (1 MiB) 0.321 / 0.550 ms; 256
-#: (512 KiB) 0.276 / 0.597; 128 (256 KiB) 0.300 / 0.723. (On the
-#: (batch, max_len / BK) grid before it, whose dead tiles were empty
-#: steps: 0.380 / 0.550, 0.334 / 0.599, 0.387 / 0.746.)
+#: stream. The tile is the granularity of the STEPS; since the kernel
+#: copies a row's last tile only as far as it is live (_copy_lanes) it
+#: is no longer that of the bytes, so a wide tile costs no over-read.
+#: Measured at 96 rows x 16 heads x 64 x 1024 bf16 on a v5e
+#: (benchmarks/attend_fetch_bench.py; PERF.md §6, PR 37), one call with
+#: a 32-row tail on a server's mix of contexts (mean 265) / with every
+#: row at max_len, ring of 3: BK 128 (256 KiB) 0.250 / 0.649 ms; 256
+#: (512 KiB) 0.233 / 0.635; 512 (1 MiB) 0.234 / 0.592. (The BlockSpec
+#: pipeline before it, whole tiles, one copy in flight: 0.304 / 0.773,
+#: 0.295 / 0.651, 0.348 / 0.719.) With the compute stubbed out the
+#: same copies take the same time: the step IS its bytes, at the
+#: 620-680 GB/s these copies stream at (512-byte runs of a seq-minor
+#: tile at BK 256; 819 is the chip's figure), plus ~0.05 us. What PR 30
+#: read as "a grid step's fixed cost, ~0.25 us on top of the tile's
+#: bytes" was that rate and a copy's start-up at every row end, which
+#: the ring now hides. 256 and 512 read alike on the mix; 256 keeps the
+#: ring at 3 MiB and every caller's accumulation order.
 _TILE_BYTES = 512 << 10
 
 #: the same for a latent cache (one stream of d = 576 rows a tile, 128
-#: query rows, compute at the ridge), and the widest tile it may take.
-#: Measured on a v5e at 128 rows x 576 x 4096 bf16 with a 32-row tail
-#: (PERF.md, PR 30), one call on a reasoning server's contexts (mean
-#: 1137) / every row at pos 300 / at max_len: BK 256 1.015 / 0.546 /
-#: 2.776 ms; 512 0.786 / 0.432 / 1.866; 1024 (1152 KiB) 0.755 / 0.547 /
-#: 1.504; 2048 0.849 / 0.775 / 1.322; and in the cell itself (contexts
-#: that grow from the prompts, mean ~0.9k) 512 0.634, 1024 0.611. A
-#: step here costs ~0.9 us before its bytes (max_len / steps: 1.36,
-#: 1.82, 2.94, 5.16 us a step), four times a 16-head step's, so
-#: halving the tile buys less over-read than it pays in steps unless
-#: the contexts are short. (On the (batch, max_len / BK) grid before
-#: it: 1.292 / 0.874 / 2.838, 0.931 / 0.603 / 1.898, 0.831 / 0.633 /
-#: 1.518, 0.893 / 0.827 / 1.328; the cell 0.656 at 1024.)
+#: query rows), and the widest tile it may take. A latent step
+#: COMPUTES: at 128 rows x 576 x 4096 bf16 with a 32-row tail a call
+#: takes 0.644 ms on a reasoning server's contexts (mean 940) and
+#: 1.495 with every row at max_len where the same copies with the
+#: compute stubbed out take 0.234 and 0.837 (PERF.md §6, PR 37): ~2.5
+#: us of matmul and softmax on a 1024-lane tile against 1.4 us of
+#: bytes. So the tile trades the compute on a row's dead lanes (a
+#: shorter copy does not shorten it) against the steps' own work, not
+#: bytes against steps. Ring of 3, mix / every row at 300 / at
+#: max_len: BK 256 0.834 / 0.488 / 2.715 ms; 512 0.647 / 0.374 /
+#: 1.818; 1024 (1152 KiB) 0.644 / 0.498 / 1.495; 2048 0.783 / 0.742 /
+#: 1.321. (The BlockSpec pipeline: 512 0.624 / 0.361 / 1.723; 1024
+#: 0.614 / 0.482 / 1.422. What PR 30 called "~0.9 us a step before its
+#: bytes" was this compute.) At 32 rows x 576 x 24576 under a
+#: selection (contexts 8k-20k / at max_len): 512 1.525 / 2.465; 1024
+#: 1.276 / 2.023; 2048 1.179 / 1.792 — long contexts would take 2048,
+#: a reasoning server's would not; one rule serves both until a cell
+#: says otherwise (PERF.md §7).
 _LATENT_TILE_BYTES = 1152 << 10
 _LATENT_BLOCK_K = 1024
+
+
+#: slots of the kernel's K/V ring (_ring_fetch): one being computed
+#: from and _RING_SLOTS - 1 copies in flight behind it. 3 and 4 read
+#: alike at every shape and tile (PERF.md §6, PR 37)
+_RING_SLOTS = 3
+
+#: matmul flops a byte of K/V from which a grid step computes longer
+#: than it streams: half of a v5e's ridge (197 TFLOP/s over 819 GB/s =
+#: 240). Such a step hides its bytes whoever fetches them and shows
+#: every scalar cycle, so it keeps the BlockSpec pipeline's fetch: the
+#: kernel's own costs it ~0.1 us a step (128 heads on one latent stream
+#: read 241 and ran 3-5% slower with the ring; one query a head reads
+#: 1, a block of 4 over 8 heads a kv head 32: PERF.md §6, PR 37)
+_COMPUTE_BOUND = 120
+
+
+def _ring_slots(R: int, d: int, dv: int, streams: int,
+                itemsize: int) -> int:
+    """Depth of the ring a call fetches its K/V through, by its shape:
+    _RING_SLOTS where a step streams longer than it computes — R query
+    rows a kv head against ``streams`` tensors of d features and
+    ``itemsize`` bytes, dv of them values — and 0, the pipeline's
+    whole-tile fetch, where it is _COMPUTE_BOUND."""
+    flops_a_byte = 2 * R * (d + dv) / (streams * d * itemsize)
+    return 0 if flops_a_byte >= _COMPUTE_BOUND else _RING_SLOTS
 
 
 def _tile_rule(latent: bool):
@@ -809,15 +988,23 @@ def _tile_rule(latent: bool):
             else (_BLOCK_K, _TILE_BYTES))
 
 
+def _ring_bytes(nkv: int, d: int, bk: int, itemsize: int,
+                streams: int) -> int:
+    """VMEM the kernel's K/V ring holds: _RING_SLOTS tiles a tensor
+    (``streams``: K and V, or a latent cache's one)."""
+    return _RING_SLOTS * streams * nkv * bk * d * itemsize
+
+
 def _pick_bk(L: int, d: int, nkv: int, r: int, itemsize: int,
-             block_k: int, tile_bytes: int = _TILE_BYTES) -> int:
+             block_k: int, tile_bytes: int = _TILE_BYTES,
+             streams: int = 2) -> int:
     """Cache-tile width: at most ``block_k``, no wider than streams
     _TILE_BYTES of K a grid step (finer tiles skip more of a short
-    row's dead context), and within the T=1 VMEM budget (two
-    (kvh, bk, d) tiles in the dot dtype + the f32 score/probability
-    tensors within ~10 MB). A rule of the shape alone, deliberately
-    independent of T: every block size must tile the cache identically
-    or verify/decode numerics diverge."""
+    row's dead context), and within the T=1 VMEM budget (the ring's
+    _RING_SLOTS (kvh, bk, d) tiles a tensor in the dot dtype + the f32
+    score/probability tensors within ~10 MB). A rule of the shape
+    alone, deliberately independent of T: every block size must tile
+    the cache identically or verify/decode numerics diverge."""
     bk = min(block_k, max(L, 1))
     if bk > 128:
         bk = min(bk, max(128, tile_bytes // (nkv * d * itemsize)
@@ -827,7 +1014,7 @@ def _pick_bk(L: int, d: int, nkv: int, r: int, itemsize: int,
         # the whole cache operand (materialized XLA pads per step)
         while bk > 128 and L % bk:
             bk -= 128
-    while bk > 128 and (2 * nkv * bk * d * itemsize
+    while bk > 128 and (_ring_bytes(nkv, d, bk, itemsize, streams)
                         + 2 * nkv * r * bk * 4) > (10 << 20):
         # halve, but stay on the multiple-of-128 grid can_flash_decode
         # gated on (e.g. 384 -> 192 would fail Mosaic tiling; use 128)
@@ -847,7 +1034,37 @@ def flash_decode_tile(k_cache, n_heads: int, latent: bool = False) -> int:
     _, nkv, d, L = k_cache.shape
     itemsize = 4 if k_cache.dtype == jnp.float32 else 2
     return _pick_bk(L, d, nkv, n_heads // nkv, itemsize,
-                    *_tile_rule(latent))
+                    *_tile_rule(latent), streams=1 if latent else 2)
+
+
+def flash_decode_slots(k_cache, n_heads: int, v_dim: int = 0,
+                       T: int = 1) -> int:
+    """Slots of the K/V ring flash_decode / flash_block_decode fetch a
+    (b, kv_heads, head_dim, max_len) cache through for T queries a row
+    (_ring_slots; one less is the copies a tensor in flight; ``v_dim``
+    as the calls take it: a latent cache's value width), 0 where the
+    pipeline fetches whole tiles. For a server's
+    ``serve.attend_fetch_depth``, and decode_lanes_fetched."""
+    _, nkv, d, _ = k_cache.shape
+    return _ring_slots(T * n_heads // nkv, d, v_dim or d,
+                       1 if v_dim else 2, k_cache.dtype.itemsize)
+
+
+def decode_lanes_fetched(pos, T: int, bk: int, max_len: int,
+                         slots: int = _RING_SLOTS):
+    """The cache positions ONE call's K/V fetch moves for each row of
+    ``pos`` (rows,), on the host: the row's tiles before its last
+    whole, the last by _copy_lanes where the kernel copies for itself
+    (``slots``: flash_decode_slots) — the kernel's own rule, exported
+    as flash_decode_tile is, for callers that count (DecodeServer's
+    ``serve.attend_lanes_fetched``) — and whole too where it does
+    not."""
+    pos = np.asarray(pos, np.int64)
+    last = np.clip((pos + (T - 1)) // bk, 0, -(-max_len // bk) - 1)
+    if not slots:
+        return np.minimum((last + 1) * bk, max_len)
+    return last * bk + _copy_lanes(np.minimum(pos + T, max_len), last,
+                                   bk, max_len, xp=np)
 
 
 def _last_live_tile(pos, T: int, bk: int, n_k: int):
@@ -889,11 +1106,14 @@ def decode_work_list(pos, T: int, bk: int, n_k: int):
 
 def _block_fits_vmem(L: int, d: int, nkv: int, r: int, T: int,
                      itemsize: int, block_k: int = _BLOCK_K,
-                     tile_bytes: int = _TILE_BYTES) -> bool:
+                     tile_bytes: int = _TILE_BYTES,
+                     streams: int = 2) -> bool:
     """Whether a T-query block fits VMEM at the T=1 tile size (the
-    only tile size that preserves shared numerics with plain decode)."""
-    bk = _pick_bk(L, d, nkv, r, itemsize, block_k, tile_bytes)
-    return (2 * nkv * bk * d * itemsize + 2 * nkv * T * r * bk * 4
+    only tile size that preserves shared numerics with plain decode):
+    the K/V ring, the f32 scores and probabilities, the accumulator."""
+    bk = _pick_bk(L, d, nkv, r, itemsize, block_k, tile_bytes, streams)
+    return (_ring_bytes(nkv, d, bk, itemsize, streams)
+            + 2 * nkv * T * r * bk * 4
             + nkv * T * r * d * 4) <= (14 << 20)
 
 
@@ -992,7 +1212,7 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     if block_k is None:
         block_k = widest
     if latent != bool(v_dim) or (latent and (
-            quant or not can_flash_decode(L, d, block_k, v_dim))):
+            quant or not _head_dims_ok(d, v_dim))):
         raise ValueError(
             f"flash_block_decode: a latent cache comes without v_cache "
             f"and scales and with v_dim (a 128-multiple <= head_dim "
@@ -1015,60 +1235,91 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     # would differ from plain decode's, breaking the shared-numerics
     # guarantee speculative losslessness rests on.
     itemsize = 4 if k_cache.dtype == jnp.float32 else 2
-    bk = _pick_bk(L, d, nkv, r, itemsize, block_k, tile_bytes)
+    streams = 1 if latent else 2
+    bk = _pick_bk(L, d, nkv, r, itemsize, block_k, tile_bytes, streams)
     # the T-scaled tensors at that same bk must still fit VMEM; a
     # block too big to share the T=1 tiling cannot share numerics, so
     # refuse rather than silently retile (caller falls back to einsum)
     if not _block_fits_vmem(L, d, nkv, r, T, itemsize, block_k,
-                            tile_bytes):
+                            tile_bytes, streams):
         raise ValueError(
             f"flash_block_decode: T={T} block exceeds the VMEM budget "
             f"at the T=1 tile size bk={bk} (nkv={nkv}, r={r}, d={d}) "
             f"— use the einsum block attend for this shape")
     n_k = -(-L // bk)
-
-    # t-major query rows: row t*r + rr = block token t, group member rr
-    qg = (q.reshape(b, T, nkv, r, d).transpose(0, 2, 1, 3, 4)
-          .reshape(b, nkv, R, d))
     posv = jnp.asarray(pos0, jnp.int32)
     posv = jnp.full((b,), posv) if posv.ndim == 0 else posv.reshape(b)
     if work is None:
         work = decode_work_list(posv, T, bk, n_k)
-    row_of, tile_of, n_work = work
-    if row_of.shape != (b * n_k + 1,) or tile_of.shape != row_of.shape:
+    if work[0].shape != (b * n_k + 1,) or work[1].shape != work[0].shape:
         raise ValueError(
             f"flash_block_decode: a work list for {b} rows of {n_k} "
-            f"tiles has {b * n_k + 1} entries, got {row_of.shape} and "
-            f"{tile_of.shape}")
-    # the list, then pos, are scalar-prefetched: the index maps read
-    # step i's (row, tile) from it, so the pipeline's look-ahead has
-    # step i + 1's tile — the next row's first tile, its q and its
-    # tail rows included — under way behind step i. Past the last
-    # pair it reads the sentinel, clamped to a block that exists.
-    # (A tail brings one more prefetched scalar, ``newest``; no index
-    # map reads it or pos.)
+            f"tiles has {b * n_k + 1} entries, got {work[0].shape} and "
+            f"{work[1].shape}")
+    if tail is not None:
+        tail = (tk, tv, jnp.asarray(newest, jnp.int32).reshape(1))
+    return _flash_call(q, k_cache, v_cache, posv, k_scale, v_scale, tail,
+                       tuple(work), select, scale=float(scale), bk=bk,
+                       n_slots=_ring_slots(R, d, dv, streams,
+                                           k_cache.dtype.itemsize),
+                       interpret=bool(interpret), v_dim=v_dim,
+                       block_len=block_len)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "bk", "n_slots", "interpret", "v_dim", "block_len"))
+def _flash_call(q, k_cache, v_cache, posv, k_scale, v_scale, tail, work,
+                select, *, scale: float, bk: int, n_slots: int,
+                interpret: bool, v_dim: int, block_len: int):
+    """flash_block_decode's kernel call, its shapes and tiling settled.
+    A jitted function of its own so that a program traces and lowers
+    the kernel body ONCE for all the layers that call it alike, and a
+    process once for all its programs, instead of once a call: the
+    body holds the fetch's loop and switches beside the attend, and
+    every round, admission and check program calls it in every layer
+    (PERF.md §6, PR 36 and 37: set-up is an end-to-end metric)."""
+    b, T, nh, d = q.shape
+    nkv, L = k_cache.shape[1], k_cache.shape[3]
+    r = nh // nkv
+    R = T * r
+    quant = k_scale is not None
+    latent = v_cache is None
+    streams = 1 if latent else 2
+    dv = v_dim or d
+    n_tail = 0 if tail is None else tail[0].shape[0]
+    # t-major query rows: row t*r + rr = block token t, group member rr
+    qg = (q.reshape(b, T, nkv, r, d).transpose(0, 2, 1, 3, 4)
+          .reshape(b, nkv, R, d))
+    row_of, tile_of, n_work = work
+    # the list, then pos, are scalar-prefetched: the kernel's own K/V
+    # copies read steps i .. i + n_slots - 1's (row, tile) from it
+    # (and pos, for a row's last copy), the index maps of what stays
+    # on BlockSpecs step i's, so the pipeline's look-ahead has the
+    # next row's q and tail rows under way behind step i. Past the
+    # last pair an index map reads the sentinel, clamped to a block
+    # that exists. (A tail brings one more prefetched scalar,
+    # ``newest``; no index map reads it or pos.)
     row_map = lambda i, row_ref, tile_ref, pos_ref, _newest=None: (  # noqa: E731
         jnp.minimum(row_ref[i], b - 1), 0, 0, 0)
     cache_map = lambda i, row_ref, tile_ref, pos_ref, _newest=None: (  # noqa: E731
         jnp.minimum(row_ref[i], b - 1), 0, 0, tile_ref[i])
-    kv_spec = pl.BlockSpec((1, nkv, d, bk), cache_map)
+    # K and V stay where they are and the kernel copies its own tiles
+    # (_ring_fetch) — or, for a step that computes longer than it
+    # streams (n_slots 0: _ring_slots), come tile by tile on the list
+    kv_spec = (pl.BlockSpec(memory_space=pl.ANY) if n_slots
+               else pl.BlockSpec((1, nkv, d, bk), cache_map))
     in_specs = [pl.BlockSpec((1, nkv, R, d), row_map), kv_spec]
     args = [qg, k_cache]
     if not latent:
         in_specs += [kv_spec]
         args += [v_cache]
-    if quant:
-        # scales reshaped (b, kvh, 1, L): the (1, bk) trailing block
-        # dims satisfy Mosaic's tiling rule for any bk multiple of 128
-        s_spec = pl.BlockSpec((1, nkv, 1, bk), cache_map)
-        in_specs += [s_spec, s_spec]
-        args += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
     # inside shard_map (vma typing) every kernel operand must carry
     # the same varying-axes set: the replicated scalars (the list,
     # pos, the grid's bound) ride along with the tp-sharded q/cache
     from rlo_tpu.parallel.mesh import vary_like
     scalars = [row_of, tile_of, posv]
     if n_tail:
+        tk, tv, newest = tail
         t_spec = pl.BlockSpec(
             (n_tail, 1, nkv, d),
             lambda i, row_ref, tile_ref, pos_ref, _newest: (
@@ -1076,11 +1327,17 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
         for rows in (tk,) if latent else (tk, tv):
             in_specs += [t_spec]
             args += [vary_like(rows.astype(k_cache.dtype), k_cache)]
-        scalars += [jnp.asarray(newest, jnp.int32).reshape(1)]
+        scalars += [newest]
     if select is not None:
         in_specs += [pl.BlockSpec((1, 1, 1, bk), cache_map)]
         args += [vary_like(select.astype(jnp.float32)[:, None, None, :],
                            k_cache)]
+    if quant:
+        # scales reshaped (b, kvh, 1, L): the (1, bk) trailing block
+        # dims satisfy Mosaic's tiling rule for any bk multiple of 128
+        s_spec = pl.BlockSpec((1, nkv, 1, bk), cache_map)
+        in_specs += [s_spec, s_spec]
+        args += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
     scalars = [vary_like(vary_like(x, q), k_cache) for x in scalars]
     n_work = vary_like(vary_like(n_work, q), k_cache)
 
@@ -1095,14 +1352,17 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
         out_specs=pl.BlockSpec((1, nkv, R, dv), row_map),
         scratch_shapes=[pltpu.VMEM((nkv, R), jnp.float32),
                         pltpu.VMEM((nkv, R), jnp.float32),
-                        pltpu.VMEM((nkv, R, dv), jnp.float32)],
+                        pltpu.VMEM((nkv, R, dv), jnp.float32)]
+        + ([pltpu.VMEM((n_slots, nkv, d, bk), k_cache.dtype)] * streams
+           + [pltpu.SemaphoreType.DMA((streams, n_slots))]
+           if n_slots else []),
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=float(scale),
+        functools.partial(_decode_kernel, scale=scale,
                           bk=bk, max_len=L, quant=quant, r=r, T=T,
                           v_dim=v_dim, n_tail=n_tail,
                           selected=select is not None,
-                          block_len=block_len),
+                          block_len=block_len, n_slots=n_slots),
         grid_spec=grid_spec,
         out_shape=out_struct((b, nkv, R, dv), jnp.float32, q, k_cache),
         interpret=interpret,

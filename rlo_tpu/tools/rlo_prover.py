@@ -47,7 +47,14 @@ catalogue (docs/DESIGN.md §16):
      every operand — including hostile scalar-prefetch values (an
      out-of-range slot position / page id must be clamped to a legal
      block, the paged NULL-page-0 discipline).  Aliased outputs must
-     shape-match their input.
+     shape-match their input.  An operand with no block and no index
+     map (``memory_space=pl.ANY``: flash_block_decode's K and V,
+     which the kernel copies for itself) is proven by walking the
+     kernel's own fetch over the probe's work list with the module's
+     ``_ring_span`` / ``_copy_lanes`` / ``_copy_sizes``: every copy's
+     source slice inside the operand, every size one the kernel holds
+     a copy for, every step's copy started once, before its wait,
+     into a free slot, none past the list.
   P4 shard_map axis discipline — axis names consumed by
      ``lax.ppermute/psum/pmin/...`` or the ``tpu_collectives``
      wrappers inside per-shard code must flow from a parameter, never
@@ -90,7 +97,7 @@ import importlib.util
 import itertools
 import math
 import sys
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
@@ -909,17 +916,34 @@ class StubModule:
 
 
 class BlockSpecVal:
-    def __init__(self, block, index_map):
+    def __init__(self, block, index_map, memory_space=None):
         self.block = block          # tuple of ints (or OPAQUE)
         self.index_map = index_map  # ClosureVal or None
+        self.memory_space = memory_space
+
+    @property
+    def unblocked(self) -> bool:
+        """``memory_space=pl.ANY``: the operand stays where it is and
+        the kernel copies from it itself — no block, no index map."""
+        return self.memory_space == ("stub", "pl", "ANY")
+
+
+class PartialVal:
+    """``functools.partial(kernel, **static)``: the kernel body is never
+    executed, but its static parameters say what its own copies do."""
+
+    def __init__(self, kwargs):
+        self.kwargs = kwargs
 
 
 class GridSpecVal:
-    def __init__(self, grid, in_specs, out_specs, num_scalar_prefetch):
+    def __init__(self, grid, in_specs, out_specs, num_scalar_prefetch,
+                 scratch_shapes=()):
         self.grid = grid
         self.in_specs = in_specs
         self.out_specs = out_specs
         self.num_scalar_prefetch = num_scalar_prefetch
+        self.scratch_shapes = scratch_shapes
 
 
 class ShapeStructVal:
@@ -939,13 +963,19 @@ class KernelSite:
     operands: List[object]
     num_scalar_prefetch: int
     aliases: Dict[int, int]
+    #: the kernel's static parameters, its VMEM scratch shapes and the
+    #: module's helpers: what a kernel that copies for itself goes by
+    static: Dict[str, object] = field(default_factory=dict)
+    scratch: List[Tuple[int, ...]] = field(default_factory=list)
+    env: Dict[str, object] = field(default_factory=dict)
 
 
 class PallasCallable:
-    def __init__(self, interp, line, kwargs):
+    def __init__(self, interp, line, kwargs, kernel=None):
         self.interp = interp
         self.line = line
         self.kwargs = kwargs
+        self.kernel = kernel
 
     def __call__(self, *operands):
         kw = self.kwargs
@@ -954,7 +984,9 @@ class PallasCallable:
             grid, in_specs, out_specs = gs.grid, gs.in_specs, \
                 gs.out_specs
             npf = gs.num_scalar_prefetch
+            scratch = gs.scratch_shapes
         else:
+            scratch = kw.get("scratch_shapes", ())
             grid = kw.get("grid", OPAQUE)
             in_specs, out_specs = kw.get("in_specs", OPAQUE), \
                 kw.get("out_specs", OPAQUE)
@@ -971,7 +1003,14 @@ class PallasCallable:
             in_specs=in_specs if isinstance(in_specs, list) else [],
             out_specs=spec_list, out_shapes=out_list,
             operands=list(operands), num_scalar_prefetch=npf,
-            aliases=aliases if isinstance(aliases, dict) else {}))
+            aliases=aliases if isinstance(aliases, dict) else {},
+            static=self.kernel.kwargs
+            if isinstance(self.kernel, PartialVal) else {},
+            scratch=[x.shape for x in scratch
+                     if isinstance(x, ShapeStructVal)
+                     and x.shape is not OPAQUE]
+            if isinstance(scratch, (list, tuple)) else [],
+            env=self.interp.module_env))
         outs = [ArrayVal(o.shape) if isinstance(o, ShapeStructVal)
                 and o.shape is not OPAQUE else OPAQUE
                 for o in out_list]
@@ -1272,6 +1311,10 @@ class Interp:
         fn = self._BINOPS.get(type(op))
         if fn is None:
             return OPAQUE
+        if isinstance(a, list) and (
+                isinstance(op, ast.Add) and isinstance(b, list)
+                or isinstance(op, ast.Mult) and isinstance(b, int)):
+            return fn(a, b)
         return _elemwise(fn, a, b)
 
     def eval(self, node, env):
@@ -1529,20 +1572,23 @@ class Interp:
                 block = args[0] if args else kwargs.get("block_shape")
                 imap = args[1] if len(args) > 1 else \
                     kwargs.get("index_map")
-                return BlockSpecVal(block, imap)
+                return BlockSpecVal(block, imap,
+                                    kwargs.get("memory_space"))
             if attr == "cdiv":
                 if _is_op(*args):
                     return OPAQUE
                 return -(-args[0] // args[1])
             if attr == "pallas_call":
-                return PallasCallable(self, node.lineno, kwargs)
+                return PallasCallable(self, node.lineno, kwargs,
+                                      args[0] if args else None)
         if mod == "pltpu":
             if attr == "PrefetchScalarGridSpec":
                 return GridSpecVal(
                     kwargs.get("grid", OPAQUE),
                     kwargs.get("in_specs", OPAQUE),
                     kwargs.get("out_specs", OPAQUE),
-                    kwargs.get("num_scalar_prefetch", 0))
+                    kwargs.get("num_scalar_prefetch", 0),
+                    kwargs.get("scratch_shapes", ()))
             if attr == "VMEM":
                 return ShapeStructVal(args[0]) if args and \
                     not _is_op(args[0]) else OPAQUE
@@ -1556,7 +1602,8 @@ class Interp:
             except Exception:
                 return OPAQUE
         if mod == "functools" and attr == "partial":
-            return OPAQUE  # the kernel body itself is never executed
+            # the kernel body itself is never executed
+            return PartialVal(kwargs)
         return OPAQUE
 
 
@@ -1863,6 +1910,98 @@ def _check_spec_against(f: List[Finding], site: KernelSite,
                 return
 
 
+def _check_ring_fetch(f: List[Finding], site: KernelSite, which: str,
+                      operand, scalar_ops) -> None:
+    """An operand the kernel copies for itself (pallas.decode
+    ._ring_fetch): walk the grid as the kernel does — step i starts the
+    copies of the steps _ring_span gives it, then waits for its own —
+    with the module's own _ring_span, _copy_lanes and _copy_sizes, over
+    the site's work list, and prove every copy's source slice inside
+    the operand, every size one the kernel holds a copy for, every
+    step's copy started once, before its wait, into a slot whose last
+    tile has been computed from, and no step named past the list."""
+    where = f"{site.func} {which}"
+
+    def bad(msg):
+        f.append(Finding("P3", site.file, site.line, f"{where}: {msg}"))
+
+    fns = [site.env.get(n) for n in ("_ring_span", "_copy_lanes",
+                                     "_copy_sizes")]
+    bk, max_len, T = (site.static.get(k) for k in ("bk", "max_len", "T"))
+    rings = [x for x in site.scratch if len(x) == 4]
+    if not all(isinstance(fn, ClosureVal) for fn in fns) or \
+            not all(isinstance(v, int) for v in (bk, max_len, T)) or \
+            not rings or not isinstance(operand, ArrayVal) or \
+            operand.ndim != 4 or len(scalar_ops) < 3 or \
+            any(not isinstance(op, ArrayVal) or op.data is None
+                for op in scalar_ops[:3]):
+        return bad("an unblocked (pl.ANY) operand whose copies did not "
+                   "ground: the kernel's bk / max_len / T, its ring "
+                   "scratch, the work list or the module's _ring_span "
+                   "/ _copy_lanes / _copy_sizes")
+    def module_fn(fn):
+        def call(*args):
+            try:
+                return fn(*args)
+            except _Return as r:
+                return r.value
+        return call
+
+    span, copy_lanes, copy_sizes = map(module_fn, fns)
+    row_of, tile_of, pos = (op.data for op in scalar_ops[:3])
+    b, L = operand.shape[0], operand.shape[3]
+    n_slots, n_work = rings[0][0], site.grid[0]
+    if any(r != (n_slots,) + operand.shape[1:3] + (bk,) for r in rings) \
+            or L != max_len:
+        return bad(f"ring slots {rings} do not hold (n_slots, "
+                   f"{operand.shape[1]}, {operand.shape[2]}, bk={bk}) "
+                   f"tiles of an operand {operand.shape}")
+    sizes = copy_sizes(bk, max_len)
+    if not isinstance(sizes, list) or \
+            any(not isinstance(n, int) for n in sizes):
+        return bad(f"_copy_sizes did not ground ({sizes!r})")
+    started = 0        # steps 0 .. started - 1 have had their copies started
+    for i in range(n_work):
+        lo, hi = span(i, n_work, n_slots)
+        if not isinstance(lo, int) or not isinstance(hi, int):
+            return bad(f"_ring_span did not ground at step {i}")
+        for j in range(lo, hi):
+            if j >= n_work:
+                return bad(f"step {i} starts the copy of step {j}, past "
+                           f"the work list's {n_work} steps")
+            if j != started:
+                return bad(f"step {i} starts step {j}'s copy, expected "
+                           f"step {started}'s: a copy started twice or "
+                           f"never")
+            if j - n_slots >= i:
+                return bad(f"step {i} starts step {j}'s copy into the "
+                           f"slot step {j - n_slots} has not been "
+                           f"computed from yet ({n_slots} slots)")
+            started += 1
+            row, tile = row_of[j], tile_of[j]
+            if not 0 <= row < b:
+                return bad(f"step {j}'s copy reads row {row} of {b}")
+            lanes = copy_lanes(min(pos[row] + T, max_len), tile, bk,
+                               max_len)
+            if not isinstance(lanes, int) or lanes not in sizes:
+                return bad(f"step {j} copies {lanes!r} lanes, not one "
+                           f"of the kernel's static sizes {sizes}: "
+                           f"nothing would be started and its wait "
+                           f"never ends")
+            if tile < 0 or lanes < 1 or tile * bk + lanes > L:
+                return bad(f"step {j}'s copy [{tile * bk}, "
+                           f"{tile * bk + lanes}) of row {row} runs "
+                           f"outside the operand's {L} positions")
+            if (tile * bk % LANE or lanes % LANE) and lanes != L:
+                return bad(f"step {j}'s copy [{tile * bk}, "
+                           f"{tile * bk + lanes}) is neither whole "
+                           f"{LANE}-lane blocks nor the whole axis")
+        if started <= i:
+            return bad(f"step {i} waits for a copy no step has started")
+    if started != n_work:
+        bad(f"{started} of {n_work} steps' copies were started")
+
+
 def _check_site(f: List[Finding], site: KernelSite) -> None:
     grid = site.grid
     if _is_op(grid) or not isinstance(grid, tuple) or \
@@ -1890,6 +2029,9 @@ def _check_site(f: List[Finding], site: KernelSite) -> None:
             f"{site.func}: {len(site.in_specs)} in_specs but "
             f"{len(data_ops)} data operands"))
     for i, (spec, op) in enumerate(zip(site.in_specs, data_ops)):
+        if isinstance(spec, BlockSpecVal) and spec.unblocked:
+            _check_ring_fetch(f, site, f"in_specs[{i}]", op, scalar_ops)
+            continue
         _check_spec_against(f, site, f"in_specs[{i}]", spec, op, grid,
                             refs)
     if len(site.out_specs) != len(site.out_shapes):
@@ -1927,31 +2069,12 @@ def rule_p3(ctx: ProverContext) -> List[Finding]:
     f: List[Finding] = []
     probes = P3_PROBES
     probed = {(p.file, p.func) for p in probes}
-    # coverage: every pallas_call in the pallas package must sit in a
-    # probed function — a new kernel without a probe is a finding, not
-    # a silent gap
     funcs: Dict[Tuple[str, str], ast.FunctionDef] = {}
     for rel in PALLAS_FILES:
-        mod = ctx.mod(rel)
-        for node in mod.tree.body:
+        for node in ctx.mod(rel).tree.body:
             if isinstance(node, ast.FunctionDef):
                 funcs[(rel, node.name)] = node
-        for node in ast.walk(mod.tree):
-            if isinstance(node, ast.Call) and \
-                    isinstance(node.func, ast.Attribute) and \
-                    node.func.attr == "pallas_call":
-                owner = None
-                for (r, name), fn in funcs.items():
-                    if r == rel and fn.lineno <= node.lineno <= \
-                            max(getattr(fn, "end_lineno", fn.lineno),
-                                fn.lineno):
-                        owner = (r, name)
-                if owner is None or owner not in probed:
-                    f.append(Finding(
-                        "P3", rel, node.lineno,
-                        f"pallas_call outside any probed wrapper "
-                        f"(enclosing: {owner and owner[1]}) — add a "
-                        f"P3_PROBES entry so its geometry is proven"))
+    reached: Set[Tuple[str, int]] = set()   # call sites a probe grounded
     for probe in probes:
         mod = ctx.mod(probe.file)
         fn = funcs.get((probe.file, probe.func))
@@ -1976,7 +2099,30 @@ def rule_p3(ctx: ProverContext) -> List[Finding]:
                     f"expected {want} — the wrapper no longer "
                     f"evaluates under the committed shapes"))
             for site in interp.sites:
+                reached.add((site.file, site.line))
                 _check_site(f, site)
+    # coverage: every pallas_call in the pallas package must sit in a
+    # probed function, or in a helper a probed wrapper's run reached
+    # (a jitted call function of its own) — a new kernel without a
+    # probe is a finding, not a silent gap
+    for rel in PALLAS_FILES:
+        for node in ast.walk(ctx.mod(rel).tree):
+            if isinstance(node, ast.Call) and \
+                    isinstance(node.func, ast.Attribute) and \
+                    node.func.attr == "pallas_call":
+                owner = None
+                for (r, name), fn in funcs.items():
+                    if r == rel and fn.lineno <= node.lineno <= \
+                            max(getattr(fn, "end_lineno", fn.lineno),
+                                fn.lineno):
+                        owner = (r, name)
+                if owner not in probed and \
+                        (rel, node.lineno) not in reached:
+                    f.append(Finding(
+                        "P3", rel, node.lineno,
+                        f"pallas_call outside any probed wrapper "
+                        f"(enclosing: {owner and owner[1]}) — add a "
+                        f"P3_PROBES entry so its geometry is proven"))
     return f
 
 

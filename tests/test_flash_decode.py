@@ -128,9 +128,14 @@ def test_int8_padded_tail(data):
 
 def test_gate():
     assert can_flash_decode(1024, 64)
-    assert can_flash_decode(16, 128)
-    assert not can_flash_decode(16, 80)  # lane-hostile head_dim
-    assert can_flash_decode(1216, 64)  # non-dividing L: padded tail
+    assert can_flash_decode(128, 128)
+    assert not can_flash_decode(128, 80)  # lane-hostile head_dim
+    assert can_flash_decode(1280, 64)   # any whole number of 128s
+    # the kernel's own K/V copies slice the cache axis, and Mosaic
+    # slices it in 128s only: a ragged last tile (1216 = 9.5 x 128) or
+    # a cache shorter than one block goes to the einsum, by its shape
+    assert not can_flash_decode(1216, 64)
+    assert not can_flash_decode(16, 128)
     assert not can_flash_decode(0, 64)
 
 
@@ -630,14 +635,16 @@ def test_tail_fold_drops_what_row_writes_drop(kind, path, monkeypatch):
                                   np.asarray(cache)[3:])
 
 
-# the call as PR 29 left it, before the grid became a work list: the
-# kernel (verbatim but for comments), its (b, n_k) grid over max_len
-# and the index maps that parked a row's dead steps on the next row's
-# first tile. What "same work, same answers" is held to.
-def _pr29_decode_kernel(pos_ref, *refs, scale: float,
-                   n_k: int, bk: int, max_len: int, quant: bool,
-                   r: int, T: int, v_dim: int = 0, n_tail: int = 0):
-    if n_tail:          # a second prefetched scalar: the tail's newest row
+# the call as PR 36 left it, before the kernel fetched its own K/V: the
+# kernel (verbatim but for comments) with K, V and the scales on
+# BlockSpecs whose index maps read the work list, double-buffered by the
+# pipeline. What "same tile, same bits" is held to (ROADMAP D14: the one
+# oracle; the (b, n_k) grid of PR 29 that stood here went with it).
+def _pr36_decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
+                   bk: int, max_len: int, quant: bool,
+                   r: int, T: int, v_dim: int = 0, n_tail: int = 0,
+                   selected: bool = False, block_len: int = 0):
+    if n_tail:          # a fourth prefetched scalar: the tail's newest row
         newest_ref, refs = refs[0], refs[1:]
     q_ref, k_ref, *rest = refs
     if not v_dim:       # a V operand; a latent cache has none
@@ -646,12 +653,15 @@ def _pr29_decode_kernel(pos_ref, *refs, scale: float,
         tk_ref, rest = rest[0], rest[1:]
         if not v_dim:
             tv_ref, rest = rest[0], rest[1:]
+    if selected:        # the positions this row may attend, 1.0 or 0.0
+        sel_ref, rest = rest[0], rest[1:]
     if quant:
         ks_ref, vs_ref, o_ref, m_s, l_s, o_s = rest
     else:
         o_ref, m_s, l_s, o_s = rest
-    ib = pl.program_id(0)
-    ik = pl.program_id(1)
+    i = pl.program_id(0)
+    ib = row_ref[i]
+    ik = tile_ref[i]
     dot_dt = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
 
     @pl.when(ik == 0)
@@ -683,44 +693,47 @@ def _pr29_decode_kernel(pos_ref, *refs, scale: float,
 
     pos = pos_ref[ib]
 
-    @pl.when(ik <= decode_mod._last_live_tile(pos, T, bk, n_k))
-    def _attend():
-        q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
-        k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
-        v = (k[:, :v_dim, :] if v_dim
-             else v_ref[0].astype(dot_dt))               # (g, d, BK)
-        base = ik * bk
-        row = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
-        qoff = jax.lax.broadcasted_iota(jnp.int32, (1, T * r, 1), 1) // r
-        mask_row = (row <= pos + qoff) & (row < max_len)  # (1, T*r, BK)
-        mask_col = (row <= pos + (T - 1)) & (row < max_len)  # (1, 1, BK)
 
-        s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * scale
-        if quant:
-            s = s * ks_ref[0]                            # (g, 1, BK)
-        s = jnp.where(mask_row, s, decode_mod._NEG)                 # (g, T*r, BK)
-        v = jnp.where(mask_col, v, jnp.zeros((), dot_dt))
+    q = q_ref[0].astype(dot_dt)                      # (g, T*r, d)
+    k = k_ref[0].astype(dot_dt)                      # (g, d, BK)
+    v = (k[:, :v_dim, :] if v_dim
+         else v_ref[0].astype(dot_dt))               # (g, d, BK)
+    base = ik * bk
+    row = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+    qoff = jax.lax.broadcasted_iota(jnp.int32, (1, T * r, 1), 1) // r
+    if block_len:
+        qoff = qoff // block_len * block_len + (block_len - 1)
+    mask_row = (row <= pos + qoff) & (row < max_len)  # (1, T*r, BK)
+    if selected:        # a token selector's choice, every head the same
+        mask_row = mask_row & (sel_ref[0] > 0.0)     # (1, 1, BK)
+    mask_col = (row <= pos + (T - 1)) & (row < max_len)  # (1, 1, BK)
 
-        m = m_s[...]                                     # (g, T*r)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.where(mask_row, jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.exp(m - m_new)
-        m_s[...] = m_new
-        l_s[...] = l_s[...] * corr + p.sum(axis=-1)
-        pv = jnp.where(mask_row, p * vs_ref[0], 0.0) if quant else p
-        o_s[...] = o_s[...] * corr[..., None] + jax.lax.dot_general(
-            pv.astype(dot_dt), v, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+    s = jax.lax.dot_general(q, k, (((2,), (1,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32) * scale
+    if quant:
+        s = s * ks_ref[0]                            # (g, 1, BK)
+    s = jnp.where(mask_row, s, decode_mod._NEG)                 # (g, T*r, BK)
+    v = jnp.where(mask_col, v, jnp.zeros((), dot_dt))
 
-    @pl.when(ik == n_k - 1)
+    m = m_s[...]                                     # (g, T*r)
+    m_new = jnp.maximum(m, s.max(axis=-1))
+    p = jnp.where(mask_row, jnp.exp(s - m_new[..., None]), 0.0)
+    corr = jnp.exp(m - m_new)
+    m_s[...] = m_new
+    l_s[...] = l_s[...] * corr + p.sum(axis=-1)
+    pv = jnp.where(mask_row, p * vs_ref[0], 0.0) if quant else p
+    o_s[...] = o_s[...] * corr[..., None] + jax.lax.dot_general(
+        pv.astype(dot_dt), v, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(row_ref[i + 1] != ib)
     def _flush():
         o_ref[0] = o_s[...] / l_s[...][..., None]
 
 
-def _pr29_flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
+def _pr36_flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
                              v_scale=None, *, block_k=None, v_dim=0,
-                             tail=None):
+                             tail=None, select=None, block_len=0):
     b, T, nh, d = q.shape
     nkv, L_ = k_cache.shape[1], k_cache.shape[3]
     r, R, dv = nh // nkv, T * (nh // nkv), v_dim or d
@@ -728,47 +741,47 @@ def _pr29_flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     widest, tile_bytes = decode_mod._tile_rule(latent)
     itemsize = 4 if k_cache.dtype == jnp.float32 else 2
     bk = decode_mod._pick_bk(L_, d, nkv, r, itemsize, block_k or widest,
-                             tile_bytes)
+                             tile_bytes, streams=1 if latent else 2)
     n_k = -(-L_ // bk)
     qg = (q.reshape(b, T, nkv, r, d).transpose(0, 2, 1, 3, 4)
           .reshape(b, nkv, R, d))
     posv = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32), (b,))
-
-    def cache_block(ib, ik, pos_ref, _newest=None):
-        last = decode_mod._last_live_tile(pos_ref[ib], T, bk, n_k)
-        ahead = (ik > last) & (ib + 1 < b)
-        return (jnp.where(ahead, ib + 1, ib), 0, 0,
-                jnp.where(ahead, 0, jnp.minimum(ik, last)))
-
-    def row_map(ib, ik, pos_ref, _newest=None):
-        return ib, 0, 0, 0
-
-    kv_spec = pl.BlockSpec((1, nkv, d, bk), cache_block)
+    row_of, tile_of, n_work = decode_work_list(posv, T, bk, n_k)
+    row_map = lambda i, row_ref, tile_ref, pos_ref, _newest=None: (  # noqa: E731
+        jnp.minimum(row_ref[i], b - 1), 0, 0, 0)
+    cache_map = lambda i, row_ref, tile_ref, pos_ref, _newest=None: (  # noqa: E731
+        jnp.minimum(row_ref[i], b - 1), 0, 0, tile_ref[i])
+    kv_spec = pl.BlockSpec((1, nkv, d, bk), cache_map)
     in_specs = [pl.BlockSpec((1, nkv, R, d), row_map), kv_spec]
-    args, scalars, n_tail = [qg, k_cache], [posv], 0
+    args, scalars, n_tail = [qg, k_cache], [row_of, tile_of, posv], 0
     if not latent:
         in_specs += [kv_spec]
         args += [v_cache]
     if quant:
-        s_spec = pl.BlockSpec((1, nkv, 1, bk), cache_block)
+        s_spec = pl.BlockSpec((1, nkv, 1, bk), cache_map)
         in_specs += [s_spec, s_spec]
         args += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
     if tail is not None:
         tk, tv, newest = tail
         n_tail = tk.shape[0]
         t_spec = pl.BlockSpec(
-            (n_tail, 1, nkv, d), lambda ib, ik, pos_ref, _newest: (
-                0, jnp.where((ik > 0) & (ib + 1 < b), ib + 1, ib), 0, 0))
+            (n_tail, 1, nkv, d),
+            lambda i, row_ref, tile_ref, pos_ref, _newest: (
+                0, jnp.minimum(row_ref[i], b - 1), 0, 0))
         for rows in (tk,) if latent else (tk, tv):
             in_specs += [t_spec]
             args += [rows.astype(k_cache.dtype)]
         scalars += [jnp.asarray(newest, jnp.int32).reshape(1)]
+    if select is not None:
+        in_specs += [pl.BlockSpec((1, 1, 1, bk), cache_map)]
+        args += [select.astype(jnp.float32)[:, None, None, :]]
     out = pl.pallas_call(
-        functools.partial(_pr29_decode_kernel, scale=float(scale), n_k=n_k,
-                          bk=bk, max_len=L_, quant=quant, r=r, T=T,
-                          v_dim=v_dim, n_tail=n_tail),
+        functools.partial(_pr36_decode_kernel, scale=float(scale), bk=bk,
+                          max_len=L_, quant=quant, r=r, T=T, v_dim=v_dim,
+                          n_tail=n_tail, selected=select is not None,
+                          block_len=block_len),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars), grid=(b, n_k),
+            num_scalar_prefetch=len(scalars), grid=(n_work,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, nkv, R, dv), row_map),
             scratch_shapes=[pltpu.VMEM((nkv, R), jnp.float32),
@@ -790,6 +803,11 @@ PARENT_CASES = {
     "T1-latent": (1, [0, L - 1, _EDGE], False, True, None),
     "T4-latent": (4, [_EDGE - 3, 0, L - 4], False, True, None),
     "T1-retired-slot": (1, [2 * L, 3, 10 * L], False, False, _EDGE),
+    # 128 heads on one latent stream: a step that computes longer than
+    # it streams keeps the pipeline's whole-tile fetch (_ring_slots)
+    "T1-latent-128-heads": (1, [0, L - 1, _EDGE], False, True, None),
+    "T1-select": (1, [0, L - 1, _EDGE], False, False, _EDGE),
+    "T4-block-causal": (4, [_EDGE - 4, 0, L - 4], False, False, _EDGE),
 }
 
 
@@ -798,15 +816,19 @@ def test_is_bitwise_the_parent_call(data, name):
     """At an unchanged tile width a row's tiles arrive in the same
     order into the same accumulators: every caller (generate's scan,
     the speculative verify, the long-prompt extend, int8 and latent
-    caches) gets the bits the (b, n_k) grid gave."""
+    caches, a selection, the block-causal mask) gets the bits the
+    BlockSpec pipeline's tiles gave."""
     T, pos0, quant, latent, block_k = PARENT_CASES[name]
     _, kc, vc, scale = data
     rng = np.random.default_rng(sorted(PARENT_CASES).index(name))
     pos0 = jnp.asarray(pos0, jnp.int32)
     if latent:
-        q = jnp.asarray(rng.standard_normal((B, T, NH, 144)), jnp.float32)
+        nh = 128 if name == "T1-latent-128-heads" else NH
+        q = jnp.asarray(rng.standard_normal((B, T, nh, 144)), jnp.float32)
         k = jnp.asarray(rng.standard_normal((B, 1, 144, L)), jnp.float32)
         args, kw = (q, k, None, pos0, scale), {"v_dim": 128}
+        assert bool(decode_mod.flash_decode_slots(k, nh, 128, T)) == (
+            nh == NH)
     elif quant:
         q = jnp.asarray(rng.standard_normal((B, T, NH, D)), jnp.float32)
         qk, ks = _quant_seqminor(kc)
@@ -815,9 +837,13 @@ def test_is_bitwise_the_parent_call(data, name):
     else:
         q = jnp.asarray(rng.standard_normal((B, T, NH, D)), jnp.float32)
         args, kw = (q, kc, vc, pos0, scale), {}
+    if name == "T1-select":     # a selector's choice: about half of all
+        kw["select"] = jnp.asarray(rng.random((B, L)) < 0.5)
+    if name == "T4-block-causal":
+        kw["block_len"] = 4
     got = np.asarray(flash_block_decode(*args, interpret=True,
                                         block_k=block_k, **kw))
-    want = np.asarray(_pr29_flash_block_decode(*args, block_k=block_k,
+    want = np.asarray(_pr36_flash_block_decode(*args, block_k=block_k,
                                                **kw))
     np.testing.assert_array_equal(got, want)
 
@@ -832,6 +858,159 @@ def test_tail_is_bitwise_the_parent_call(kind, newest):
     tail = (tk, tv, jnp.int32(newest))
     got = flash_decode(q, kc, vc, pos0 - 1, scale, interpret=True,
                        block_k=128, v_dim=v_dim, tail=tail)
-    want = _pr29_flash_block_decode(q, kc, vc, pos0 - 1, scale,
+    want = _pr36_flash_block_decode(q, kc, vc, pos0 - 1, scale,
                                     block_k=128, v_dim=v_dim, tail=tail)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# -- the kernel's own fetch (PR 37): K and V stay in HBM and the kernel
+# copies its tiles into a ring of VMEM slots, _ring_span's steps ahead,
+# a row's last tile only as far as it is live (_copy_lanes: whole
+# 128-lane blocks). Two kv heads x 64 x 1024 in tiles of 256: a copy is
+# 128 or 256 lanes; what a slot holds past a short copy is an older
+# tile's.
+RING_L, RING_BK, RING_NH, RING_NKV = 1024, 256, 4, 2
+RING_CASES = {
+    # name: (T, pos0 row by row)
+    "fewer-steps-than-slots": (1, [5]),                 # n_work = 1
+    "one-step-short-of-the-ring": (1, [RING_BK + 40]),  # n_work = N - 1
+    "as-many-steps-as-slots": (1, [2 * RING_BK + 40]),  # n_work = N
+    "every-row-one-tile": (1, [3, 100, 255, 0, 17, 128, 127]),
+    "retired-slot-past-max-len": (1, [5 * RING_L, 3, 2 * RING_L, 300]),
+    "last-tile-live-in-1-lane": (1, [RING_BK, 700, 2 * RING_BK, 0]),
+    "last-tile-live-in-128": (1, [RING_BK + 127, 5, 127, 900]),
+    "last-tile-live-whole": (1, [2 * RING_BK - 1, 1023, 255, 64]),
+    "T4-block-crosses-a-copy-edge": (4, [126, 254, 380, 0, 1020]),
+    "T4-block-causal": (4, [124, 252, 0, 1020]),
+}
+
+
+def _ring_case(name, dtype=jnp.float32):
+    T, pos0 = RING_CASES[name]
+    nb = len(pos0)
+    rng = np.random.default_rng(sorted(RING_CASES).index(name))
+    q = jnp.asarray(rng.standard_normal((nb, T, RING_NH, D)), dtype)
+    kc = jnp.asarray(rng.standard_normal((nb, RING_NKV, D, RING_L)), dtype)
+    vc = jnp.asarray(rng.standard_normal((nb, RING_NKV, D, RING_L)), dtype)
+    kw = {"block_len": 4} if name == "T4-block-causal" else {}
+    return q, kc, vc, jnp.asarray(pos0, jnp.int32), 1.0 / np.sqrt(D), kw
+
+
+def test_fetch_form_follows_the_shape():
+    """_ring_slots: the kernel copies its own K/V where a grid step
+    streams longer than it computes (the matmuls' flops a byte of K/V
+    it reads under half a v5e's ridge), and leaves a compute-bound
+    step to the pipeline's whole tiles. The four cells' caches, a
+    speculative verify, a long-prompt chunk; decode_lanes_fetched
+    counts whole tiles where there is no ring."""
+    def cache(nkv, d, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((2, nkv, d, 1024), dtype)
+
+    slots = decode_mod.flash_decode_slots
+    n = decode_mod._RING_SLOTS
+    assert slots(cache(16, 64), 16) == n                  # gpt2-medium
+    assert slots(cache(16, 64, jnp.int8), 16) == n
+    assert slots(cache(16, 64), 16, T=8) == n             # a verify of 8
+    assert slots(cache(16, 64), 16, T=256) == 0           # a prompt chunk
+    assert slots(cache(4, 128), 32, T=4) == n             # sdar's block
+    assert slots(cache(1, 576), 128, 512) == 0            # DeepSeek's latent
+    assert slots(cache(1, 576), 16, 512) == n             # a shard of it
+    pos = np.asarray([5, 300, 1023, 5000])
+    np.testing.assert_array_equal(
+        decode_mod.decode_lanes_fetched(pos, 1, 256, 1024, slots=0),
+        [256, 512, 1024, 1024])
+    np.testing.assert_array_equal(
+        decode_mod.decode_lanes_fetched(pos, 1, 256, 1024),
+        [128, 384, 1024, 1024])
+
+
+def test_ring_cases_hit_what_they_name():
+    """The three short lists are n_work = 1, N - 1 and N at the
+    kernel's own ring depth, and the copy rule takes every static size
+    somewhere in the cases."""
+    n = decode_mod._RING_SLOTS
+    steps = {name: int(decode_work_list(
+        jnp.asarray(pos0, jnp.int32), T, RING_BK, RING_L // RING_BK)[2])
+        for name, (T, pos0) in RING_CASES.items()}
+    assert steps["fewer-steps-than-slots"] == 1 < n
+    assert steps["one-step-short-of-the-ring"] == n - 1
+    assert steps["as-many-steps-as-slots"] == n
+    assert steps["every-row-one-tile"] == 7
+    assert decode_mod._copy_sizes(RING_BK, RING_L) == [128, 256]
+    assert decode_mod._copy_sizes(512, 1536) == [128, 256, 384, 512]
+    # a cache the tile does not divide: its last tile's own width
+    assert decode_mod._copy_sizes(32, 48) == [16, 32]
+    assert decode_mod._copy_sizes(48, 48) == [48]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", sorted(RING_CASES))
+def test_ring_is_bitwise_the_parent_call(name, dtype):
+    """Whatever the ring's depth against the list's length and however
+    short a row's last copy, the tiles reach the same accumulators in
+    the same order with the same live lanes: the bits of the parent's
+    BlockSpec call."""
+    q, kc, vc, pos0, scale, kw = _ring_case(name, jnp.dtype(dtype))
+    got = flash_block_decode(q, kc, vc, pos0, scale, interpret=True,
+                             block_k=RING_BK, **kw)
+    want = _pr36_flash_block_decode(q, kc, vc, pos0, scale,
+                                    block_k=RING_BK, **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("latent", [False, True])
+def test_ring_stale_lanes_are_masked(latent):
+    """NaN in K and inf in V at every dead position, and rows ordered
+    so that a short copy (a context under 128: 128 lanes) lands in a
+    slot whose last tenant copied 256 lanes, the upper half of them
+    dead: the slot's stale half then holds NaN that no copy of this
+    row brought, and it must reach nothing."""
+    pos = np.asarray([200] * 4 + [5] * 4 + [130] * 4 + [100] * 4, np.int32)
+    nb, (d, v_dim) = len(pos), (144, 128) if latent else (D, 0)
+    nkv = 1 if latent else RING_NKV
+    rng = np.random.default_rng(37)
+    q = jnp.asarray(rng.standard_normal((nb, 1, RING_NH, d)), jnp.float32)
+    kc = jnp.asarray(rng.standard_normal((nb, nkv, d, RING_L)), jnp.float32)
+    vc = None if latent else jnp.asarray(
+        rng.standard_normal((nb, nkv, d, RING_L)), jnp.float32)
+    dead = np.arange(RING_L)[None, None, None, :] > pos[:, None, None, None]
+    kp = jnp.where(dead, jnp.nan, kc)
+    vp = None if latent else jnp.where(dead, jnp.inf, vc)
+    scale = 1.0 / np.sqrt(d)
+    got = np.asarray(flash_decode(q, kp, vp, jnp.asarray(pos), scale,
+                                  interpret=True, block_k=RING_BK,
+                                  v_dim=v_dim))
+    assert np.isfinite(got).all()
+    want = _attend_cache(q, kc, vc, jnp.asarray(pos), scale,
+                         use_flash=False, v_dim=v_dim)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("bk,max_len,T", [
+    (256, 1024, 1), (512, 1536, 4), (1024, 4096, 1), (128, 1024, 1),
+    (32, 48, 1), (48, 48, 1)])
+def test_copy_rule(bk, max_len, T):
+    """_copy_lanes / decode_lanes_fetched, the rule the kernel copies
+    by and the server counts by: for pos at every granule edge +- 1, at
+    -1 (an empty row under the tail's rule) and far past max_len, a
+    row's copies cover its live positions, stop inside its last live
+    tile and inside the cache, come in whole granules (or end with the
+    cache), and every one is a size the kernel holds a copy for."""
+    g = decode_mod._copy_granule(bk)
+    sizes = decode_mod._copy_sizes(bk, max_len)
+    n_k = -(-max_len // bk)
+    edges = {e + d for e in range(0, max_len + g, g) for d in (-1, 0, 1)}
+    pos = np.asarray(sorted(p for p in edges | {-1, 3 * max_len}
+                            if p >= -1), np.int64)
+    fetched = decode_mod.decode_lanes_fetched(pos, T, bk, max_len)
+    live = np.clip(pos + T, 0, max_len)
+    last = np.clip((pos + T - 1) // bk, 0, n_k - 1)
+    assert (fetched >= np.maximum(live, 1)).all()
+    assert (fetched <= np.minimum((last + 1) * bk, max_len)).all()
+    assert ((fetched % g == 0) | (fetched == max_len)).all()
+    assert (fetched - np.maximum(live, 1) < g).all()
+    for p, f_, l_ in zip(pos, fetched, last):
+        lanes = int(decode_mod._copy_lanes(
+            min(int(p) + T, max_len), int(l_), bk, max_len, xp=np))
+        assert lanes in sizes and f_ == l_ * bk + lanes, (p, lanes)

@@ -171,6 +171,70 @@ def test_p3_fires_on_unclamped_scalar_index(tree):
                for f in hits), hits
 
 
+# flash_block_decode's K and V are pl.ANY operands: no block, no index
+# map. P3 walks the kernel's own fetch (_ring_fetch) over the probes'
+# work lists with the module's _ring_span / _copy_lanes / _copy_sizes.
+RING_MUTATIONS = {
+    # the refill's bound dropped: the last steps name steps past n_work
+    "look-ahead-past-the-list": (
+        "            xp.minimum(i + n_slots, n_work))",
+        "            i + n_slots)",
+        "past the work list"),
+    # the refill one step further ahead: step N's copy is never
+    # started, and its wait would never end
+    "a-step-nobody-fetches": (
+        "    return (xp.where(i == 0, 0, i + (n_slots - 1)),",
+        "    return (xp.where(i == 0, 0, i + n_slots),",
+        "no step has started"),
+    # the refill one step early: it lands in the slot of the step
+    # being computed, a copy started twice
+    "refill-into-a-live-slot": (
+        "    return (xp.where(i == 0, 0, i + (n_slots - 1)),",
+        "    return (xp.where(i == 0, 0, i + (n_slots - 2)),",
+        "started twice or never"),
+    # a row's last tile copied one granule short of nothing held: the
+    # size is none of the static copies, so no copy starts
+    "a-size-the-kernel-holds-no-copy-for": (
+        "    return xp.minimum(xp.minimum(lanes, bk), "
+        "max_len - tile * bk)",
+        "    return xp.minimum(lanes, bk) + 64",
+        "static sizes"),
+    # the copy no longer stops where the tile does
+    "copy-past-the-cache": (
+        "    return xp.minimum(xp.minimum(lanes, bk), "
+        "max_len - tile * bk)",
+        "    return lanes + bk",
+        "static sizes"),
+    # a retired slot's pos past max_len, unclipped: tiles that are not
+    # there
+    "tile-past-max-len": (
+        "    return jnp.clip((pos + (T - 1)) // bk, 0, n_k - 1)",
+        "    return (pos + (T - 1)) // bk",
+        "flash_block_decode"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(RING_MUTATIONS))
+def test_p3_grounds_the_kernels_own_copies(tree, name):
+    old, new, want = RING_MUTATIONS[name]
+    mutate(tree, "rlo_tpu/pallas/decode.py", old, new)
+    hits = findings_for(tree, "P3")
+    assert any(f.file == "rlo_tpu/pallas/decode.py" and want in f.msg
+               and "flash_block_decode" in f.msg for f in hits), hits
+
+
+def test_p3_refuses_an_unblocked_operand_it_cannot_ground(tree):
+    """A pl.ANY operand is proven or reported, never skipped: without
+    the kernel's static tile width the copies cannot be walked."""
+    mutate(tree, "rlo_tpu/pallas/decode.py",
+           "                          bk=bk, max_len=L, quant=quant, r=r, "
+           "T=T,",
+           "                          max_len=L, quant=quant, r=r, T=T,")
+    hits = findings_for(tree, "P3")
+    assert any("did not ground" in f.msg and "pl.ANY" in f.msg
+               for f in hits), hits
+
+
 def test_p4_fires_on_hardcoded_axis(tree):
     """A literal axis name in a per-shard collective drifts silently
     when the mesh is renamed — it must flow from a parameter."""

@@ -780,7 +780,10 @@ def test_attend_tile_counters_equal_the_hand_count(monkeypatch,
     counts at all; the model still attends through the einsum here,
     and the count needs only pos."""
     from rlo_tpu.models import serve as serve_mod
-    from rlo_tpu.pallas.decode import decode_work_list, flash_decode_tile
+    from rlo_tpu.pallas.decode import (decode_lanes_fetched,
+                                       decode_work_list,
+                                       flash_decode_slots,
+                                       flash_decode_tile)
     from rlo_tpu.utils.metrics import Registry
 
     monkeypatch.setattr(serve_mod, "_on_tpu", lambda: True)
@@ -829,6 +832,21 @@ def test_attend_tile_counters_equal_the_hand_count(monkeypatch,
         steps += int(decode_work_list(jnp.asarray(held, jnp.int32), 1,
                                       bk, n_k)[2])
     assert c["serve.attend_steps"] == steps
+    # what the kernel's copies move, by its own rule for every step's
+    # positions: whole tiles before a row's last, that one granule-rounded;
+    # between what the contexts hold and the live tiles whole
+    fetched = lanes_live = 0
+    for step in range(rounds * kk):
+        held = np.asarray(plens + [0]) + (
+            step // kk * kk - 1 if cache == "tail" else step)
+        fetched += int(decode_lanes_fetched(held, 1, bk, max_len).sum())
+        lanes_live += int(np.clip(held + 1, 0, max_len).sum())
+    assert c["serve.attend_lanes_fetched"] == fetched
+    assert c["serve.attend_lanes_live"] == lanes_live
+    assert lanes_live <= fetched <= c["serve.attend_tiles_live"] * bk
+    assert fetched % 128 == 0 and fetched < live * bk
+    assert srv.stats()["gauges"]["serve.attend_fetch_depth"] == \
+        flash_decode_slots(srv.cache[0]["k"], cfg.n_heads) >= 2
     # the short rows reach one tile a step. The long one reaches two
     # from the step its context passes the edge — or, with the tail,
     # from the first round that FINDS it past the edge: the second
@@ -854,7 +872,8 @@ def test_attend_tile_counters_absent_on_the_einsum_path(setup):
     # a shape the kernel's gate refuses holds none on the chip either
     srv._count_attend_tiles(4)
     assert not any(k.startswith("serve.attend_")
-                   for k in srv.stats()["counters"])
+                   for kind in ("counters", "gauges")
+                   for k in srv.stats()[kind])
 
 
 

@@ -62,6 +62,59 @@ def test_flash_decode_compiles_for_v5e(one_chip, T, cache_dtype):
     assert ("flash_decode" if T == 1 else "flash_block_decode") in text
 
 
+# the four cells' caches as (kv heads, head_dim, max_len, query heads,
+# queries a row, latent, the tile the rule picks)
+CELL_CACHES = {
+    "gpt2m-decode-sat": (16, 64, 1024, 16, 1, False, 256),
+    "dsv3-ep16-reason-sat": (1, 576, 4096, 128, 1, True, 1024),
+    "dsv32-ep32-longctx-decode": (1, 576, 24576, 128, 1, True, 1024),
+    "sdar-6l-blockgen-sat": (4, 128, 1536, 32, 4, False, 512),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_CACHES))
+def test_the_ring_is_in_the_vmem_budget_and_fits_v5e(one_chip, cell,
+                                                     monkeypatch):
+    """The kernel fetches K/V through a ring of _RING_SLOTS VMEM slots
+    a tensor: the tile rule and the block gate count every slot (not
+    the pipeline's two), the cells keep their tiles, and the deepest
+    ring the rule was measured at (4) still compiles inside v5e's
+    scoped VMEM at each cell's shape."""
+    from rlo_tpu.pallas import decode
+    nkv, d, max_len, nh, T, latent, tile = CELL_CACHES[cell]
+    streams = 1 if latent else 2
+    cache = jax.ShapeDtypeStruct((2, nkv, d, max_len), jnp.bfloat16,
+                                 sharding=one_chip)
+    monkeypatch.setattr(decode, "_RING_SLOTS", 4)
+    # the ring at every cell, the two whose step computes longer than
+    # it streams and keeps the pipeline (_ring_slots) too
+    monkeypatch.setattr(decode, "_COMPUTE_BOUND", float("inf"))
+    assert flash_decode_tile(cache, nh, latent=latent) == tile
+    ring = decode._ring_bytes(nkv, d, tile, 2, streams)
+    assert ring == 4 * streams * nkv * d * tile * 2 <= 5 << 20
+    rule = decode._tile_rule(latent)
+    assert decode._block_fits_vmem(max_len, d, nkv, nh // nkv, T, 2,
+                                   *rule, streams)
+    # the gate counts the ring: the largest block that fits beside two
+    # tiles a tensor does not fit beside the ring
+    per_t = 2 * nh * tile * 4 + nh * d * 4
+    t_max = ((14 << 20) - ring // 2) // per_t
+    assert not decode._block_fits_vmem(max_len, d, nkv, nh // nkv,
+                                       t_max, 2, *rule, streams)
+
+    def attend(q, k, pos):
+        return flash_block_decode(q, k, None if latent else k, pos, 0.125,
+                                  v_dim=512 if latent else 0,
+                                  interpret=False)
+
+    text = jax.jit(attend).lower(
+        jax.ShapeDtypeStruct((2, T, nh, d), jnp.bfloat16,
+                             sharding=one_chip), cache,
+        jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert ("flash_decode" if T == 1 else "flash_block_decode") in text
+
+
 # deepseek-v3-ep16 x reason-sat: 128 slots, 128 heads, latent rows of
 # 512 + 64, 16 of 256 experts held
 SLOTS, HEADS, LATENT, V_DIM, MAX_LEN = 128, 128, 576, 512, 4096
