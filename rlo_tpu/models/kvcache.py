@@ -642,7 +642,10 @@ def topk_mask(scores, k: int):
     rank last and are taken only when fewer than k others exist; mask
     them off afterwards. (Settling 2 / 4 / 8 bits a pass, one count
     against 3 / 15 / 255 candidates, measured 0.139 / 0.200 / 2.77 ms
-    against 0.134 at 32 x 24576 on a v5e: PERF.md, PR 31.)"""
+    against 0.134 at 32 x 24576 on a v5e: PERF.md §6.) The CPU's
+    form and the reference of pallas.decode.topk_select, which computes
+    the same mask in one kernel on the tpu backend (select_tokens): here
+    every count re-reads the whole score array."""
     n = scores.shape[-1]
     if n <= k:
         return jnp.ones(scores.shape, bool)
@@ -697,9 +700,12 @@ def select_tokens(index, entry, pos_q, topk: int, *, work=None,
     scored (one branch on the largest position). ``work``: the step's
     select_work. ``info``: a list that receives {"scores", "select",
     "keys_scored"} (the live contexts this call scored: 0 where it
-    scored nothing) for callers that check them; the attend that
-    follows adds how it read the choice (_attend_cache)."""
-    from rlo_tpu.pallas.decode import can_index_score, index_score
+    scored nothing) and "select_kernel" (1 where the choice ran
+    pallas.decode.topk_select, 0 where it ran topk_mask) for callers
+    that check them; the attend that follows adds how it read the
+    choice (_attend_cache)."""
+    from rlo_tpu.pallas.decode import (can_index_score, can_topk_select,
+                                       index_score, topk_select)
     ik = entry["ik"]
     b, T = pos_q.shape
     L = ik.shape[3]
@@ -707,6 +713,8 @@ def select_tokens(index, entry, pos_q, topk: int, *, work=None,
         return None
     live = jnp.arange(L)[None, None, :] <= pos_q[:, :, None]
     ctx = jnp.minimum(pos_q + 1, L)
+    use_kernel = kernel_gate(can_topk_select(L, topk),
+                             f"exact top-k (max_len={L}, topk={topk})")
 
     def chosen():
         with jax.named_scope("dsa.score"):
@@ -718,7 +726,9 @@ def select_tokens(index, entry, pos_q, topk: int, *, work=None,
             else:
                 scores = _index_scores(index, ik, pos_q)
         with jax.named_scope("dsa.select"):
-            return scores, topk_mask(scores, topk) & live, jnp.sum(ctx)
+            mask = (topk_select(scores, topk) if use_kernel
+                    else topk_mask(scores, topk))
+            return scores, mask & live, jnp.sum(ctx)
 
     def everything():
         return jnp.where(live, 0.0, -jnp.inf), live, jnp.int32(0)
@@ -727,7 +737,8 @@ def select_tokens(index, entry, pos_q, topk: int, *, work=None,
                                       chosen)
     if info is not None:
         info.append({"scores": scores, "select": select,
-                     "keys_scored": scored})
+                     "keys_scored": scored,
+                     "select_kernel": int(use_kernel)})
     return select
 
 

@@ -214,7 +214,10 @@ class DecodeServer:
     ``serve.dsa.latent_rows_read`` for a token selector
     (_count_dsa: once a round from the host's ``pos``, by the rule
     kvcache.select_counts keeps beside the program's own, the attend's
-    form as the traced program reported it),
+    form as the traced program reported it) and the gauge
+    ``serve.dsa.select_kernel`` (1 where the traced round's selections
+    ran the kernel pallas.decode.topk_select, 0 where XLA's
+    kvcache.topk_mask),
     ``serve.moe.<count>`` for 'sigmoid_group' expert layers
     (models.moe.STATS, summed over the
     round's steps and layers on the device and read back with the
@@ -399,7 +402,9 @@ class DecodeServer:
         # a token selector's threshold, where the cache is long
         # enough for it to choose (_count_dsa); else 0. How its attends
         # read a selection is what the round's program says of itself
-        # when it is traced (kvcache.select_counts' ``reads``)
+        # when it is traced (kvcache.select_counts' ``reads``), and so
+        # is the form its selections ran (the gauge
+        # serve.dsa.select_kernel, set when the round is traced)
         self._dsa_topk = cfg.index_topk if cfg.dsa and \
             max_len > cfg.index_topk else 0
         self._dsa_reads = None
@@ -422,8 +427,10 @@ class DecodeServer:
                     dsa_info=dsa)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 stats = [n + sum(i["stats"] for i in info) for n in stats]
-                if dsa:         # traced: the form the attends took
+                if dsa:         # traced: the forms the round took
                     self._dsa_reads = dsa[0]["reads"]
+                    self.metrics.gauge("serve.dsa.select_kernel").set(
+                        dsa[0]["select_kernel"])
                 return (tok, kv, *stats), tok
 
             stats = ([jnp.zeros((len(moe.STATS),), jnp.int32)]

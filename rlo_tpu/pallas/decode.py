@@ -1598,3 +1598,173 @@ def index_score(q, w, k_cache, pos, *, work=None,
       w.astype(jnp.float32)[..., None], k_cache)
     live = jnp.arange(L)[None, :] <= posv[:, None]
     return jnp.where(live, out[:, 0], -jnp.inf)
+
+
+# ---- the token selector's exact top-k ----------------------------------
+
+#: rows of scores one grid step of topk_select holds: a row a sublane of
+#: two f32 vregs. Alone at 32 x 24576 on a v5e 8 rows took 0.087 ms, 16
+#: rows 0.074; at 256 x 24576 0.428 and 0.314 (PERF.md §6)
+_TOPK_ROWS = 16
+#: lanes of one step of a count's walk over the block: (16, 1024) is 16
+#: vregs a compare and 16 accumulators (512 lanes measured slower)
+_TOPK_LANES = 1024
+#: VMEM a step may take: the scores' block and the mask's, double
+#: buffered, and the keys' scratch, 5 x 16 x n x 4 B (7.5 MiB at n =
+#: 24576)
+_TOPK_VMEM_BYTES = 12 << 20
+
+_SIGN = np.int32(-2 ** 31)
+
+
+def _topk_kernel(s_ref, o_ref, key_ref, cut_ref, *, k: int, lanes: int):
+    # one block of rows, whole, in VMEM: the keys are written once, then
+    # every count is a walk over them (compare, accumulate lane-wise,
+    # one cross-lane sum a row at its end)
+    rows, n = s_ref.shape
+    steps = n // lanes
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+
+    def at(j):
+        return pl.ds(pl.multiple_of(j * lanes, lanes), lanes)
+
+    def image(j, c):
+        # the floats' order as signed int32: negatives' magnitude bits
+        # flipped (-0.0 -> -1 < +0.0 -> 0, -inf the least finite key)
+        raw = jax.lax.bitcast_convert_type(s_ref[:, at(j)], jnp.int32)
+        key_ref[:, at(j)] = raw ^ ((raw >> 31) & 0x7FFFFFFF)
+        return c
+
+    jax.lax.fori_loop(0, steps, image, 0)
+
+    def count(*preds):
+        # per row, how many keys each predicate of (keys, step) holds
+        # for: (rows, 1)s
+        def walk(j, accs):
+            key = key_ref[:, at(j)]
+            return tuple(a + jnp.where(p(key, j), 1, 0)
+                         for a, p in zip(accs, preds))
+        zero = jnp.zeros((rows, lanes), jnp.int32)
+        accs = jax.lax.fori_loop(0, steps, walk, (zero,) * len(preds))
+        return [jnp.sum(a, axis=1, keepdims=True) for a in accs]
+
+    def wide(x):
+        return jnp.broadcast_to(x, (rows, lanes))
+
+    def bit(i, t):
+        # t: the k-th largest key's bits above bit 31 - i, in the
+        # unsigned order; keep the next one where k keys reach it
+        cand = t | jnp.left_shift(jnp.int32(1), 31 - i)
+        low = wide(cand ^ _SIGN)
+        n_ge, = count(lambda key, j: key >= low)
+        return jnp.where(n_ge >= k, cand, t)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros((rows, 1), jnp.int32)) ^ _SIGN
+    kth_w = wide(kth)
+    above, ties = count(lambda key, j: key > kth_w,
+                        lambda key, j: key == kth_w)
+    room = k - above            # ties at the k-th value still taken
+    cut_ref[...] = jnp.full((rows, 1), np.iinfo(np.int32).max, jnp.int32)
+
+    @pl.when(jnp.max(jnp.where(ties > room, 1, 0)) > 0)
+    def _cut():
+        # a row whose ties overflow its room takes the lowest positions:
+        # the largest P with count(tie at a position <= P) <= room
+        bits = max(1, (n - 1).bit_length())
+
+        def pbit(i, p):
+            cand = p | jnp.left_shift(jnp.int32(1), bits - 1 - i)
+            cand_w = wide(cand)
+            n_le, = count(lambda key, j: (key == kth_w)
+                          & (j * lanes + col <= cand_w))
+            return jnp.where(n_le <= room, cand, p)
+
+        cut_ref[...] = jax.lax.fori_loop(0, bits, pbit,
+                                         jnp.zeros((rows, 1), jnp.int32))
+
+    cut_w = wide(cut_ref[...])
+
+    def write(j, c):
+        key = key_ref[:, at(j)]
+        o_ref[:, at(j)] = jnp.where(
+            (key > kth_w) | ((key == kth_w) & (j * lanes + col <= cut_w)),
+            1, 0)
+        return c
+
+    jax.lax.fori_loop(0, steps, write, 0)
+
+
+def can_topk_select(n: int, k: int) -> bool:
+    """Shape gate of topk_select: whole 128-lane blocks of the row, more
+    positions than are kept, and a block of rows whole in VMEM."""
+    return (n % 128 == 0 and 0 < k < n
+            and 5 * _TOPK_ROWS * n * 4 <= _TOPK_VMEM_BYTES)
+
+
+def topk_select(scores, k: int, *, interpret: Optional[bool] = None):
+    """models.kvcache.topk_mask as one kernel: the EXACT top-``k`` of
+    ``scores`` (..., n) f32 as a bool mask, to the bit — the k largest,
+    ties at the k-th value to the lowest positions, the floats ordered
+    by their integer image (-0.0 below +0.0; -inf last, taken only where
+    fewer than k others exist).
+
+    A grid step holds _TOPK_ROWS rows whole in VMEM, read from HBM once:
+    their keys (the signed image ``raw ^ ((raw >> 31) & 0x7fffffff)``,
+    the order of topk_mask's unsigned one) go to a scratch, the k-th
+    largest key is settled bit by bit in 32 counts over it, then one
+    count of the keys above it and of the ties. Where a row's ties fit
+    its room (k less those above) the mask is key >= k-th; only in a
+    block where some row's do not, the position cut — the largest P
+    with count(tie at a position <= P) <= room, at most
+    ceil(log2 n) more counts — runs, under a pl.when. The mask is
+    written once. XLA's form (topk_mask) reads the whole score array
+    from HBM in each of its 32 counts and cuts the ties with a running
+    sum (PERF.md §6 has both timed on a v5e)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    shape = scores.shape
+    n = shape[-1]
+    if not can_topk_select(n, k):
+        raise ValueError(f"topk_select: no kernel for rows of {n} "
+                         f"keeping {k} (can_topk_select)")
+    x = scores.astype(jnp.float32).reshape(-1, n)
+    rows = x.shape[0]
+    pad = -rows % _TOPK_ROWS
+    if pad:
+        # distinct filler: no ties, so no position cut for its block
+        x = jnp.concatenate(
+            [x, jnp.broadcast_to(jnp.arange(n, dtype=jnp.float32),
+                                 (pad, n))])
+    lanes = _TOPK_LANES
+    while n % lanes:
+        lanes //= 2
+    out = _topk_call(x, k=k, block=_TOPK_ROWS, lanes=lanes,
+                     interpret=bool(interpret))
+    return (out[:rows] != 0).reshape(shape)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "block", "lanes",
+                                             "interpret"))
+def _topk_call(x, *, k: int, block: int, lanes: int, interpret: bool):
+    """topk_select's kernel call over (rows, n) scores, rows a multiple
+    of ``block``: a jitted function of its own, so that a program
+    traces and lowers the kernel body once for all the layers that call
+    it, and a process once for all its programs (as _flash_call)."""
+    rows, n = x.shape
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",))
+    return pl.pallas_call(
+        functools.partial(_topk_kernel, k=k, lanes=lanes),
+        grid=(rows // block,),
+        in_specs=[pl.BlockSpec((block, n), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((block, n), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, n), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((block, n), jnp.int32),
+                        pltpu.VMEM((block, 1), jnp.int32)],
+        interpret=interpret,
+        name="topk_select",
+        **kwargs,
+    )(x)

@@ -1155,6 +1155,13 @@ def _jnp_concatenate(arrs, axis=0, **_k):
     return ArrayVal(base, None, arrs[0].dtype)
 
 
+def _jnp_broadcast_to(x, shape, **_k):
+    if _is_op(x, shape) or any(_is_op(d) for d in shape):
+        return OPAQUE
+    return ArrayVal(shape, None,
+                    x.dtype if isinstance(x, ArrayVal) else "float32")
+
+
 def _jnp_elemwise1(x, *a, **k):
     """exp / abs / zeros_like-style shape-preserving unary."""
     if isinstance(x, ArrayVal):
@@ -1168,7 +1175,7 @@ _JNP_FNS = {
     "arange": _jnp_arange, "where": _jnp_where,
     "concatenate": _jnp_concatenate, "exp": _jnp_elemwise1,
     "zeros_like": _jnp_elemwise1, "abs": _jnp_elemwise1,
-    "cumsum": _jnp_cumsum,
+    "cumsum": _jnp_cumsum, "broadcast_to": _jnp_broadcast_to,
 }
 
 
@@ -1757,6 +1764,14 @@ def _p3_probes() -> List[Probe]:
             dict(q=A((2, 8, 128)), w=A((2, 8)),
                  k_cache=A((2, 1, 128, 4096)), pos=A((2,), [9000, 5]),
                  work=None, block_k=1024, interpret=True),
+        ], sites=1),
+        # the selector's exact top-k: blocks of whole rows, a step each
+        Probe("rlo_tpu/pallas/decode.py", "topk_select", [
+            # the cell's decode step and extend chunk
+            dict(scores=A((32, 1, 24576)), k=2048, interpret=True),
+            dict(scores=A((1, 256, 24576)), k=2048, interpret=True),
+            # rows no multiple of the block: the filler rows pad it
+            dict(scores=A((3, 5, 384)), k=17, interpret=True),
         ], sites=1),
         Probe("rlo_tpu/pallas/flash.py", "_flash_fwd_call", [
             dict(q=A((8, 1024, 128)), k=A((8, 2048, 128)),

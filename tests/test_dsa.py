@@ -22,8 +22,9 @@ from rlo_tpu.models.generate import (block_decode, decode_step,
 from rlo_tpu.models.serve import DecodeServer, extend_widths
 from rlo_tpu.models.transformer import (TransformerConfig, forward,
                                         init_params)
-from rlo_tpu.pallas.decode import (decode_work_list, flash_decode,
-                                   index_score, index_score_tile)
+from rlo_tpu.pallas.decode import (can_topk_select, decode_work_list,
+                                   flash_decode, index_score,
+                                   index_score_tile, topk_select)
 from rlo_tpu.utils.metrics import Registry
 
 PERF = Path(__file__).resolve().parent.parent / "perf"
@@ -169,6 +170,93 @@ def test_topk_mask_is_the_stable_sort(n, k, ties):
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(
         got, np.asarray(REF.select_topk(s, k)) | (got & ~np.isfinite(s)))
+
+
+def _topk_case(case):
+    """(scores, k) of one case of the kernel's bit-equality test."""
+    key = jax.random.PRNGKey(len(case))
+    if case == "random":
+        return jax.random.normal(key, (16, 512)), 64
+    if case == "ties_overflow":     # quarter steps: the k-th value's
+        s = jnp.round(jax.random.normal(key, (8, 1024)) * 4) / 4
+        return s, 200               # ties overflow its room in every row
+    if case == "neg_inf_tails":
+        # rows 0-7 keep 20-90 finite scores of k = 100 (the rest -inf),
+        # rows 8-15 are whole: a short block beside a full one
+        s = jax.random.normal(key, (16, 256))
+        n_fin = jnp.concatenate([20 + 10 * jnp.arange(8),
+                                 jnp.full((8,), 256)])
+        return jnp.where(jnp.arange(256) < n_fin[:, None], s, -jnp.inf), 100
+    if case == "signed_zeros":      # +0.0 ranks above -0.0, both above
+        s = jax.random.normal(key, (8, 256))            # the negatives
+        zero = jnp.where(s > 0, 0.0, -0.0)
+        pick = jax.random.uniform(jax.random.PRNGKey(1), s.shape) < 0.4
+        return jnp.where(pick, zero, -jnp.abs(s)), 70
+    if case == "ragged_rows":       # 13 rows: no multiple of the block
+        return jax.random.normal(key, (13, 384)), 30
+    if case == "b_T_n":             # an extend chunk's (b, T, n), ties
+        return jnp.round(jax.random.normal(key, (2, 3, 1024)) * 2) / 2, 300
+    raise KeyError(case)
+
+
+@pytest.mark.parametrize("case", ["random", "ties_overflow",
+                                  "neg_inf_tails", "signed_zeros",
+                                  "ragged_rows", "b_T_n"])
+def test_topk_select_kernel_is_topk_mask_to_the_bit(case):
+    s, k = _topk_case(case)
+    got = np.asarray(topk_select(s, k, interpret=True))
+    want = np.asarray(jax.jit(lambda s: kvcache.topk_mask(s, k))(s))
+    assert got.shape == s.shape and got.dtype == bool
+    np.testing.assert_array_equal(got, want)
+    assert (got.sum(-1) == k).all()
+    if case == "signed_zeros":      # the cut falls inside the zeros
+        pos = np.asarray(s) == 0
+        assert (got & pos).any() and (~got & pos).any()
+
+
+def test_topk_select_gate_refuses_what_it_cannot_hold():
+    assert can_topk_select(24576, 2048)
+    assert not can_topk_select(24576 + 64, 2048)       # no 128-lane rows
+    assert not can_topk_select(2048, 2048)             # keeps everything
+    assert not can_topk_select(256, 0)
+    assert not can_topk_select(1 << 20, 2048)          # no block in VMEM
+    with pytest.raises(ValueError, match="can_topk_select"):
+        topk_select(jnp.zeros((2, 200)), 10, interpret=True)
+
+
+@pytest.mark.parametrize("T", [1, 4])
+def test_select_tokens_through_the_kernel_is_the_xla_path(monkeypatch, T):
+    """The gate opened (the tpu backend's decision, the kernels in
+    interpret mode): select_tokens' mask is topk_mask's of its own scores
+    and, where the scores come from the same XLA form (T > 1), every
+    output and record is the closed gate's, but the form it reports."""
+    import rlo_tpu.pallas.reduce as red
+    b, heads, d, L, k = 2, 4, 32, 512, 64
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    index = {"q": jax.random.normal(keys[0], (b, T, heads, d)),
+             "w": jax.random.normal(keys[1], (b, T, heads))}
+    entry = {"ik": jax.random.normal(keys[2], (b, 1, d, L))}
+    pos_q = jnp.array([[300], [90]]) + jnp.arange(T)[None]
+
+    def run():
+        info = []
+        got = kvcache.select_tokens(index, entry, pos_q, k, info=info)
+        return np.asarray(got), jax.tree.map(np.asarray, info[0])
+
+    closed, closed_info = run()
+    monkeypatch.setattr(red, "_on_tpu", lambda: True)
+    opened, info = run()
+    assert (info["select_kernel"], closed_info["select_kernel"]) == (1, 0)
+    live = np.arange(L)[None, None] <= np.asarray(pos_q)[..., None]
+    np.testing.assert_array_equal(
+        opened, np.asarray(kvcache.topk_mask(info["scores"], k)) & live)
+    np.testing.assert_array_equal(opened, info["select"])
+    assert (opened.sum(-1) == k).all()
+    assert int(info["keys_scored"]) == int(closed_info["keys_scored"])
+    if T > 1:
+        np.testing.assert_array_equal(opened, closed)
+        for name in ("scores", "select", "keys_scored"):
+            np.testing.assert_array_equal(info[name], closed_info[name])
 
 
 # ---- the score kernel ---------------------------------------------------
@@ -495,6 +583,8 @@ def test_server_admits_long_prompts_and_counts_the_selector(
     assert "serve.kv_tail.rounds" not in c
     assert reg.snapshot()["gauges"]["serve.cache_bytes_per_token"] == \
         kvcache.bytes_per_position(srv.cache)
+    # off the tpu backend the round selects through XLA's topk_mask
+    assert reg.snapshot()["gauges"]["serve.dsa.select_kernel"] == 0
     # two rounds of 4 steps from pos (90, 20): contexts pos + s + 1;
     # what the program counted of itself is what its rule says
     ctx = np.array(plens)[:, None] + 1 + np.arange(8)[None]

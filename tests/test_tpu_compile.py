@@ -6,6 +6,8 @@ round: the attend with a 32-row write-behind tail and the tail's fold;
 every attend on its work-list grid, a 1-D grid with a dynamic bound;
 gpt2-medium's admission program, a device loop around the one-row
 prefill with the pool cache in its carry;
+deepseek-v3.2-ep32: the selector's score and exact top-2048 kernels
+at 32 x 24576 and an extend chunk's 256 x 24576;
 sdar-30b-a3b-6l: a pass's block write and block-causal attend of 4
 queries at 192 rows x 32 / 4 heads x 128 x 1536, and the grouped expert
 FFN over 128 held experts at 2048 x 768; trinity-large-ep16: a sliding
@@ -313,10 +315,10 @@ DSA_SLOTS, DSA_LEN, INDEX_HEADS, INDEX_DIM, INDEX_TOPK = 32, 24576, 64, 128, 204
 def test_index_score_and_selected_attend_compile_for_v5e(one_chip):
     """A decode step of the sparse-attention cell, a layer's worth: the
     row write of both tensors of the entry, index_score on its own
-    work list, the exact top-2048 as a mask, and the latent attend
-    with that mask streamed beside the cache."""
-    from rlo_tpu.models.kvcache import topk_mask
-    from rlo_tpu.pallas.decode import index_score, index_score_tile
+    work list, the exact top-2048 as a mask (the topk_select kernel),
+    and the latent attend with that mask streamed beside the cache."""
+    from rlo_tpu.pallas.decode import (index_score, index_score_tile,
+                                       topk_select)
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -334,7 +336,7 @@ def test_index_score_and_selected_attend_compile_for_v5e(one_chip):
         scores = index_score(
             qi, w, ik, pos, interpret=False,
             work=decode_work_list(pos, 1, ibk, DSA_LEN // ibk))
-        select = topk_mask(scores, INDEX_TOPK)
+        select = topk_select(scores, INDEX_TOPK, interpret=False)
         out = flash_block_decode(
             q, cache, None, pos, 0.135, v_dim=V_DIM, interpret=False,
             work=decode_work_list(pos, 1, bk, DSA_LEN // bk),
@@ -350,8 +352,24 @@ def test_index_score_and_selected_attend_compile_for_v5e(one_chip):
         shape((DSA_SLOTS, 1, INDEX_DIM), bf16), latent, keys,
         shape((DSA_SLOTS,), jnp.int32))
     assert hlo.mosaic_kernels(lowered.as_text()) == {
-        "flash_decode": 1, "index_score": 1, "write_kv_row": 2}
-    assert lowered.compile().as_text().count("tpu_custom_call") >= 4
+        "flash_decode": 1, "index_score": 1, "topk_select": 1,
+        "write_kv_row": 2}
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 5
+
+
+def test_topk_select_compiles_for_an_extend_chunk_for_v5e(one_chip):
+    """The exact top-2048 of an admission's 256-token extend chunk in
+    the sparse-attention cell: (1, 256, 24576) scores, 32 blocks of 8
+    rows held whole in VMEM."""
+    from rlo_tpu.pallas.decode import can_topk_select, topk_select
+    from rlo_tpu.utils import hlo
+    assert can_topk_select(DSA_LEN, INDEX_TOPK)
+    lowered = jax.jit(lambda s: topk_select(s, INDEX_TOPK,
+                                            interpret=False)).lower(
+        jax.ShapeDtypeStruct((1, 256, DSA_LEN), jnp.float32,
+                             sharding=one_chip))
+    assert hlo.mosaic_kernels(lowered.as_text()) == {"topk_select": 1}
+    assert lowered.compile().as_text().count("tpu_custom_call") == 1
 
 
 def test_admission_program_runs_its_loop_in_place_for_v5e(one_chip,

@@ -399,7 +399,9 @@ def test_expert_shares_sum_to_the_uncut_layer():
 #: step, block decode and DecodeServer round, at its tiny preset, as
 #: PR 37 traced them: on the CPU, and with the kernel gates open for the
 #: TPU (kernel bodies included). A PR that changes one of these programs
-#: ON PURPOSE records the new digest here and says so.
+#: ON PURPOSE records the new digest here and says so: deepseek-v3.2-ep32's
+#: TPU decode, block and round select through the topk_select kernel;
+#: every other entry is as it was.
 DIGESTS = {"cpu": {
                 "gpt2-medium.init": "dd8c92089d6d711c",
                 "gpt2-medium.forward": "05c3c695836fd569",
@@ -439,9 +441,9 @@ DIGESTS = {"cpu": {
                 "deepseek-v3.2-ep32.init": "7febe268d0d9f783",
                 "deepseek-v3.2-ep32.forward": "09a20d8bb8882ff7",
                 "deepseek-v3.2-ep32.prefill": "3a52625620d06a85",
-                "deepseek-v3.2-ep32.decode": "fc12adda432caa49",
-                "deepseek-v3.2-ep32.block": "dff144b1b2e76085",
-                "deepseek-v3.2-ep32.round": "eb881c92187f5454",
+                "deepseek-v3.2-ep32.decode": "37b4edf758549f2d",
+                "deepseek-v3.2-ep32.block": "def86255f6685c69",
+                "deepseek-v3.2-ep32.round": "d2b1d1e900dcb2a3",
                 "sdar-30b-a3b-6l.init": "e2d9c7cb45311477",
                 "sdar-30b-a3b-6l.forward": "8e95b39c5e0056bc",
                 "sdar-30b-a3b-6l.block": "adc67bcc30b40356",
