@@ -37,6 +37,7 @@ from rlo_tpu.models.kvcache import (  # noqa: F401
     init_kv_cache, init_kv_tail, kv_cache_pspecs)
 from rlo_tpu.models.transformer import (TransformerConfig, apply_layer,
                                         check_unselected, embed_tokens,
+                                        hc_boundary, hc_enter, head_logits,
                                         head_weights, mla_unabsorbed,
                                         _local_attention, _rmsnorm)
 
@@ -62,14 +63,16 @@ def _mla_absorbed(q_nope, q_rope, layer, cfg, attend):
     the rotated dims), ``attend`` (q (b, T, H, kv_lora + rope)) ->
     (b, T, H, kv_lora) attends the latent rows as keys and, in their
     leading kv_lora features, as values, and W_uv brings the result
-    out: no per-head key or value is ever formed."""
+    out: no per-head key or value is ever formed. A layer's attention
+    sinks (``attn_sink``) go to the attend as its ``sinks``."""
     dt = q_nope.dtype
     with jax.named_scope("mla.absorb"):
         q_lat = jnp.einsum("bthn,chn->bthc", q_nope,
                            layer["wuk"].astype(dt))
         q = jnp.concatenate([q_lat, q_rope], -1)
     with jax.named_scope("mla.attend"):
-        o_lat = attend(q).astype(dt)
+        o_lat = (attend(q, sinks=layer["attn_sink"]) if cfg.attn_sinks
+                 else attend(q)).astype(dt)
     with jax.named_scope("mla.v_up"):
         return jnp.einsum("bthc,chv->bthv", o_lat,
                           layer["wuv"].astype(dt))
@@ -89,8 +92,12 @@ def _forward(params: dict, tokens, pos, caches, cfg: TransformerConfig,
     Returns
     (x (b, T, d), the layers' new entries). A sliding layer's window
     goes to apply_layer (its rotation); the step functions hand it to
-    the cache functions beside ``lc`` (_windows)."""
+    the cache functions beside ``lc`` (_windows). Hyper-connections
+    (``cfg.hc``): the embedding enters as the streams' state and their
+    sum leaves (transformer.hc_enter / hc_boundary)."""
     x = embed_tokens(params["embed"], tokens, pos, cfg)
+    if cfg.hc:
+        x = hc_enter(x, cfg)
     entries = []
     for i, (layer, lc) in enumerate(zip(params["layers"], caches)):
         def attention(*args, layer=layer, lc=lc):
@@ -101,6 +108,8 @@ def _forward(params: dict, tokens, pos, caches, cfg: TransformerConfig,
         x, _ = apply_layer(x, layer, cfg, attention=attention,
                            tp_axis=tp_axis, ep_axis=ep_axis, pos=pos,
                            moe_info=moe_info, window=cfg.layer_window(i))
+    if cfg.hc:      # the last output into the streams, and their sum
+        x = hc_boundary(x, None, cfg)
     return _rmsnorm(x, params["ln_f"]["g"], cfg.norm_eps), entries
 
 
@@ -108,9 +117,12 @@ def _head(params: dict, x, cfg: TransformerConfig, at=None):
     """f32 logits of _forward's ``x`` (b, T, d) under the output head
     (tied or not: head_weights): at position ``at`` of every row (an
     int), at row b's own position ``at`` (b,) (a scalar: the same for
-    all rows, but traced), or at all T positions (None)."""
+    all rows, but traced), or at all T positions (None): the product
+    is transformer.head_logits' (in float32 under ``cfg.lm_head_fp32``)."""
     dt = cfg.act_dtype
     if at is None:
+        if cfg.lm_head_fp32:
+            return head_logits(params, x, cfg)
         return jnp.einsum("btd,vd->btv", x, head_weights(params).astype(dt)
                           ).astype(jnp.float32)
     if isinstance(at, int):
@@ -121,7 +133,7 @@ def _head(params: dict, x, cfg: TransformerConfig, at=None):
         xl = jnp.take_along_axis(
             x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[-1])),
             axis=1)[:, 0]
-    return (xl @ head_weights(params).T.astype(dt)).astype(jnp.float32)
+    return head_logits(params, xl, cfg)
 
 
 def _windows(cache, cfg: TransformerConfig):
@@ -129,6 +141,32 @@ def _windows(cache, cfg: TransformerConfig):
     functions beside a layer's item of ``cache``; all 0 without sliding
     layers."""
     return [cfg.layer_window(i) for i in range(len(cache))]
+
+
+def _shared_selection(cfg: TransformerConfig, select_full):
+    """The step functions' token selection, layer by layer in the order
+    _forward runs them: ``pick(i, index, entry) -> (select, record)``
+    calls ``select_full(index, entry)`` (-> (select, record)) on a layer
+    that chooses for itself and keeps its choice; a shared layer
+    (cfg.index_types) takes the choice the nearest full layer before it
+    made for the same queries, scores nothing, and is recorded as
+    {"select", "shared": True}. Without a selector: (None, None)."""
+    last = [None]
+
+    def pick(i, index, entry, info=None):
+        kind = cfg.index_kind(i)
+        if kind is None:
+            return None, None
+        if kind == "shared":
+            record = None
+            if info is not None and last[0] is not None:
+                record = {"select": last[0], "shared": True}
+                info.append(record)
+            return last[0], record
+        last[0], record = select_full(index, entry)
+        return last[0], record
+
+    return pick
 
 
 def _cache_hook(cfg: TransformerConfig, step):
@@ -218,26 +256,31 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
         iwork = kvcache.select_work(cache, cfg, upto)
         upto_q = jnp.broadcast_to(upto, token.shape)[:, None]
 
+    def select_full(index, entry):
+        select = kvcache.select_tokens(index, entry, upto_q,
+                                       cfg.index_topk, work=iwork,
+                                       info=dsa_info)
+        # this layer's record: the attend adds how it read the set
+        return select, (dsa_info[-1] if dsa_info is not None
+                        and select is not None else None)
+
+    pick = _shared_selection(cfg, select_full)
+
     def step(lc_tl, k, v, index):
-        lc, tl, window = lc_tl
+        lc, tl, window, i = lc_tl
         row = kvcache.new_row(lc, k, v, index)
         if window:
             return _window_step(lc, tl, row, window)
         if tl is None:
             entry = kvcache.write_row(lc, row, posv)
-            select = None if index is None else kvcache.select_tokens(
-                index, entry, upto_q, cfg.index_topk, work=iwork,
-                info=dsa_info)
-            # this layer's record: the attend adds how it read the set
-            record = (dsa_info[-1] if dsa_info is not None
-                      and select is not None else None)
-            return entry, lambda q: kvcache.attend(
+            select, record = pick(i, index, entry, dsa_info)
+            return entry, lambda q, **kw: kvcache.attend(
                 q, entry, upto, cfg.attn_scale, v_dim=v_dim, work=work,
-                select=select, info=record)
+                select=select, info=record, **kw)
         tl = kvcache.store_tail_row(tl, row, newest)
-        return tl, lambda q: kvcache.attend(
+        return tl, lambda q, **kw: kvcache.attend(
             q, lc, upto, cfg.attn_scale, v_dim=v_dim, tail=(tl, newest),
-            work=work)
+            work=work, **kw)
 
     def _window_step(lc, tl, row, window):
         # a sliding layer's ring: the same write and attend, modulo its
@@ -252,7 +295,8 @@ def decode_step(params: dict, token, pos, cache, cfg: TransformerConfig,
             window=window)
 
     x, new = _forward(params, token[:, None], pos_arr,
-                      list(zip(cache, tails, _windows(cache, cfg))), cfg,
+                      list(zip(cache, tails, _windows(cache, cfg),
+                               range(len(cache)))), cfg,
                       _cache_hook(cfg, step),
                       tp_axis=tp_axis, ep_axis=ep_axis, moe_info=moe_info)
     return _head(params, x, cfg, 0), new
@@ -299,8 +343,12 @@ def block_decode(params: dict, tokens, pos0, cache,
                 f"{cfg.sliding_window}: size the ring's spare for it "
                 f"(init_kv_cache's ring_spare)")
 
+    pick = _shared_selection(cfg, lambda index, entry: (
+        kvcache.select_tokens(index, entry, pos_arr, cfg.index_topk,
+                              info=dsa_info), None))
+
     def step(lc_w, k, v, index):
-        lc, window = lc_w
+        lc, window, i = lc_w
         if window:      # a sliding layer's ring
             entry = kvcache.write_block(lc, kvcache.new_block(lc, k, v),
                                         pos0, pos_arr, window=window)
@@ -309,14 +357,14 @@ def block_decode(params: dict, tokens, pos0, cache,
                 window=window)
         entry = kvcache.write_block(
             lc, kvcache.new_block(lc, k, v, index), pos0, pos_arr)
-        select = None if index is None else kvcache.select_tokens(
-            index, entry, pos_arr, cfg.index_topk, info=dsa_info)
-        return entry, lambda q: kvcache.attend_block(
+        select, _ = pick(i, index, entry, dsa_info)
+        return entry, lambda q, **kw: kvcache.attend_block(
             q, entry, pos_arr, cfg.attn_scale, pos0=pos0, v_dim=v_dim,
-            work=work, select=select, block_len=cfg.block_len)
+            work=work, select=select, block_len=cfg.block_len, **kw)
 
     x, new = _forward(params, tokens, pos_arr,
-                      list(zip(cache, _windows(cache, cfg))), cfg,
+                      list(zip(cache, _windows(cache, cfg),
+                               range(len(cache)))), cfg,
                       _cache_hook(cfg, step), tp_axis=tp_axis,
                       ep_axis=ep_axis, moe_info=moe_info)
     return _head(params, x, cfg), new

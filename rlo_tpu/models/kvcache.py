@@ -128,12 +128,12 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
         # sequence-minor layout; no "v" (the values are the row's
         # leading kv_lora_rank features)
         width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-        widths = {"k": width}
-        if cfg.dsa:     # a token selector's index key beside the row
-            widths["ik"] = cfg.index_head_dim
+        # a token selector's index key beside the row, on the layers that
+        # choose for themselves (a shared layer attends another's choice)
+        widths = [{"k": width, "ik": cfg.index_head_dim} if cfg.selects(i)
+                  else {"k": width} for i in range(cfg.n_layers)]
         return [{name: jnp.zeros((batch, 1, w, max_len), cfg.act_dtype)
-                 for name, w in widths.items()}
-                for _ in range(cfg.n_layers)]
+                 for name, w in layer.items()} for layer in widths]
     shape = (batch, kvh, cfg.head_dim, max_len)
     # DISTINCT buffers per entry: sharing one zeros array across k/v/
     # layers breaks donation ("attempt to donate the same buffer
@@ -513,7 +513,7 @@ def window_work(cache, cfg: TransformerConfig, pos, T: int = 1,
 
 def attend(q, entry, pos, scale, *, v_dim: int = 0, tail=None,
            work=None, select=None, info: Optional[dict] = None,
-           window: int = 0):
+           window: int = 0, sinks=None):
     """One query a row, q (b, 1, H, head_dim), against ``entry``'s
     positions <= pos with the entry's own scales (_attend_cache).
     ``v_dim``: latent attention's kv_lora_rank — a latent entry cannot
@@ -524,7 +524,8 @@ def attend(q, entry, pos, scale, *, v_dim: int = 0, tail=None,
     those. ``info``: select_tokens' record of that choice; the attend
     adds how it read it (select_counts' ``reads``). ``window`` > 0: a
     sliding layer's ring, whose keys past the window are masked by the
-    position each column holds (``work``: window_work's list)."""
+    position each column holds (``work``: window_work's list). ``sinks``
+    (H,): a logit a head in the softmax's denominator (_attend_cache)."""
     if tail is not None:
         rows, newest = tail
         tail = (rows["k"], rows.get("v"), newest)
@@ -532,24 +533,26 @@ def attend(q, entry, pos, scale, *, v_dim: int = 0, tail=None,
                          k_scale=entry.get("ks"),
                          v_scale=entry.get("vs"), v_dim=v_dim, tail=tail,
                          work=work, select=select, info=info,
-                         window=window)
+                         window=window, sinks=sinks)
 
 
 def attend_block(q, entry, pos_q, scale, *, pos0, v_dim: int = 0,
                  work=None, select=None, block_len: int = 0,
-                 window: int = 0):
+                 window: int = 0, sinks=None):
     """T queries a row, q (b, T, H, head_dim), query i at position
     pos_q[b, i] = pos0_b + i (_attend_cache_block). ``work``: the
     step's attend_work, for ``pos0`` and this T. ``select``
     (b, T, max_len) bool: select_tokens' choice, query by query.
     ``block_len`` > 0: the block-causal mask (whole blocks, ``pos0`` a
     multiple of it): a query attends every position of its block.
-    ``window`` > 0: a sliding layer's ring (attend)."""
+    ``window`` > 0: a sliding layer's ring (attend). ``sinks``: see
+    attend."""
     return _attend_cache_block(q, entry["k"], entry.get("v"), pos_q,
                                scale, k_scale=entry.get("ks"),
                                v_scale=entry.get("vs"), pos0=pos0,
                                v_dim=v_dim, work=work, select=select,
-                               block_len=block_len, window=window)
+                               block_len=block_len, window=window,
+                               sinks=sinks)
 
 
 # ---- the token selector ------------------------------------------------
@@ -805,7 +808,8 @@ def _ring_positions(newest, R: int):
 def _attend_cache(q, k_cache, v_cache, pos, scale,
                   k_scale=None, v_scale=None, use_flash=None,
                   v_dim: int = 0, tail=None, work=None, select=None,
-                  info: Optional[dict] = None, window: int = 0):
+                  info: Optional[dict] = None, window: int = 0,
+                  sinks=None):
     """q (b, 1, H, hd) against the cache prefix [0, pos]: full-length
     matmul over the static cache, masked beyond the position. ``pos``
     is a scalar (all rows at the same position) or a (b,) vector
@@ -845,7 +849,11 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
     (kvcache's module docstring) whose newest position is ``pos``; the
     query (at ``pos``, or with a tail at pos + 1 + newest) attends the
     keys of its last ``window`` positions alone, each column masked by
-    the position it holds."""
+    the position it holds.
+
+    ``sinks`` (H,): attention sinks, a logit a head that joins its
+    softmax's denominator beside the keys: sum_j exp(s_j - m) v_j /
+    (sum_j exp(s_j - m) + exp(sink - m))."""
     b, one, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
     if info is not None:
@@ -864,11 +872,14 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
         # materializing the dequant at batch 32), online softmax, one
         # pass — rlo_tpu.pallas.decode
         from rlo_tpu.pallas.decode import flash_decode
+        if sinks is not None:
+            with jax.named_scope("mla.sink"):
+                sinks = sinks.astype(jnp.float32)
         return flash_decode(
             q, k_cache, v_cache, pos, scale, k_scale, v_scale,
             v_dim=v_dim, tail=tail, work=work,
             select=None if select is None else select[:, 0],
-            window=window)
+            window=window, sinks=sinks)
     # the einsum path IS the T=1 case of the block attend — one
     # implementation, so a dequant/mask/dtype fix can never diverge
     # decode_step from block_decode (speculative decoding's
@@ -879,14 +890,14 @@ def _attend_cache(q, k_cache, v_cache, pos, scale,
     return _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                                k_scale=k_scale, v_scale=v_scale,
                                v_dim=v_dim, tail=tail, select=select,
-                               window=window)
+                               window=window, sinks=sinks)
 
 
 def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                         k_scale=None, v_scale=None, pos0=None,
                         use_flash=None, v_dim: int = 0, tail=None,
                         work=None, select=None, block_len: int = 0,
-                        window: int = 0):
+                        window: int = 0, sinks=None):
     """Block variant of the cache attend: q (b, T, nh, hd) where query
     i of row b sits at position pos_q[b, i] and attends cache
     positions <= pos_q[b, i]. Because the block's own K/V rows are
@@ -925,7 +936,9 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
     ``window`` > 0: a sliding layer's ring (_attend_cache), whose newest
     position is the last query's pos_q (with a tail: pos_q itself, the
     queries at pos_q + 1 + newest); a query attends its last ``window``
-    positions alone."""
+    positions alone.
+
+    ``sinks`` (H,): attention sinks (_attend_cache), in every form."""
     b, T, nh, hd = q.shape
     nkv, max_len = k_cache.shape[1], k_cache.shape[3]
     if window and (block_len or v_dim or select is not None
@@ -975,14 +988,17 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
         use_flash = fits
     if use_flash:
         from rlo_tpu.pallas.decode import flash_block_decode
+        if sinks is not None:
+            with jax.named_scope("mla.sink"):
+                sinks = sinks.astype(jnp.float32)
         return flash_block_decode(
             q, k_cache, v_cache, pos0, scale, k_scale, v_scale,
             v_dim=v_dim, work=work, block_len=block_len,
             select=None if select is None else select[:, 0],  # T == 1
-            window=window)
+            window=window, sinks=sinks)
     if blocked:
         return _attend_latent_blocked(q, k_cache, pos_q, scale, v_dim,
-                                      select)
+                                      select, sinks)
     if v_dim:  # latent: the values are the stream's leading features
         v_cache = k_cache[:, :, :v_dim]
     rep = nh // nkv
@@ -1023,7 +1039,15 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
                          preferred_element_type=jnp.float32) * scale
         s = jnp.concatenate(
             [s, jnp.where(live[:, None, None, None, :], s_t, _NEG)], -1)
-    p = jax.nn.softmax(s, axis=-1)
+    if sinks is not None:
+        with jax.named_scope("mla.sink"):
+            sink = jnp.broadcast_to(
+                sinks.astype(jnp.float32).reshape(1, nkv, rep, 1, 1),
+                s.shape[:-1] + (1,))
+            p = jax.nn.softmax(jnp.concatenate([s, sink], -1),
+                               axis=-1)[..., :-1]
+    else:
+        p = jax.nn.softmax(s, axis=-1)
     if tail is not None:
         p, p_t = p[..., :max_len], p[..., max_len:]
     if v_scale is not None:
@@ -1040,14 +1064,15 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
 
 
 def _attend_latent_blocked(q, k_cache, pos_q, scale, v_dim: int,
-                           select=None):
+                           select=None, sinks=None):
     """_attend_cache_block's einsum for a latent cache with NO
     (heads, T, max_len) tensor: the live context tiles of width
     _CTX_TILE one after another, (m, l, o) carried through an online
     softmax. q (b, T, H, d), k_cache (b, 1, d, max_len), pos_q (b, T),
     ``select`` (b, T, max_len) bool or None; returns (b, T, H, v_dim)
     f32. What a (heads, T, tile) f32 block costs is the caller's
-    choice of T."""
+    choice of T. ``sinks`` (H,): attention sinks (_attend_cache), added
+    to the denominator once the tiles are done."""
     b, T, nh, d = q.shape
     bt, n_live, dot_dt = _ctx_tiles(k_cache, pos_q)
     qh = q.transpose(0, 2, 1, 3).astype(dot_dt)          # (b, H, T, d)
@@ -1078,4 +1103,7 @@ def _attend_latent_blocked(q, k_cache, pos_q, scale, v_dim: int,
             jnp.zeros((b, nh, T), jnp.float32),
             jnp.zeros((b, nh, T, v_dim), jnp.float32))
     m, l, o = lax.fori_loop(0, n_live, tile, init)
+    if sinks is not None:
+        with jax.named_scope("mla.sink"):
+            l = l + jnp.exp(sinks.astype(jnp.float32)[None, :, None] - m)
     return (o / l[..., None]).transpose(0, 2, 1, 3)

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from rlo_tpu.ops import tpu_collectives as tc
+from rlo_tpu.pallas.expert_ffn import swiglu
 
 
 def init_moe_params(rng: jax.Array, d_model: int, d_ff: int,
@@ -241,14 +242,17 @@ def row_tile(n_assign: int, n_experts: int) -> int:
     return min(256, 1 << (want - 1).bit_length())
 
 
-def _gated(x, wg, wu, wd):
+def _gated(x, wg, wu, wd, limit: float = 0.0):
     dt = x.dtype
+    if limit:
+        return swiglu(x @ wg.astype(dt), x @ wu.astype(dt), limit) \
+            @ wd.astype(dt)
     gate = jax.nn.silu(x @ wg.astype(dt))
     return (gate * (x @ wu.astype(dt))) @ wd.astype(dt)
 
 
 def held_experts_ffn(params: dict, x, ids, w, first: int,
-                     use_kernel=None, interpret=None):
+                     use_kernel=None, interpret=None, limit: float = 0.0):
     """Sum over the held experts e in [first, first + held) of
     w[t, e] * ffn_e(x[t]) for tokens ``x`` (T, d) -> ((T, d) f32,
     stats). Kernel path: every (token, choice) that landed on a held
@@ -257,7 +261,8 @@ def held_experts_ffn(params: dict, x, ids, w, first: int,
     pallas.expert_ffn runs the live tiles (an expert with no row costs
     nothing). The buffer holds the worst case — every assignment here —
     so nothing is ever dropped. Oracle path: every held expert on every
-    token, masked."""
+    token, masked. ``limit`` > 0: the clamped SwiGLU
+    (pallas.expert_ffn.swiglu)."""
     from rlo_tpu.pallas import expert_ffn as ek
     from rlo_tpu.pallas.reduce import kernel_gate
     t, d = x.shape
@@ -289,11 +294,17 @@ def held_experts_ffn(params: dict, x, ids, w, first: int,
         # landed here, summed per expert
         we = jnp.sum(onto * w[..., None], axis=1)        # (T, held)
         dt = x.dtype
-        gate = jax.nn.silu(jnp.einsum("td,edf->tef", x,
-                                      params["wg"].astype(dt)))
-        up = jnp.einsum("td,edf->tef", x, params["wu"].astype(dt))
-        out = jnp.einsum("tef,efd->ted", gate * up,
-                         params["wd"].astype(dt))
+        if limit:
+            act = swiglu(
+                jnp.einsum("td,edf->tef", x, params["wg"].astype(dt)),
+                jnp.einsum("td,edf->tef", x, params["wu"].astype(dt)),
+                limit)
+        else:
+            gate = jax.nn.silu(jnp.einsum("td,edf->tef", x,
+                                          params["wg"].astype(dt)))
+            act = gate * jnp.einsum("td,edf->tef", x,
+                                    params["wu"].astype(dt))
+        out = jnp.einsum("tef,efd->ted", act, params["wd"].astype(dt))
         return (jnp.einsum("te,ted->td", we, out.astype(jnp.float32)),
                 stats(0))
 
@@ -316,7 +327,7 @@ def held_experts_ffn(params: dict, x, ids, w, first: int,
     n_live = (placed // tm).astype(jnp.int32)
     rows = ek.expert_ffn(x[token_of_row], params["wg"], params["wu"],
                          params["wd"], tile_expert, n_live, tile=tm,
-                         interpret=interpret)            # (n_rows, d)
+                         interpret=interpret, limit=limit)  # (n_rows, d)
     # back to tokens: each choice reads its own row (a select, never a
     # product: a tile that did not run holds no number)
     picked = rows[jnp.minimum(dest, n_rows - 1).reshape(t, k)]
@@ -344,10 +355,12 @@ def routed_ffn(params: dict, h, cfg):
                 routed_scale=cfg.routed_scale)
     with jax.named_scope("moe.experts"):
         out, stats = held_experts_ffn(params, x, ids, w,
-                                      cfg.expert_first)
+                                      cfg.expert_first,
+                                      limit=cfg.swiglu_limit)
     if "swg" in params:
         with jax.named_scope("moe.shared"):
             out = out + _gated(x, params["swg"], params["swu"],
-                               params["swd"]).astype(jnp.float32)
+                               params["swd"],
+                               cfg.swiglu_limit).astype(jnp.float32)
     info = {"ids": ids, "choice": choice, "stats": stats}
     return out.reshape(shape).astype(dt), info

@@ -66,6 +66,10 @@ def init_page_pool(cfg: TransformerConfig, n_pages: int,
     must be a 128-lane multiple so a page is a legal cache block.
     ``cfg.kv_cache_dtype='int8'`` adds (n_pages, kv_heads, page_size)
     f32 scale sidecars at the same page indexes."""
+    if cfg.hc or cfg.index_types is not None:
+        raise ValueError("a page pool carries one residual's per-head K/V: "
+                         "hyper-connections and a shared token selection "
+                         "run on the dense cache so far")
     # rlo-prover: lane-pinned (a page IS one 128-lane cache block)
     if jax.default_backend() == "tpu" and page_size % 128:
         raise ValueError(

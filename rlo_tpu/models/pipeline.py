@@ -89,6 +89,12 @@ def _make_stage_fn(cfg: TransformerConfig):
         raise NotImplementedError(
             "a scan over stacked layers has one attention kind: sliding "
             "layers train through models.transformer.train_step so far")
+    if cfg.hc or cfg.index_types is not None:
+        raise NotImplementedError(
+            "a pipeline stage passes one residual and no token selection "
+            "between layers: hyper-connected streams and a shared "
+            "selection run through models.transformer.forward and the "
+            "dense DecodeServer so far")
 
     def one_layer(x, lp):
         pos = jnp.arange(x.shape[1])  # full sequence per microbatch

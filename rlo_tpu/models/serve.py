@@ -214,7 +214,12 @@ class DecodeServer:
     ``serve.dsa.latent_rows_read`` for a token selector
     (_count_dsa: once a round from the host's ``pos``, by the rule
     kvcache.select_counts keeps beside the program's own, the attend's
-    form as the traced program reported it) and the gauge
+    form as the traced program reported it; keys are scored on the
+    layers whose selection scored alone), ``serve.dsa.
+    selections_scored`` and ``serve.dsa.selections_reused`` (layer-
+    steps that chose a set by scoring it, or took the one the full
+    layer before them chose: cfg.index_types; the layers of each kind
+    as the traced round's step recorded its selections) and the gauge
     ``serve.dsa.select_kernel`` (1 where the traced round's selections
     ran the kernel pallas.decode.topk_select, 0 where XLA's
     kvcache.topk_mask),
@@ -319,6 +324,11 @@ class DecodeServer:
         if paged and (cfg.block_len or cfg.windowed):
             raise ValueError("block diffusion and sliding layers run on "
                              "the dense scheduler so far (paged=False)")
+        if paged and (cfg.hc or cfg.index_types is not None):
+            raise ValueError(
+                "hyper-connections and a shared token selection run on "
+                "the dense scheduler so far (paged=False): the page pool "
+                "holds per-head K/V without index keys")
         # what the server allocates, builds and runs eagerly before its
         # first request is a stage like any other: its seconds, and the
         # programs JAX obtains in them, are the server's (BUILDS)
@@ -408,6 +418,7 @@ class DecodeServer:
         self._dsa_topk = cfg.index_topk if cfg.dsa and \
             max_len > cfg.index_topk else 0
         self._dsa_reads = None
+        self._dsa_layers = None     # (layers that scored, that reused)
 
         # a sliding layer's ring takes the fold modulo its length
         windows = [cfg.layer_window(i) for i in range(cfg.n_layers)]
@@ -431,6 +442,9 @@ class DecodeServer:
                     self._dsa_reads = dsa[0]["reads"]
                     self.metrics.gauge("serve.dsa.select_kernel").set(
                         dsa[0]["select_kernel"])
+                    self._dsa_layers = (
+                        sum(1 for d in dsa if "keys_scored" in d),
+                        sum(1 for d in dsa if d.get("shared")))
                 return (tok, kv, *stats), tok
 
             stats = ([jnp.zeros((len(moe.STATS),), jnp.int32)]
@@ -1326,18 +1340,31 @@ class DecodeServer:
         writes, then selects, then attends), cut at max_len. The rule
         is kvcache.select_counts', kept beside select_tokens; how the
         attend read a selection is what the traced round reported.
-        Summed over the layers, but for ``dense_row_steps``, which are
-        row-steps. Every slot counts, free and finished ones too: the
-        program runs them."""
+        Summed over the layers that do each, as the traced round's
+        step recorded them (``_dsa_layers``): ``keys_scored`` over the
+        layers whose selection scored, the attends' counts over all;
+        ``dense_row_steps`` are row-steps. ``selections_scored`` and
+        ``selections_reused`` count layer-steps that chose a set: by
+        scoring it, or (a shared layer of cfg.index_types) by taking
+        the set the full layer before it chose. Every slot counts, free
+        and finished ones too: the program runs them."""
         if not self._dsa_topk:
             return
         ctx = np.minimum(self.pos[:, None].astype(np.int64) + 1
                          + np.arange(kk), self.max_len)    # (rows, kk)
         counts = kvcache.select_counts(ctx, self._dsa_topk,
                                        self._dsa_reads)
+        scored, reused = self._dsa_layers
         for name, n in counts.items():
             self.metrics.counter("serve.dsa." + name).inc(
-                n if name == "dense_row_steps" else n * self.cfg.n_layers)
+                n if name == "dense_row_steps" else
+                n * scored if name == "keys_scored" else
+                n * self.cfg.n_layers)
+        chose = int((ctx > self._dsa_topk).any(axis=0).sum())  # steps
+        self.metrics.counter("serve.dsa.selections_scored").inc(
+            chose * scored)
+        self.metrics.counter("serve.dsa.selections_reused").inc(
+            chose * reused)
 
     def _count_window(self, kk: int) -> None:
         """The sliding layers' attends in the round just launched, from

@@ -204,8 +204,43 @@ class TransformerConfig:
     sandwich_norm: bool = False
     # the embedding's rows times this (muP's input scale); 1 is none
     embed_scale: float = 1.0
+    # hyper-connections, selected by hc_mult > 0: the residual is hc_mult
+    # streams of width d. Each sublayer reads a learned mix of them and
+    # is written back through a doubly stochastic hc_mult x hc_mult
+    # mixing and an output weight of at most hc_magnitude (hc_mix;
+    # ``hc_attn`` / ``hc_ffn`` a layer); hc_eps is the Sinkhorn's
+    hc_mult: int = 0
+    hc_magnitude: float = 2.0
+    hc_eps: float = 1e-6
+    # a learned logit a head in the attention softmax's denominator
+    # (``attn_sink`` (heads,) a layer): latent attention only
+    attn_sinks: bool = False
+    # shared token selection: each layer's selector kind, "full" (it
+    # scores and chooses) or "shared" (it attends the set the nearest
+    # full layer before it chose for the same query, and holds no
+    # selector and no index keys); None: every layer full
+    index_types: Optional[tuple] = None
+    # the experts' SwiGLU clamped: silu(min(x wg, L)) * clip(x wu, -L, L)
+    # in routed and shared experts (0: none)
+    swiglu_limit: float = 0.0
+    # the output head in float32: ``head`` stored in float32, the normed
+    # activation upcast, the product at full precision
+    lm_head_fp32: bool = False
 
     def __post_init__(self):
+        if self.index_types is not None:
+            kinds = tuple(self.index_types)
+            object.__setattr__(self, "index_types", kinds)
+            if (len(kinds) != self.n_layers or not self.dsa
+                    or set(kinds) - {"full", "shared"}
+                    or kinds[0] != "full"):
+                raise ValueError(
+                    f"index_types needs 'full' or 'shared' a layer "
+                    f"({self.n_layers}), the first 'full', and a token "
+                    f"selector (index_topk > 0); got {kinds}")
+        if self.attn_sinks and not self.mla:
+            raise ValueError("attention sinks enter latent attention "
+                             "(kv_lora_rank > 0) only")
         if self.layer_types is None:
             return
         kinds = tuple(self.layer_types)
@@ -218,6 +253,22 @@ class TransformerConfig:
                 f"{len(kinds)} entries, unknown {sorted(unknown)}")
         if "sliding_attention" in kinds and self.sliding_window < 1:
             raise ValueError("sliding layers need sliding_window >= 1")
+
+    def selects(self, i: int) -> bool:
+        """Whether layer i scores and chooses its tokens itself: a token
+        selector's layer, unless index_types makes it a shared one."""
+        return self.dsa and (self.index_types is None
+                             or self.index_types[i] == "full")
+
+    def index_kind(self, i: int) -> Optional[str]:
+        """Layer i's selector: "full", "shared", or None without one."""
+        if not self.dsa:
+            return None
+        return "full" if self.selects(i) else "shared"
+
+    @property
+    def hc(self) -> bool:
+        return self.hc_mult > 0
 
     def layer_window(self, i: int) -> int:
         """Layer i's attention window: sliding_window on a sliding layer,
@@ -315,7 +366,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
         "ln_f": {"g": ones(d)},
         "layers": [],
     }
-    if not cfg.tie_embeddings:
+    if cfg.lm_head_fp32:
+        params["head"] = jax.random.normal(keys[1], (cfg.vocab, d),
+                                           jnp.float32) * d ** -0.5
+    elif not cfg.tie_embeddings:
         params["head"] = norm(keys[1], (cfg.vocab, d), d ** -0.5)
     k = 2
     for i in range(cfg.n_layers):
@@ -340,7 +394,7 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             layer["wuk"] = norm(k4, (kl, cfg.n_heads, nope), kl ** -0.5)
             layer["wuv"] = norm(k5, (kl, cfg.n_heads, cfg.v_head_dim),
                                 kl ** -0.5)
-            if cfg.dsa:     # its own keys: the others draw what they drew
+            if cfg.selects(i):  # its own keys: the others draw theirs
                 hi, di = cfg.index_n_heads, cfg.index_head_dim
                 k6, k7, k8 = jax.random.split(
                     jax.random.fold_in(keys[k], 1), 3)
@@ -370,6 +424,17 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
                     kn1, (d,), 0.1).astype(jnp.float32)).astype(pdt)}
                 layer["ln_ffn_out"] = {"g": (1.0 + norm(
                     kn2, (d,), 0.1).astype(jnp.float32)).astype(pdt)}
+        if cfg.attn_sinks:
+            # about ln(index_topk) + N(0, 1): a sink then takes a share of
+            # a head's softmax comparable to its selected keys' (at 0,
+            # the release's initial value, it would take 1 / index_topk)
+            layer["attn_sink"] = (jax.random.normal(
+                jax.random.fold_in(keys[k], 3), (cfg.n_heads,), jnp.float32)
+                + float(np.log(max(cfg.index_topk, 2)))).astype(pdt)
+        if cfg.hc:
+            ka, kf = jax.random.split(jax.random.fold_in(keys[k], 4))
+            layer["hc_attn"] = init_hc_params(ka, cfg)
+            layer["hc_ffn"] = init_hc_params(kf, cfg)
         if cfg.layer_is_moe(i) and cfg.moe_router in moe.ROUTED:
             layer["moe"] = moe.init_routed_params(keys[k + 2], cfg)
         elif cfg.layer_is_moe(i):
@@ -396,6 +461,19 @@ def head_weights(params: dict):
     """The output head (vocab, d): ``head`` where the configuration
     unties it, else the embedding."""
     return params.get("head", params["embed"])
+
+
+def head_logits(params: dict, x, cfg: TransformerConfig):
+    """f32 logits of the normed activations ``x`` (..., d): in the
+    activation dtype with f32 out, or under ``cfg.lm_head_fp32`` with
+    ``x`` upcast and the float32 head at full precision."""
+    if cfg.lm_head_fp32:
+        with jax.named_scope("head.fp32"):
+            return jnp.dot(x.astype(jnp.float32),
+                           params["head"].astype(jnp.float32).T,
+                           precision=lax.Precision.HIGHEST)
+    return (x @ head_weights(params).T.astype(cfg.act_dtype)).astype(
+        jnp.float32)
 
 
 def param_pspecs(cfg: TransformerConfig, tp_axis: Optional[str] = None,
@@ -725,9 +803,124 @@ def mla_project(h, layer: dict, cfg: TransformerConfig, pos):
         q = (c_q @ layer["wuq"].astype(dt)).reshape(
             b, blk, cfg.n_heads, nope + rope)
         q_rope = _rope_cfg(q[..., nope:], pos, cfg)
-    index = index_project(h, c_q, layer, cfg, pos) if cfg.dsa else None
+    index = (index_project(h, c_q, layer, cfg, pos)
+             if cfg.dsa and "wiq" in layer else None)
     return (q[..., :nope], q_rope, jnp.concatenate([c_kv, k_r], -1),
             index)
+
+
+#: Sinkhorn normalisations of a hyper-connection's mixing matrix (rows,
+#: then columns, divided by their sums), each boundary
+HC_SINKHORN_ITERS = 20
+
+
+def init_hc_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
+    """One sublayer's hyper-connection, n = hc_mult: ``phi`` (2n + n²,
+    n·d) in param_dtype at (n·d)^-0.5 (so that every coefficient's
+    product with the normed streams is of order 1), and in float32
+    ``scale`` (3,) = [alpha_pre, alpha_post, alpha_res] at 0.5 and
+    ``bias`` (2n + n²,) drawn N(0, 1): streams that part from the first
+    sublayer on, and a mixing far from the identity (hc_mix)."""
+    n, d = cfg.hc_mult, cfg.d_model
+    width = 2 * n + n * n
+    k1, k2 = jax.random.split(rng)
+    return {"phi": (jax.random.normal(k1, (width, n * d), jnp.float32)
+                    * (n * d) ** -0.5).astype(cfg.weight_dtype),
+            "scale": jnp.full((3,), 0.5, jnp.float32),
+            "bias": jax.random.normal(k2, (width,), jnp.float32)}
+
+
+def hc_mix(x, out, coef, hc: Optional[dict], cfg: TransformerConfig):
+    """One hyper-connection boundary as XLA ops, on rows of streams ``x``
+    (..., n·d) (stream i in features [i·d, (i+1)·d)). On the TPU this is
+    the boundary too: a Pallas kernel of the same arithmetic (one pass
+    over the streams in VMEM) ran the decode round of
+    hy4-ep32-longctx32k-decode 0.38% SLOWER than these ops (v5e, 20
+    rounds a form, alternated), so the model has this one form.
+
+    ``out`` (..., d) and ``coef`` (..., 2n + n²) f32 are the finished
+    sublayer's output and its coefficients [h_pre | h_post | H_res,
+    row-major] (None before the first sublayer): the streams become
+    x'_i = sum_j H_res[i, j] x_j + h_post[i] out, in x's dtype. ``hc`` is
+    the next sublayer's (init_hc_params; None after the last, when the
+    result is sum_i x'_i). From x': x̂ = x' / rms(x') over all n·d
+    features (norm_eps, no gain), a = x̂ phi^T, h_pre = sigmoid(alpha_pre
+    a[:n] + b[:n]), h_post = hc_magnitude sigmoid(alpha_post a[n:2n] +
+    b[n:2n]), H_res = Sinkhorn(exp(alpha_res a[2n:] + b[2n:])) (rows then
+    columns divided by their sum + hc_eps, HC_SINKHORN_ITERS times), and
+    the next sublayer's input u = sum_i h_pre[i] x'_i. Returns (x', u,
+    coef'), all in float32 but the streams and u."""
+    n = cfg.hc_mult
+    lead = x.shape[:-1]
+    d = x.shape[-1] // n
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    xs = x.reshape(lead + (n, d)).astype(f32)
+    if out is not None:
+        with jax.named_scope("hc.post"):
+            H = coef[..., 2 * n:].reshape(lead + (n, n))
+            mixed = (jnp.einsum("...ij,...jd->...id", H, xs, precision=hi)
+                     + coef[..., n:2 * n, None] * out.astype(f32)[
+                         ..., None, :]).astype(x.dtype)
+            if hc is None:
+                return jnp.sum(mixed.astype(f32), axis=-2).astype(x.dtype)
+            x = mixed.reshape(lead + (n * d,))
+            xs = mixed.astype(f32)
+    with jax.named_scope("hc.pre"):
+        flat = xs.reshape(lead + (n * d,))
+        xh = flat * lax.rsqrt(jnp.mean(flat * flat, axis=-1, keepdims=True)
+                              + cfg.norm_eps)
+        a = jnp.dot(xh, hc["phi"].astype(f32).T, precision=hi)
+        scale, bias = hc["scale"].astype(f32), hc["bias"].astype(f32)
+        pre = jax.nn.sigmoid(scale[0] * a[..., :n] + bias[:n])
+        post = cfg.hc_magnitude * jax.nn.sigmoid(
+            scale[1] * a[..., n:2 * n] + bias[n:2 * n])
+        H = jnp.exp(scale[2] * a[..., 2 * n:] + bias[2 * n:]).reshape(
+            lead + (n, n))
+
+        def sinkhorn(_, H):
+            H = H / (jnp.sum(H, axis=-1, keepdims=True) + cfg.hc_eps)
+            return H / (jnp.sum(H, axis=-2, keepdims=True) + cfg.hc_eps)
+
+        H = lax.fori_loop(0, HC_SINKHORN_ITERS, sinkhorn, H)
+        u = jnp.einsum("...i,...id->...d", pre, xs, precision=hi)
+        return x, u.astype(x.dtype), jnp.concatenate(
+            [pre, post, H.reshape(lead + (n * n,))], -1)
+
+
+def hc_boundary(state, hc: Optional[dict], cfg: TransformerConfig):
+    """The boundary before a sublayer (or, ``hc`` None, after the last):
+    ``state`` is (streams (b, T, n·d), the finished sublayer's output
+    (b, T, d) or None, its coefficients (b, T, 2n + n²) or None). Returns
+    (streams', the next sublayer's input u (b, T, d), its coefficients),
+    or after the last the streams' sum (b, T, d): hc_mix."""
+    x, out, coef = state
+    return hc_mix(x, out, coef, hc, cfg)
+
+
+def hc_enter(x, cfg: TransformerConfig):
+    """The embedding (b, T, d) as a hyper-connected residual's state: n
+    copies of it as the streams, no sublayer pending (hc_boundary)."""
+    return jnp.tile(x, (1, 1, cfg.hc_mult)), None, None
+
+
+def attention_with_sinks(q, k, v, scale: float, sinks):
+    """Causal attention of q (b, L, H, D) over k (b, L, H, D) and v
+    (b, L, H, Dv) in which every head's softmax denominator holds
+    exp(sinks[h]) beside its keys: a_h = sum_j exp(s_hj - m) v_j /
+    (sum_j exp(s_hj - m) + exp(sink_h - m)), float32 scores. A plain
+    einsum: it attends a prompt block, no longer than index_topk under a
+    selector. Returns (b, L, H, Dv) f32."""
+    b, L, nh, _ = q.shape
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    causal = jnp.arange(L)[:, None] >= jnp.arange(L)[None, :]
+    s = jnp.where(causal, s, -jnp.inf)
+    with jax.named_scope("mla.sink"):
+        sink = jnp.broadcast_to(
+            sinks.astype(jnp.float32)[None, :, None, None], (b, nh, L, 1))
+        p = jax.nn.softmax(jnp.concatenate([s, sink], -1), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p[..., :L].astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
 
 
 def check_unselected(cfg: TransformerConfig, blk: int) -> None:
@@ -757,6 +950,9 @@ def mla_unabsorbed(q_nope, q_rope, latent, layer: dict,
         k = jnp.concatenate([k_nope, k_r], -1)
         q = jnp.concatenate([q_nope, q_rope], -1)
     with jax.named_scope("mla.attend"):
+        if cfg.attn_sinks:
+            return attention_with_sinks(q, k, v, cfg.attn_scale,
+                                        layer["attn_sink"])
         return _local_attention(q, k, v, scale=cfg.attn_scale)
 
 
@@ -816,9 +1012,17 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
     callers that count or check it. ``window``: the layer's attention
     window (cfg.layer_window; 0 for a full layer). Under cfg.nope_full a
     full layer rotates nothing; the attention hook is told nothing of
-    the window — its caller knows the layer."""
-    b, blk, _ = x.shape
-    dt = x.dtype
+    the window — its caller knows the layer. Under hyper-connections
+    (``cfg.hc``) ``x`` and the result are the residual's STATE
+    (hc_boundary: the streams, the pending sublayer's output and its
+    coefficients); the FFN's output leaves pending, for the next
+    layer's first boundary or hc_boundary(state, None, cfg)."""
+    if cfg.hc and (sp_axis is not None or tp_axis is not None):
+        raise ValueError("hyper-connections run unsharded so far (no "
+                         "sp_axis / tp_axis): the ring, Ulysses and "
+                         "tensor-parallel paths carry one residual")
+    b, blk, _ = (x[0] if cfg.hc else x).shape
+    dt = (x[0] if cfg.hc else x).dtype
     ntp = lax.axis_size(tp_axis) if tp_axis is not None else 1
     assert cfg.n_heads % ntp == 0 and cfg.d_ff % ntp == 0, \
         f"tp={ntp} must divide n_heads {cfg.n_heads} and d_ff {cfg.d_ff}"
@@ -837,6 +1041,21 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
             out = _rmsnorm(out, layer["ln_ffn_out"]["g"], cfg.norm_eps)
         return x + out, aux
 
+    def hc_ffn_sublayer(out):
+        # the attention's output into the streams, the FFN on its mix of
+        # them; its output waits for the next boundary
+        streams, u, c = hc_boundary((hc_streams, out, hc_coef),
+                                    layer["hc_ffn"], cfg)
+        out, aux = _ffn(_rmsnorm(u, layer["ln2"]["g"], cfg.norm_eps),
+                        layer, cfg, tp_sum, ep_axis, moe_info)
+        if cfg.sandwich_norm:
+            out = _rmsnorm(out, layer["ln_ffn_out"]["g"], cfg.norm_eps)
+        return (streams, out, c), aux
+
+    if cfg.hc:
+        # ``x`` is the hyper-connected residual's state (hc_boundary):
+        # the attention reads its mix of the streams
+        hc_streams, x, hc_coef = hc_boundary(x, layer["hc_attn"], cfg)
     h = _rmsnorm(x, layer["ln1"]["g"], cfg.norm_eps)
     if cfg.mla:
         if sp_axis is not None or tp_axis is not None:
@@ -849,9 +1068,16 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
         else:
             check_unselected(cfg, blk)
             att = mla_unabsorbed(q_nope, q_rope, latent, layer, cfg)
+        att = att.reshape(b, blk, -1).astype(dt)
+        if cfg.attn_gate:
+            with jax.named_scope("mla.gate"):
+                att = att * jax.nn.sigmoid(h @ layer["attn_gate"].astype(dt))
+        if cfg.hc:
+            with jax.named_scope("mla.out"):
+                out = att @ layer["wo"].astype(dt)
+            return hc_ffn_sublayer(out)
         with jax.named_scope("mla.out"):
-            x = x + att.reshape(b, blk, -1).astype(dt) @ layer[
-                "wo"].astype(dt)
+            x = x + att @ layer["wo"].astype(dt)
         return ffn_sublayer(x)
     if cfg.kv_heads == cfg.n_heads:
         w = layer["wqkv"].astype(dt)   # (d, 3, local heads x hd)
@@ -903,6 +1129,8 @@ def apply_layer(x, layer: dict, cfg: TransformerConfig, *,
     out = tp_sum(att @ layer["wo"].astype(dt))
     if cfg.sandwich_norm:
         out = _rmsnorm(out, layer["ln_attn_out"]["g"], cfg.norm_eps)
+    if cfg.hc:
+        return hc_ffn_sublayer(out)
     return ffn_sublayer(x + out)
 
 
@@ -1040,8 +1268,7 @@ def forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     """
     x, aux_total = _features(params, tokens, cfg, sp_axis, tp_axis,
                              tp_algorithm, ep_axis)
-    dt = cfg.act_dtype
-    logits = (x @ head_weights(params).T.astype(dt)).astype(jnp.float32)
+    logits = head_logits(params, x, cfg)
     if with_aux:
         return logits, aux_total
     return logits
@@ -1065,6 +1292,8 @@ def _features(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     pos = pos0 + jnp.arange(blk)
 
     x = embed_tokens(params["embed"], tokens, pos, cfg)
+    if cfg.hc:
+        x = hc_enter(x, cfg)
     aux_total = jnp.zeros((), jnp.float32)
 
     def block(x, layer, window=0):
@@ -1078,7 +1307,8 @@ def _features(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     for i, layer in enumerate(params["layers"]):
         x, aux = block(x, layer, cfg.layer_window(i))
         aux_total = aux_total + aux
-
+    if cfg.hc:      # the last output into the streams, and their sum
+        x = hc_boundary(x, None, cfg)
     return _rmsnorm(x, params["ln_f"]["g"], cfg.norm_eps), aux_total
 
 
@@ -1112,9 +1342,7 @@ def loss_fn(params: dict, tokens: jax.Array, cfg: TransformerConfig,
         local, count = nll_sum_chunked(x, head_weights(params), targets,
                                        valid, chunk)
     else:
-        logits = (x @ head_weights(params).T.astype(cfg.act_dtype)) \
-            .astype(jnp.float32)
-        local, count = nll_sum(logits, targets, valid)
+        local, count = nll_sum(head_logits(params, x, cfg), targets, valid)
     if sp_axis is not None:
         local = lax.psum(local, sp_axis)
         count = lax.psum(count, sp_axis)

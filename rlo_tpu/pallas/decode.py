@@ -258,7 +258,7 @@ def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
                    bk: int, max_len: int, quant: bool,
                    r: int, T: int, v_dim: int = 0, n_tail: int = 0,
                    selected: bool = False, block_len: int = 0,
-                   n_slots: int = 0, window: int = 0):
+                   n_slots: int = 0, window: int = 0, sinks: bool = False):
     if n_tail:          # a fourth prefetched scalar: the tail's newest row
         newest_ref, refs = refs[0], refs[1:]
     if window:          # and a ring's lap: the position its column 0 holds
@@ -274,6 +274,8 @@ def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
             tv_ref, rest = rest[0], rest[1:]
     if selected:        # the positions this row may attend, 1.0 or 0.0
         sel_ref, rest = rest[0], rest[1:]
+    if sinks:           # a logit a query row in its softmax's denominator
+        sink_ref, rest = rest[0], rest[1:]
     if quant:
         ks_ref, vs_ref, rest = rest[0], rest[1], rest[2:]
     o_ref, m_s, l_s, o_s, *rings = rest
@@ -415,6 +417,10 @@ def _decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
     # sentinel that ends the list
     @pl.when(row_ref[i + 1] != ib)
     def _flush():
+        if sinks:
+            den = l_s[...] + jnp.exp(sink_ref[...] - m_s[...])
+            o_ref[0] = o_s[...] / den[..., None]
+            return
         o_ref[0] = o_s[...] / l_s[...][..., None]
 
 
@@ -1210,28 +1216,29 @@ def _block_fits_vmem(L: int, d: int, nkv: int, r: int, T: int,
 def flash_decode(q, k_cache, v_cache, pos, scale, k_scale=None,
                  v_scale=None, *, block_k: Optional[int] = None,
                  interpret: Optional[bool] = None, v_dim: int = 0,
-                 tail=None, work=None, select=None, window: int = 0):
+                 tail=None, work=None, select=None, window: int = 0,
+                 sinks=None):
     """Fused decode attention. ``q`` is (b, 1, n_heads, head_dim) (the
     _attend_cache caller layout); caches head-leading as in
     models.generate. ``pos`` scalar or (b,). Returns
     (b, 1, n_heads, head_dim) f32. A latent cache passes ``v_cache``
     None and ``v_dim``; ``tail`` is a round's write-behind rows;
     ``work`` a prebuilt work list; ``select`` a token selector's choice;
-    ``window`` a sliding layer's ring (all five: see
-    flash_block_decode)."""
+    ``window`` a sliding layer's ring; ``sinks`` a logit a head in the
+    softmax's denominator (all six: see flash_block_decode)."""
     assert q.shape[1] == 1, q.shape  # single query; flash_block_decode for T>1
     return flash_block_decode(q, k_cache, v_cache, pos, scale,
                               k_scale=k_scale, v_scale=v_scale,
                               block_k=block_k, interpret=interpret,
                               v_dim=v_dim, tail=tail, work=work,
-                              select=select, window=window)
+                              select=select, window=window, sinks=sinks)
 
 
 def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
                        v_scale=None, *, block_k: Optional[int] = None,
                        interpret: Optional[bool] = None, v_dim: int = 0,
                        tail=None, work=None, select=None,
-                       block_len: int = 0, window: int = 0):
+                       block_len: int = 0, window: int = 0, sinks=None):
     """Fused T-query block decode attention (the speculative-decoding
     verify shape): ``q`` is (b, T, n_heads, head_dim) where row b's
     query t sits at sequence position ``pos0[b] + t`` and attends
@@ -1290,7 +1297,13 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     (_window_masks). The grid walks only the ring tiles that hold a
     row's window (window_work_list, ``work``), whole tiles, and the
     kernel is ``flash_decode_window`` in the trace. Per-head K/V without
-    scales, selection or block mask."""
+    scales, selection or block mask.
+
+    ``sinks`` (n_heads,): a logit a head that joins its softmax's
+    denominator beside the keys (attention sinks): the output is
+    sum_j exp(s_j - m) v_j / (sum_j exp(s_j - m) + exp(sink - m)),
+    applied when a row's last tile is flushed. Without it the kernel is,
+    to the bit, the one it was."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, T, nh, d = q.shape
@@ -1373,8 +1386,16 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
             f"{work[1].shape}")
     if tail is not None:
         tail = (tk, tv, jnp.asarray(newest, jnp.int32).reshape(1))
+    if sinks is not None:
+        if sinks.shape != (nh,):
+            raise ValueError(f"flash_block_decode: a sink a head, "
+                             f"({nh},); got {sinks.shape}")
+        # the kernel's query rows, head g * r + rr at row t * r + rr
+        sinks = jnp.tile(sinks.astype(jnp.float32).reshape(nkv, 1, r),
+                         (1, T, 1)).reshape(nkv, R)
     return _flash_call(q, k_cache, v_cache, posv, k_scale, v_scale, tail,
-                       tuple(work), select, scale=float(scale), bk=bk,
+                       tuple(work), select, sinks, scale=float(scale),
+                       bk=bk,
                        n_slots=_ring_slots(R, d, dv, streams,
                                            k_cache.dtype.itemsize),
                        interpret=bool(interpret), v_dim=v_dim,
@@ -1384,7 +1405,7 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
 @functools.partial(jax.jit, static_argnames=(
     "scale", "bk", "n_slots", "interpret", "v_dim", "block_len", "window"))
 def _flash_call(q, k_cache, v_cache, posv, k_scale, v_scale, tail, work,
-                select, *, scale: float, bk: int, n_slots: int,
+                select, sinks=None, *, scale: float, bk: int, n_slots: int,
                 interpret: bool, v_dim: int, block_len: int,
                 window: int):
     """flash_block_decode's kernel call, its shapes and tiling settled.
@@ -1452,6 +1473,11 @@ def _flash_call(q, k_cache, v_cache, posv, k_scale, v_scale, tail, work,
         in_specs += [pl.BlockSpec((1, 1, 1, bk), cache_map)]
         args += [vary_like(select.astype(jnp.float32)[:, None, None, :],
                            k_cache)]
+    if sinks is not None:
+        in_specs += [pl.BlockSpec(
+            (nkv, R), lambda i, row_ref, tile_ref, pos_ref, _newest=None,
+            _lap=None: (0, 0))]
+        args += [vary_like(sinks, k_cache)]
     if quant:
         # scales reshaped (b, kvh, 1, L): the (1, bk) trailing block
         # dims satisfy Mosaic's tiling rule for any bk multiple of 128
@@ -1483,7 +1509,8 @@ def _flash_call(q, k_cache, v_cache, posv, k_scale, v_scale, tail, work,
                           v_dim=v_dim, n_tail=n_tail,
                           selected=select is not None,
                           block_len=block_len, n_slots=n_slots,
-                          window=window),
+                          window=window,
+                          **({"sinks": True} if sinks is not None else {})),
         grid_spec=grid_spec,
         out_shape=out_struct((b, nkv, R, dv), jnp.float32, q, k_cache),
         interpret=interpret,

@@ -130,8 +130,18 @@ def _dot(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
+def swiglu(g, u, limit: float = 0.0):
+    """silu(g) * u, or with ``limit`` > 0 the clamped form
+    silu(min(g, limit)) * clip(u, -limit, limit): the kernel's body and
+    models.moe's XLA forms alike."""
+    if limit:
+        g = jnp.minimum(g, limit)
+        u = jnp.clip(u, -limit, limit)
+    return jax.nn.silu(g) * u
+
+
 def _kernel(te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
-            *scratch, nd: int, nf: int, fb: int):
+            *scratch, nd: int, nf: int, fb: int, limit: float = 0.0):
     i = pl.program_id(0)
     s = pl.program_id(1)
 
@@ -139,7 +149,11 @@ def _kernel(te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
     def _live():
         if not scratch:          # the expert's matrices whole: one step
             x = x_ref[...]
-            h = jax.nn.silu(_dot(x, wg_ref[0])) * _dot(x, wu_ref[0])
+            if limit:
+                h = swiglu(_dot(x, wg_ref[0]), _dot(x, wu_ref[0]),
+                           limit)
+            else:
+                h = jax.nn.silu(_dot(x, wg_ref[0])) * _dot(x, wu_ref[0])
             o_ref[...] = _dot(h.astype(x.dtype),
                               wd_ref[0]).astype(o_ref.dtype)
             return
@@ -163,7 +177,10 @@ def _kernel(te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
 
         @pl.when(s == nd - 1)
         def _activate():
-            h = (jax.nn.silu(g_s[...]) * u_s[...]).astype(h_s.dtype)
+            if limit:
+                h = swiglu(g_s[...], u_s[...], limit).astype(h_s.dtype)
+            else:
+                h = (jax.nn.silu(g_s[...]) * u_s[...]).astype(h_s.dtype)
             for j in range(nf):                          # static slices
                 h_s[j] = h[:, j * fb:(j + 1) * fb]
 
@@ -186,7 +203,7 @@ def _kernel(te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
 
 
 def _call(x, wg, wu, wd, tile_expert, n_live, *, tile: int,
-          n_tiles: int, interpret: bool):
+          n_tiles: int, interpret: bool, limit: float = 0.0):
     n_rows, d = x.shape
     _, _, f = wg.shape
     item = x.dtype.itemsize
@@ -246,7 +263,8 @@ def _call(x, wg, wu, wd, tile_expert, n_live, *, tile: int,
             pltpu.VMEM(shape, dt) for shape, dt in scratch],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, nd=nd, nf=nf, fb=fb),
+        functools.partial(_kernel, nd=nd, nf=nf, fb=fb,
+                          **({"limit": limit} if limit else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows, d), x.dtype),
         interpret=interpret,
@@ -257,11 +275,13 @@ def _call(x, wg, wu, wd, tile_expert, n_live, *, tile: int,
 
 
 def expert_ffn(x, wg, wu, wd, tile_expert, n_live, *, tile: int,
-               interpret: Optional[bool] = None):
+               interpret: Optional[bool] = None, limit: float = 0.0):
     """Grouped gated FFN: row r of ``x`` (n_rows, d), in tile r // tile
     whose expert is ``tile_expert[r // tile]``, through that expert's
-    down(silu(x wg) * (x wu)). Only the first ``n_live`` tiles run;
-    the rows of the others are not written. Returns (n_rows, d)."""
+    down(silu(x wg) * (x wu)) — with ``limit`` > 0, down(silu(min(x wg,
+    limit)) * clip(x wu, -limit, limit)), a static setting of the
+    body. Only the first ``n_live`` tiles run; the rows of the others are
+    not written. Returns (n_rows, d)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n_rows, d = x.shape
@@ -274,7 +294,8 @@ def expert_ffn(x, wg, wu, wd, tile_expert, n_live, *, tile: int,
     tile_expert = jnp.asarray(tile_expert, jnp.int32).reshape(n_tiles)
     n_live = jnp.asarray(n_live, jnp.int32).reshape(())
     run = functools.partial(_call, x, wg, wu, wd, tile_expert, n_live,
-                            tile=tile, interpret=interpret)
+                            tile=tile, interpret=interpret,
+                            **({"limit": float(limit)} if limit else {}))
     if n_tiles <= n_held:
         return run(n_tiles=n_tiles)
     # the common case, at most a tile an expert, on a grid of n_held

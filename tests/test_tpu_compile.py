@@ -12,7 +12,10 @@ sdar-30b-a3b-6l: a pass's block write and block-causal attend of 4
 queries at 192 rows x 32 / 4 heads x 128 x 1536, and the grouped expert
 FFN over 128 held experts at 2048 x 768; trinity-large-ep16: a sliding
 layer's window attend over its ring of 4352 positions at 48 rows x 48 / 8
-heads x 128, with and without the round's tail, and the ring's writes),
+heads x 128, with and without the round's tail, and the ring's writes;
+hy4-preview-ep32: the hyper-connection boundary (XLA's ops) at 16, 256
+and 1024 rows of 4 x 6144, the selected latent attend of 64 heads with a sink
+a head at 16 x 49152, and the clamped expert FFN at 6144 x 2048),
 without a chip: Mosaic's refusals (block shapes, scoped VMEM, scalar-prefetch index
 maps) show up here, numerics and times do not. The topology is described
 inside a fixture, never at import (only one process may load libtpu, and
@@ -467,3 +470,95 @@ def test_ring_writes_compile_for_v5e(one_chip):
     text = put.lower(one, block, jax.ShapeDtypeStruct(
         (1,), jnp.int32, sharding=one_chip)).compile().as_text()
     assert "write_kv_block" in text
+
+
+# hy4-preview-ep32 x longctx32k-decode: 16 slots of 49152 positions, 64
+# heads on one latent stream of 576 with a sink a head, the 32-head
+# selector's top-2048; 4 hyper-connected streams of 6144; 8 held experts
+# of 6144 x 2048 under the clamped SwiGLU
+HY4_SLOTS, HY4_LEN, HY4_HEADS, HY4_D, HY4_STREAMS = 16, 49152, 64, 6144, 4
+
+
+@pytest.mark.parametrize("rows,mode", [(16, "start"), (16, "mix"),
+                                       (16, "readout"), (256, "mix"),
+                                       (1024, "mix")])
+def test_hc_boundary_compiles_for_v5e(one_chip, rows, mode):
+    """One hyper-connection boundary (transformer.hc_mix, XLA's ops on
+    every backend) at a decode step's 16 rows, an extend chunk's 256 and
+    the widest prompt bucket's 1024: no kernel, and temporaries of at
+    most three float32 copies of the streams (none up to 256 rows, where
+    one fusion holds the boundary; three at 1024, 0.3 GB beside a
+    prompt bucket's program)."""
+    from rlo_tpu.models.transformer import TransformerConfig, hc_mix
+    from rlo_tpu.utils import hlo
+    n, d = HY4_STREAMS, HY4_D
+    width = 2 * n + n * n
+    cfg = TransformerConfig(vocab=256, d_model=d, n_heads=HY4_HEADS,
+                            n_layers=1, hc_mult=n, dtype="bfloat16")
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def boundary(x, out, coef, phi, scale, bias):
+        hc = None if mode == "readout" else {"phi": phi, "scale": scale,
+                                             "bias": bias}
+        return hc_mix(x, None if mode == "start" else out,
+                      None if mode == "start" else coef, hc, cfg)
+
+    lowered = jax.jit(boundary).lower(
+        shape((rows, n * d), jnp.bfloat16), shape((rows, d), jnp.bfloat16),
+        shape((rows, width), jnp.float32),
+        shape((width, n * d), jnp.bfloat16), shape((3,), jnp.float32),
+        shape((width,), jnp.float32))
+    assert hlo.mosaic_kernels(lowered.as_text()) == {}
+    memory = lowered.compile().memory_analysis()
+    assert memory.temp_size_in_bytes <= 3 * rows * n * d * 4 + (1 << 20)
+
+
+def test_selected_latent_attend_with_sinks_compiles_for_v5e(one_chip):
+    """The latent attend of the cell's decode step: 64 heads over 576 x
+    49152 with the selection streamed beside the cache and a sink a head
+    joining each softmax when a row's last tile is flushed."""
+    from rlo_tpu.utils import hlo
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cache = shape((HY4_SLOTS, 1, LATENT, HY4_LEN), jnp.bfloat16)
+    bk = flash_decode_tile(cache, HY4_HEADS, latent=True)
+
+    def step(q, c, pos, select, sinks):
+        return flash_block_decode(
+            q, c, None, pos, 256 ** -0.5, v_dim=V_DIM, interpret=False,
+            work=decode_work_list(pos, 1, bk, HY4_LEN // bk),
+            select=select, sinks=sinks)
+
+    lowered = jax.jit(step).lower(
+        shape((HY4_SLOTS, 1, HY4_HEADS, LATENT), jnp.bfloat16), cache,
+        shape((HY4_SLOTS,), jnp.int32), shape((HY4_SLOTS, HY4_LEN), bool),
+        shape((HY4_HEADS,), jnp.float32))
+    assert hlo.mosaic_kernels(lowered.as_text()) == {"flash_decode": 1}
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 1
+
+
+def test_clamped_expert_ffn_compiles_for_v5e(one_chip):
+    """The cell's grouped expert FFN, 16 rows x 8 choices over 8 held
+    experts of 6144 x 2048, with the SwiGLU clamp at 10 in the body."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    held, f, tile = 8, 2048, 16
+    n_rows = buffer_rows(HY4_SLOTS * 8, held, tile)
+
+    def ffn(x, wg, wu, wd, tile_expert, n_live):
+        return expert_ffn(x, wg, wu, wd, tile_expert, n_live, tile=tile,
+                          interpret=False, limit=10.0)
+
+    text = jax.jit(ffn).lower(
+        shape((n_rows, HY4_D), jnp.bfloat16),
+        shape((held, HY4_D, f), jnp.bfloat16),
+        shape((held, HY4_D, f), jnp.bfloat16),
+        shape((held, f, HY4_D), jnp.bfloat16),
+        shape((n_rows // tile,), jnp.int32),
+        shape((), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "expert_ffn" in text

@@ -18,8 +18,9 @@ band and causal masks, no cache, no ring):
   (e) the share test of the model-configs guide for the one-group sigmoid
       router with a shared expert;
   (f) the configurations the benchmark already had trace the programs they
-      traced before sliding layers existed (digests of their parameter
-      trees and jaxprs, kernel bodies included).
+      traced before sliding layers existed, and Trinity the ones it traced
+      before hyper-connections did (digests of their parameter trees and
+      jaxprs, kernel bodies included).
 """
 
 import dataclasses
@@ -400,8 +401,10 @@ def test_expert_shares_sum_to_the_uncut_layer():
 #: PR 37 traced them: on the CPU, and with the kernel gates open for the
 #: TPU (kernel bodies included). A PR that changes one of these programs
 #: ON PURPOSE records the new digest here and says so: deepseek-v3.2-ep32's
-#: TPU decode, block and round select through the topk_select kernel;
-#: every other entry is as it was.
+#: TPU decode, block and round select through the topk_select kernel.
+#: trinity-large-ep16's entries were recorded before hyper-connections,
+#: attention sinks, shared selection and the clamped SwiGLU joined the
+#: layer code, and hold after it (off: nothing changes).
 DIGESTS = {"cpu": {
                 "gpt2-medium.init": "dd8c92089d6d711c",
                 "gpt2-medium.forward": "05c3c695836fd569",
@@ -424,7 +427,13 @@ DIGESTS = {"cpu": {
                 "sdar-30b-a3b-6l.init": "e2d9c7cb45311477",
                 "sdar-30b-a3b-6l.forward": "6fd3a4668ad10944",
                 "sdar-30b-a3b-6l.block": "243b46d4f482e513",
-                "sdar-30b-a3b-6l.round": "e20950c7150a025d",},
+                "sdar-30b-a3b-6l.round": "e20950c7150a025d",
+                "trinity-large-ep16.init": "db07edb5b5f99c7f",
+                "trinity-large-ep16.forward": "59f60d00e7dc46f1",
+                "trinity-large-ep16.prefill": "db39b0f41938e578",
+                "trinity-large-ep16.decode": "f7f5be696541f799",
+                "trinity-large-ep16.block": "39d5f74f2e0d920b",
+                "trinity-large-ep16.round": "19ba1a4fa2ec1821",},
            "tpu": {
                 "gpt2-medium.init": "dd8c92089d6d711c",
                 "gpt2-medium.forward": "25bf5ab646b0f0fb",
@@ -447,7 +456,13 @@ DIGESTS = {"cpu": {
                 "sdar-30b-a3b-6l.init": "e2d9c7cb45311477",
                 "sdar-30b-a3b-6l.forward": "8e95b39c5e0056bc",
                 "sdar-30b-a3b-6l.block": "adc67bcc30b40356",
-                "sdar-30b-a3b-6l.round": "24c36315fac99605",}}
+                "sdar-30b-a3b-6l.round": "24c36315fac99605",
+                "trinity-large-ep16.init": "db07edb5b5f99c7f",
+                "trinity-large-ep16.forward": "3c4e828c9b112238",
+                "trinity-large-ep16.prefill": "2108ceed0efe7442",
+                "trinity-large-ep16.decode": "031af144e5ab66de",
+                "trinity-large-ep16.block": "76d35c8f7cae0060",
+                "trinity-large-ep16.round": "4156b264007af7ee",}}
 
 
 def _digests(tpu: bool, monkeypatch) -> dict:
@@ -461,7 +476,7 @@ def _digests(tpu: bool, monkeypatch) -> dict:
 
     out = {}
     for name in ["gpt2-medium", "deepseek-v3-ep16", "deepseek-v3.2-ep32",
-                 "sdar-30b-a3b-6l"]:
+                 "sdar-30b-a3b-6l", "trinity-large-ep16"]:
         cfg = TransformerConfig(**json.loads(
             (CONFIGS / f"{name}.json").read_text())["tiny"]["model"])
         shapes = jax.eval_shape(lambda k: init_params(k, cfg),
