@@ -1631,14 +1631,15 @@ def index_score(q, w, k_cache, pos, *, work=None,
 
 #: rows of scores one grid step of topk_select holds: a row a sublane of
 #: two f32 vregs. Alone at 32 x 24576 on a v5e 8 rows took 0.087 ms, 16
-#: rows 0.074; at 256 x 24576 0.428 and 0.314 (PERF.md §6)
+#: rows 0.074; at 256 x 24576 0.428 and 0.314; at 256 x 49152 0.733 and
+#: 0.563 (PERF.md §6)
 _TOPK_ROWS = 16
 #: lanes of one step of a count's walk over the block: (16, 1024) is 16
 #: vregs a compare and 16 accumulators (512 lanes measured slower)
 _TOPK_LANES = 1024
-#: VMEM a step may take: the scores' block and the mask's, double
-#: buffered, and the keys' scratch, 5 x 16 x n x 4 B (7.5 MiB at n =
-#: 24576)
+#: VMEM a step may take: the scores' block and the mask's, each as many
+#: times as it is buffered, and the keys' scratch (5 x 16 x n x 4 B,
+#: 7.5 MiB, at n = 24576)
 _TOPK_VMEM_BYTES = 12 << 20
 
 _SIGN = np.int32(-2 ** 31)
@@ -1722,11 +1723,26 @@ def _topk_kernel(s_ref, o_ref, key_ref, cut_ref, *, k: int, lanes: int):
     jax.lax.fori_loop(0, steps, write, 0)
 
 
+def _topk_vmem(buffers: int, n: int) -> int:
+    """VMEM of a topk_select step over rows of ``n`` scores: the scores'
+    block and the mask's, ``buffers`` deep, and the keys' scratch."""
+    return (2 * buffers + 1) * _TOPK_ROWS * n * 4
+
+
+def _topk_buffers(n: int) -> int:
+    """How deep topk_select buffers its score and mask blocks at rows of
+    ``n``: two where VMEM holds them (rows up to 39 296), else one. The
+    kernel walks a block 34 times or more for each copy of it: on a v5e
+    both forms took the same time at rows of 49152 (PERF.md §6)."""
+    return 2 if _topk_vmem(2, n) <= _TOPK_VMEM_BYTES else 1
+
+
 def can_topk_select(n: int, k: int) -> bool:
     """Shape gate of topk_select: whole 128-lane blocks of the row, more
-    positions than are kept, and a block of rows whole in VMEM."""
+    positions than are kept, and a block of rows whole in VMEM, single-
+    buffered (rows up to 65 536)."""
     return (n % 128 == 0 and 0 < k < n
-            and 5 * _TOPK_ROWS * n * 4 <= _TOPK_VMEM_BYTES)
+            and _topk_vmem(1, n) <= _TOPK_VMEM_BYTES)
 
 
 def topk_select(scores, k: int, *, interpret: Optional[bool] = None):
@@ -1736,7 +1752,8 @@ def topk_select(scores, k: int, *, interpret: Optional[bool] = None):
     by their integer image (-0.0 below +0.0; -inf last, taken only where
     fewer than k others exist).
 
-    A grid step holds _TOPK_ROWS rows whole in VMEM, read from HBM once:
+    A grid step holds _TOPK_ROWS rows whole in VMEM, read from HBM once
+    (_topk_buffers deep):
     their keys (the signed image ``raw ^ ((raw >> 31) & 0x7fffffff)``,
     the order of topk_mask's unsigned one) go to a scratch, the k-th
     largest key is settled bit by bit in 32 counts over it, then one
@@ -1766,28 +1783,33 @@ def topk_select(scores, k: int, *, interpret: Optional[bool] = None):
     lanes = _TOPK_LANES
     while n % lanes:
         lanes //= 2
-    out = _topk_call(x, k=k, block=_TOPK_ROWS, lanes=lanes,
-                     interpret=bool(interpret))
+    out = _topk_call(x, k=k, block=_TOPK_ROWS, buffers=_topk_buffers(n),
+                     lanes=lanes, interpret=bool(interpret))
     return (out[:rows] != 0).reshape(shape)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block", "lanes",
-                                             "interpret"))
-def _topk_call(x, *, k: int, block: int, lanes: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("k", "block", "buffers",
+                                             "lanes", "interpret"))
+def _topk_call(x, *, k: int, block: int, buffers: int, lanes: int,
+               interpret: bool):
     """topk_select's kernel call over (rows, n) scores, rows a multiple
-    of ``block``: a jitted function of its own, so that a program
-    traces and lowers the kernel body once for all the layers that call
-    it, and a process once for all its programs (as _flash_call)."""
+    of ``block``, the scores' and the mask's blocks ``buffers`` deep: a
+    jitted function of its own, so that a program traces and lowers the
+    kernel body once for all the layers that call it, and a process once
+    for all its programs (as _flash_call)."""
     rows, n = x.shape
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel",))
+    # two buffers are the pipeline's default: ask for none there, so
+    # that rows up to 39 296 keep the program they had
+    mode = {} if buffers == 2 else {"pipeline_mode": pl.Buffered(buffers)}
     return pl.pallas_call(
         functools.partial(_topk_kernel, k=k, lanes=lanes),
         grid=(rows // block,),
-        in_specs=[pl.BlockSpec((block, n), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block, n), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((block, n), lambda i: (i, 0), **mode)],
+        out_specs=pl.BlockSpec((block, n), lambda i: (i, 0), **mode),
         out_shape=jax.ShapeDtypeStruct((rows, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block, n), jnp.int32),
                         pltpu.VMEM((block, 1), jnp.int32)],

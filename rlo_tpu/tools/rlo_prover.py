@@ -1770,6 +1770,8 @@ def _p3_probes() -> List[Probe]:
             # the cell's decode step and extend chunk
             dict(scores=A((32, 1, 24576)), k=2048, interpret=True),
             dict(scores=A((1, 256, 24576)), k=2048, interpret=True),
+            # hy4's decode step: rows of 49152, blocks single-buffered
+            dict(scores=A((16, 1, 49152)), k=2048, interpret=True),
             # rows no multiple of the block: the filler rows pad it
             dict(scores=A((3, 5, 384)), k=17, interpret=True),
         ], sites=1),

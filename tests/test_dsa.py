@@ -22,6 +22,7 @@ from rlo_tpu.models.generate import (block_decode, decode_step,
 from rlo_tpu.models.serve import DecodeServer, extend_widths
 from rlo_tpu.models.transformer import (TransformerConfig, forward,
                                         init_params)
+from rlo_tpu.pallas import decode
 from rlo_tpu.pallas.decode import (can_topk_select, decode_work_list,
                                    flash_decode, index_score,
                                    index_score_tile, topk_select)
@@ -196,14 +197,39 @@ def _topk_case(case):
         return jax.random.normal(key, (13, 384)), 30
     if case == "b_T_n":             # an extend chunk's (b, T, n), ties
         return jnp.round(jax.random.normal(key, (2, 3, 1024)) * 2) / 2, 300
+    if case == "random_1buf":       # single-buffered blocks, ragged
+        return jax.random.normal(key, (21, 512)), 64
+    if case == "ties_1buf":
+        return jnp.round(jax.random.normal(key, (16, 512)) * 4) / 4, 100
+    if case == "neg_inf_1buf":
+        s, k = _topk_case("neg_inf_tails")
+        return jnp.concatenate([s, s[::-1]], axis=1), k
+    if case == "hy4_decode":
+        # a decode step of hy4-preview-ep32 x longctx32k-decode: 16 rows
+        # of 49152, contexts 20k-37k, quarter steps (the position cut)
+        s = jnp.round(jnp.abs(jax.random.normal(key, (16, 1, 49152))) * 4)
+        ctx = jnp.linspace(20000, 37000, 16).astype(jnp.int32)
+        return jnp.where(jnp.arange(49152) <= ctx[:, None, None],
+                         s / 4, -jnp.inf), 2048
     raise KeyError(case)
+
+
+#: cases whose score and mask blocks are single-buffered: rows of 512
+#: under a VMEM budget lowered to hold them once, so that interpret mode
+#: stays cheap, and the real rows of 49152 under the real budget
+_ONE_BUFFER = ("random_1buf", "ties_1buf", "neg_inf_1buf", "hy4_decode")
 
 
 @pytest.mark.parametrize("case", ["random", "ties_overflow",
                                   "neg_inf_tails", "signed_zeros",
-                                  "ragged_rows", "b_T_n"])
-def test_topk_select_kernel_is_topk_mask_to_the_bit(case):
+                                  "ragged_rows", "b_T_n", *_ONE_BUFFER])
+def test_topk_select_kernel_is_topk_mask_to_the_bit(case, monkeypatch):
     s, k = _topk_case(case)
+    n = s.shape[-1]
+    if case in _ONE_BUFFER and case != "hy4_decode":
+        monkeypatch.setattr(decode, "_TOPK_VMEM_BYTES",
+                            decode._topk_vmem(1, n))
+    assert decode._topk_buffers(n) == (1 if case in _ONE_BUFFER else 2)
     got = np.asarray(topk_select(s, k, interpret=True))
     want = np.asarray(jax.jit(lambda s: kvcache.topk_mask(s, k))(s))
     assert got.shape == s.shape and got.dtype == bool
@@ -216,6 +242,11 @@ def test_topk_select_kernel_is_topk_mask_to_the_bit(case):
 
 def test_topk_select_gate_refuses_what_it_cannot_hold():
     assert can_topk_select(24576, 2048)
+    assert decode._topk_buffers(24576) == 2            # dsv32: as before
+    assert can_topk_select(49152, 2048)                # hy4: 16 rows,
+    assert decode._topk_buffers(49152) == 1            # single-buffered
+    assert can_topk_select(65536, 2048)
+    assert not can_topk_select(65536 + 128, 2048)      # no block in VMEM
     assert not can_topk_select(24576 + 64, 2048)       # no 128-lane rows
     assert not can_topk_select(2048, 2048)             # keeps everything
     assert not can_topk_select(256, 0)

@@ -15,7 +15,8 @@ layer's window attend over its ring of 4352 positions at 48 rows x 48 / 8
 heads x 128, with and without the round's tail, and the ring's writes;
 hy4-preview-ep32: the hyper-connection boundary (XLA's ops) at 16, 256
 and 1024 rows of 4 x 6144, the selected latent attend of 64 heads with a sink
-a head at 16 x 49152, and the clamped expert FFN at 6144 x 2048),
+a head at 16 x 49152, the exact top-2048 of a decode step's 16 rows and an
+extend chunk's 256 of 49152, and the clamped expert FFN at 6144 x 2048),
 without a chip: Mosaic's refusals (block shapes, scoped VMEM, scalar-prefetch index
 maps) show up here, numerics and times do not. The topology is described
 inside a fixture, never at import (only one process may load libtpu, and
@@ -539,6 +540,25 @@ def test_selected_latent_attend_with_sinks_compiles_for_v5e(one_chip):
         shape((HY4_HEADS,), jnp.float32))
     assert hlo.mosaic_kernels(lowered.as_text()) == {"flash_decode": 1}
     assert lowered.compile().as_text().count("tpu_custom_call") >= 1
+
+
+@pytest.mark.parametrize("shape", [(HY4_SLOTS, 1, HY4_LEN),
+                                   (1, 256, HY4_LEN)])
+def test_topk_select_compiles_at_rows_of_49152_for_v5e(one_chip, shape):
+    """The exact top-2048 of the cell's decode step, (16, 1, 49152)
+    scores, and of an admission's 256-token extend chunk: 16 rows a grid
+    step, their blocks single-buffered, 9 MiB of VMEM (double-buffered
+    they would take 15)."""
+    from rlo_tpu.pallas import decode
+    from rlo_tpu.utils import hlo
+    assert decode._TOPK_ROWS == 16 and decode._topk_buffers(HY4_LEN) == 1
+    assert decode._topk_vmem(1, HY4_LEN) <= decode._TOPK_VMEM_BYTES \
+        < decode._topk_vmem(2, HY4_LEN)
+    lowered = jax.jit(lambda s: decode.topk_select(
+        s, INDEX_TOPK, interpret=False)).lower(
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip))
+    assert hlo.mosaic_kernels(lowered.as_text()) == {"topk_select": 1}
+    assert lowered.compile().as_text().count("tpu_custom_call") == 1
 
 
 def test_clamped_expert_ffn_compiles_for_v5e(one_chip):
