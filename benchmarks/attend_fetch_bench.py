@@ -15,13 +15,14 @@ one work list (the way a decode step runs its layers). Per shape:
   stub@N         a fetch (N = 0: the pipeline's) with the body's compute
                  replaced by one small read of the tile: what the stream
                  alone costs
-  @W             the same at another tile width
+  @W             the same at another tile width (pipe@W: the pipeline's
+                 fetch at W lanes, whatever width the rule would take)
 
 Each variant forces its form whatever the shape's own rule says; the
 rule's choice is printed beside the shape.
 
 Usage: python benchmarks/attend_fetch_bench.py [--tiny] [shape ...]
-(shapes: gpt2 dsv3 dsv32 sdar). --tiny runs toy sizes in interpret mode
+(shapes: gpt2 dsv3 dsv32 sdar hy4). --tiny runs toy sizes in interpret mode
 on the CPU: a rehearsal, not a measurement.
 """
 
@@ -45,19 +46,28 @@ from rlo_tpu.utils.device import bench_device  # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # name: (rows, heads, kv heads, head_dim, max_len, v_dim, T, block_len,
-#        tail rows, selection, the cell's traffic file)
+#        tail rows, selection, the cell's traffic file, a sink a head)
 SHAPES = {
-    "gpt2": (96, 16, 16, 64, 1024, 0, 1, 0, 32, False, "decode-sat"),
-    "dsv3": (128, 128, 1, 576, 4096, 512, 1, 0, 32, False, "reason-sat"),
+    "gpt2": (96, 16, 16, 64, 1024, 0, 1, 0, 32, False, "decode-sat",
+             False),
+    "dsv3": (128, 128, 1, 576, 4096, 512, 1, 0, 32, False, "reason-sat",
+             False),
     "dsv32": (32, 128, 1, 576, 24576, 512, 1, 0, 0, True,
-              "longctx-decode"),
-    "sdar": (192, 32, 4, 128, 1536, 0, 4, 4, 0, False, "blockgen-sat"),
+              "longctx-decode", False),
+    "sdar": (192, 32, 4, 128, 1536, 0, 4, 4, 0, False, "blockgen-sat",
+             False),
+    "hy4": (16, 64, 1, 576, 49152, 512, 1, 0, 0, True,
+            "longctx32k-decode", True),
 }
 TINY = {
-    "gpt2": (4, 4, 4, 64, 1024, 0, 1, 0, 8, False, "decode-sat"),
-    "dsv3": (3, 16, 1, 576, 2048, 512, 1, 0, 8, False, "reason-sat"),
-    "dsv32": (2, 16, 1, 576, 4096, 512, 1, 0, 0, True, "reason-sat"),
-    "sdar": (4, 8, 2, 128, 1536, 0, 4, 4, 0, False, "blockgen-sat"),
+    "gpt2": (4, 4, 4, 64, 1024, 0, 1, 0, 8, False, "decode-sat", False),
+    "dsv3": (3, 16, 1, 576, 2048, 512, 1, 0, 8, False, "reason-sat",
+             False),
+    "dsv32": (2, 16, 1, 576, 4096, 512, 1, 0, 0, True, "reason-sat",
+              False),
+    "sdar": (4, 8, 2, 128, 1536, 0, 4, 4, 0, False, "blockgen-sat",
+             False),
+    "hy4": (2, 8, 1, 576, 4096, 512, 1, 0, 0, True, "reason-sat", True),
 }
 # (label, ring slots, stub, tile width or None for the rule's)
 PLANS = {
@@ -68,11 +78,17 @@ PLANS = {
     "dsv3": [("pipe", 0, False, None), ("stub@0", 0, True, None),
              ("N2", 2, False, None), ("N3", 3, False, None),
              ("N4", 4, False, None), ("stub@3", 3, True, None),
-             ("N3@512", 3, False, 512), ("N3@2048", 3, False, 2048)],
+             ("N3@512", 3, False, 512), ("N3@2048", 3, False, 2048),
+             ("pipe@1536", 0, False, 1536), ("pipe@2048", 0, False, 2048)],
     "dsv32": [("pipe", 0, False, None), ("stub@0", 0, True, None),
               ("N2", 2, False, None), ("N3", 3, False, None),
               ("N4", 4, False, None), ("stub@3", 3, True, None),
-              ("N3@2048", 3, False, 2048)],
+              ("N3@2048", 3, False, 2048), ("pipe@1024", 0, False, 1024),
+              ("pipe@1536", 0, False, 1536), ("pipe@2048", 0, False, 2048)],
+    "hy4": [("pipe", 0, False, None), ("stub@0", 0, True, None),
+            ("N3", 3, False, None), ("N3@2048", 3, False, 2048),
+            ("pipe@1024", 0, False, 1024), ("pipe@1536", 0, False, 1536),
+            ("pipe@2048", 0, False, 2048)],
     "sdar": [("pipe", 0, False, None), ("stub@0", 0, True, None),
              ("N2", 2, False, None), ("N3", 3, False, None),
              ("N4", 4, False, None), ("stub@3", 3, True, None),
@@ -81,13 +97,16 @@ PLANS = {
 
 
 def stub_kernel(row_ref, tile_ref, pos_ref, *refs, bk, max_len, T, r,
-                v_dim=0, n_tail=0, selected=False, n_slots=0, **_):
+                v_dim=0, n_tail=0, selected=False, n_slots=0, sinks=False,
+                **_):
     """_decode_kernel's fetch with no compute behind it."""
     refs = refs[bool(n_tail):]              # the tail's prefetched scalar
     streams = 1 if v_dim else 2
     caches = refs[1:1 + streams]
-    # past q, the caches, the tail's rows (one a tensor) and a selection
-    skip = 1 + streams + (streams if n_tail else 0) + bool(selected)
+    # past q, the caches, the tail's rows (one a tensor), a selection
+    # and the sinks
+    skip = (1 + streams + (streams if n_tail else 0) + bool(selected)
+            + bool(sinks))
     o_ref, m_s, _l, _o, *rings = refs[skip:]
     i = pl.program_id(0)
     if n_slots:
@@ -127,7 +146,8 @@ def traffic_mix(rng, n, traffic, max_len):
 
 
 def run_shape(name, shape, tiny):
-    b, nh, nkv, d, L, v_dim, T, block_len, n_tail, select, traffic = shape
+    (b, nh, nkv, d, L, v_dim, T, block_len, n_tail, select, traffic,
+     sinks) = shape
     dt = jnp.bfloat16
     keys = jax.random.split(jax.random.key(1), 5)
     kc = jax.random.normal(keys[0], (b, nkv, d, L), dt)
@@ -140,13 +160,16 @@ def run_shape(name, shape, tiny):
             keys[4], (n_tail, b, nkv, d), dt)
         tail = (tk, tv, jnp.int32(n_tail // 2))
     sel = jnp.ones((b, L), bool) if select else None
+    sink = jnp.full((nh,), 2.0, jnp.float32) if sinks else None
     rng = np.random.default_rng(37)
     ctxs = {"mix": traffic_mix(rng, b, traffic, L - T),
             "full": np.full(b, L - T, np.int32)}
     calls = 4 if tiny else 40 if L < 8192 else 16
     real = decode._decode_kernel
-    saved = (decode._TILE_BYTES, decode._LATENT_TILE_BYTES,
-             decode._LATENT_BLOCK_K, decode._BLOCK_K, decode._RING_SLOTS,
+    widths = ("_TILE_BYTES", "_LATENT_TILE_BYTES",
+              "_LATENT_LONG_TILE_BYTES", "_BLOCK_K", "_LATENT_BLOCK_K",
+              "_LATENT_LONG_BLOCK_K")
+    saved = ([getattr(decode, w) for w in widths], decode._RING_SLOTS,
              decode._COMPUTE_BOUND)
     rule = decode.flash_decode_slots(kc, nh, v_dim, T)
     out = {}
@@ -155,7 +178,8 @@ def run_shape(name, shape, tiny):
         posj = jnp.asarray(pos)
         live = int(np.minimum(pos + T, L).sum())
         print(f"## {name} {cname}: {b} rows x {nkv} x {d} x {L}, T={T}, "
-              f"tail {n_tail}, mean context {pos.mean():.0f}; the rule "
+              f"tail {n_tail}, sinks {sinks}, mean context "
+              f"{pos.mean():.0f}; the rule "
               f"takes {'a ring of %d' % rule if rule else 'the pipeline'}",
               file=sys.stderr, flush=True)
         for label, slots, stub, width in PLANS[name]:
@@ -163,10 +187,10 @@ def run_shape(name, shape, tiny):
                 continue
             try:
                 if width is not None:
-                    decode._TILE_BYTES = decode._LATENT_TILE_BYTES = \
-                        nkv * d * width * 2
-                    decode._LATENT_BLOCK_K = decode._BLOCK_K = width
-                decode._RING_SLOTS = slots or saved[4]
+                    for w in widths:
+                        setattr(decode, w, width if w.endswith("_K")
+                                else nkv * d * width * 2)
+                decode._RING_SLOTS = slots or saved[1]
                 decode._COMPUTE_BOUND = float("inf") if slots else 0
                 decode._decode_kernel = stub_kernel if stub else real
                 # the kernel body is no static argument of the jitted
@@ -177,18 +201,18 @@ def run_shape(name, shape, tiny):
 
                 # the caches are arguments of the jitted chain: closed
                 # over, they would be baked into it as constants
-                def chain(q, kc, vc, tail, sel, work):
+                def chain(q, kc, vc, tail, sel, sink, work):
                     def call(_, q):
                         o = decode.flash_block_decode(
                             q, kc, vc, posj, 1.0 / np.sqrt(d),
                             v_dim=v_dim, tail=tail, work=work,
-                            select=sel, block_len=block_len,
+                            select=sel, block_len=block_len, sinks=sink,
                             interpret=tiny or None)
                         return q + (o[..., :1] * 1e-30).astype(q.dtype)
                     return jax.lax.fori_loop(0, calls, call, q)
 
                 f = jax.jit(chain)
-                ops = (q0, kc, vc, tail, sel, work)
+                ops = (q0, kc, vc, tail, sel, sink, work)
                 jax.block_until_ready(f(*ops))
                 ts = []
                 for _ in range(1 if tiny else 7):
@@ -196,9 +220,9 @@ def run_shape(name, shape, tiny):
                     jax.block_until_ready(f(*ops))
                     ts.append((time.perf_counter() - t) / calls)
             finally:
-                (decode._TILE_BYTES, decode._LATENT_TILE_BYTES,
-                 decode._LATENT_BLOCK_K, decode._BLOCK_K,
-                 decode._RING_SLOTS, decode._COMPUTE_BOUND) = saved
+                for w, v in zip(widths, saved[0]):
+                    setattr(decode, w, v)
+                decode._RING_SLOTS, decode._COMPUTE_BOUND = saved[1:]
                 decode._decode_kernel = real
                 decode._flash_call.clear_cache()
             steps = int(work[2])

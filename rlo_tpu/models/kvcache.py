@@ -971,7 +971,7 @@ def _attend_cache_block(q, k_cache, v_cache, pos_q, scale,
             f"v_dim={v_dim}; XLA form: {path})")
         fits = gate and _block_fits_vmem(
             max_len, hd, nkv, nh // nkv, T, itemsize,
-            *_tile_rule(bool(v_dim)), streams=1 if v_dim else 2)
+            *_tile_rule(bool(v_dim), max_len), streams=1 if v_dim else 2)
         if gate and not fits:
             # T=1 would flash but this block cannot share its tiling:
             # the XLA fallback DIVERGES numerically from the flash

@@ -22,8 +22,9 @@ kernel at T=gamma (pallas.decode.flash_block_decode), with identical
 tile shapes, accumulation order, and dot dtypes per query row — and on
 CPU both take the einsum path, so the parity holds by shared numerics
 on both backends. One carve-out: a gamma-wide block too large for
-VMEM at the T=1 tiling (pallas.decode._block_fits_vmem; needs extreme
-nkv*gamma*head_dim, far beyond any shipped config at gamma <= 8)
+VMEM at the T=1 tiling (pallas.decode._block_fits_vmem; extreme
+nkv*gamma*head_dim, or a latent cache of 16384 positions or more,
+whose tiles are 2048 lanes wide: from gamma 4 at 128 heads, 7 at 64)
 falls back to einsum with a RuntimeWarning and the parity degrades to
 near-tie class there. On the chip, chip_smoke.py pins the T=1 and the
 T=128 kernel's LOGITS against the reference forward; speculative

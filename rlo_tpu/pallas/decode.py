@@ -103,7 +103,8 @@ serves scores and values, for all heads (r = n_heads query rows):
   k        (b, 1, d, max_len)       v = k[:, :, :v_dim]  (512)
   out      (b, 1, T*n_heads, v_dim) f32
 Tile picking and the work list are the same code (_pick_bk with its
-own bytes-a-step constant, decode_work_list).
+own bytes-a-step constants, wider from a cache length on: _tile_rule;
+decode_work_list).
 
 A WRITE-BEHIND TAIL (T = 1; DecodeServer's round, docs/DESIGN.md §4):
 the rows a round has produced so far wait token-major beside the cache
@@ -1007,11 +1008,32 @@ _TILE_BYTES = 512 << 10
 #: 0.614 / 0.482 / 1.422. What PR 30 called "~0.9 us a step before its
 #: bytes" was this compute.) At 32 rows x 576 x 24576 under a
 #: selection (contexts 8k-20k / at max_len): 512 1.525 / 2.465; 1024
-#: 1.276 / 2.023; 2048 1.179 / 1.792 — long contexts would take 2048,
-#: a reasoning server's would not; one rule serves both until a cell
-#: says otherwise (PERF.md §7).
+#: 1.276 / 2.023; 2048 1.179 / 1.792 — long contexts take 2048, a
+#: reasoning server's do not: the rule goes by the cache's length
+#: (_tile_rule, below).
 _LATENT_TILE_BYTES = 1152 << 10
 _LATENT_BLOCK_K = 1024
+
+#: from this cache length on a latent cache takes tiles of up to
+#: _LATENT_LONG_BLOCK_K lanes (one step's K: 2048 x 576 x 2 B). A
+#: deployment that holds 16k positions a row serves contexts of
+#: several thousand, where a step's fixed work (the pipeline's step, q,
+#: the rescale of the (heads x v_dim) accumulator) outweighs the compute
+#: on a row's dead lanes. The length, not the contexts: every block size
+#: must tile the cache alike (_pick_bk). The BlockSpec pipeline the
+#: rule keeps at 128 and 64 heads, ms a call, the cell's contexts /
+#: every row at max_len (PERF.md §6): 32 rows x 128 heads x 576 x
+#: 24576 under a selection (mean context 14.8k), BK 1024 1.206 /
+#: 1.900; 1536 1.132 / 1.754; 2048 1.111 / 1.694. 16 rows x 64 heads x
+#: 576 x 49152, a selection and a sink a head (mean 31.3k): 1024
+#: 0.962 / 1.459; 1536 0.868 / 1.294; 2048 0.849 / 1.262 (the same
+#: copies with the compute stubbed out 0.852 / 1.255: at 64 heads a
+#: step of 2048 is its bytes). 128 rows x 4096 with a 32-row tail
+#: (mean 940): 1024 0.618 / 1.421; 2048 0.749 / 1.253 — on short
+#: contexts the dead lanes cost more than the steps save.
+_LATENT_LONG_LEN = 16384
+_LATENT_LONG_TILE_BYTES = 2304 << 10
+_LATENT_LONG_BLOCK_K = 2048
 
 
 #: slots of the kernel's K/V ring (_ring_fetch): one being computed
@@ -1040,11 +1062,14 @@ def _ring_slots(R: int, d: int, dv: int, streams: int,
     return 0 if flops_a_byte >= _COMPUTE_BOUND else _RING_SLOTS
 
 
-def _tile_rule(latent: bool):
+def _tile_rule(latent: bool, L: int):
     """(widest tile, K bytes a grid step) of _pick_bk for a per-head
-    K/V cache or a latent one."""
-    return ((_LATENT_BLOCK_K, _LATENT_TILE_BYTES) if latent
-            else (_BLOCK_K, _TILE_BYTES))
+    K/V cache or a latent one of ``L`` positions a row."""
+    if not latent:
+        return _BLOCK_K, _TILE_BYTES
+    if L >= _LATENT_LONG_LEN:
+        return _LATENT_LONG_BLOCK_K, _LATENT_LONG_TILE_BYTES
+    return _LATENT_BLOCK_K, _LATENT_TILE_BYTES
 
 
 def _ring_bytes(nkv: int, d: int, bk: int, itemsize: int,
@@ -1093,7 +1118,7 @@ def flash_decode_tile(k_cache, n_heads: int, latent: bool = False) -> int:
     _, nkv, d, L = k_cache.shape
     itemsize = 4 if k_cache.dtype == jnp.float32 else 2
     return _pick_bk(L, d, nkv, n_heads // nkv, itemsize,
-                    *_tile_rule(latent), streams=1 if latent else 2)
+                    *_tile_rule(latent, L), streams=1 if latent else 2)
 
 
 def flash_decode_slots(k_cache, n_heads: int, v_dim: int = 0,
@@ -1328,7 +1353,7 @@ def flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
             f"row, an unquantized cache and no tail, as (b, max_len); "
             f"got T={T}, int8={quant}, tail {tail is not None}, "
             f"select {select.shape}")
-    widest, tile_bytes = _tile_rule(latent)
+    widest, tile_bytes = _tile_rule(latent, L)
     if block_k is None:
         block_k = widest
     if latent != bool(v_dim) or (latent and (

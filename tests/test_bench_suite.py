@@ -97,3 +97,22 @@ def test_spec_bench_emits_json_line():
     assert len(lines) == 1, proc.stdout
     rec = json.loads(lines[0])
     assert rec["unit"] == "x" and rec["value"] > 0
+
+
+def test_attend_fetch_bench_rehearses_the_long_latent_shape():
+    """The decode-attention bench at --tiny sizes (interpret mode): the
+    Hy4 shape, a selection and a sink a head, runs every variant and
+    the pipe@W ones at the widths they name."""
+    bench = Path(__file__).resolve().parent.parent / "benchmarks" / \
+        "attend_fetch_bench.py"
+    proc = subprocess.run(
+        [sys.executable, str(bench), "--tiny", "hy4"],
+        capture_output=True, text=True, timeout=600, env=CPU_ENV)
+    assert proc.returncode == 0, proc.stderr
+    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
+    assert len(lines) == 1, proc.stdout
+    rows = json.loads(lines[0])["attend_fetch"]["hy4"]
+    for ctx in ("mix", "full"):
+        assert rows[f"{ctx}/pipe@1024"]["bk"] == 1024
+        assert rows[f"{ctx}/pipe@2048"]["bk"] == 2048
+        assert all(r["ms"] > 0 for r in rows.values())

@@ -643,7 +643,8 @@ def test_tail_fold_drops_what_row_writes_drop(kind, path, monkeypatch):
 def _pr36_decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
                    bk: int, max_len: int, quant: bool,
                    r: int, T: int, v_dim: int = 0, n_tail: int = 0,
-                   selected: bool = False, block_len: int = 0):
+                   selected: bool = False, block_len: int = 0,
+                   sinks: bool = False):
     if n_tail:          # a fourth prefetched scalar: the tail's newest row
         newest_ref, refs = refs[0], refs[1:]
     q_ref, k_ref, *rest = refs
@@ -655,6 +656,8 @@ def _pr36_decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
             tv_ref, rest = rest[0], rest[1:]
     if selected:        # the positions this row may attend, 1.0 or 0.0
         sel_ref, rest = rest[0], rest[1:]
+    if sinks:           # a logit a query row, joined at the flush
+        sink_ref, rest = rest[0], rest[1:]
     if quant:
         ks_ref, vs_ref, o_ref, m_s, l_s, o_s = rest
     else:
@@ -728,17 +731,22 @@ def _pr36_decode_kernel(row_ref, tile_ref, pos_ref, *refs, scale: float,
 
     @pl.when(row_ref[i + 1] != ib)
     def _flush():
+        if sinks:
+            den = l_s[...] + jnp.exp(sink_ref[...] - m_s[...])
+            o_ref[0] = o_s[...] / den[..., None]
+            return
         o_ref[0] = o_s[...] / l_s[...][..., None]
 
 
 def _pr36_flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
                              v_scale=None, *, block_k=None, v_dim=0,
-                             tail=None, select=None, block_len=0):
+                             tail=None, select=None, block_len=0,
+                             sinks=None):
     b, T, nh, d = q.shape
     nkv, L_ = k_cache.shape[1], k_cache.shape[3]
     r, R, dv = nh // nkv, T * (nh // nkv), v_dim or d
     quant, latent = k_scale is not None, v_cache is None
-    widest, tile_bytes = decode_mod._tile_rule(latent)
+    widest, tile_bytes = decode_mod._tile_rule(latent, L_)
     itemsize = 4 if k_cache.dtype == jnp.float32 else 2
     bk = decode_mod._pick_bk(L_, d, nkv, r, itemsize, block_k or widest,
                              tile_bytes, streams=1 if latent else 2)
@@ -775,11 +783,15 @@ def _pr36_flash_block_decode(q, k_cache, v_cache, pos0, scale, k_scale=None,
     if select is not None:
         in_specs += [pl.BlockSpec((1, 1, 1, bk), cache_map)]
         args += [select.astype(jnp.float32)[:, None, None, :]]
+    if sinks is not None:   # head g * r + rr at query row t * r + rr
+        in_specs += [pl.BlockSpec((nkv, R), lambda *_: (0, 0))]
+        args += [jnp.tile(sinks.astype(jnp.float32).reshape(nkv, 1, r),
+                          (1, T, 1)).reshape(nkv, R)]
     out = pl.pallas_call(
         functools.partial(_pr36_decode_kernel, scale=float(scale), bk=bk,
                           max_len=L_, quant=quant, r=r, T=T, v_dim=v_dim,
                           n_tail=n_tail, selected=select is not None,
-                          block_len=block_len),
+                          block_len=block_len, sinks=sinks is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(n_work,),
             in_specs=in_specs,
@@ -845,6 +857,61 @@ def test_is_bitwise_the_parent_call(data, name):
                                         block_k=block_k, **kw))
     want = np.asarray(_pr36_flash_block_decode(*args, block_k=block_k,
                                                **kw))
+    np.testing.assert_array_equal(got, want)
+
+
+# -- a long latent cache's tile: from _LATENT_LONG_LEN positions a row
+# the rule takes tiles of 2048 lanes, below it 1024; a per-head cache
+# keeps its own rule at any length.
+@pytest.mark.parametrize("shape,heads,latent,want", [
+    ((32, 1, 576, 24576), 128, True, 2048),   # deepseek-v3.2-ep32's
+    ((16, 1, 576, 49152), 64, True, 2048),    # hy4-preview-ep32's
+    ((128, 1, 576, 4096), 128, True, 1024),   # deepseek-v3-ep16's
+    ((2, 1, 576, 16384), 128, True, 2048),    # the threshold
+    ((2, 1, 576, 12288), 128, True, 1024),    # under it
+    ((48, 8, 128, 16384), 48, False, 256),    # trinity's full layer
+    ((2, 8, 128, 32768), 32, False, 256)])
+def test_tile_follows_the_cache_length(shape, heads, latent, want):
+    cache = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    assert flash_decode_tile(cache, heads, latent=latent) == want
+    assert shape[3] % want == 0
+    assert can_flash_decode(shape[3], shape[2], v_dim=512 if latent else 0)
+
+
+LONG_L, LONG_BK = decode_mod._LATENT_LONG_LEN, 2048
+LONG_CASES = {
+    # name: pos by row (the last position a row's query attends)
+    "last-tile-partial": [LONG_BK, 5000, LONG_L - 700],
+    "ends-on-a-tile-boundary": [LONG_BK - 1, 2 * LONG_BK - 1, LONG_L - 1],
+    "shorter-than-a-tile": [100, 0, LONG_BK - 2],
+}
+
+
+@pytest.mark.parametrize("heads", [16, 128])
+@pytest.mark.parametrize("name", sorted(LONG_CASES))
+def test_long_latent_tile_is_bitwise_the_parent_call(name, heads):
+    """A latent cache of _LATENT_LONG_LEN positions at the rule's 2048
+    lanes, under a selection and with a sink a head: the tiles reach
+    the same accumulators in the same order as the BlockSpec call's
+    at that width, through the kernel's own ring (16 heads) and the
+    pipeline (128: a step that computes longer than it streams)."""
+    rng = np.random.default_rng(sorted(LONG_CASES).index(name))
+    pos = jnp.asarray(LONG_CASES[name], jnp.int32)
+    nb = pos.shape[0]
+    q = jnp.asarray(rng.standard_normal((nb, 1, heads, 144), np.float32))
+    k = jnp.asarray(rng.standard_normal((nb, 1, 144, LONG_L), np.float32))
+    assert flash_decode_tile(k, heads, latent=True) == LONG_BK
+    assert bool(decode_mod.flash_decode_slots(k, heads, 128)) == (
+        heads == 16)
+    kw = {"v_dim": 128, "select": jnp.asarray(rng.random((nb, LONG_L))
+                                              < 0.5),
+          "sinks": jnp.asarray(2.0 + rng.standard_normal(heads),
+                               jnp.float32)}
+    got = np.asarray(flash_block_decode(q, k, None, pos, 144 ** -0.5,
+                                        interpret=True, **kw))
+    want = np.asarray(_pr36_flash_block_decode(q, k, None, pos, 144 ** -0.5,
+                                               block_k=LONG_BK, **kw))
+    assert np.isfinite(got).all()
     np.testing.assert_array_equal(got, want)
 
 

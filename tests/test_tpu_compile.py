@@ -70,12 +70,13 @@ def test_flash_decode_compiles_for_v5e(one_chip, T, cache_dtype):
     assert ("flash_decode" if T == 1 else "flash_block_decode") in text
 
 
-# the four cells' caches as (kv heads, head_dim, max_len, query heads,
-# queries a row, latent, the tile the rule picks)
+# four cells' caches as (kv heads, head_dim, max_len, query heads,
+# queries a row, latent, the tile the rule picks): a latent cache of
+# 16384 positions or more takes 2048 lanes, a shorter one 1024
 CELL_CACHES = {
     "gpt2m-decode-sat": (16, 64, 1024, 16, 1, False, 256),
     "dsv3-ep16-reason-sat": (1, 576, 4096, 128, 1, True, 1024),
-    "dsv32-ep32-longctx-decode": (1, 576, 24576, 128, 1, True, 1024),
+    "dsv32-ep32-longctx-decode": (1, 576, 24576, 128, 1, True, 2048),
     "sdar-6l-blockgen-sat": (4, 128, 1536, 32, 4, False, 512),
 }
 
@@ -87,20 +88,25 @@ def test_the_ring_is_in_the_vmem_budget_and_fits_v5e(one_chip, cell,
     a tensor: the tile rule and the block gate count every slot (not
     the pipeline's two), the cells keep their tiles, and the deepest
     ring the rule was measured at (4) still compiles inside v5e's
-    scoped VMEM at each cell's shape."""
+    scoped VMEM at each cell's shape — at the tile the rule takes for
+    it, which for a long latent cache is 1024, not 2048 (four slots of
+    2048 x 576 and the scores pass _pick_bk's 10 MB)."""
     from rlo_tpu.pallas import decode
     nkv, d, max_len, nh, T, latent, tile = CELL_CACHES[cell]
     streams = 1 if latent else 2
     cache = jax.ShapeDtypeStruct((2, nkv, d, max_len), jnp.bfloat16,
                                  sharding=one_chip)
+    assert flash_decode_tile(cache, nh, latent=latent) == tile
     monkeypatch.setattr(decode, "_RING_SLOTS", 4)
-    # the ring at every cell, the two whose step computes longer than
-    # it streams and keeps the pipeline (_ring_slots) too
+    # the ring at every cell, those whose step computes longer than
+    # it streams and keep the pipeline (_ring_slots) too
     monkeypatch.setattr(decode, "_COMPUTE_BOUND", float("inf"))
+    if 4 * streams * nkv * d * tile * 2 + 2 * nh * tile * 4 > 10 << 20:
+        tile //= 2
     assert flash_decode_tile(cache, nh, latent=latent) == tile
     ring = decode._ring_bytes(nkv, d, tile, 2, streams)
     assert ring == 4 * streams * nkv * d * tile * 2 <= 5 << 20
-    rule = decode._tile_rule(latent)
+    rule = decode._tile_rule(latent, max_len)
     assert decode._block_fits_vmem(max_len, d, nkv, nh // nkv, T, 2,
                                    *rule, streams)
     # the gate counts the ring: the largest block that fits beside two
@@ -314,6 +320,7 @@ def test_small_expert_ffn_compiles_for_v5e(one_chip, tokens):
 # token selector's 64 heads of 128 over one 128-wide key a token, the
 # attend over the 2048 positions it keeps
 DSA_SLOTS, DSA_LEN, INDEX_HEADS, INDEX_DIM, INDEX_TOPK = 32, 24576, 64, 128, 2048
+DSA_TILE = 2048     # _LATENT_LONG_BLOCK_K: a latent cache of >= 16384
 
 
 def test_index_score_and_selected_attend_compile_for_v5e(one_chip):
@@ -332,7 +339,7 @@ def test_index_score_and_selected_attend_compile_for_v5e(one_chip):
     keys = shape((DSA_SLOTS, 1, INDEX_DIM, DSA_LEN), bf16)
     bk = flash_decode_tile(latent, HEADS, latent=True)
     ibk = index_score_tile(DSA_LEN)
-    assert (bk, ibk) == (LATENT_TILE, 2048)
+    assert (bk, ibk) == (DSA_TILE, 2048)
 
     def step(q, qi, w, row, irow, cache, ik, pos):
         cache = write_kv_row(cache, row, pos, interpret=False)
@@ -527,6 +534,7 @@ def test_selected_latent_attend_with_sinks_compiles_for_v5e(one_chip):
 
     cache = shape((HY4_SLOTS, 1, LATENT, HY4_LEN), jnp.bfloat16)
     bk = flash_decode_tile(cache, HY4_HEADS, latent=True)
+    assert bk == DSA_TILE
 
     def step(q, c, pos, select, sinks):
         return flash_block_decode(
